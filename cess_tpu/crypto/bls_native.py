@@ -13,25 +13,15 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SO = os.path.join(_NATIVE_DIR, "libcessbls.so")
+from .. import native
 
 
 def _load() -> ctypes.CDLL:
     if os.environ.get("CESS_TPU_NO_NATIVE_BLS"):
         raise ImportError("native BLS disabled by CESS_TPU_NO_NATIVE_BLS")
-    if not os.path.exists(_SO):
-        try:
-            subprocess.run(["make", "-C", _NATIVE_DIR, "-s",
-                            "libcessbls.so"], check=True,
-                           capture_output=True)
-        except (OSError, subprocess.CalledProcessError) as e:
-            raise ImportError(f"cannot build native BLS: {e}") from e
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(native.ensure_built("libcessbls.so"))
     except OSError as e:
         raise ImportError(f"cannot load native BLS: {e}") from e
     u8p, szp = ctypes.c_char_p, ctypes.POINTER(ctypes.c_size_t)

@@ -11,20 +11,15 @@ uint8 -> fragments [B, k+m, fragment_size] uint8 (+ per-fragment tags
 once the audit backend is wired in).
 
 The direct (engine-less) ``forward`` is ONE jitted device program —
-encode and tag fused, with the segment buffer DONATED on accelerator
-backends so XLA can reclaim it for the program's intermediates
-instead of holding staged input alongside the packed-element temps
-(the CPU backend skips donation — it cannot use an unaliased donated
-buffer and would warn per dispatch). Donation contract: on
-accelerators, callers must not reuse a device-resident ``segments``
-array after ``forward`` (host numpy inputs are unaffected — jit
-stages a fresh device copy and donates that). The double-buffered
-streaming driver (cess_tpu/serve/stream.py) is built on exactly this
-program.
+encode and tag fused. The double-buffered streaming driver
+(cess_tpu/serve/stream.py) is built on exactly this program. The
+segment buffer is not donated: no output has its shape, so XLA
+cannot alias it (the chip reports such a donation as unusable).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +28,39 @@ from .. import constants
 from ..obs import trace
 from ..ops import gf, podr2
 from ..ops.rs import default_strategy, _MatrixApply
+
+
+# The two row regroupings of the data plane, written WITHOUT
+# ``reshape``. On the TPU a uint8 array is tiled over its last two
+# dimensions, so moving bytes between the row and the batch dimension
+# is a real relayout, and the TPU compiler's reshape lowering takes
+# compile time proportional to the array for it: [8, 16 MiB] ->
+# [8, 4, 4 MiB] took over eight minutes and [4, 3, 8 MiB] ->
+# [12, 8 MiB] 168 s for a described v5e (PR 22 rehearsals), against
+# under two seconds for the slice/concatenate forms below. Same bytes.
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def split_rows(segments: jax.Array, k: int) -> jax.Array:
+    """[B, k*n] -> [B, k, n]: row j of a segment is its j-th slice."""
+    n = segments.shape[1] // k
+    return jnp.stack([segments[:, j * n:(j + 1) * n] for j in range(k)],
+                     axis=1)
+
+
+@jax.jit
+def merge_rows(shards: jax.Array) -> jax.Array:
+    """[B, rows, n] -> [B*rows, n], one segment's rows at a time."""
+    b, rows, n = shards.shape
+
+    def body(i, out):
+        return jax.lax.dynamic_update_slice(out, shards[i], (i * rows, 0))
+
+    # seeded with segment 0 so that under shard_map the carry varies
+    # over the same mesh axes as the shards
+    init = jnp.concatenate(
+        [shards[0], jnp.zeros(((b - 1) * rows, n), shards.dtype)])
+    return jax.lax.fori_loop(1, b, body, init)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,7 +132,7 @@ class StoragePipeline:
         cfg = self.config
         segments = jnp.asarray(segments)
         b = segments.shape[0]
-        data = segments.reshape(b, cfg.k, cfg.fragment_size)
+        data = split_rows(segments, cfg.k)
         with trace.span("pipeline.encode", sys="pipeline", segments=b):
             if self.engine is not None and self.engine.codec is not None:
                 # zero-copy handoff: the engine accepts and returns
@@ -127,7 +155,7 @@ class StoragePipeline:
         """
         fragments = jnp.asarray(fragments)
         b, rows, n = fragments.shape
-        flat = fragments.reshape(b * rows, n)
+        flat = merge_rows(fragments)
         if fragment_ids is None:
             fragment_ids = jnp.arange(b * rows, dtype=jnp.int32)
         else:
@@ -149,9 +177,9 @@ class StoragePipeline:
 
     def fused_program(self):
         """The fused encode+tag device program: ONE jitted call,
-        segments DONATED (see module doc), results bit-identical to
-        encode_step -> tag_step. jit caches per batch/id shape, so the
-        streaming driver reuses one compiled program per bucket.
+        results bit-identical to encode_step -> tag_step. jit caches
+        per batch/id shape, so the streaming driver reuses one
+        compiled program per bucket.
 
         Signature: (segments [B, segment_size] u8,
                     fragment_ids [B*(k+m)] | [B, k+m] | [B, k+m, 2])
@@ -162,11 +190,11 @@ class StoragePipeline:
 
             def run(segments, fragment_ids):
                 b = segments.shape[0]
-                data = segments.reshape(b, cfg.k, cfg.fragment_size)
+                data = split_rows(segments, cfg.k)
                 parity = self._parity(data)
                 shards = jnp.concatenate([data, parity], axis=-2)
                 rows = shards.shape[-2]
-                flat = shards.reshape(b * rows, cfg.fragment_size)
+                flat = merge_rows(shards)
                 ids = fragment_ids.reshape(
                     (b * rows, 2) if fragment_ids.ndim == 3
                     else (b * rows,))
@@ -174,16 +202,7 @@ class StoragePipeline:
                 return {"fragments": shards,
                         "tags": tags.reshape(b, rows, *tags.shape[1:])}
 
-            # donate the staged segment batch: the buffer is dead the
-            # moment the program consumes it (the streaming driver
-            # stages a fresh one per batch), so XLA may reclaim it for
-            # the program's own intermediates instead of carrying
-            # 2 GiB of input alongside ~4x that of packed-element
-            # temps. The CPU backend cannot use an unaliased donation
-            # (no output matches the [B, seg] shape) and would warn on
-            # every dispatch, so the gate: accelerator-only.
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            self._fused = jax.jit(run, donate_argnums=donate)
+            self._fused = jax.jit(run)
         return self._fused
 
     def forward(self, segments: jnp.ndarray,
@@ -193,10 +212,10 @@ class StoragePipeline:
         OSS-encode + TEE-tag off-chain compute as one device program).
 
         Without an engine this is the FUSED path: one jitted call, no
-        intermediate materialization between encode and tag, segment
-        buffer donated. With an engine the two steps submit through its
-        queues (still zero-copy for device-resident inputs), carrying
-        the optional per-tenant accounting tag."""
+        intermediate materialization between encode and tag. With an
+        engine the two steps submit through its queues (still
+        zero-copy for device-resident inputs), carrying the optional
+        per-tenant accounting tag."""
         segments = jnp.asarray(segments)
         with trace.span("pipeline.forward", sys="pipeline",
                         segments=int(segments.shape[0])):

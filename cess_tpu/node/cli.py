@@ -288,6 +288,12 @@ def main(argv=None) -> int:
                          "family. Requires --engine; 'off' (default) "
                          "keeps the engine fail-fast")
     args = ap.parse_args(argv)
+    if args.engine in ("auto", "tpu"):
+        # the engines that compile device programs: keep what they
+        # compile across processes (cess_tpu/jaxcache.py)
+        from .. import jaxcache
+
+        jaxcache.enable()
 
     def unhex(s: str) -> bytes:
         return bytes.fromhex(s[2:] if s.startswith("0x") else s)

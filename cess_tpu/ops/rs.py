@@ -243,10 +243,7 @@ def _placement_device():
     bound to the device it was compiled for, so a warm hit compiled
     under device 0's scope must never be dispatched inside device 3's
     (the one-device-assumption bug this key component fixes)."""
-    try:
-        return jax.config.jax_default_device
-    except AttributeError:   # very old jax: no such config state
-        return None
+    return jax.config.jax_default_device
 
 
 def default_strategy() -> Strategy:
@@ -425,6 +422,13 @@ def make_codec(k: int, m: int, backend: str = "cpu", strategy: Strategy | None =
                 "`make -C cess_tpu/native` or use backend='cpu'"
             ) from e
         return NativeCodec(k, m)
+    if backend == "tpu" and jax.default_backend() == "cpu":
+        # an EXPLICIT accelerator request must fail loudly, like
+        # audit_backend._device_for: "jax" is the name for "wherever
+        # JAX runs" (the CPU test mesh included)
+        raise RuntimeError(
+            "ErasureCodec 'tpu' requested but no accelerator is "
+            "present; use 'jax', 'cpu' or 'auto'")
     if backend in ("tpu", "jax"):
         return TPUCodec(k, m, strategy=strategy)
     if backend == "regen":

@@ -11,38 +11,21 @@ TPU-speedup benchmark (BASELINE.md, ≥40×).
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 
 import numpy as np
 
+from .. import native
 from . import gf
-
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SO = os.path.join(_NATIVE_DIR, "libcessrs.so")
-
-
-def _build() -> None:
-    # build ONLY the RS target: a compile failure in another native
-    # backend (e.g. bls381.cpp on an exotic toolchain) must not take
-    # down this one
-    subprocess.run(["make", "-C", _NATIVE_DIR, "-s", "libcessrs.so"],
-                   check=True, capture_output=True)
 
 
 def _load() -> ctypes.CDLL:
-    if not os.path.exists(_SO):
-        try:
-            _build()
-        except (OSError, subprocess.CalledProcessError) as e:
-            raise ImportError(f"cannot build native codec: {e}") from e
+    so = native.ensure_built("libcessrs.so")
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
     except OSError as e:
-        # stale / wrong-arch .so: importers expect ImportError so the
-        # ErasureCodec gate (and bench) can fall back cleanly
-        raise ImportError(f"cannot load {_SO}: {e}") from e
+        # importers expect ImportError so the ErasureCodec gate (and
+        # bench) can fall back cleanly
+        raise ImportError(f"cannot load {so}: {e}") from e
     lib.cess_rs_apply.argtypes = [
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int64,

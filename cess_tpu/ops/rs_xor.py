@@ -1,14 +1,16 @@
 """Bit-sliced executor for compiled XOR schedules (cess_tpu/ops/xor_sched).
 
 Instead of materialising 0/1 bit-planes (8x expansion) or riding the
-MXU (rs_pallas.py), this path keeps the data packed: 4 consecutive
-data bytes are viewed as one uint32 lane, and bit-plane b of byte row
-j is ``(row_u32 >> b) & 0x01010101`` — the information bit of every
+MXU (rs_pallas.py), this path keeps the data packed: 4 data bytes of
+a row (one from each quarter of the byte axis, see ``_pack_u32``)
+share one uint32 lane, and bit-plane b of byte row j is
+``(row_u32 >> b) & 0x01010101`` — the information bit of every
 byte sits at bit position 0 of its byte lane, so every schedule op is
 one full-lane uint32 XOR over the column tile, covering 4 data bytes
 per lane. Unpack is a shift+mask per touched input plane, pack is a
 shift+or per output plane; byte order round-trips exactly because no
-op ever mixes bit positions across byte lanes.
+op ever mixes bit positions across byte lanes, so WHICH four bytes
+share a word is free as long as unpacking inverts packing.
 
 Two executors run the SAME schedule, bit-identical to
 rs.py::_apply_bitmatrix by construction (both compute the same GF(2)
@@ -17,8 +19,8 @@ linear map exactly — pinned in tests/test_xor_sched.py):
 - a Pallas TPU kernel: grid over (batch row, column tile), input and
   output tiles plus the schedule's liveness-allocated scratch slots
   in VMEM, every op a full-lane VPU uint32 instruction;
-- a pure-jnp fallback executing the same op list for CPU and
-  interpret-free testing (the CPU test mesh default).
+- a pure-jnp fallback executing the same op list wherever the kernel
+  would only be interpreted (the CPU test mesh default).
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import target
 from .xor_sched import OP_ACC, OP_COPY, OP_XOR, XorSchedule
 
 DEFAULT_TILE_LANES = 8192          # uint32 lanes per column tile
@@ -121,8 +124,6 @@ def _apply_pallas(sched: XorSchedule, tile_lanes: int,
     b, q, n4 = u32.shape
     r = sched.r8 // 8
     grid = (b, n4 // tile_lanes)
-    # interpret mode lets the same kernel run on the CPU test mesh
-    interpret = jax.default_backend() == "cpu"
     return pl.pallas_call(
         _make_kernel(sched, tile_lanes),
         grid=grid,
@@ -133,12 +134,33 @@ def _apply_pallas(sched: XorSchedule, tile_lanes: int,
         out_specs=pl.BlockSpec((1, r, tile_lanes),
                                lambda i, t: (i, 0, t),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, r, n4), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((b, r, n4), jnp.uint32,
+                                       vma=jax.typeof(u32).vma),
         scratch_shapes=[
             pltpu.VMEM((sched.n_scratch, tile_lanes), jnp.uint32),
         ],
-        interpret=interpret,
+        interpret=target.interpret(),
     )(u32)
+
+
+def _pack_u32(data: jax.Array) -> jax.Array:
+    """[..., n] uint8 (n % 4 == 0) -> [..., n/4] uint32: byte lane j
+    of word i is byte ``i + j*n/4``. A quarter-strided packing instead
+    of a ``[..., n/4, 4]`` bitcast view: a minor dimension of 4 is
+    padded to 128 by the TPU's tiled layout (32x the batch in HBM)."""
+    n4 = data.shape[-1] // 4
+    word = data[..., :n4].astype(jnp.uint32)
+    for j in range(1, 4):
+        word = word | (data[..., j * n4:(j + 1) * n4].astype(jnp.uint32)
+                       << (8 * j))
+    return word
+
+
+def _unpack_u32(word: jax.Array) -> jax.Array:
+    """Inverse of :func:`_pack_u32`: [..., n4] uint32 -> [..., 4*n4]."""
+    return jnp.concatenate(
+        [((word >> (8 * j)) & 0xFF).astype(jnp.uint8) for j in range(4)],
+        axis=-1)
 
 
 def apply_schedule(sched: XorSchedule, data: jax.Array,
@@ -147,8 +169,9 @@ def apply_schedule(sched: XorSchedule, data: jax.Array,
     """Apply a compiled schedule to [..., q, n] uint8 data.
 
     Returns [..., r, n] uint8. ``force`` pins the executor ("pallas" |
-    "jnp"); default is the Pallas kernel on real devices and the jnp
-    fallback on the CPU backend. n is padded to the lane/tile multiple
+    "jnp"); default is the Pallas kernel where it compiles for the TPU
+    and the jnp fallback where it would be interpreted. n is padded to
+    the lane/tile multiple
     (zero columns produce zero outputs — harmless, stripped)."""
     q, r = sched.q8 // 8, sched.r8 // 8
     data = jnp.asarray(data, dtype=jnp.uint8)
@@ -156,20 +179,18 @@ def apply_schedule(sched: XorSchedule, data: jax.Array,
     if q_in != q:
         raise ValueError(f"data rows {q_in} != schedule inputs {q}")
     use_pallas = force == "pallas" or (
-        force is None and jax.default_backend() != "cpu")
+        force is None and not target.interpret())
     step = 4 * tile_lanes if use_pallas else 4
     pad = (-n) % step
     if pad:
         data = jnp.pad(data, [(0, 0)] * len(lead) + [(0, 0), (0, pad)])
     n_pad = n + pad
-    flat = data.reshape(-1, q, n_pad // 4, 4)
-    u32 = jax.lax.bitcast_convert_type(flat, jnp.uint32)  # [B, q, n4]
+    u32 = _pack_u32(data.reshape(-1, q, n_pad))           # [B, q, n4]
     if use_pallas:
         out32 = _apply_pallas(sched, tile_lanes, u32)
     else:
         out32 = _apply_jnp(sched, u32)
-    out = jax.lax.bitcast_convert_type(out32, jnp.uint8)  # [B, r, n4, 4]
-    out = out.reshape(*lead, r, n_pad)
+    out = _unpack_u32(out32).reshape(*lead, r, n_pad)
     if pad:
         out = out[..., :n]
     return out

@@ -25,10 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.pipeline import StoragePipeline
+from ..models.pipeline import StoragePipeline, merge_rows
 from ..ops import pfield as pf
 from ..ops import podr2
-from .compat import shard_map
 
 
 def make_mesh(devices=None, seg: int | None = None, byte: int = 1) -> Mesh:
@@ -81,7 +80,7 @@ def sharded_pipeline_step(pipeline: StoragePipeline, mesh: Mesh):
 
         # --- tag: global PRF, local slice --------------------------------
         off = jax.lax.axis_index("byte") * blocks_local
-        m = podr2.fragment_to_elems(shards.reshape(b * rows, n_local),
+        m = podr2.fragment_to_elems(merge_rows(shards),
                                     sectors)                   # [F, bl_local, s]
         f_all = jax.vmap(
             lambda i: podr2.prf_elems(key.prf_key, i, blocks_total,
@@ -109,7 +108,7 @@ def sharded_pipeline_step(pipeline: StoragePipeline, mesh: Mesh):
         return (shards, tags.reshape(b, rows, blocks_local, 2),
                 ok.reshape(b, rows))
 
-    mapped = shard_map(        # compat: jax.shard_map moved across versions
+    mapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P("seg", None, "byte"), P("seg", None), P(), P()),
@@ -149,8 +148,7 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
         rows = shards.shape[-2]
         frag_ids = ids.reshape((b * rows, 2) if pair_ids else (b * rows,))
         off = jax.lax.axis_index("byte") * blocks_local
-        m = podr2.fragment_to_elems(shards.reshape(b * rows, n_local),
-                                    sectors)
+        m = podr2.fragment_to_elems(merge_rows(shards), sectors)
         f_all = jax.vmap(
             lambda i: podr2.prf_elems(key.prf_key, i, blocks_total,
                                       key.limbs))(frag_ids)
@@ -161,7 +159,7 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
         return shards, tags.reshape(b, rows, blocks_local, key.limbs)
 
     ids_spec = P("seg", None, None) if pair_ids else P("seg", None)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P("seg", None, "byte"), ids_spec),

@@ -1099,10 +1099,7 @@ class SubmissionEngine:
         span, so a captured XLA profile lines up with the trace."""
         if tracer is None or not tracer.jax_annotations:
             return contextlib.nullcontext()
-        annotation = getattr(jax.profiler, "TraceAnnotation", None)
-        if annotation is None:
-            return contextlib.nullcontext()
-        return annotation(f"cess:{op}")
+        return jax.profiler.TraceAnnotation(f"cess:{op}")
 
     def _run_batch(self, batch: list[_Request], lane=None,
                    tried=None) -> bool:

@@ -13,8 +13,8 @@ dedicated staging to keep the chip busy (PAPERS: "Ragged Paged
 Attention ... for TPU").
 
 :class:`StreamingIngest` drives the pipeline's FUSED encode+tag
-program (models/pipeline.py ``fused_program``: one jitted call, the
-segment buffer donated) over a host byte stream:
+program (models/pipeline.py ``fused_program``: one jitted call) over
+a host byte stream:
 
 - each batch is staged ONCE with ``jax.device_put`` (one H2D copy from
   host bytes to device tags — the fused program never materializes an
@@ -208,9 +208,8 @@ class StreamingIngest:
         profiler's per-step view matches the driver's batch spans."""
         if tracer is None or not tracer.jax_annotations:
             return None
-        annotation = getattr(jax.profiler, "StepTraceAnnotation", None)
-        return None if annotation is None \
-            else annotation("cess_stream", step_num=step)
+        return jax.profiler.StepTraceAnnotation("cess_stream",
+                                                step_num=step)
 
     def _run(self, segments, fragment_ids) -> Iterator[dict]:
         cfg = self.pipeline.config

@@ -36,9 +36,8 @@ def _free_port() -> int:
 
 SIXTEEN = """
 import jax
-from cess_tpu.parallel import compat
 jax.config.update("jax_platforms", "cpu")
-compat.set_cpu_device_count(16)    # version-guarded (jax 0.4.x compat)
+jax.config.update("jax_num_cpu_devices", 16)
 import numpy as np
 import jax.numpy as jnp
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
@@ -80,10 +79,9 @@ def test_sixteen_device_mesh():
 TWO_PROC = """
 import sys
 import jax
-from cess_tpu.parallel import compat
 port, pid = sys.argv[1], int(sys.argv[2])
 jax.config.update("jax_platforms", "cpu")
-compat.set_cpu_device_count(4)     # version-guarded (jax 0.4.x compat)
+jax.config.update("jax_num_cpu_devices", 4)
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 import numpy as np

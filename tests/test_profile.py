@@ -13,7 +13,7 @@
 - zero-cost-when-off: a disarmed engine holds no profile plane, the
   program cache times nothing, and no ``cess_profile_*`` key reaches
   GET /metrics;
-- baseline loaders parse the checked-in ``BENCH_r*.json`` rounds and
+- baseline loaders parse driver ``BENCH_r*.json`` round wrappers and
   the ``bench_diff --baseline-out`` artifact (fixture under
   tests/data/), and an unanchored watchdog stays inert;
 - wire-up: the ``cess_profileDump`` RPC, the ``node.cli --profile``
@@ -52,11 +52,24 @@ def make_pipe():
     return StoragePipeline(PipelineConfig(k=K, m=M, segment_size=SEG))
 
 
+def write_round_record(directory, name="BENCH_r05.json") -> str:
+    """A driver round wrapper (metric lines in ``tail``) holding the
+    checked-in baseline fixture's values, written under ``directory``
+    — the repo root carries no bench record of its own."""
+    vals = profile.load_baseline(BASELINE_FIXTURE)
+    path = os.path.join(str(directory), name)
+    with open(path, "w") as f:
+        json.dump({"n": 5, "cmd": "python bench.py", "rc": 0,
+                   "tail": "\n".join(
+                       json.dumps({"metric": m, "value": v})
+                       for m, v in sorted(vals.items()))}, f)
+    return path
+
+
 # -- baseline loading --------------------------------------------------------
 class TestBaselineLoaders:
-    def test_parse_checked_in_round_wrapper(self):
-        vals = profile.parse_bench_record(
-            os.path.join(REPO, "BENCH_r05.json"))
+    def test_parse_round_wrapper(self, tmp_path):
+        vals = profile.parse_bench_record(write_round_record(tmp_path))
         assert ENCODE_METRIC in vals and vals[ENCODE_METRIC] > 0
 
     def test_parse_raw_jsonl_skips_garbage(self, tmp_path):
@@ -82,17 +95,21 @@ class TestBaselineLoaders:
         assert profile.latest_bench_baseline(str(tmp_path / "empty")) \
             == {}
 
-    def test_repo_records_anchor_the_default_tracked_metric(self):
-        base = profile.latest_bench_baseline(REPO)
+    def test_fixture_anchors_the_default_tracked_metric(self, tmp_path):
+        base = profile.load_baseline(BASELINE_FIXTURE)
         assert base[ENCODE_METRIC] > 0
         assert profile.TRACKED_DEFAULT["encode"] == ENCODE_METRIC
+        # a record beside the process anchors the default lookup too
+        write_round_record(tmp_path)
+        assert profile.latest_bench_baseline(str(tmp_path)) == base
 
-    def test_checked_in_artifact_matches_the_bench_record(self):
-        # the fixture is the exact bench_diff --baseline-out output
-        # for the newest checked-in round — what --profile=PATH loads
+    def test_checked_in_artifact_matches_the_bench_record(self, tmp_path):
+        # the fixture has the shape of a bench_diff --baseline-out
+        # artifact — what --profile=PATH loads — and round-trips
+        # through a driver round wrapper value for value
         base = profile.load_baseline(BASELINE_FIXTURE)
         assert base == profile.parse_bench_record(
-            os.path.join(REPO, "BENCH_r05.json"))
+            write_round_record(tmp_path))
 
     def test_load_baseline_rejects_non_artifact(self, tmp_path):
         p = tmp_path / "not_an_artifact.json"
@@ -425,8 +442,8 @@ def _run_perf_drill(seed: bytes):
     """Drive 4 sequential encodes through an engine whose dispatch is
     delayed by a seeded FaultPlan, under an armed flight recorder with
     a profile-aware IncidentReporter; returns the replay evidence."""
-    baseline = profile.latest_bench_baseline(REPO)
-    assert baseline[ENCODE_METRIC] > 0   # anchored by checked-in bench
+    baseline = profile.load_baseline(BASELINE_FIXTURE)
+    assert baseline[ENCODE_METRIC] > 0   # anchored by the fixture
     plane = profile.ProfilePlane(baseline=baseline, window=DRILL_WINDOW)
     eng = make_engine(K, M, profile=plane)
     rec = flight.FlightRecorder(seed)

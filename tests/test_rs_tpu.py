@@ -140,3 +140,11 @@ def test_property_encode_corrupt_repair_random_patterns():
                 (trial, backend, k, m, missing)
             got_data = np.asarray(codec.decode_data(surv, present))
             assert np.array_equal(got_data, data), (trial, backend)
+
+
+def test_explicit_tpu_backend_refuses_without_an_accelerator():
+    # "tpu" names the accelerator and must never become a TPUCodec on
+    # the CPU backend in silence; "jax" is "wherever JAX runs"
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        make_codec(2, 1, backend="tpu")
+    assert make_codec(2, 1, backend="jax").k == 2

@@ -208,8 +208,7 @@ def test_pipeline_engine_path_returns_device_arrays(pkey):
     try:
         piped = StoragePipeline(cfg, podr2_key=pkey, engine=eng)
         direct = StoragePipeline(cfg, podr2_key=pkey)
-        # host segments: the fused direct path donates its staged
-        # device copy on accelerators, so the shared input stays numpy
+        # host segments, shared by both pipelines
         segs = rnd((2, K * FRAG), 6)
         ids = jnp.asarray(rnd((2, K + M, 2), 7, dtype=np.uint32))
         out = piped.forward(segs, ids)

@@ -1,0 +1,146 @@
+"""The main path's Pallas kernels, compiled at protocol widths for a
+DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
+refuses here what it would refuse on the chip — a kernel Mosaic cannot
+lower, a program that does not fit 16 GiB of HBM — at no chip time.
+
+Nothing runs, so nothing here says anything about results or speed.
+Interpret-mode tests (test_rs_tpu / test_xor_sched / test_podr2) pin
+the results; chip_smoke.py is the run on the chip.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one xdist worker may load the TPU library, and every
+worker imports every test file), and all cases live in this one file
+so one worker holds the library for all of them.
+"""
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from cess_tpu import constants
+from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
+from cess_tpu.ops import gf, podr2, podr2_pallas, rs_pallas, rs_xor, \
+    target, xor_sched
+
+MiB = 1 << 20
+HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
+# A kernel compiles in a second or two and the fused program in three.
+# The bound is for the relayouting uint8 reshape, whose compile time
+# grows with the array (minutes at these widths; models/pipeline.py
+# split_rows/merge_rows are written without it).
+COMPILE_SECONDS = 60
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here / library held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_tpu(monkeypatch):
+    """Steer the one interpret-mode decision (ops/target.py) to 'lower
+    for the TPU' — the process still sees only the CPU backend — with
+    the persistent compile cache off (a described-device executable
+    cannot be read back without a chip), and drop every trace
+    afterwards so a TPU-lowered kernel never serves a later CPU test."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(target, "interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+
+
+def _rs_encode(k, m):
+    bmat = gf.expand_bitmatrix(gf.cauchy_parity_matrix(k, m))
+    return lambda d: rs_pallas.apply_bitmatrix(bmat, d)
+
+
+def _rs_repair_one_row():
+    # RS(4,8), row 0 lost, rebuilt from the 4 lowest survivors
+    bmat = gf.expand_bitmatrix(
+        gf.repair_matrix(4, 8, (1, 2, 3, 4), (0,)))
+    return lambda d: rs_pallas.apply_bitmatrix(bmat, d)
+
+
+def _xor_encode(k, m):
+    sched = xor_sched.compile_schedule(
+        gf.expand_bitmatrix(gf.cauchy_parity_matrix(k, m)))
+    return lambda d: rs_xor.apply_schedule(sched, d, force="pallas")
+
+
+def _podr2_tags():
+    key = podr2.Podr2Key.generate(0)
+    w0, w1 = podr2_pallas._weight_limbs(
+        (key.alpha.shape[0], key.limbs,
+         np.asarray(key.alpha, dtype=np.uint32).tobytes()))
+    lanes = 2 * key.alpha.shape[0]
+
+    def run(prf, data):
+        return podr2_pallas._tags_3d(
+            jnp.asarray(w0), jnp.asarray(w1), prf, data, key.limbs,
+            lanes, podr2_pallas.DEFAULT_BLOCK_TILE)
+    return run
+
+
+def _fused_forward():
+    # strategy named: default_strategy() asks the (CPU) backend
+    cfg = PipelineConfig(k=4, m=8, segment_size=constants.SEGMENT_SIZE,
+                         strategy="pallas")
+    return StoragePipeline(cfg).fused_program()
+
+
+CASES = [
+    ("rs_pallas-rs4p8-encode", lambda: _rs_encode(4, 8),
+     [((8, 4, 4 * MiB), jnp.uint8)]),
+    ("rs_pallas-rs2p1-encode", lambda: _rs_encode(2, 1),
+     [((8, 2, 8 * MiB), jnp.uint8)]),
+    ("rs_pallas-repair-one-row", _rs_repair_one_row,
+     [((1, 4, 8 * MiB), jnp.uint8)]),
+    ("podr2_pallas-tags", _podr2_tags,
+     [((8, 2, 16384), jnp.uint32), ((8, 16384, 512), jnp.uint8)]),
+    ("rs_xor-rs4p8-encode", lambda: _xor_encode(4, 8),
+     [((8, 4, 4 * MiB), jnp.uint8)]),
+    ("fused-forward-rs4p8", _fused_forward,
+     [((8, 16 * MiB), jnp.uint8), ((8 * 12,), jnp.int32)]),
+]
+
+
+@pytest.mark.parametrize("build,shapes",
+                         [pytest.param(b, s, id=i) for i, b, s in CASES])
+def test_kernel_compiles_for_v5e(one_chip, for_tpu, build, shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    t0 = time.perf_counter()
+    compiled = jax.jit(build()).lower(*args).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    print(f"temp {mem.temp_size_in_bytes / MiB:.0f} MiB, arguments "
+          f"{mem.argument_size_in_bytes / MiB:.0f} MiB, outputs "
+          f"{mem.output_size_in_bytes / MiB:.0f} MiB")     # pytest -s
+    assert total < HBM_BYTES, mem
