@@ -11,6 +11,13 @@ shapes are structural:
 - starting inside a ``try:`` whose ``finally`` owns the ``finish()``
   (the generator/driver shape — serve/stream.py).
 
+Stage hooks (``trace.stage(...)`` / ``obs.stage(...)``, and the
+engine's ``self._stage(...)`` wrapper) are held to the same shapes:
+a stage object that is never entered times nothing, and one entered
+by hand and not exited leaves a profiler annotation open — ``with
+trace.stage(...):`` is accepted exactly as ``with trace.span(...):``
+and ``with tracer.start(...):`` are, anything else is a finding.
+
 A span that legitimately OUTLIVES its frame (the engine's per-request
 spans are finished by the batcher thread at resolve time) is the
 exception, not the rule — those sites carry an inline
@@ -44,11 +51,29 @@ def _is_tracer_start(node: ast.AST) -> bool:
     return recv.rsplit(".", 1)[-1].lower().endswith("tracer")
 
 
+def _is_stage(node: ast.AST) -> bool:
+    """A stage hook call: ``trace.stage(...)`` / ``obs.stage(...)``
+    (the module hook, obs/trace.py) or a ``<recv>._stage(...)``
+    wrapper that returns one (serve/engine.py)."""
+    if not (isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)):
+        return False
+    if node.func.attr == "_stage":
+        return True
+    recv = dotted(node.func.value)
+    return node.func.attr == "stage" and recv is not None \
+        and recv.rsplit(".", 1)[-1] in ("trace", "obs")
+
+
+def _is_opening(node: ast.AST) -> bool:
+    return _is_tracer_start(node) or _is_stage(node)
+
+
 @register
 class SpanBalance(Rule):
     id = "span-balance"
-    description = ("Tracer.start(...) not managed by a with block or "
-                   "a try/finally")
+    description = ("Tracer.start(...) / trace.stage(...) not managed "
+                   "by a with block or a try/finally")
     hint = ("wrap the call: `with tracer.start(...) as span:` (or use "
             "obs.span(...)), or start inside a try: whose finally: "
             "calls span.finish(); a span that must outlive the frame "
@@ -73,17 +98,22 @@ class SpanBalance(Rule):
                 # closed by __exit__ (IfExp-wrapped starts included)
                 for item in node.items:
                     for sub in ast.walk(item.context_expr):
-                        if _is_tracer_start(sub):
+                        if _is_opening(sub):
                             managed.add(id(sub))
             elif isinstance(node, ast.Try) and node.finalbody:
                 # a start anywhere under a try/finally is treated as
                 # balanced — the finally path owns the finish()
                 for sub in ast.walk(node):
-                    if _is_tracer_start(sub):
+                    if _is_opening(sub):
                         managed.add(id(sub))
+            elif isinstance(node, ast.Return) and node.value is not None \
+                    and _is_stage(node.value):
+                # a wrapper that hands the stage object to ITS caller's
+                # with-statement (SubmissionEngine._stage)
+                managed.add(id(node.value))
         out = []
         for node in ast.walk(mod.tree):
-            if _is_tracer_start(node) and id(node) not in managed:
+            if _is_opening(node) and id(node) not in managed:
                 out.append(self.finding(
                     mod, node,
                     f"`{dotted(node.func)}(...)` is not closed by a "

@@ -63,6 +63,11 @@ def merge_rows(shards: jax.Array) -> jax.Array:
     return jax.lax.fori_loop(1, b, body, init)
 
 
+# jax.named_scope of the fused encode+tag step (fused_program): its
+# operations read "…/cess_fused_step/…" in a device trace's op_name
+FUSED_SCOPE = "cess_fused_step"
+
+
 @dataclasses.dataclass(frozen=True)
 class PipelineConfig:
     k: int = constants.REF_K
@@ -189,18 +194,23 @@ class StoragePipeline:
             cfg = self.config
 
             def run(segments, fragment_ids):
-                b = segments.shape[0]
-                data = split_rows(segments, cfg.k)
-                parity = self._parity(data)
-                shards = jnp.concatenate([data, parity], axis=-2)
-                rows = shards.shape[-2]
-                flat = merge_rows(shards)
-                ids = fragment_ids.reshape(
-                    (b * rows, 2) if fragment_ids.ndim == 3
-                    else (b * rows,))
-                tags = podr2.tag_fragments(self.podr2_key, ids, flat)
-                return {"fragments": shards,
-                        "tags": tags.reshape(b, rows, *tags.shape[1:])}
+                # every operation of the step carries the scope in its
+                # op_name metadata, so a device trace can tell the
+                # fused step's relayouts from anything else's
+                with jax.named_scope(FUSED_SCOPE):
+                    b = segments.shape[0]
+                    data = split_rows(segments, cfg.k)
+                    parity = self._parity(data)
+                    shards = jnp.concatenate([data, parity], axis=-2)
+                    rows = shards.shape[-2]
+                    flat = merge_rows(shards)
+                    ids = fragment_ids.reshape(
+                        (b * rows, 2) if fragment_ids.ndim == 3
+                        else (b * rows,))
+                    tags = podr2.tag_fragments(self.podr2_key, ids, flat)
+                    return {"fragments": shards,
+                            "tags": tags.reshape(b, rows,
+                                                 *tags.shape[1:])}
 
             self._fused = jax.jit(run)
         return self._fused

@@ -85,44 +85,59 @@ class OssGateway:
         frag_hashes = [
             [fragment_hash(b"pending")] * (cfg.k + cfg.m)
             for _ in range(n_segs)]
-        with trace.span("offchain.upload", sys="offchain",
-                        file=file_name, segments=n_segs,
-                        size=len(data)):
+        # one stage each per upload (obs.trace.stage): the upload and
+        # its six stages are cess:offchain.upload / cess:gateway.* in
+        # any profiler trace, and spans of an armed tracer
+        with trace.stage("offchain.upload", sys="offchain",
+                         file=file_name, segments=n_segs,
+                         size=len(data)):
             # hash fragments first (ids feed the tag PRF), then tag on
             # device. The device-resident fragments feed tag_step
             # DIRECTLY (zero-copy engine handoff): the hashing fetch is
             # the only D2H, and the fragment bytes are never
             # re-uploaded for tagging
-            frags_dev = self.pipeline.encode_step(jnp.asarray(segments),
+            with trace.stage("gateway.encode"):
+                frags_dev = self.pipeline.encode_step(
+                    jnp.asarray(segments), tenant=owner)
+            with trace.stage("gateway.fetch"):
+                out_frags = np.asarray(frags_dev)
+            with trace.stage("gateway.hash"):
+                ids = np.zeros((n_segs, cfg.k + cfg.m, 2), dtype=np.uint32)
+                for i in range(n_segs):
+                    for j in range(cfg.k + cfg.m):
+                        h = fragment_hash(out_frags[i, j].tobytes())
+                        frag_hashes[i][j] = h
+                        ids[i, j] = podr2.fragment_id_from_hash(h)
+                seg_hashes = [fragment_hash(segments[i].tobytes())
+                              for i in range(n_segs)]
+            with trace.stage("gateway.tag"):
+                tags_dev = self.pipeline.tag_step(frags_dev,
+                                                  jnp.asarray(ids),
                                                   tenant=owner)
-            out_frags = np.asarray(frags_dev)
-            ids = np.zeros((n_segs, cfg.k + cfg.m, 2), dtype=np.uint32)
-            for i in range(n_segs):
-                for j in range(cfg.k + cfg.m):
-                    h = fragment_hash(out_frags[i, j].tobytes())
-                    frag_hashes[i][j] = h
-                    ids[i, j] = podr2.fragment_id_from_hash(h)
-            tags = np.asarray(self.pipeline.tag_step(frags_dev,
-                                                     jnp.asarray(ids),
-                                                     tenant=owner))
-            for i in range(n_segs):
-                for j in range(cfg.k + cfg.m):
-                    h = frag_hashes[i][j]
-                    self.fragment_store[h] = out_frags[i, j].tobytes()
-                    self.tag_store[h] = tags[i, j]
-            seg_list = [(fragment_hash(segments[i].tobytes()),
-                         tuple(frag_hashes[i])) for i in range(n_segs)]
-            file_hash = fragment_hash(b"".join(h for _, fs in seg_list
-                                               for h in fs))
-            self.node.submit_extrinsic(
-                self.account, "file_bank.upload_declaration", file_hash,
-                seg_list, UserBrief(owner, file_name, bucket), len(data))
-            # custody lineage: one encode+dispatch event per upload —
-            # the declared seg_list is exactly what the ledger needs
-            # (the guarded note is free when no recorder is armed)
-            _flight.note("custody", "dispatch", owner=owner,
-                         file=file_hash, k=cfg.k, m=cfg.m,
-                         segments=seg_list)
+            with trace.stage("gateway.fetch"):
+                tags = np.asarray(tags_dev)
+            with trace.stage("gateway.store"):
+                for i in range(n_segs):
+                    for j in range(cfg.k + cfg.m):
+                        h = frag_hashes[i][j]
+                        self.fragment_store[h] = out_frags[i, j].tobytes()
+                        self.tag_store[h] = tags[i, j]
+            with trace.stage("gateway.declare"):
+                seg_list = [(seg_hashes[i], tuple(frag_hashes[i]))
+                            for i in range(n_segs)]
+                file_hash = fragment_hash(b"".join(h for _, fs in seg_list
+                                                   for h in fs))
+                self.node.submit_extrinsic(
+                    self.account, "file_bank.upload_declaration",
+                    file_hash, seg_list,
+                    UserBrief(owner, file_name, bucket), len(data))
+                # custody lineage: one encode+dispatch event per upload
+                # — the declared seg_list is exactly what the ledger
+                # needs (the guarded note is free when no recorder is
+                # armed)
+                _flight.note("custody", "dispatch", owner=owner,
+                             file=file_hash, k=cfg.k, m=cfg.m,
+                             segments=seg_list)
             return file_hash
 
 

@@ -40,7 +40,7 @@ from .prom import (LATENCY_BUCKETS_S, Histogram, escape_label,
                    format_labels, format_le, render_histogram)
 from .slo import (DEFAULT_TARGETS, SloBoard, SloTarget, parse_targets)
 from .trace import (NOOP_SPAN, Span, Tracer, arm, armed, armed_tracer,
-                    context, current_span, disarm, event, span)
+                    context, current_span, disarm, event, span, stage)
 # flight before incident: incident.py imports from the flight/trace
 # layer it listens on
 from .flight import FlightRecorder
@@ -70,4 +70,5 @@ __all__ = [
     "parse_targets",
     "render_histogram",
     "span",
+    "stage",
 ]

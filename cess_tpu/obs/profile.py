@@ -12,8 +12,9 @@ instead of waiting for a human to run ``bench_diff --history``:
 
 - :class:`OpProfiler` — per-(class, bucket-shape, device) accounting
   of every engine dispatch: stage breakdown (queue-wait / h2d /
-  dispatch / sync), served vs padded rows, bytes moved, and a
-  count-windowed throughput gauge per class. Fed from the existing
+  dispatch / sync, from the engine's own stage clock), served vs
+  padded rows, bytes moved, and a count-windowed throughput gauge per
+  class. Fed from the existing
   span-attribute seams in ``serve/engine.py`` (``_account_batch``),
   ``serve/stream.py`` (the double-buffered drive loop) and
   ``serve/pool.py`` lanes (the lane index rides the account key).
@@ -170,12 +171,30 @@ class OpProfiler:
 
     One account per distinct (request class, bucket row count, device
     lane) triple: batch/request/row/byte counters plus the host-side
-    stage breakdown the caller measured (queue-wait, h2d copy,
-    dispatch, sync). A per-class deque of the last ``window``
-    (bytes, busy-seconds) observations backs the windowed-throughput
-    gauge. Counters are replay-deterministic and form the ops third
-    of the witness; the ``*_s`` stage sums are host timings and stay
-    out of it.
+    stage breakdown the caller measured. What each stage is:
+
+    - ``queue_s``    engine: each member's enqueue -> its batch starts
+                     to run, summed over members (the engine's
+                     ``queue`` stage, serve/stats.py STAGES);
+    - ``dispatch_s`` engine: the program call returns — the program
+                     and its implicit host->device copies are
+                     ENQUEUED (the ``dispatch`` stage); stream: the
+                     fused program's call returns, likewise;
+    - ``sync_s``     engine: ``block_until_ready`` on the result — the
+                     device works, the host waits (the ``wait``
+                     stage); the stream driver's stall is in
+                     StreamStats, not here;
+    - ``h2d_s``      stream only: ``device_put`` returns, an enqueue
+                     too. The engine has no separate host->device
+                     step (its copies ride the program call) and
+                     feeds 0.
+
+    ``dispatch_s + sync_s`` is thus issue -> result ready: the busy
+    time the windowed gauge and the watchdog divide bytes by. A
+    per-class deque of the last ``window`` (bytes, busy-seconds)
+    observations backs the windowed-throughput gauge. Counters are
+    replay-deterministic and form the ops third of the witness; the
+    ``*_s`` stage sums are host timings and stay out of it.
     """
 
     def __init__(self, *, window: int = 8):

@@ -44,9 +44,15 @@ chrome://tracing), the ``cess_traceDump`` RPC serves the same dump
 from a live node, and ``node.cli --trace[=PATH]`` /
 ``bench.py --trace`` arm a tracer for a whole run.
 
-``Tracer(jax_annotations=True)`` additionally wraps device batches in
-``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation`` scopes so
-an XLA profile captured during the run lines up with framework spans.
+Stage spans (:func:`stage`) are the one way the program writes into a
+profiler trace: every stage of an engine batch, a gateway upload and a
+streamed batch runs under ``jax.profiler.TraceAnnotation("cess:" +
+name)`` whether or not a tracer is armed, so any ``.xplane.pb`` taken
+while the program runs holds them on the same clock as the device's
+``XLA Ops`` line. Span timing (``Span.t0``/``dur_s``) is
+``time.monotonic``; a stage's seconds are ``time.perf_counter`` — on
+Linux the same clock, but only the profiler's own clock can be laid
+against device idle gaps, which is why the stages go into its trace.
 """
 from __future__ import annotations
 
@@ -210,13 +216,9 @@ class Tracer:
     trace_id:        the session identity every root span carries;
                      spans started from a remote ``context()`` adopt
                      the sender's instead (distributed traces).
-    jax_annotations: instrumented device dispatch sites additionally
-                     open ``jax.profiler`` annotation scopes so an XLA
-                     profile lines up with framework spans.
     """
 
-    def __init__(self, capacity: int = 4096, trace_id: int = 1,
-                 jax_annotations: bool = False):
+    def __init__(self, capacity: int = 4096, trace_id: int = 1):
         if capacity < 1:
             raise ValueError(f"tracer capacity {capacity} < 1")
         self._mu = threading.Lock()
@@ -225,7 +227,6 @@ class Tracer:
         self.capacity = capacity
         self._spans: collections.deque[Span] = collections.deque(
             maxlen=capacity)
-        self.jax_annotations = jax_annotations
         self.origin = time.monotonic()   # ts origin for exports
         self.pid = os.getpid()
         self.started = 0                 # spans started (ever)
@@ -403,6 +404,97 @@ def span(name: str, *, sys: str = "", **attrs):
     if tracer is None:
         return NOOP_SPAN
     return tracer.start(name, sys=sys, current=True, **attrs)
+
+
+STAGE_PREFIX = "cess:"    # every stage's name in a profiler trace
+
+_ANNOTATION = None        # jax.profiler.TraceAnnotation, bound at first use
+
+
+class _Stage:
+    """The context object :func:`stage` returns; ``seconds`` holds the
+    stage's duration once the block has been left."""
+
+    __slots__ = ("name", "sink", "parent", "sys", "attrs", "seconds",
+                 "_ann", "_span", "_t0")
+
+    def __init__(self, name, sink, parent, sys, attrs):
+        self.name = name
+        self.sink = sink
+        self.parent = parent
+        self.sys = sys
+        self.attrs = attrs
+        self.seconds = 0.0
+        self._span = NOOP_SPAN
+
+    def __enter__(self) -> "_Stage":
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            # this module is imported by the sim and the node long
+            # before any JAX work: the profiler binds at the first
+            # stage, which is always device-adjacent code
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        self._ann = _ANNOTATION(STAGE_PREFIX + self.name)
+        self._ann.__enter__()
+        parent = self.parent
+        if parent is None:
+            tracer = _TRACER
+            # an explicit tracer's spans (make_engine(tracer=...)) are
+            # current without being armed: follow the current span
+            cur = _CURRENT.get()
+            if isinstance(cur, Span):
+                tracer = cur.tracer
+        else:
+            tracer = getattr(parent, "tracer", None)   # NOOP_SPAN: none
+        if tracer is not None:
+            self._span = tracer.start(
+                self.name, sys=self.sys or self.name.partition(".")[0],
+                parent=parent, current=True, **self.attrs)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.seconds = dt = time.perf_counter() - self._t0
+        sink = self.sink
+        if sink is not None:
+            acc = sink.get(self.name)
+            if acc is None:
+                sink[self.name] = [1, dt]
+            else:
+                acc[0] += 1
+                acc[1] += dt
+        if self._span is not NOOP_SPAN:
+            self._span.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def stage(name: str, sink: dict | None = None, *, parent=None,
+          sys: str = "", **attrs) -> _Stage:
+    """The ``with``-style hook for one STAGE of a unit of device work
+    (an engine batch, a gateway upload, a streamed batch) — the only
+    way the program writes into a profiler trace. The block
+
+    - runs under ``jax.profiler.TraceAnnotation("cess:" + name)``,
+      ALWAYS: whenever a profiler session is live the stage is in its
+      ``.xplane.pb`` on the same clock as the device's ``XLA Ops``
+      line; outside a session the annotation is a flag check;
+    - is timed with ``time.perf_counter()`` at both ends: the seconds
+      land on the returned object (``.seconds``) and, when a ``sink``
+      dict is given, ``sink[name]`` accumulates ``[count, seconds]``
+      (unlocked: give each writer thread its own sink and merge);
+    - when tracing is on, is a child :class:`Span` too, so
+      ``cess_traceDump`` shows the same stages per request. ``parent``
+      is the explicit parent Span (its tracer serves; passing
+      :data:`NOOP_SPAN` means "no span": the caller's own span
+      machinery is off, or already covers this extent); without one
+      the span is a child of the context's current span, on that
+      span's tracer or the armed one.
+
+    One stage per unit of work, never per row or per fragment."""
+    return _Stage(name, sink, parent, sys, attrs)
 
 
 def current_span():

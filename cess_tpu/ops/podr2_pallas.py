@@ -79,6 +79,12 @@ def _kernel(limbs: int, lanes: int):
     return kernel
 
 
+# The kernel's name on the device: "%_tags_3d.<n> = ..." custom calls
+# in a profiler trace, matched by the benchmark's
+# tag_kernel_roofline.ingest reader (pinned as in ops/rs_pallas.py).
+KERNEL_NAME = "_tags_3d"
+
+
 @functools.partial(jax.jit, static_argnums=(4, 5, 6),
                    donate_argnums=(2,))
 def _tags_3d(w0: jax.Array, w1: jax.Array, prf: jax.Array,
@@ -115,6 +121,7 @@ def _tags_3d(w0: jax.Array, w1: jax.Array, prf: jax.Array,
         out_shape=jax.ShapeDtypeStruct((fcount, limbs, blocks),
                                        jnp.uint32),
         interpret=target.interpret(),
+        name=KERNEL_NAME,
     )(w0, w1, prf, data)
 
 

@@ -42,6 +42,12 @@ DEFAULT_TILE_N = 32768
 DEFAULT_GROUP = 2      # v5e probe: mxupack g=2/32k 51.9 GiB/s, the peak
 DEFAULT_SUBTILES = 1
 PACK_W = (1, 2, 4, 8, 16, 32, 64, -128)   # int8-safe byte weights
+# The kernel's name on the device: its events in a profiler trace are
+# "%_apply_3d.<n> = ..." custom calls, which is what the benchmark's
+# rs_kernel_roofline.* readers match. Pinned here (and in
+# tests/test_kernel_names.py) so that renaming the jitted wrapper
+# cannot silently empty them.
+KERNEL_NAME = "_apply_3d"
 
 
 def _make_kernel(q: int, r: int, g: int, tile_n: int, subtiles: int,
@@ -103,6 +109,7 @@ def _apply_3d(bmat: jax.Array, packmat: jax.Array, q: int, r: int, g: int,
         out_shape=jax.ShapeDtypeStruct((b, r, n), jnp.uint8,
                                        vma=jax.typeof(data3d).vma),
         interpret=target.interpret(),
+        name=KERNEL_NAME,
     )(bmat, packmat, data3d)
 
 

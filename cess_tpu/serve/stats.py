@@ -20,11 +20,30 @@ from . import policy
 
 LATENCY_WINDOW = 512     # per-class sliding window for percentiles
 
+# The stages of one engine batch, in order (serve/engine.py times each
+# through obs.trace.stage, once per batch):
+#   queue     each member's enqueue -> the batch starts to run (lane
+#             wait on the pool path included), summed over members
+#   assemble  host-side coalescing: concatenate/pad, or the stacked
+#             classes' zero-fill + copy loops
+#   dispatch  the program call returns: the program and its implicit
+#             host->device copies are ENQUEUED (the un-jitted vmaps
+#             issue op by op here), the result slice with them
+#   wait      jax.block_until_ready on the result: the device works,
+#             the host waits
+#   fetch     np.asarray of the result and the per-request slicing
+#             (prove: the release of the stacked host batch too)
+#   resolve   accounting + resolving the members' futures
+# For a class whose batches hold one request, the stage seconds sum to
+# its submit -> resolve latency, within the clock reads between them.
+STAGES = ("queue", "assemble", "dispatch", "wait", "fetch", "resolve")
+
 
 class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
                  "saturated", "shed", "batches", "batched_requests",
-                 "rows", "padded_rows", "latencies", "hist")
+                 "rows", "padded_rows", "latencies", "hist", "stage_n",
+                 "stage_s")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -43,6 +62,18 @@ class ClassStats:
         # is mergeable across nodes/scrapes, rendered as cumulative
         # _bucket{le=...}/_sum/_count lines by node/metrics.py
         self.hist = prom.Histogram(prom.LATENCY_BUCKETS_S)
+        # per-stage batch counts and raw, unrounded seconds (STAGES):
+        # merged from each batch's own sink when the batch is done
+        self.stage_n = dict.fromkeys(STAGES, 0)
+        self.stage_s = dict.fromkeys(STAGES, 0.0)
+
+    def add_stages(self, sink: dict) -> None:
+        """Merge one batch's stage sink (``{"engine.<cls>.<stage>":
+        [count, seconds]}``, obs.trace.stage's shape)."""
+        for name, (n, seconds) in sink.items():
+            stage = name.rpartition(".")[2]
+            self.stage_n[stage] += n
+            self.stage_s[stage] += seconds
 
     # -- derived -----------------------------------------------------------
     @property
@@ -70,15 +101,22 @@ class StreamStats:
     (SubmissionEngine.attach_stream) to export through the same
     ``cess_engine_*`` exposition, prefixed ``cess_engine_stream_``.
 
-    Reading the two stage clocks against wall time tells you where the
-    streamed workload is bound:
+    What each clock times, and what it can and cannot tell you:
     - ``stall_s`` is host time spent BLOCKED on device results (the
-      in-flight throttle + final drain) — a high stall fraction means
-      the device is saturated: good occupancy, compute-bound.
-    - ``h2d_s`` is host time spent staging bytes to the device — a
-    high h2d fraction with near-zero stall means the transfer side
-    cannot keep the chip busy: transfer-bound, the overlap is the
-    only thing hiding it.
+      in-flight throttle + final drain). A high stall fraction means
+      the host runs ahead and the device's queue is full: the stream
+      is bound by what the device works through per batch — program
+      and transfers alike, which overlap there.
+    - ``h2d_s`` and ``dispatch_s`` time an ENQUEUE, never the work:
+      ``device_put`` and the program call return once the transfer or
+      the program is queued (128 MiB "staged" in 0.65 ms on a v5e,
+      PERF.md). A high ``h2d_frac`` therefore does NOT mean
+      transfer-bound; it means the host-side call itself is slow (a
+      synchronous backend such as the CPU's, a non-contiguous source
+      being copied, a full transfer queue). How long the bytes take is
+      only in a device trace, where the same extents are the
+      ``cess:stream.put`` / ``.dispatch`` / ``.stall`` stage spans
+      (obs.trace.stage) beside the device's own line.
     """
 
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
@@ -90,8 +128,8 @@ class StreamStats:
         self.segments = 0          # real segments ingested
         self.padded_segments = 0   # zero rows added to the ragged tail
         self.bytes_in = 0          # host bytes staged (real, not pad)
-        self.h2d_s = 0.0           # host time in device_put staging
-        self.dispatch_s = 0.0      # host time dispatching the program
+        self.h2d_s = 0.0           # host time ENQUEUEING device_put
+        self.dispatch_s = 0.0      # host time ENQUEUEING the program
         self.stall_s = 0.0         # host time blocked on device results
         self.wall_s = 0.0          # wall time of completed run() calls
         # per-batch host time (staging + dispatch) histogram — the
@@ -169,6 +207,9 @@ class EngineStats:
                 "pad_waste": round(st.pad_waste, 4),
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
+                "stages": {stage: {"n": st.stage_n[stage],
+                                   "s": st.stage_s[stage]}
+                           for stage in STAGES},
             }
         if self.streams:
             out["streams"] = [s.snapshot() for s in self.streams]
@@ -191,6 +232,9 @@ class EngineStats:
         out = {"cess_engine_programs_built": snap["programs_built"],
                "cess_engine_programs_reused": snap["programs_reused"]}
         for cls, st in snap["classes"].items():
+            for stage, acc in st.pop("stages").items():
+                out[f"cess_engine_{cls}_stage_{stage}_seconds"] = acc["s"]
+                out[f"cess_engine_{cls}_stage_{stage}_count"] = acc["n"]
             for name, val in st.items():
                 out[f"cess_engine_{cls}_{name}"] = val
         if self.streams:

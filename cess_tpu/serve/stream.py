@@ -27,9 +27,11 @@ a host byte stream:
   program shape (no tail recompile; every pipeline op is
   row-independent, so the pad rows are sliced off bit-exactly);
 - every stage is counted in :class:`~cess_tpu.serve.stats.StreamStats`
-  (staging time, dispatch time, stall time, pad waste) and exported
-  through the engine's ``cess_engine_stream_*`` metrics when attached
-  (SubmissionEngine.attach_stream).
+  (enqueue time of the staging and of the program, stall time, pad
+  waste) and exported through the engine's ``cess_engine_stream_*``
+  metrics when attached (SubmissionEngine.attach_stream); the same
+  four extents are ``cess:stream.stage`` / ``.put`` / ``.dispatch`` /
+  ``.stall`` in any profiler trace taken meanwhile (obs.trace.stage).
 
 Results are bit-identical to the direct per-step path
 (``encode_step`` -> ``tag_step``) — tests/test_stream.py pins this on
@@ -201,16 +203,6 @@ class StreamingIngest:
             return self._engine.tracer
         return trace.armed_tracer()
 
-    @staticmethod
-    def _step_annotation(tracer, step: int):
-        """XLA-profile alignment for the streamed path: each batch
-        dispatch runs under a jax.profiler.StepTraceAnnotation, so the
-        profiler's per-step view matches the driver's batch spans."""
-        if tracer is None or not tracer.jax_annotations:
-            return None
-        return jax.profiler.StepTraceAnnotation("cess_stream",
-                                                step_num=step)
-
     def _run(self, segments, fragment_ids) -> Iterator[dict]:
         cfg = self.pipeline.config
         rows = cfg.k + cfg.m
@@ -224,9 +216,9 @@ class StreamingIngest:
         def drain_one():
             nonlocal stalls
             out, real = inflight.popleft()
-            t0 = time.perf_counter()
-            jax.block_until_ready(out["tags"])
-            stall = time.perf_counter() - t0
+            with trace.stage("stream.stall", parent=run_span) as stalled:
+                jax.block_until_ready(out["tags"])
+            stall = stalled.seconds
             st.stall_s += stall
             stalls += 1
             if run_span is not trace.NOOP_SPAN:
@@ -243,46 +235,53 @@ class StreamingIngest:
                                         batch=self.batch,
                                         depth=self.depth)
             seg_off = 0
-            for chunk in _rebatch(segments, self.batch):
-                # enforce the in-flight window BEFORE staging the next
-                # batch: at most ``depth`` batches are ever enqueued
-                # (depth=2 = one computing + one staged), which is what
-                # bounds in-flight device memory
+            chunks = _rebatch(segments, self.batch)
+            while True:
+                # host-side staging of the next batch: the source's
+                # next rows, contiguous, padded, with their ids
+                with trace.stage("stream.stage", parent=run_span):
+                    chunk = next(chunks, None)
+                    if chunk is not None:
+                        chunk = np.ascontiguousarray(chunk,
+                                                     dtype=np.uint8)
+                        real = chunk.shape[0]
+                        pad = 0
+                        if real < self.batch:  # ragged tail: pad, reuse
+                            chunk = _pad_axis0(chunk, self.batch)
+                            pad = self.batch - real
+                            st.padded_segments += pad
+                        if fragment_ids is None:
+                            ids = np.arange(seg_off * rows,
+                                            (seg_off + self.batch) * rows,
+                                            dtype=np.int32)
+                        else:
+                            ids = _pad_axis0(
+                                fragment_ids[seg_off:seg_off + real],
+                                self.batch)
+                if chunk is None:
+                    break
+                # enforce the in-flight window BEFORE putting the next
+                # batch on the device: at most ``depth`` batches are
+                # ever enqueued (depth=2 = one computing + one staged),
+                # which is what bounds in-flight device memory
                 while len(inflight) >= self.depth:
                     yield drain_one()
-                chunk = np.ascontiguousarray(chunk, dtype=np.uint8)
-                real = chunk.shape[0]
-                pad = 0
-                if real < self.batch:          # ragged tail: pad, reuse
-                    chunk = _pad_axis0(chunk, self.batch)
-                    pad = self.batch - real
-                    st.padded_segments += pad
-                if fragment_ids is None:
-                    ids = np.arange(seg_off * rows,
-                                    (seg_off + self.batch) * rows,
-                                    dtype=np.int32)
-                else:
-                    ids = _pad_axis0(fragment_ids[seg_off:seg_off + real],
-                                     self.batch)
                 bspan = trace.NOOP_SPAN if tracer is None \
                     else tracer.start("stream.batch", sys="stream",
                                       parent=run_span, rows=real,
                                       pad=pad)
                 try:
-                    bt0 = t0 = time.perf_counter()
-                    faults.inject("stream.h2d")   # chaos seam: staging
-                    dev = self._put(chunk)
-                    ids_dev = self._put_ids(ids)
-                    h2d = time.perf_counter() - t0
+                    bt0 = time.perf_counter()
+                    with trace.stage("stream.put", parent=bspan) as put:
+                        faults.inject("stream.h2d")   # chaos: staging
+                        dev = self._put(chunk)
+                        ids_dev = self._put_ids(ids)
+                    h2d = put.seconds
                     st.h2d_s += h2d
-                    t0 = time.perf_counter()
-                    faults.inject("stream.dispatch")  # chaos: launch
-                    ann = self._step_annotation(tracer, st.batches)
-                    if ann is None:
+                    with trace.stage("stream.dispatch",
+                                     parent=bspan) as launch:
+                        faults.inject("stream.dispatch")  # chaos: launch
                         out = program(dev, ids_dev)
-                    else:
-                        with ann:
-                            out = program(dev, ids_dev)
                 except BaseException as e:
                     # a staging/dispatch failure (fault injection, OOM)
                     # must still land the batch span in the ring, error
@@ -303,7 +302,7 @@ class StreamingIngest:
                     # escape the stream driver — an incident trigger
                     _flight.note("stream", "escape", error=repr(e))
                     raise
-                dispatch = time.perf_counter() - t0
+                dispatch = launch.seconds
                 st.dispatch_s += dispatch
                 st.hist.observe(h2d + dispatch)
                 # SLO/tenant feed (obs/slo.py): streamed batches ride

@@ -474,7 +474,50 @@ CLEAN_SPAN = """
 """
 
 
+DIRTY_STAGE = """
+    from ..obs import trace
+
+    def go(work):
+        st = trace.stage("engine.encode.wait")
+        st.__enter__()                   # a raise in work() leaves the
+        work()                           # profiler annotation open
+        st.__exit__(None, None, None)
+"""
+
+CLEAN_STAGE = """
+    from .. import obs
+    from ..obs import trace
+
+    class Engine:
+        def _stage(self, cls, stage):
+            return trace.stage(f"engine.{cls}.{stage}")
+
+        def go(self, work, sink):
+            with trace.stage("gateway.hash"):
+                work()
+            with obs.stage("stream.put", sink) as put:
+                work()
+            with self._stage("encode", "wait"):
+                work()
+            return put.seconds
+"""
+
+
 class TestSpanBalance:
+    @pytest.mark.parametrize("dirty,clean,needle", [
+        (DIRTY_STAGE, CLEAN_STAGE, "trace.stage"),
+        (DIRTY_STAGE.replace('trace.stage("engine.encode.wait")',
+                             'self._stage("encode", "wait")'),
+         CLEAN_STAGE, "_stage")], ids=["hook", "wrapper"])
+    def test_stage_hooks_are_with_items(self, dirty, clean, needle):
+        """ISSUE 25: a stage is a with-item like a span — entered by
+        hand it can leave a profiler annotation open."""
+        r = lint(dirty, "cess_tpu/serve/fixture.py")
+        assert [f.rule for f in r.findings] == ["span-balance"]
+        assert needle in r.findings[0].message
+        r = lint(clean, "cess_tpu/serve/fixture.py")
+        assert r.findings == [] and r.suppressed == []
+
     def test_dirty_fixture_fires(self):
         r = lint(DIRTY_SPAN, "cess_tpu/serve/fixture.py")
         assert [f.rule for f in r.findings] == ["span-balance"]

@@ -12,6 +12,7 @@ import: only one xdist worker may load the TPU library, and every
 worker imports every test file), and all cases live in this one file
 so one worker holds the library for all of them.
 """
+import re
 import time
 
 import numpy as np
@@ -112,31 +113,41 @@ def _fused_forward():
     return StoragePipeline(cfg).fused_program()
 
 
+# the last element: the pinned kernel names (ops/*.KERNEL_NAME) whose
+# custom calls the compiled program must hold under exactly that name —
+# a device trace's events are these instructions, and the benchmark's
+# roofline readers match "%_apply_3d" / "%_tags_3d"
+RS, TAGS = rs_pallas.KERNEL_NAME, podr2_pallas.KERNEL_NAME
 CASES = [
     ("rs_pallas-rs4p8-encode", lambda: _rs_encode(4, 8),
-     [((8, 4, 4 * MiB), jnp.uint8)]),
+     [((8, 4, 4 * MiB), jnp.uint8)], (RS,)),
     ("rs_pallas-rs2p1-encode", lambda: _rs_encode(2, 1),
-     [((8, 2, 8 * MiB), jnp.uint8)]),
+     [((8, 2, 8 * MiB), jnp.uint8)], (RS,)),
     ("rs_pallas-repair-one-row", _rs_repair_one_row,
-     [((1, 4, 8 * MiB), jnp.uint8)]),
+     [((1, 4, 8 * MiB), jnp.uint8)], (RS,)),
     ("podr2_pallas-tags", _podr2_tags,
-     [((8, 2, 16384), jnp.uint32), ((8, 16384, 512), jnp.uint8)]),
+     [((8, 2, 16384), jnp.uint32), ((8, 16384, 512), jnp.uint8)],
+     (TAGS,)),
     ("rs_xor-rs4p8-encode", lambda: _xor_encode(4, 8),
-     [((8, 4, 4 * MiB), jnp.uint8)]),
+     [((8, 4, 4 * MiB), jnp.uint8)], ()),
     ("fused-forward-rs4p8", _fused_forward,
-     [((8, 16 * MiB), jnp.uint8), ((8 * 12,), jnp.int32)]),
+     [((8, 16 * MiB), jnp.uint8), ((8 * 12,), jnp.int32)], (RS, TAGS)),
 ]
 
 
-@pytest.mark.parametrize("build,shapes",
-                         [pytest.param(b, s, id=i) for i, b, s in CASES])
-def test_kernel_compiles_for_v5e(one_chip, for_tpu, build, shapes):
+@pytest.mark.parametrize("build,shapes,kernels",
+                         [pytest.param(b, s, k, id=i)
+                          for i, b, s, k in CASES])
+def test_kernel_compiles_for_v5e(one_chip, for_tpu, build, shapes, kernels):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
     t0 = time.perf_counter()
     compiled = jax.jit(build()).lower(*args).compile()
     assert time.perf_counter() - t0 < COMPILE_SECONDS
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in kernels:
+        assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", text), name
     mem = compiled.memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
