@@ -280,17 +280,34 @@ def gen_challenge(seed_bytes: bytes | int, num_blocks: int,
     return idx, nu
 
 
+def prove_at(blocks_i, tags_i, nu):
+    """The proof from the challenged blocks alone -> (mu [sectors],
+    sigma [limbs]): nothing outside them enters it.
+
+    blocks_i [c, ...] are the c challenged blocks in challenge order
+    (a block named twice appears twice) — raw bytes uint8
+    [c, sectors*2], or the same bytes already read as little-endian
+    field elements [c, sectors] (uint16 from a host ``view``, uint32
+    from fragment_to_elems); tags_i [c, limbs] their tags. THE one
+    definition of the proof: ``prove`` is this after its gather, and
+    the submission engine gathers on the host and ships only these.
+    """
+    m_i = (pf.pack_bytes(blocks_i) if blocks_i.dtype == jnp.uint8
+           else blocks_i.astype(jnp.uint32))                 # [c, s]
+    # m < 2^16 (pack_bytes width 2): data-side fast multiply
+    mu = pf.summod(pf.mulmod_u16(m_i, nu[:, None]), axis=0)  # [s]
+    sigma = pf.dotmod(nu[:, None], tags_i, axis=0)
+    return mu, sigma
+
+
 def prove(fragment, tags, idx, nu, sectors: int = SECTORS):
     """Miner-side proof for one fragment -> (mu [sectors], sigma [2]).
 
     Needs only public data: the fragment bytes and its tags [blocks, 2].
     """
     m = fragment_to_elems(fragment, sectors)       # [B, s]
-    m_i = jnp.take(m, idx, axis=0)                 # [c, s]
-    # m < 2^16 (pack_bytes width 2): data-side fast multiply
-    mu = pf.summod(pf.mulmod_u16(m_i, nu[:, None]), axis=0)  # [s]
-    sigma = pf.dotmod(nu[:, None], jnp.take(tags, idx, axis=0), axis=0)
-    return mu, sigma
+    return prove_at(jnp.take(m, idx, axis=0),
+                    jnp.take(tags, idx, axis=0), nu)
 
 
 def prove_batch(fragments, tags, idx, nu, sectors: int = SECTORS):
@@ -332,15 +349,28 @@ def aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
     return jax.vmap(one)(ids)
 
 
+def _fold_proofs(mu_f, sigma_f, r):
+    """Per-fragment proofs (mu [F, sectors], sigma [F, limbs]) folded
+    by r [F] into the one aggregated proof (see aggregate_coeffs)."""
+    mu = pf.summod(pf.mulmod(r[:, None], mu_f), axis=0)
+    sigma = pf.dotmod(r[:, None], sigma_f, axis=0)
+    return mu, sigma
+
+
 def prove_aggregate(fragments, tags, idx, nu, r, sectors: int = SECTORS):
     """[F, bytes], [F, blocks, 2], r [F] -> (mu [sectors], sigma [2]).
 
     The constant-size aggregated proof across all of a miner's
     challenged fragments (see aggregate_coeffs)."""
-    mu_f, sigma_f = prove_batch(fragments, tags, idx, nu, sectors)
-    mu = pf.summod(pf.mulmod(r[:, None], mu_f), axis=0)
-    sigma = pf.dotmod(r[:, None], sigma_f, axis=0)
-    return mu, sigma
+    return _fold_proofs(*prove_batch(fragments, tags, idx, nu, sectors), r)
+
+
+def prove_aggregate_at(blocks_i, tags_i, nu, r):
+    """prove_aggregate from the challenged blocks alone: blocks_i
+    [F, c, ...] and tags_i [F, c, limbs] as prove_at takes them per
+    fragment, r [F] -> (mu [sectors], sigma [limbs])."""
+    return _fold_proofs(*jax.vmap(
+        lambda b, t: prove_at(b, t, nu))(blocks_i, tags_i), r)
 
 
 def verify_aggregate(key: Podr2Key, fragment_ids, num_blocks: int,

@@ -52,12 +52,15 @@ class ProgramCache:
     (b) the engine can report compile-vs-reuse counts, and (c) the
     bucket policy has one place to be enforced.
 
-    Bounded: prove/verify keys embed the challenge-round digest, so a
-    long-running engine sees a stream of keys that are hot for one
-    audit round and dead afterwards — an unbounded dict would be a
-    slow leak of closures (and their captured round arrays). LRU with
-    a generous capacity keeps every live round's programs resident
-    while letting dead rounds fall out.
+    Bounded: the keys that do name data — one repair program per
+    erasure pattern (present, missing), and the per-fragment
+    verify_batch closure, whose key still carries its round's digest —
+    are hot for a while and dead afterwards, and an unbounded dict
+    would be a slow leak of closures and what they captured. LRU with
+    a generous capacity keeps everything live resident and lets the
+    dead fall out. The stacked audit programs (prove, verify_agg) take
+    the round and the PoDR2 key as operands: one entry per batch
+    shape, the same round after round.
     """
 
     CAPACITY = 256

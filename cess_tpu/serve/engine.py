@@ -29,8 +29,12 @@ submitters keep getting numpy back, even when a batch mixes both. So
 concat-coalesced classes (encode / repair / tag / verify), provided
 the payloads live on the backend's device. The stacked classes
 (prove / verify_agg) assemble their [R, F, ...] mission batches
-host-side — their callers are host agents and their payloads are
-KiB-scale proofs, not fragment bytes.
+host-side and run ONE compiled program per batch shape, reused across
+rounds: the round (idx, nu) and the PoDR2 key are operands, never
+constants. Their callers are host agents: a miner's fragments stay in
+host memory, and ``assemble`` gathers the challenged blocks there
+(4.6% of the set at protocol widths), so only what the round reads
+travels to the device; verify ships KiB-scale proofs.
 
 Protocol determinism is the hard constraint: engine-mediated results
 are bit-identical to the direct calls. That falls out of two facts —
@@ -86,6 +90,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import threading
 import time
@@ -197,6 +202,55 @@ def _pad_axis0(arr, rows: int):
         return jnp.concatenate([arr, pad], axis=0)
     pad = np.zeros((rows - arr.shape[0],) + arr.shape[1:], arr.dtype)
     return np.concatenate([arr, pad], axis=0)
+
+
+def _check_round(idx, nu, num_blocks: int) -> tuple:
+    """A challenge round's (idx, nu) as host arrays. The indices come
+    from outside and gather host memory here and device memory in the
+    verifier, where an out-of-range read raises nothing: refuse them
+    at submit."""
+    idx = np.asarray(idx)
+    nu = np.ascontiguousarray(nu, dtype=np.uint32)
+    if idx.ndim != 1 or nu.shape != idx.shape \
+            or not np.issubdtype(idx.dtype, np.integer):
+        raise ValueError("expected idx [c] of integers and nu [c]")
+    if idx.size and not 0 <= idx.min() <= idx.max() < num_blocks:
+        raise ValueError(f"challenged block outside [0, {num_blocks})")
+    return np.ascontiguousarray(idx, dtype=np.int32), nu
+
+
+def _prove_missions(blocks_i, tags_i, r, nu):
+    """The prove class's device program, one row per miner: challenged
+    blocks [R, F, c, sectors], their tags [R, F, c, limbs], fold
+    coefficients r [R, F] (0 on pad rows: exact modular zeros) and the
+    round's nu [c] -> (mu [R, sectors], sigma [R, limbs])."""
+    from ..ops import podr2
+
+    return jax.vmap(podr2.prove_aggregate_at,
+                    in_axes=(0, 0, None, 0))(blocks_i, tags_i, nu, r)
+
+
+def _verify_missions(ids, r, mu, sigma, idx, nu, alpha, prf_key_data, *,
+                     num_blocks: int, prf_impl: str):
+    """The verify_agg class's device program, one row per mission: ids
+    [R, F, 2], r [R, F] (0 on pad rows), proofs mu [R, sectors] and
+    sigma [R, limbs], the round (idx, nu) and the PoDR2 key (alpha and
+    the PRF key's raw words) -> bool [R]. The key is an operand, so
+    one executable serves every key."""
+    from ..ops import podr2
+
+    key = podr2.Podr2Key(alpha, jax.random.wrap_key_data(prf_key_data,
+                                                         impl=prf_impl))
+    return jax.vmap(lambda i, rr, u, s: podr2.verify_aggregate(
+        key, i, num_blocks, idx, nu, rr, u, s))(ids, r, mu, sigma)
+
+
+# jitted once for the process: a round changes operands, never the
+# program, so a shape compiles once however many rounds and engines
+# run it (the engine's ProgramCache counts the shapes it has met)
+_PROVE_PROGRAM = jax.jit(_prove_missions)
+_VERIFY_PROGRAM = jax.jit(_verify_missions,
+                          static_argnames=("num_blocks", "prf_impl"))
 
 
 class SubmissionEngine:
@@ -434,9 +488,13 @@ class SubmissionEngine:
                                tenant: str | None = None) -> EngineFuture:
         """One miner's aggregated proof over its held set: fragments
         [F, bytes], tags [F, blocks, limbs], coefficients r [F] ->
-        future of (mu [sectors], sigma [limbs]). Requests from miners
-        answering the SAME round (same idx/nu) coalesce into one
-        F-padded vmap batch; r's zero padding contributes exact
+        future of (mu [sectors], sigma [limbs]). The fragments stay
+        where they are (host memory, no copy of a contiguous uint8
+        array): the batch gathers the round's challenged blocks and
+        their tags from them, and only those go to the device. Requests
+        from miners answering the SAME round (same idx/nu) coalesce
+        into one batch [miners, F-bucket, challenged blocks, ...] for
+        one compiled program; r's zero padding contributes exact
         modular zeros to the fold, so results are bit-identical."""
         self._need_audit()
         from ..ops import podr2
@@ -444,16 +502,19 @@ class SubmissionEngine:
         frags = np.ascontiguousarray(np.asarray(fragments, dtype=np.uint8))
         tag_arr = np.ascontiguousarray(np.asarray(tags, dtype=np.uint32))
         r_arr = np.ascontiguousarray(np.asarray(r, dtype=np.uint32))
-        idx = np.asarray(idx)
-        nu = np.asarray(nu)
+        sectors = podr2.SECTORS if sectors is None else sectors
         if frags.ndim != 2 or tag_arr.ndim != 3 or r_arr.ndim != 1 \
                 or not frags.shape[0] == tag_arr.shape[0] == r_arr.shape[0]:
             raise ValueError("expected fragments [F, bytes], tags "
                              "[F, blocks, limbs], r [F]")
-        sectors = podr2.SECTORS if sectors is None else sectors
-        key = ("prove", frags.shape[1], tag_arr.shape[1],
-               tag_arr.shape[2], sectors,
-               _round_digest(tag_arr.shape[1], idx, nu))
+        blocks = tag_arr.shape[1]
+        if frags.shape[1] != blocks * sectors * podr2.pf.BYTES_PER_ELEM:
+            raise ValueError(
+                f"fragments of {frags.shape[1]} B are not {blocks} "
+                f"blocks of {sectors} sectors")
+        idx, nu = _check_round(idx, nu, blocks)
+        key = ("prove", frags.shape[1], blocks, tag_arr.shape[2], sectors,
+               _round_digest(blocks, idx, nu))
         return self._submit("prove", key, frags.shape[0],
                             {"fragments": frags, "tags": tag_arr,
                              "r": r_arr},
@@ -509,19 +570,20 @@ class SubmissionEngine:
         ids [F, 2], r [F], mu [sectors], sigma [limbs] -> future of
         bool. Missions of the same round coalesce: each mission's owed
         set is padded to a shared F bucket with r = 0 rows (exact
-        modular zeros in the fold) and the checks run as one vmap."""
+        modular zeros in the fold) and the checks run as one compiled
+        program over [missions, F-bucket], the round and the key its
+        operands."""
         self._need_audit()
         ids = np.ascontiguousarray(np.asarray(fragment_ids,
                                               dtype=np.uint32)).reshape(-1, 2)
         r_arr = np.ascontiguousarray(np.asarray(r, dtype=np.uint32))
         mu = np.ascontiguousarray(np.asarray(mu, dtype=np.uint32))
         sigma = np.ascontiguousarray(np.asarray(sigma, dtype=np.uint32))
-        idx = np.asarray(idx)
-        nu = np.asarray(nu)
         if r_arr.ndim != 1 or ids.shape[0] != r_arr.shape[0] \
                 or mu.ndim != 1 or sigma.ndim != 1:
             raise ValueError("expected ids [F, 2], r [F], mu [s], "
                              "sigma [limbs]")
+        idx, nu = _check_round(idx, nu, num_blocks)
         key = ("verify_agg", num_blocks, mu.shape[0], sigma.shape[0],
                _round_digest(num_blocks, idx, nu))
         return self._submit("verify", key, ids.shape[0],
@@ -1594,9 +1656,36 @@ class SubmissionEngine:
             out = prog(ids, mu, sigma)[:total]
         return self._split_rows(batch, out), bucket
 
-    def _op_verify_agg(self, batch, degraded=False, lane=None):
-        from ..ops import podr2
+    def _stacked_program(self, batch, fb: int, rb: int, degraded: bool,
+                         lane, bind):
+        """The stacked classes' cached program for one batch shape:
+        the request key WITHOUT its round digest (the digest decides
+        which requests coalesce, never which program runs) plus the
+        challenge length and the (F, miners) buckets — the same entry
+        round after round. ``bind(audit)`` gives the process-wide
+        jitted program and the operands that are the backend's own
+        (its key); the entry adds them to the batch's, counts what it
+        hands over (``operand_bytes``) and places the call on the
+        audit backend's device, as every other audit op is."""
+        cls = batch[0].cls
+        audit = self._audit_backend(degraded, lane)
 
+        def build():
+            fn, fixed = bind(audit)
+
+            def placed(*operands):
+                operands += fixed
+                nbytes = sum(a.nbytes for a in operands)
+                with self._lock:
+                    self.stats.classes[cls].operand_bytes += nbytes
+                with jax.default_device(audit.device):
+                    return fn(*operands)
+            return placed
+
+        key = batch[0].key[:-1] + (len(batch[0].aux["idx"]), fb, rb)
+        return self.programs.get(self._key(key, degraded, lane), build)
+
+    def _op_verify_agg(self, batch, degraded=False, lane=None):
         aux = batch[0].aux
         with self._stage("verify", "assemble"):
             fb = bucket_rows(max(r.rows for r in batch))
@@ -1611,24 +1700,22 @@ class SubmissionEngine:
                 rs[i, :r.rows] = r.arrays["r"]
                 mu[i] = r.arrays["mu"]
                 sigma[i] = r.arrays["sigma"]
-        num_blocks, idx, nu = (aux["num_blocks"], aux["idx"], aux["nu"])
-        audit = self._audit_backend(degraded, lane)
+        num_blocks = aux["num_blocks"]
 
-        def build():
-            fn = jax.vmap(lambda i, rr, u, s: podr2.verify_aggregate(
-                audit.key, i, num_blocks, idx, nu, rr, u, s))
-
-            def run(i, rr, u, s):
-                with jax.default_device(audit.device):
-                    return fn(i, rr, u, s)
-            return run
+        def bind(audit):
+            # the key as host words, read once per cached program: every
+            # operand is then a host array, placed with the program
+            key = audit.key
+            return (functools.partial(
+                        _VERIFY_PROGRAM, num_blocks=num_blocks,
+                        prf_impl=str(jax.random.key_impl(key.prf_key))),
+                    (np.asarray(key.alpha),
+                     np.asarray(jax.random.key_data(key.prf_key))))
 
         with self._stage("verify", "dispatch"):
-            prog = self.programs.get(
-                self._key(("verify_agg", batch[0].key, fb, rb), degraded,
-                          lane),
-                build)
-            out = prog(ids, rs, mu, sigma)
+            prog = self._stacked_program(batch, fb, rb, degraded, lane,
+                                         bind)
+            out = prog(ids, rs, mu, sigma, aux["idx"], aux["nu"])
         with self._stage("verify", "wait"):
             # the fetch below would block on the device anyway: the
             # wait is named, nothing is added to the path
@@ -1639,39 +1726,34 @@ class SubmissionEngine:
         return results, rb * fb
 
     def _op_prove(self, batch, degraded=False, lane=None):
-        from ..ops import podr2
-
         aux = batch[0].aux
+        idx, sectors = aux["idx"], aux["sectors"]
         with self._stage("prove", "assemble"):
             fb = bucket_rows(max(r.rows for r in batch))
             rb = bucket_rows(len(batch))
-            nbytes = batch[0].arrays["fragments"].shape[1]
             blocks, limbs = batch[0].arrays["tags"].shape[1:]
-            frags = np.zeros((rb, fb, nbytes), dtype=np.uint8)
-            tags = np.zeros((rb, fb, blocks, limbs), dtype=np.uint32)
+            # only what the round reads: the challenged blocks of every
+            # fragment, gathered where the miner holds them. Read as
+            # little-endian uint16 (pfield.pack_bytes' embedding; a free
+            # view on a little-endian host), so the device packs nothing
+            blocks_i = np.zeros((rb, fb, len(idx), sectors), np.uint16)
+            tags_i = np.zeros((rb, fb, len(idx), limbs), np.uint32)
             rs = np.zeros((rb, fb), dtype=np.uint32)
             for i, r in enumerate(batch):
-                frags[i, :r.rows] = r.arrays["fragments"]
-                tags[i, :r.rows] = r.arrays["tags"]
+                elems = r.arrays["fragments"].view("<u2").reshape(
+                    r.rows, blocks, sectors)
+                # mode="clip": unbuffered writes into the batch (idx
+                # was range-checked at submit)
+                np.take(elems, idx, axis=1, out=blocks_i[i, :r.rows],
+                        mode="clip")
+                np.take(r.arrays["tags"], idx, axis=1,
+                        out=tags_i[i, :r.rows], mode="clip")
                 rs[i, :r.rows] = r.arrays["r"]
-        idx, nu, sectors = aux["idx"], aux["nu"], aux["sectors"]
-        audit = self._audit_backend(degraded, lane)
-
-        def build():
-            fn = jax.vmap(lambda f, t, rr: podr2.prove_aggregate(
-                f, t, idx, nu, rr, sectors))
-
-            def run(f, t, rr):
-                with jax.default_device(audit.device):
-                    return fn(f, t, rr)
-            return run
-
         with self._stage("prove", "dispatch"):
-            prog = self.programs.get(
-                self._key(("prove", batch[0].key, fb, rb), degraded,
-                          lane),
-                build)
-            mu, sigma = prog(frags, tags, rs)
+            prog = self._stacked_program(
+                batch, fb, rb, degraded, lane,
+                lambda audit: (_PROVE_PROGRAM, ()))
+            mu, sigma = prog(blocks_i, tags_i, rs, aux["nu"])
         with self._stage("prove", "wait"):
             # as in _op_verify_agg: the fetch would block anyway
             jax.block_until_ready((mu, sigma))
@@ -1679,10 +1761,6 @@ class SubmissionEngine:
             mu = np.asarray(mu)
             sigma = np.asarray(sigma)
             results = [(mu[i], sigma[i]) for i in range(len(batch))]
-            # the stacked host batch dies here rather than at the
-            # return below, so that releasing it (20 ms for a miner's
-            # 256 MiB) is inside a stage and not between two
-            del frags, tags, rs
         return results, rb * fb
 
 

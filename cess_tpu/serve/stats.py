@@ -42,8 +42,8 @@ STAGES = ("queue", "assemble", "dispatch", "wait", "fetch", "resolve")
 class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
                  "saturated", "shed", "batches", "batched_requests",
-                 "rows", "padded_rows", "latencies", "hist", "stage_n",
-                 "stage_s")
+                 "rows", "padded_rows", "operand_bytes", "latencies",
+                 "hist", "stage_n", "stage_s")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -56,6 +56,11 @@ class ClassStats:
         self.batched_requests = 0   # requests across those batches
         self.rows = 0               # real rows across those batches
         self.padded_rows = 0        # pad rows added to reach buckets
+        # bytes of the arrays handed to the device program, summed over
+        # batches — counted by the stacked ops (prove, verify_agg), whose
+        # batches are built on the host from what a round reads; the
+        # concatenated classes leave it 0
+        self.operand_bytes = 0
         self.latencies = collections.deque(maxlen=LATENCY_WINDOW)
         # real Prometheus histogram of the same submit->resolve
         # latencies: unlike the sliding-window percentiles above this
@@ -205,6 +210,7 @@ class EngineStats:
                 "batches": st.batches,
                 "batch_occupancy": round(st.occupancy, 4),
                 "pad_waste": round(st.pad_waste, 4),
+                "operand_bytes": st.operand_bytes,
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
                 "stages": {stage: {"n": st.stage_n[stage],

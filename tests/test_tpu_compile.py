@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels, compiled at protocol widths for a
+"""The main path's Pallas kernels and the engine's two audit programs,
+compiled at protocol widths for a
 DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
 refuses here what it would refuse on the chip — a kernel Mosaic cannot
 lower, a program that does not fit 16 GiB of HBM — at no chip time.
@@ -12,6 +13,7 @@ import: only one xdist worker may load the TPU library, and every
 worker imports every test file), and all cases live in this one file
 so one worker holds the library for all of them.
 """
+import functools
 import re
 import time
 
@@ -26,6 +28,7 @@ from cess_tpu import constants
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
 from cess_tpu.ops import gf, podr2, podr2_pallas, rs_pallas, rs_xor, \
     target, xor_sched
+from cess_tpu.serve import engine
 
 MiB = 1 << 20
 HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
@@ -155,3 +158,38 @@ def test_kernel_compiles_for_v5e(one_chip, for_tpu, build, shapes, kernels):
           f"{mem.argument_size_in_bytes / MiB:.0f} MiB, outputs "
           f"{mem.output_size_in_bytes / MiB:.0f} MiB")     # pytest -s
     assert total < HBM_BYTES, mem
+
+
+# the submission engine's two audit programs (serve/engine.py) at the
+# shapes of a protocol round: one miner (R = 1) of F fragments, c
+# challenged blocks of 256 sectors, limbs = 2
+def _audit_shapes(f, c):
+    u16, u32 = jnp.uint16, jnp.uint32
+    prove = [((1, f, c, 256), u16), ((1, f, c, 2), u32), ((1, f), u32),
+             ((c,), u32)]
+    verify = [((1, f, 2), u32), ((1, f), u32), ((1, 256), u32),
+              ((1, 2), u32), ((c,), jnp.int32), ((c,), u32),
+              ((256, 2), u32), ((2,), u32)]
+    return prove, verify
+
+
+@pytest.mark.parametrize("f,c,blocks", [
+    pytest.param(32, 753, 16384, id="rs2p1-32x8MiB"),
+    pytest.param(16, 376, 8192, id="rs4p8-16x4MiB")])
+def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
+    prove, verify = _audit_shapes(f, c)
+    programs = (
+        (engine._prove_missions, prove),
+        (functools.partial(engine._verify_missions, num_blocks=blocks,
+                           prf_impl="threefry2x32"), verify))
+    for fn, shapes in programs:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert time.perf_counter() - t0 < COMPILE_SECONDS
+        mem = compiled.memory_analysis()
+        print(f"temp {mem.temp_size_in_bytes / MiB:.1f} MiB, arguments "
+              f"{mem.argument_size_in_bytes / MiB:.1f} MiB")  # pytest -s
+        assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes) < HBM_BYTES, mem
