@@ -180,6 +180,27 @@ class StoragePipeline:
                                            flat)
         return tags.reshape(b, rows, *tags.shape[1:])
 
+    def fused_step(self, data, fragment_ids):
+        """The body of the fused encode+tag step over fragment-major
+        rows: data [B, k, n] u8 + ids ([B*(k+m)] | [B, k+m] |
+        [B, k+m, 2]) -> {"fragments": [B, k+m, n], "tags":
+        [B, k+m, blocks, limbs]}. Traced by exactly two callers, each
+        under ``jax.named_scope(FUSED_SCOPE)``: :meth:`fused_program`
+        (one chip, after ``split_rows``) and parallel/mesh.py's
+        sharded stream step on a (lanes, 1) mesh (per device, on rows
+        the host already staged fragment-major) — one body, so the
+        one-chip and the pooled program cannot drift apart."""
+        b = data.shape[0]
+        parity = self._parity(data)
+        shards = jnp.concatenate([data, parity], axis=-2)
+        rows = shards.shape[-2]
+        flat = merge_rows(shards)
+        ids = fragment_ids.reshape(
+            (b * rows, 2) if fragment_ids.ndim == 3 else (b * rows,))
+        tags = podr2.tag_fragments(self.podr2_key, ids, flat)
+        return {"fragments": shards,
+                "tags": tags.reshape(b, rows, *tags.shape[1:])}
+
     def fused_program(self):
         """The fused encode+tag device program: ONE jitted call,
         results bit-identical to encode_step -> tag_step. jit caches
@@ -198,19 +219,8 @@ class StoragePipeline:
                 # op_name metadata, so a device trace can tell the
                 # fused step's relayouts from anything else's
                 with jax.named_scope(FUSED_SCOPE):
-                    b = segments.shape[0]
-                    data = split_rows(segments, cfg.k)
-                    parity = self._parity(data)
-                    shards = jnp.concatenate([data, parity], axis=-2)
-                    rows = shards.shape[-2]
-                    flat = merge_rows(shards)
-                    ids = fragment_ids.reshape(
-                        (b * rows, 2) if fragment_ids.ndim == 3
-                        else (b * rows,))
-                    tags = podr2.tag_fragments(self.podr2_key, ids, flat)
-                    return {"fragments": shards,
-                            "tags": tags.reshape(b, rows,
-                                                 *tags.shape[1:])}
+                    return self.fused_step(split_rows(segments, cfg.k),
+                                           fragment_ids)
 
             self._fused = jax.jit(run)
         return self._fused

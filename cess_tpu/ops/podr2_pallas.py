@@ -118,8 +118,11 @@ def _tags_3d(w0: jax.Array, w1: jax.Array, prf: jax.Array,
         out_specs=pl.BlockSpec((1, limbs, block_tile),
                                lambda i, t: (i, 0, t),
                                memory_space=pltpu.VMEM),
+        # vma: inside shard_map (parallel/mesh.py) the tags vary over
+        # the same mesh axes as the fragment bytes they were made from
         out_shape=jax.ShapeDtypeStruct((fcount, limbs, blocks),
-                                       jnp.uint32),
+                                       jnp.uint32,
+                                       vma=jax.typeof(data).vma),
         interpret=target.interpret(),
         name=KERNEL_NAME,
     )(w0, w1, prf, data)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.pipeline import StoragePipeline, merge_rows
+from ..models.pipeline import FUSED_SCOPE, StoragePipeline, merge_rows
 from ..ops import pfield as pf
 from ..ops import podr2
 
@@ -122,10 +122,16 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
                         pair_ids: bool = False):
     """The fused encode+tag step (no prove/verify) as ONE shard_map
     program over (seg, byte) — the multi-chip program behind
-    :func:`stream_entry`. Same topology-invariance contract as
-    sharded_pipeline_step: PRF values are generated for the full block
-    range and sliced locally, so tags are bit-identical to the
-    single-device fused forward on any mesh shape.
+    :func:`stream_entry`. Tags are bit-identical to the single-device
+    fused forward on any mesh shape.
+
+    On a (lanes, 1) mesh — the one a DevicePool builds, the only one a
+    user path reaches — each device runs the one-chip step's own body
+    (``StoragePipeline.fused_step``: Pallas RS and tag kernels) on its
+    segments. With the byte axis sharded, a device holds a slice of
+    every fragment: PRF values are generated for the full block range
+    and sliced locally (the topology-invariance contract of
+    sharded_pipeline_step), and the tags go through the plain jnp MAC.
 
     In: data [B, k, n] uint8 (fragment-major), ids [B, k+m] int32
     (or [B, k+m, 2] uint32 hash word pairs when ``pair_ids``).
@@ -141,7 +147,12 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
         f"{blocks_total} blocks not divisible by byte axis {byte_shards}")
     blocks_local = blocks_total // byte_shards
 
-    def step(data, ids):
+    def whole_fragments(data, ids):
+        with jax.named_scope(FUSED_SCOPE):
+            out = pipeline.fused_step(data, ids)
+        return out["fragments"], out["tags"]
+
+    def sliced_fragments(data, ids):
         b, k, n_local = data.shape
         parity = pipeline._parity(data)
         shards = jnp.concatenate([data, parity], axis=-2)
@@ -158,12 +169,19 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
             key.alpha, f_loc, m)
         return shards, tags.reshape(b, rows, blocks_local, key.limbs)
 
+    step = whole_fragments if byte_shards == 1 else sliced_fragments
     ids_spec = P("seg", None, None) if pair_ids else P("seg", None)
     mapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P("seg", None, "byte"), ids_spec),
         out_specs=(P("seg", None, "byte"), P("seg", None, "byte", None)),
+        # the whole-fragment step has no collective and nothing
+        # replicated, so the varying-axes check has nothing to prove
+        # there — and Pallas' HLO interpreter (the CPU test mesh)
+        # slices a kernel's varying blocks by unvarying grid indices,
+        # which that check refuses
+        check_vma=byte_shards > 1,
     )
     jitted = jax.jit(mapped)
 

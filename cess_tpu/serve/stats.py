@@ -118,7 +118,12 @@ class StreamStats:
       PERF.md). A high ``h2d_frac`` therefore does NOT mean
       transfer-bound; it means the host-side call itself is slow (a
       synchronous backend such as the CPU's, a non-contiguous source
-      being copied, a full transfer queue). How long the bytes take is
+      being copied, a full transfer queue). The last is what a
+      four-lane pool shows: the sharded put of 512 MiB over four v5e
+      chips takes 10.4–11.3 ms of the host thread a batch, seven times
+      the one-chip 1.58 ms for a quarter of the bytes, with the stream
+      paced by the host → device path (PERF.md, PR 27): no longer an
+      enqueue alone, still not the transfer. How long the bytes take is
       only in a device trace, where the same extents are the
       ``cess:stream.put`` / ``.dispatch`` / ``.stall`` stage spans
       (obs.trace.stage) beside the device's own line.
@@ -126,7 +131,7 @@ class StreamStats:
 
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
                  "h2d_s", "dispatch_s", "stall_s", "wall_s")
-    __slots__ = _COUNTERS + ("hist",)
+    __slots__ = _COUNTERS + ("lanes", "hist")
 
     def __init__(self):
         self.batches = 0           # device batches dispatched
@@ -137,12 +142,18 @@ class StreamStats:
         self.dispatch_s = 0.0      # host time ENQUEUEING the program
         self.stall_s = 0.0         # host time blocked on device results
         self.wall_s = 0.0          # wall time of completed run() calls
+        # a GAUGE, not a counter: devices the last staged batch was
+        # placed over (1 on one device; a DevicePool's lane count, or a
+        # mesh's size, once a sharded put has staged a batch)
+        self.lanes = 1
         # per-batch host time (staging + dispatch) histogram — the
         # mergeable form beside the aggregate stage clocks above
         self.hist = prom.Histogram(prom.LATENCY_BUCKETS_S)
 
     def raw(self) -> dict:
-        return {name: getattr(self, name) for name in self._COUNTERS}
+        out = {name: getattr(self, name) for name in self._COUNTERS}
+        out["lanes"] = self.lanes
+        return out
 
     def snapshot(self) -> dict:
         return stream_gauges(self.raw())
@@ -248,8 +259,10 @@ class EngineStats:
             # adding per-driver fractions would be meaningless
             totals = self.streams[0].raw()
             for s in self.streams[1:]:
-                for k, v in s.raw().items():
-                    totals[k] += v
+                for k in StreamStats._COUNTERS:
+                    totals[k] += getattr(s, k)
+                # the gauge does not add up: the widest placement
+                totals["lanes"] = max(totals["lanes"], s.lanes)
             for name, val in stream_gauges(totals).items():
                 out[f"cess_engine_stream_{name}"] = float(val)
         if self.resilience is not None:

@@ -278,6 +278,8 @@ class StreamingIngest:
                         ids_dev = self._put_ids(ids)
                     h2d = put.seconds
                     st.h2d_s += h2d
+                    # gauge: the devices this batch was placed over
+                    st.lanes = len(dev.sharding.device_set)
                     with trace.stage("stream.dispatch",
                                      parent=bspan) as launch:
                         faults.inject("stream.dispatch")  # chaos: launch

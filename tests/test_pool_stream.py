@@ -1,0 +1,181 @@
+"""The pooled streamed ingest at RS(4,8) — ``StreamingIngest(pipe, batch,
+pool=DevicePool of 4 lanes)``, the four-chip tee-worker host's path
+(benchmark cell ``stream-4p8.pool4``) — on four of the suite's virtual
+CPU devices:
+
+- against the PLAIN reference: ``ReferenceCodec`` for every fragment and
+  the per-fragment jnp ``podr2.tag_fragment`` for every tag, on both MAC
+  limb widths, default / scalar / (lo, hi) pair ids, ragged tail;
+- the sharded step on a (lanes, 1) mesh runs the one-chip fused step's
+  own body (``StoragePipeline.fused_step``) and is bit-identical to
+  ``fused_program`` on the same rows; with the byte axis sharded it keeps
+  the sliced-PRF jnp body and is still bit-identical;
+- ``StreamStats.lanes``: 4 with the pool, 1 without, a gauge that
+  attached streams do not sum;
+- the Pallas tag kernel types its output over the mesh axes its data
+  varies over, so it traces under a checked ``shard_map``.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
+from cess_tpu.ops import podr2, podr2_pallas, target
+from cess_tpu.ops.rs_ref import ReferenceCodec
+from cess_tpu.parallel.mesh import make_mesh, sharded_stream_step
+from cess_tpu.serve import make_engine
+from cess_tpu.serve.pool import DevicePool
+from cess_tpu.serve.stream import StreamingIngest
+
+K, M = 4, 8
+ROWS = K + M
+FRAG = 2048                 # 4 PoDR2 blocks of 512 B per fragment
+SEG = K * FRAG
+LANES = 4
+BATCH = 8                   # 2 segments a lane
+
+
+def rnd(shape, seed=0, dtype=np.uint8):
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, np.iinfo(dtype).max, shape, dtype=dtype)
+
+
+def make_pipe(limbs=2):
+    key = podr2.Podr2Key.generate(27, podr2.Podr2Params(limbs=limbs))
+    return StoragePipeline(PipelineConfig(k=K, m=M, segment_size=SEG),
+                           podr2_key=key)
+
+
+def plain_reference(pipe, segs, ids):
+    """Fragments by the NumPy codec, tags one fragment at a time through
+    the plain jnp MAC (no kernel, no batching, no mesh)."""
+    codec = ReferenceCodec(K, M)
+    frags = np.stack([codec.encode(s.reshape(K, FRAG)) for s in segs])
+    flat_ids = ids.reshape(len(segs) * ROWS, *ids.shape[2:])
+    tags = np.stack([
+        np.asarray(podr2.tag_fragment(pipe.podr2_key, fid, frag))
+        for fid, frag in zip(flat_ids, frags.reshape(-1, FRAG))])
+    return frags, tags.reshape(len(segs), ROWS, *tags.shape[1:])
+
+
+@pytest.mark.parametrize("n_segments", [16, 11], ids=["even", "ragged"])
+@pytest.mark.parametrize("id_kind", ["default", "scalar", "pair"])
+@pytest.mark.parametrize("limbs", [2, 3])
+def test_pooled_stream_matches_plain_reference(limbs, id_kind, n_segments):
+    pipe = make_pipe(limbs)
+    segs = rnd((n_segments, SEG), 100 + limbs)
+    pool = DevicePool(n=LANES)
+    if id_kind == "pair":
+        # (lo, hi) hash words: the pool's entry built for pair ids
+        ids = rnd((n_segments, ROWS, 2), 7, np.uint32)
+        ing = StreamingIngest(pipe, BATCH, **pool.stream_entry(
+            pipe, BATCH, pair_ids=True))
+    else:
+        ing = StreamingIngest(pipe, BATCH, pool=pool)
+        ids = np.arange(n_segments * ROWS, dtype=np.int32).reshape(
+            n_segments, ROWS)               # the driver's default
+        if id_kind == "scalar":
+            ids = ids[::-1] * 3 + 1
+    out = ing.ingest(segs, None if id_kind == "default" else ids)
+    want_frags, want_tags = plain_reference(pipe, segs, ids)
+    assert out["tags"].shape == (n_segments, ROWS, FRAG // 512, limbs)
+    assert np.array_equal(np.asarray(out["fragments"]), want_frags)
+    assert np.array_equal(np.asarray(out["tags"]), want_tags)
+    assert ing.stats.lanes == LANES
+    assert ing.stats.padded_segments == -n_segments % BATCH
+
+
+@pytest.mark.parametrize("seg,byte", [(4, 1), (2, 2), (1, 4)])
+@pytest.mark.parametrize("pair", [False, True], ids=["scalar", "pair"])
+def test_sharded_step_bit_identical_to_fused_program(seg, byte, pair):
+    """byte == 1 traces the fused step's own body per device; byte > 1
+    keeps the sliced-PRF jnp body. Same bits either way."""
+    pipe = make_pipe()
+    mesh = make_mesh(jax.devices()[:seg * byte], seg=seg, byte=byte)
+    segs = rnd((BATCH, SEG), 5)
+    ids = rnd((BATCH, ROWS, 2), 6, np.uint32) if pair else \
+        rnd((BATCH, ROWS), 6, np.uint32).astype(np.int32)
+    want = pipe.fused_program()(jnp.asarray(segs), jnp.asarray(ids))
+    got = sharded_stream_step(pipe, mesh, pair_ids=pair)(
+        jnp.asarray(segs.reshape(BATCH, K, FRAG)), jnp.asarray(ids))
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(want[name])), name
+
+
+def test_pooled_step_traces_the_fused_steps_body(monkeypatch):
+    """One body for one chip and for the pool: the (lanes, 1) step calls
+    StoragePipeline.fused_step (as fused_program does), the byte-sharded
+    step does not."""
+    pipe = make_pipe()
+    calls = []
+    real = StoragePipeline.fused_step
+
+    def counting(self, data, ids):
+        calls.append(data.shape)
+        return real(self, data, ids)
+
+    monkeypatch.setattr(StoragePipeline, "fused_step", counting)
+    data = jnp.asarray(rnd((BATCH, K, FRAG), 8))
+    ids = jnp.arange(BATCH * ROWS, dtype=jnp.int32).reshape(BATCH, ROWS)
+    sharded_stream_step(pipe, make_mesh(jax.devices()[:4], 4, 1))(data, ids)
+    assert calls == [(BATCH // 4, K, FRAG)]           # per-device rows
+    pipe.fused_program()(data.reshape(BATCH, SEG), ids)
+    assert calls[1:] == [(BATCH, K, FRAG)]
+    sharded_stream_step(pipe, make_mesh(jax.devices()[:4], 2, 2))(data, ids)
+    assert len(calls) == 2
+
+
+def test_stream_stats_lanes_is_a_gauge():
+    pipe = make_pipe()
+    segs = rnd((BATCH, SEG), 9)
+    one = StreamingIngest(pipe, BATCH)
+    assert one.stats.lanes == 1 and one.stats.raw()["lanes"] == 1
+    one.ingest(segs)
+    assert one.stats.lanes == 1
+    eng = make_engine(K, M, rs_backend="jax")
+    try:
+        pooled = StreamingIngest(pipe, BATCH, pool=DevicePool(n=LANES),
+                                 engine=eng)
+        pooled.ingest(segs)
+        assert pooled.stats.lanes == LANES
+        assert pooled.stats.snapshot()["lanes"] == LANES
+        assert pooled.stats.metrics()["cess_engine_stream_lanes"] == LANES
+        # two attached streams: counters add up, the gauge does not
+        second = StreamingIngest(pipe, BATCH, pool=DevicePool(n=2),
+                                 engine=eng)
+        second.ingest(segs)
+        merged = eng.stats_metrics()
+        assert merged["cess_engine_stream_lanes"] == LANES
+        assert merged["cess_engine_stream_batches"] == 2
+    finally:
+        eng.close()
+
+
+def test_tag_kernel_types_its_output_under_checked_shard_map(monkeypatch):
+    """``_tags_3d``'s out_shape carries the data operand's varying axes.
+    Traced (not run) with the kernel lowered as for the TPU: the
+    interpreter's own grid slicing is refused by the check, for a
+    reason that has nothing to do with the kernel's typing."""
+    monkeypatch.setattr(target, "interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        pipe = make_pipe()
+        mesh = make_mesh(jax.devices()[:4], seg=4, byte=1)
+        ids = jnp.arange(8, dtype=jnp.int32)
+        frags = jnp.asarray(rnd((8, FRAG), 11))
+
+        def tag(i, f):
+            out = podr2.tag_fragments(pipe.podr2_key, i, f)
+            assert jax.typeof(out).vma == frozenset({"seg"})
+            return out
+
+        mapped = jax.shard_map(tag, mesh=mesh, in_specs=(P("seg"), P("seg")),
+                               out_specs=P("seg"))        # check_vma on
+        text = str(jax.make_jaxpr(mapped)(ids, frags))
+        assert podr2_pallas.KERNEL_NAME in text
+    finally:
+        jax.clear_caches()      # no TPU-lowered trace serves a later test

@@ -1,5 +1,5 @@
-"""The main path's Pallas kernels and the engine's two audit programs,
-compiled at protocol widths for a
+"""The main path's Pallas kernels, the engine's two audit programs and
+the pooled stream step over four chips, compiled at protocol widths for a
 DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
 refuses here what it would refuse on the chip — a kernel Mosaic cannot
 lower, a program that does not fit 16 GiB of HBM — at no chip time.
@@ -22,12 +22,14 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
 
 from cess_tpu import constants
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
 from cess_tpu.ops import gf, podr2, podr2_pallas, rs_pallas, rs_xor, \
     target, xor_sched
+from cess_tpu.parallel import mesh as pmesh
 from cess_tpu.serve import engine
 
 MiB = 1 << 20
@@ -138,6 +140,17 @@ CASES = [
 ]
 
 
+def _fits_hbm(compiled):
+    """The program's bytes on one chip, against its HBM."""
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    print(f"temp {mem.temp_size_in_bytes / MiB:.0f} MiB, arguments "
+          f"{mem.argument_size_in_bytes / MiB:.0f} MiB, outputs "
+          f"{mem.output_size_in_bytes / MiB:.0f} MiB")     # pytest -s
+    assert total < HBM_BYTES, mem
+
+
 @pytest.mark.parametrize("build,shapes,kernels",
                          [pytest.param(b, s, k, id=i)
                           for i, b, s, k in CASES])
@@ -151,13 +164,7 @@ def test_kernel_compiles_for_v5e(one_chip, for_tpu, build, shapes, kernels):
     assert "tpu_custom_call" in text
     for name in kernels:
         assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", text), name
-    mem = compiled.memory_analysis()
-    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-             + mem.output_size_in_bytes)
-    print(f"temp {mem.temp_size_in_bytes / MiB:.0f} MiB, arguments "
-          f"{mem.argument_size_in_bytes / MiB:.0f} MiB, outputs "
-          f"{mem.output_size_in_bytes / MiB:.0f} MiB")     # pytest -s
-    assert total < HBM_BYTES, mem
+    _fits_hbm(compiled)
 
 
 # the submission engine's two audit programs (serve/engine.py) at the
@@ -193,3 +200,29 @@ def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
               f"{mem.argument_size_in_bytes / MiB:.1f} MiB")  # pytest -s
         assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes) < HBM_BYTES, mem
+
+
+def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):
+    """The four-lane host's program (benchmark cell stream-4p8.pool4):
+    sharded_stream_step on a (4, 1) mesh at RS(4,8), 32 segments a batch.
+    Each chip must hold the one-chip step's two kernels under their
+    pinned names, and the step needs no collective."""
+    cfg = PipelineConfig(k=4, m=8, segment_size=constants.SEGMENT_SIZE,
+                         strategy="pallas")
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("seg", "byte"))
+    step = pmesh.sharded_stream_step(StoragePipeline(cfg), mesh)
+    args = [
+        jax.ShapeDtypeStruct((32, 4, 4 * MiB), jnp.uint8,
+                             sharding=NamedSharding(
+                                 mesh, P("seg", None, "byte"))),
+        jax.ShapeDtypeStruct((32, 12), jnp.int32,
+                             sharding=NamedSharding(mesh, P("seg", None)))]
+    t0 = time.perf_counter()
+    compiled = jax.jit(step).lower(*args).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    text = compiled.as_text()
+    for name in (RS, TAGS):
+        assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", text), name
+    assert not re.search(r"all-reduce|all-gather|all-to-all|"
+                         r"collective-permute|reduce-scatter", text)
+    _fits_hbm(compiled)
