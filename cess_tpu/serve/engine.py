@@ -253,6 +253,22 @@ _VERIFY_PROGRAM = jax.jit(_verify_missions,
                           static_argnames=("num_blocks", "prf_impl"))
 
 
+@jax.jit
+def _linear_rows(out):
+    """A byte result ``[rows, r, n]`` as its ``rows * r`` rows, each a
+    1-D array: the shape in which bytes leave the device. The TPU packs
+    four rows of the second-minor dimension into each 32-bit word, so
+    ``u8[1, 1, n]`` is a buffer of 4 n bytes with the fragment strided
+    through it, and the runtime's device -> host copy of it takes 41 ms
+    for 8 MiB where a 1-D ``u8[n]`` (dense, no packing across rows)
+    takes 1.7 ms (PERF.md, PR 28). Index forms only: dropping unit
+    dimensions compiles in well under a second, where a ``reshape``
+    that moves bytes between dimensions compiles in time proportional
+    to the array (models/pipeline.py split_rows)."""
+    return tuple(out[i, j] for i in range(out.shape[0])
+                 for j in range(out.shape[1]))
+
+
 class SubmissionEngine:
     """See module docstring. Construct via :func:`make_engine` or pass
     an ``ErasureCodec`` (ops/rs.py gate) and optionally an
@@ -622,11 +638,20 @@ class SubmissionEngine:
         Populates the engine program cache under the exact keys
         ``_op_repair`` will look up, and — when the codec supports it
         (TPUCodec.warm_reconstruct) — AOT-compiles the underlying
-        reconstruct program with its decode matrix baked in."""
+        reconstruct program with its decode matrix baked in. The
+        flatten a host claim's result leaves the device through
+        (_fetch_linear) is warmed for the same shapes and devices, by
+        one run over zeros."""
         self._need_codec()
         warm = getattr(self.codec, "warm_reconstruct", None)
         pool = self.pool
         lanes = pool.lanes if pool is not None else ()
+
+        def warm_fetch(shape, lane=None):
+            with self._lane_placement(lane, False):
+                jax.block_until_ready(self._linear_rows_program(
+                    shape, lane)(jnp.zeros(shape, jnp.uint8)))
+
         for present, missing in patterns:
             present, missing = tuple(present), tuple(missing)
             for b in buckets:
@@ -644,6 +669,7 @@ class SubmissionEngine:
                     ("repair", present, missing, n, bucket) + meta,
                     lambda p=present, mi=missing:
                         (lambda a: self.codec.reconstruct(a, p, mi)))
+                warm_fetch((bucket, len(missing), n))
                 # pool path: pre-populate EVERY lane's slice of the
                 # cache under the device-component keys _op_repair
                 # will look up, and AOT-compile per lane device — a
@@ -661,6 +687,7 @@ class SubmissionEngine:
                         lambda p=present, mi=missing:
                             (lambda a: self.codec.reconstruct(a, p,
                                                               mi)))
+                    warm_fetch((bucket, len(missing), n), lane)
         # regen leg: when the codec carries the symbol surface
         # (RegenCodec.warm_fold), warm the helper-fold programs for
         # every coefficient the single-missing patterns can ask for —
@@ -1456,10 +1483,12 @@ class SubmissionEngine:
         return True
 
     # -- op runners (batcher thread only) -------------------------------
-    def _split_rows(self, batch: list[_Request], out) -> list:
+    def _split_rows(self, batch: list[_Request], out, lane=None) -> list:
         """Slice a batch result back per request. Device submitters get
         ``jax.Array`` slices (no host materialization anywhere on their
-        path); an all-host batch is fetched ONCE and sliced as numpy.
+        path); an all-host batch is fetched ONCE and sliced as numpy —
+        a byte result ``[rows, r, n]`` as linear rows (_fetch_linear),
+        every other result whole.
 
         The result is synced BEFORE futures resolve: zero-copy means
         no D2H transfer, not fire-and-forget — a future must mean
@@ -1475,7 +1504,10 @@ class SubmissionEngine:
         with self._stage(cls, "fetch"):
             if isinstance(out, jax.Array) \
                     and not any(r.device for r in batch):
-                out = np.asarray(out)
+                if out.dtype == np.uint8 and out.ndim == 3:
+                    out = self._fetch_linear(cls, out, lane)
+                else:
+                    out = np.asarray(out)
             results, off = [], 0
             for r in batch:
                 piece = out[off:off + r.rows]
@@ -1486,6 +1518,25 @@ class SubmissionEngine:
                 results.append(piece[0] if r.squeeze else piece)
                 off += r.rows
         return results
+
+    def _linear_rows_program(self, shape: tuple, lane):
+        """The cache entry of the flatten for one result shape (and,
+        on the pool path, one lane: jit compiles it per device)."""
+        return self.programs.get(
+            self._key(("linear_rows",) + shape, False, lane),
+            lambda: _linear_rows)
+
+    def _fetch_linear(self, cls: str, out: jax.Array, lane) -> np.ndarray:
+        """Fetch a byte result ``[rows, r, n]`` as ``rows * r`` linear
+        rows (_linear_rows, on the device the result is on) and put the
+        C-contiguous ``np.uint8 [rows, r, n]`` back together on the
+        host: a view of the one row, else one ``memcpy`` a row."""
+        flat = [np.asarray(row)
+                for row in self._linear_rows_program(out.shape, lane)(out)]
+        with self._lock:
+            self.stats.classes[cls].linear_fetches += 1
+        whole = flat[0] if len(flat) == 1 else np.stack(flat)
+        return whole.reshape(out.shape)
 
     def _rs_backend(self, degraded: bool):
         """The ErasureCodec serving this batch: the configured device
@@ -1559,7 +1610,7 @@ class SubmissionEngine:
                                                degraded, lane) + meta,
                                      lambda: codec.encode)
             out = prog(data)[:total]
-        return self._split_rows(batch, out), bucket
+        return self._split_rows(batch, out, lane), bucket
 
     def _op_repair(self, batch, degraded=False, lane=None):
         codec = self._rs_backend(degraded)
@@ -1574,7 +1625,7 @@ class SubmissionEngine:
         with self._stage("repair", "dispatch"):
             out = self._repair_program(codec, kind, aux, n, bucket,
                                        degraded, lane)(surv)[:total]
-        return self._split_rows(batch, out), bucket
+        return self._split_rows(batch, out, lane), bucket
 
     def _repair_program(self, codec, kind: str, aux: dict, n: int,
                         bucket: int, degraded: bool, lane):
@@ -1632,7 +1683,7 @@ class SubmissionEngine:
                                                degraded, lane),
                                      lambda: audit.tag_fragments)
             out = prog(ids, frags)[:total]
-        return self._split_rows(batch, out), bucket
+        return self._split_rows(batch, out, lane), bucket
 
     def _op_verify_batch(self, batch, degraded=False, lane=None):
         audit = self._audit_backend(degraded, lane)
@@ -1654,7 +1705,7 @@ class SubmissionEngine:
                 lambda: (lambda i, u, s: audit.verify_batch(
                     i, num_blocks, idx, nu, u, s)))
             out = prog(ids, mu, sigma)[:total]
-        return self._split_rows(batch, out), bucket
+        return self._split_rows(batch, out, lane), bucket
 
     def _stacked_program(self, batch, fb: int, rb: int, degraded: bool,
                          lane, bind):

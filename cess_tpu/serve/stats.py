@@ -32,7 +32,9 @@ LATENCY_WINDOW = 512     # per-class sliding window for percentiles
 #   wait      jax.block_until_ready on the result: the device works,
 #             the host waits
 #   fetch     np.asarray of the result and the per-request slicing
-#             (prove: the release of the stacked host batch too)
+#             (a byte result [rows, r, n]: the flatten into linear
+#             rows, their np.asarray and the host reassembly; prove:
+#             the release of the stacked host batch too)
 #   resolve   accounting + resolving the members' futures
 # For a class whose batches hold one request, the stage seconds sum to
 # its submit -> resolve latency, within the clock reads between them.
@@ -42,8 +44,8 @@ STAGES = ("queue", "assemble", "dispatch", "wait", "fetch", "resolve")
 class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
                  "saturated", "shed", "batches", "batched_requests",
-                 "rows", "padded_rows", "operand_bytes", "latencies",
-                 "hist", "stage_n", "stage_s")
+                 "rows", "padded_rows", "operand_bytes", "linear_fetches",
+                 "latencies", "hist", "stage_n", "stage_s")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -61,6 +63,11 @@ class ClassStats:
         # batches are built on the host from what a round reads; the
         # concatenated classes leave it 0
         self.operand_bytes = 0
+        # batches whose result left the device as linear rows: an
+        # all-host batch's byte result [rows, r, n] (engine.py
+        # _fetch_linear); device submitters' and non-byte results
+        # (tags, verdicts) leave it 0
+        self.linear_fetches = 0
         self.latencies = collections.deque(maxlen=LATENCY_WINDOW)
         # real Prometheus histogram of the same submit->resolve
         # latencies: unlike the sliding-window percentiles above this
@@ -222,6 +229,7 @@ class EngineStats:
                 "batch_occupancy": round(st.occupancy, 4),
                 "pad_waste": round(st.pad_waste, 4),
                 "operand_bytes": st.operand_bytes,
+                "linear_fetches": st.linear_fetches,
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
                 "stages": {stage: {"n": st.stage_n[stage],

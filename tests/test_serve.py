@@ -194,6 +194,119 @@ def test_mixed_host_device_batch_coalesces(pkey):
         eng.close()
 
 
+# -- linear fetch: a host batch's byte result leaves the device as 1-D
+# rows and is put back together on the host (PERF.md, PR 28) ---------------
+
+@pytest.mark.parametrize("k,m,missing,claims,squeezed", [
+    (2, 1, (0,), 1, False),         # rows x r = 1 x 1: a view of the row
+    (2, 1, (0,), 2, False),         # 2 x 1: two claims coalesced
+    (4, 8, (0, 5), 1, False),       # 1 x 2: a two-row repair at RS(4,8)
+    (2, 1, (1,), 1, True),          # the squeezed [k, n] form
+])
+def test_reconstruct_fetched_as_linear_rows(k, m, missing, claims, squeezed):
+    """The repaired rows equal the reference codec's byte for byte, in
+    the shape, dtype and C-contiguity a host caller always got, and
+    every batch's result left the device as linear rows."""
+    from cess_tpu.ops.rs_ref import ReferenceCodec
+
+    ref = ReferenceCodec(k, m)
+    present = tuple(i for i in range(k + m) if i not in missing)[:k]
+    n = 384
+    coded = ref.encode(rnd((claims, k, n), 31))
+    eng = make_engine(k, m, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.25))
+    try:
+        survs = [coded[c][list(present)] if squeezed
+                 else coded[c:c + 1, list(present)] for c in range(claims)]
+        futs = [eng.submit_reconstruct(s, present, missing) for s in survs]
+        outs = [f.result(timeout=60) for f in futs]
+        for c, out in enumerate(outs):
+            want = ref.reconstruct(coded[c:c + 1, list(present)], present,
+                                   missing)
+            assert np.array_equal(want[0], coded[c, list(missing)])
+            if squeezed:
+                want = want[0]
+            assert isinstance(out, np.ndarray) and out.dtype == np.uint8
+            assert out.shape == want.shape == \
+                ((len(missing), n) if squeezed else (1, len(missing), n))
+            assert out.flags.c_contiguous
+            assert out.tobytes() == want.tobytes()
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["batches"] == 1 and st["batch_occupancy"] == claims
+        assert st["linear_fetches"] == st["batches"]
+        assert eng.stats.metrics()[
+            "cess_engine_repair_linear_fetches"] == 1
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("cls", ["encode", "tag"])
+def test_linear_fetch_only_for_host_byte_results(cls, pkey):
+    """A device submitter's slice never touches the host (jax.Array in,
+    jax.Array out) and a uint32 tag batch is fetched whole, as before:
+    neither counts a linear fetch nor runs the flatten."""
+    import jax
+    import jax.numpy as jnp
+
+    from cess_tpu.serve import engine as engine_mod
+
+    eng = make_engine(K, M, rs_backend="jax", podr2_key=pkey,
+                      policy=AdmissionPolicy(max_delay=0.005))
+    try:
+        flattens = engine_mod._linear_rows._cache_size()
+        if cls == "encode":
+            host = rnd((2, K, 256), 41)
+            out = eng.encode(jnp.asarray(host))
+            assert isinstance(out, jax.Array)
+            assert np.array_equal(
+                np.asarray(out),
+                rs.make_codec(K, M, backend="cpu").encode(host))
+        else:
+            frags = rnd((3, FRAG), 42)
+            ids = rnd((3, 2), 43, dtype=np.uint32)
+            out = eng.tag_fragments(ids, frags)
+            assert isinstance(out, np.ndarray) and out.dtype == np.uint32
+            assert np.array_equal(
+                out, np.asarray(podr2.tag_fragments(pkey, ids, frags)))
+        st = eng.stats_snapshot()["classes"][cls]
+        assert st["batches"] == 1 and st["linear_fetches"] == 0
+        assert engine_mod._linear_rows._cache_size() == flattens
+    finally:
+        eng.close()
+
+
+def test_warm_repair_warms_the_linear_fetch():
+    """After warm_repair a first claim (alone, or two coalesced: the
+    default buckets) builds no program: neither an engine cache entry
+    nor a compile of the flatten."""
+    from cess_tpu.serve import engine as engine_mod
+
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.25))
+    try:
+        n = 640                         # a width no other test flattens
+        eng.warm_repair([((1, 2), (0,))], n)
+        built = eng.stats_snapshot()["programs_built"]
+        flattens = engine_mod._linear_rows._cache_size()
+        assert {("linear_rows", 1, 1, n), ("linear_rows", 2, 1, n)} \
+            <= set(eng.programs._programs)
+        coded = rs.make_codec(K, M, backend="cpu").encode(
+            rnd((2, K, n), 51))
+        rec = eng.reconstruct(coded[:1, [1, 2]], (1, 2), (0,))
+        assert np.array_equal(rec[:, 0], coded[:1, 0])
+        futs = [eng.submit_reconstruct(coded[c:c + 1, [1, 2]], (1, 2),
+                                       (0,)) for c in range(2)]
+        for c, f in enumerate(futs):
+            assert np.array_equal(f.result(timeout=60)[0, 0], coded[c, 0])
+        st = eng.stats_snapshot()
+        assert st["classes"]["repair"]["linear_fetches"] == \
+            st["classes"]["repair"]["batches"] == 2
+        assert st["programs_built"] == built
+        assert engine_mod._linear_rows._cache_size() == flattens
+    finally:
+        eng.close()
+
+
 def test_pipeline_engine_path_returns_device_arrays(pkey):
     """StoragePipeline -> engine -> device is one handoff: the engine
     path hands back jax.Array results identical to the direct path."""

@@ -1,5 +1,6 @@
-"""The main path's Pallas kernels, the engine's two audit programs and
-the pooled stream step over four chips, compiled at protocol widths for a
+"""The main path's Pallas kernels, the engine's two audit programs, its
+flatten of a byte result into linear rows and the pooled stream step
+over four chips, compiled at protocol widths for a
 DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
 refuses here what it would refuse on the chip — a kernel Mosaic cannot
 lower, a program that does not fit 16 GiB of HBM — at no chip time.
@@ -200,6 +201,31 @@ def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
               f"{mem.argument_size_in_bytes / MiB:.1f} MiB")  # pytest -s
         assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes) < HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("shape,packed", [
+    pytest.param((1, 1, 8 * MiB), 4, id="one-claim-rs2p1"),
+    pytest.param((2, 1, 8 * MiB), 2, id="two-claims-rs2p1"),
+    pytest.param((1, 4, 4 * MiB), 1, id="four-rows-rs4p8")])
+def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, packed):
+    """The engine's flatten (serve/engine.py _linear_rows) at the shapes
+    of a repair's result: it compiles fast (index forms only, no
+    relayouting reshape), every row comes out 1-D and dense, and the
+    argument is ``packed`` times its logical bytes on the device (four
+    rows to a 32-bit word: a lone row fills a quarter of each) — the
+    layout fact the linear fetch rests on (PERF.md, PR 28): a compiler
+    that stops packing rows into words should be noticed."""
+    rows, r, n = shape
+    t0 = time.perf_counter()
+    compiled = engine._linear_rows.lower(
+        jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [o.shape for o in outs] == [(n,)] * (rows * r)
+    mem = compiled.memory_analysis()
+    # dense rows: their logical bytes, plus the table of a tuple result
+    assert 0 <= mem.output_size_in_bytes - rows * r * n < 4096, mem
+    assert mem.argument_size_in_bytes == packed * rows * r * n, mem
 
 
 def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):

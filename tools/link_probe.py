@@ -1,0 +1,100 @@
+#!/usr/bin/env python
+"""Link probe: what one 8 MiB fragment costs to cross the device <-> host
+link in each shape the engine could hold it in (PERF.md section 5, PR 28).
+
+    chiprun -- python tools/link_probe.py
+
+d2h: median of REPS ``np.asarray`` calls, each on a fresh array that
+``block_until_ready`` has declared complete (a ``jax.Array`` keeps its host
+copy: a second fetch would time nothing). "as rows": the same bytes as that
+many ``u8[N]``. "flatten + fetch": the engine's path, ``[rows, r, N]``
+through one jitted program that returns its rows 1-D, then those fetched.
+h2d: ``jax.device_put`` + ``block_until_ready``. A CPU run says nothing.
+"""
+import statistics
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+N = 8 << 20          # one RS(2,1) fragment
+REPS = 20
+
+
+def _fresh(shape):
+    """A jitted maker of distinct u8 arrays of ``shape`` (elementwise over
+    the final shape: no reshape the TPU compiler would have to relayout)."""
+    def make(i):
+        x = jax.lax.broadcasted_iota(jnp.uint32, shape, len(shape) - 1)
+        for d in range(len(shape) - 1):
+            x = x + 7919 * jax.lax.broadcasted_iota(jnp.uint32, shape, d)
+        return ((x * jnp.uint32(2654435761) + i) >> 13).astype(jnp.uint8)
+    return jax.jit(make)
+
+
+def _median_ms(prepare, timed):
+    out = []
+    for i in range(REPS + 2):
+        arg = jax.block_until_ready(prepare(i))
+        t0 = time.perf_counter()
+        timed(arg)
+        out.append((time.perf_counter() - t0) * 1e3)
+    return statistics.median(out[2:]), min(out[2:])   # two calls warm up
+
+
+def _fetch_all(rows):
+    return [np.asarray(a) for a in rows]
+
+
+def whole(*shape):
+    return _median_ms(_fresh(shape), np.asarray)
+
+
+def as_rows(count):
+    one = _fresh((N,))
+    make = jax.jit(lambda i: tuple(one(i + j) for j in range(count)))
+    return _median_ms(make, _fetch_all)
+
+
+def flat(*shape):
+    """The flatten: ``[rows, r, N]`` -> rows * r arrays ``u8[N]``."""
+    rows = jax.jit(lambda a: tuple(a[i, j].reshape(-1)
+                                   for i in range(shape[0])
+                                   for j in range(shape[1])))
+    return _median_ms(_fresh(shape),
+                      lambda a: _fetch_all(jax.block_until_ready(rows(a))))
+
+
+def put(*shapes):
+    rng = np.random.default_rng(28)
+    host = [rng.integers(0, 256, s, dtype=np.uint8) for s in shapes]
+    return _median_ms(lambda i: host, lambda hs: jax.block_until_ready(
+        [jax.device_put(h) for h in hs]))
+
+
+def main():
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}; "
+          f"N = {N}; median (min) of {REPS}, ms")
+    table = [("d2h u8[1,1,N]", 1, whole(1, 1, N)),
+             ("d2h u8[1,N]", 1, whole(1, N)),
+             ("d2h u8[N]", 1, whole(N)),
+             ("d2h u8[32,N/32]", 1, whole(32, N // 32)),
+             ("d2h u8[1,1,N] flatten + fetch", 1, flat(1, 1, N)),
+             ("d2h u8[2,1,N]", 2, whole(2, 1, N)),
+             ("d2h 2 x u8[N] as rows", 2, as_rows(2)),
+             ("d2h u8[2,1,N] flatten + fetch", 2, flat(2, 1, N)),
+             ("d2h u8[4,3,N]", 12, whole(4, 3, N)),
+             ("d2h 12 x u8[N] as rows", 12, as_rows(12)),
+             ("d2h u8[4,3,N] flatten + fetch", 12, flat(4, 3, N)),
+             ("h2d u8[1,2,N]", 2, put((1, 2, N))),
+             ("h2d 2 x u8[N]", 2, put((N,), (N,)))]
+    print(f"{'what':<34}{'MiB':>5}{'median':>10}{'min':>10}{'GiB/s':>8}")
+    for what, frags, (med, low) in table:
+        print(f"{what:<34}{frags * N >> 20:>5}{med:>10.3f}{low:>10.3f}"
+              f"{frags * N / 2**30 / (med / 1e3):>8.2f}")
+
+
+if __name__ == "__main__":
+    main()
