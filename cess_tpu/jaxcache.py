@@ -2,9 +2,9 @@
 
 The fused encode+tag program takes minutes to compile for the TPU and
 every process would pay that again. ``enable()`` is called by the
-entry points (chip_smoke.py, bench.py, node/cli.py) before the first
-compile: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
-itself and nothing is set here; otherwise the cache lives at the
+entry points (chip_smoke.py, benchmark/run.py, node/cli.py) before
+the first compile: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+reads it itself and nothing is set here; otherwise the cache lives at the
 fixed path ``<checkout>/.jax_cache`` (the path is part of the cache
 key, so it never carries a temporary name, a pid or a time). Either
 way every program is kept, however quick its compile: the data plane

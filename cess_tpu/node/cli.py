@@ -213,7 +213,7 @@ def main(argv=None) -> int:
                          "per-tenant accounting, and weighted-fair "
                          "dequeue. TARGETS is ';'-separated "
                          "<class>:p99=<dur>[,err=<rate>] (e.g. "
-                         "'verify:p99=50ms,err=1%;encode:p99=2s'); "
+                         "'verify:p99=50ms,err=1%%;encode:p99=2s'); "
                          "omitted = the default targets. Gauges "
                          "appear as cess_slo_*/cess_tenant_* on GET "
                          "/metrics and via the cess_sloStatus RPC. "
@@ -257,15 +257,15 @@ def main(argv=None) -> int:
                          "unified pad ledger (engine bucket padding + "
                          "stream ragged tails in ONE account), "
                          "program-cache compile events, and a "
-                         "bench-anchored PerfWatchdog that "
-                         "edge-triggers a perf-regression incident "
-                         "when live windowed throughput drops below a "
-                         "guard fraction of the checked-in bench "
-                         "record. BASELINE is a bench_diff "
-                         "--baseline-out artifact (bare --profile "
-                         "scans ./BENCH_r*.json for the newest "
-                         "round; no record found = profiling without "
-                         "judging). Served via the cess_profileDump "
+                         "PerfWatchdog that edge-triggers a "
+                         "perf-regression incident when live "
+                         "windowed throughput drops below a guard "
+                         "fraction of BASELINE, a baseline artifact "
+                         '({"metrics": {name: {"value": v}}}, as '
+                         "tests/data/bench_baseline_r05.json). Bare "
+                         "--profile has no baseline: profiling "
+                         "without judging, watchdog off. Served via "
+                         "the cess_profileDump "
                          "RPC and cess_profile_* gauges on GET "
                          "/metrics (render with tools/"
                          "profile_view.py). Requires --engine; "
@@ -876,13 +876,11 @@ def _make_cli_engine(args, spec):
     if profile_spec is not None:
         from ..obs import profile as obs_profile
 
-        # --profile=PATH: a bench_diff --baseline-out artifact; bare
-        # --profile: the newest checked-in BENCH_r*.json round. No
-        # record found = an unanchored plane (profiling without
-        # judging) — the ledgers still fill, the watchdog stays inert.
+        # --profile=PATH: a baseline artifact anchors the watchdog;
+        # bare --profile: an unanchored plane (profiling without
+        # judging) — the ledgers still fill, there is no watchdog.
         baseline = (obs_profile.load_baseline(profile_spec)
-                    if profile_spec
-                    else obs_profile.latest_bench_baseline())
+                    if profile_spec else None)
         profile = obs_profile.ProfilePlane(baseline=baseline)
     k = max(spec.fragment_count - 1, 1)      # reference RS(k, 1) shape
     # --pool = all local devices; --pool=N = the first N lanes

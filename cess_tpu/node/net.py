@@ -132,6 +132,7 @@ class _Conn:
         self.sock = sock
         self.alive = True
         self.inbound = inbound
+        self.dial_port: int | None = None   # outbound: the port dialed
         self.warp_requested = False   # gate for warp_response acceptance
         self.dropped = 0
         self.rx = 0                   # frames received (dial liveness)
@@ -319,6 +320,24 @@ class NodeService:
                 self._dialing.add(p)
             self._spawn(self._dial_loop, p)
 
+    def _prune_stale_dials(self) -> None:
+        """Hold the out-degree to ``degree//2``: while more outbound
+        links are alive than that, close those whose port is no longer
+        a ring target. A dial loop re-checks its target only between
+        connections, so a link dialed under an earlier, smaller peer
+        set outlives the ring that chose it; enough of them fill the
+        total-connection cap of ``_accept_loop``, slack slot included,
+        and a late joiner whose only known peer is this node is
+        refused for good: two halves that never meet. A node at or
+        under the bound drops nothing, so a ring that slid past a
+        cooling port keeps its substitute link."""
+        targets = set(self._dial_targets())
+        out = [c for c in list(self.conns)
+               if c.alive and c.dial_port is not None]
+        stale = [c for c in out if c.dial_port not in targets]
+        for c in stale[:max(0, len(out) - max(1, self.degree // 2))]:
+            c.close()
+
     def _discover(self, ports) -> None:
         """Peer exchange: learn listen ports, then let the ring rule
         decide which to dial. Bounded by max_peers — an
@@ -343,6 +362,7 @@ class NodeService:
         number of deterministic rounds — a frame lost while a link was
         half-up is re-offered next round."""
         while not self._stop.wait(self.discovery_interval):
+            self._prune_stale_dials()
             self._redial()
             with self.lock:
                 known = (self.port, *sorted(self._known_peers))
@@ -443,6 +463,7 @@ class NodeService:
                     return
                 continue
             conn = _Conn(sock)
+            conn.dial_port = port
             self.conns.append(conn)
             self._send_status(conn)
             with self.lock:

@@ -32,9 +32,8 @@ Five modules, one contract (zero-cost when off, deterministic when on):
               deterministic replay witness.
 
 Wire-up: ``node.cli --trace[=PATH] --slo[=TARGETS] --flight[=DIR]``,
-``serve.make_engine(tracer=..., slo=...)``, ``bench.py --trace``, and
-the ``cess_traceDump`` / ``cess_sloStatus`` / ``cess_incidentDump``
-RPCs.
+``serve.make_engine(tracer=..., slo=...)``, and the
+``cess_traceDump`` / ``cess_sloStatus`` / ``cess_incidentDump`` RPCs.
 """
 from .prom import (LATENCY_BUCKETS_S, Histogram, escape_label,
                    format_labels, format_le, render_histogram)

@@ -106,9 +106,9 @@ def lag_state(lag: int) -> str:
 def node_state(node) -> dict:
     """Build one consensus-state dict from a live ``network.Node`` —
     the unit :meth:`ChainWatch.ingest_state` consumes, what rides the
-    fleet gossip frame under the ``"chain"`` key, and what bench.py
-    synthesizes for 100 fake nodes. Duck-typed on purpose: obs/ never
-    imports node/."""
+    fleet gossip frame under the ``"chain"`` key, and what
+    tests/test_chainwatch.py synthesizes for its fake nodes. Duck-typed
+    on purpose: obs/ never imports node/."""
     head = node.head()
     headn = int(head.number)
     chain = node.chain

@@ -1,14 +1,15 @@
 """Continuous performance profiling: device-time attribution,
-pad/compile ledgers, and a bench-anchored regression watchdog.
+pad/compile ledgers, and a baseline-anchored regression watchdog.
 
 The observability stack below this module can say *that* the serving
 plane is unhealthy (SLO burn, breaker trips, fleet quorum views) but
 not *where device time goes*. This module closes that gap: the
 serving plane continuously profiles itself — per-shape stage
 breakdowns, padded-row accounts, program-cache compile events — and
-compares its live windowed throughput against the newest checked-in
-``BENCH_r*.json`` record, so a kernel regression fires an incident
-instead of waiting for a human to run ``bench_diff --history``:
+compares its live windowed throughput against a baseline artifact it
+was handed (:func:`load_baseline`), so a kernel regression fires an
+incident instead of waiting for a human to compare two runs. Without
+an artifact the plane profiles and judges nothing:
 
 - :class:`OpProfiler` — per-(class, bucket-shape, device) accounting
   of every engine dispatch: stage breakdown (queue-wait / h2d /
@@ -29,10 +30,10 @@ instead of waiting for a human to run ``bench_diff --history``:
   (a shape churn defeating the cache) becomes a visible ranked
   account instead of a mystery latency cliff.
 
-- :class:`PerfWatchdog` — per tracked bench metric, accumulates
+- :class:`PerfWatchdog` — per tracked metric, accumulates
   (bytes, busy-seconds) over observation-COUNT windows and
   edge-triggers an ok↔regressed transition when a window's GiB/s
-  falls below ``guard`` × the bench baseline. Transitions announce
+  falls below ``guard`` × the baseline's value. Transitions announce
   exactly like FleetBoard's: a ``perf.regression`` span plus a
   ``("perf", "regression")`` flight note delivered FIFO outside the
   watchdog lock — the ``perf-regression`` incident trigger
@@ -63,10 +64,7 @@ produce byte-identical witnesses (tests/test_profile.py).
 from __future__ import annotations
 
 import collections
-import glob
 import json
-import os
-import re
 import threading
 
 from . import flight as _flight
@@ -84,54 +82,13 @@ TRACKED_DEFAULT = {
     "stream": "stream_encode_tag_GiBps",
 }
 
-_ROUND_RE = re.compile(r"BENCH_r0*(\d+)\.json$")
-
-
 # -- baseline loading --------------------------------------------------------
 
-def _rows_of(text: str) -> dict:
-    """``{metric: value}`` from bench.py JSONL output (one JSON object
-    per line; non-JSON lines and rows without a finite value skipped —
-    a truncated tail must not wedge the watchdog)."""
-    out: dict = {}
-    for ln in text.splitlines():
-        ln = ln.strip()
-        if not ln.startswith("{"):
-            continue
-        try:
-            row = json.loads(ln)
-        except ValueError:
-            continue
-        if not isinstance(row, dict) or "metric" not in row:
-            continue
-        try:
-            val = float(row.get("value"))
-        except (TypeError, ValueError):
-            continue
-        if val == val:                          # NaN never baselines
-            out[str(row["metric"])] = val
-    return out
-
-
-def parse_bench_record(path: str) -> dict:
-    """``{metric: value}`` from one bench record — either the round
-    wrapper ``{"n":..,"cmd":..,"rc":..,"tail": "<JSONL>"}`` the repo
-    checks in as ``BENCH_r*.json``, or raw bench.py JSONL."""
-    with open(path) as f:
-        text = f.read()
-    try:
-        payload = json.loads(text)
-    except ValueError:
-        payload = None
-    if isinstance(payload, dict) and isinstance(payload.get("tail"), str):
-        return _rows_of(payload["tail"])
-    return _rows_of(text)
-
-
 def load_baseline(path: str) -> dict:
-    """``{metric: value}`` from a ``bench_diff --baseline-out``
-    artifact (``{"source":.., "round":.., "metrics": {m: {"value":
-    v, ...}}}``). Raises ValueError when the file is not one."""
+    """``{metric: value}`` from a baseline artifact (``{"source":..,
+    "round":.., "metrics": {m: {"value": v, ...}}}``; the shape of
+    tests/data/bench_baseline_r05.json). Raises ValueError when the
+    file is not one."""
     with open(path) as f:
         payload = json.load(f)
     metrics = payload.get("metrics") if isinstance(payload, dict) else None
@@ -143,19 +100,6 @@ def load_baseline(path: str) -> dict:
         val = entry.get("value") if isinstance(entry, dict) else entry
         out[str(name)] = float(val)
     return out
-
-
-def latest_bench_baseline(root: str = ".") -> dict:
-    """``{metric: value}`` from the newest-round ``BENCH_r*.json``
-    under ``root`` (the watchdog's default anchor). ``{}`` when the
-    directory holds no bench records — an unanchored watchdog stays
-    inert rather than guessing."""
-    best, best_rnd = None, -1
-    for path in glob.glob(os.path.join(root, "BENCH_r*.json")):
-        m = _ROUND_RE.search(os.path.basename(path))
-        if m and int(m.group(1)) > best_rnd:
-            best_rnd, best = int(m.group(1)), path
-    return {} if best is None else parse_bench_record(best)
 
 
 # -- stage-level accounting --------------------------------------------------

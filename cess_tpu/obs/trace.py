@@ -18,9 +18,9 @@ Design contracts, in priority order:
   tracer armed every hook is one module-global load and a ``None``
   check, and returns the process-wide :data:`NOOP_SPAN` singleton — no
   span object, no dict, no clock read is allocated on the disabled
-  path. tier-1 pins the singleton identity (tests/test_obs.py) and
-  bench.py records the armed-vs-off overhead on the streamed path
-  (``trace_overhead_frac``).
+  path. tier-1 pins the singleton identity (tests/test_obs.py); what
+  an armed tracer costs on the chip is not measured (ROADMAP.md,
+  Speed 8).
 - **Deterministic span ids**: ids come from a per-tracer counter, and
   a trace id is fixed at construction — no wall clock, no randomness
   in identities — so two replays of the same workload under the same
@@ -41,8 +41,8 @@ Design contracts, in priority order:
 Exports: :meth:`Tracer.export_chrome` emits Chrome trace-event JSON
 (one ``"X"`` complete event per span — load it in Perfetto or
 chrome://tracing), the ``cess_traceDump`` RPC serves the same dump
-from a live node, and ``node.cli --trace[=PATH]`` /
-``bench.py --trace`` arm a tracer for a whole run.
+from a live node, and ``node.cli --trace[=PATH]`` arms a tracer for
+a whole run.
 
 Stage spans (:func:`stage`) are the one way the program writes into a
 profiler trace: every stage of an engine batch, a gateway upload and a

@@ -249,8 +249,9 @@ def _placement_device():
 def default_strategy() -> Strategy:
     """Pick the lowering for the current default backend.
 
-    The MXU bit-matrix path wins on TPU (measured in bench.py); the
-    gather path is the portable fallback (CPU test mesh, older chips).
+    Every cell of the benchmark runs ``pallas`` on the chip (every
+    line of PERF_LEDGER.jsonl; PERF.md section 5); ``gather`` is the
+    CPU path (the test mesh, the degraded fallback).
     """
     return "gather" if jax.default_backend() == "cpu" else "pallas"
 
@@ -273,9 +274,9 @@ class TPUCodec:
         self._parity_apply = _MatrixApply(gf.cauchy_parity_matrix(k, m), self.strategy)
         self._cache: dict[tuple, _MatrixApply] = {}
         self._warm: dict[tuple, Callable] = {}   # AOT repair programs
-        # observable warm-path dispatches: lets callers (bench.py's
-        # fragment_repair_warm_p99_ms, tests) PROVE the warm program
-        # ran rather than a silent fallback to the cold jit path
+        # observable warm-path dispatches: lets callers (the tests)
+        # PROVE the warm program ran rather than a silent fallback to
+        # the cold jit path
         self.warm_hits = 0
 
     # -- encode -------------------------------------------------------------
@@ -316,8 +317,8 @@ class TPUCodec:
         executable now, so a later ``reconstruct`` with this pattern
         and shape dispatches the compiled program directly — no jit
         cache lookup, no tracing, no first-call compile in the latency
-        budget (bench.py fragment_repair_warm_p99_ms measures the
-        difference).
+        budget (the benchmark's repair cell warms its three patterns
+        in set-up and counts 0 compilations in its window).
 
         ``device`` pins the device the executable is compiled for
         (the device-pool path warms once per lane); None warms for

@@ -222,6 +222,56 @@ class TestChainWatch:
         w.seal_round()
         assert w.anomalies.active().get("finality-stall", []) == []
 
+    def test_hundred_node_scan_finds_the_one_double_signer(self):
+        """Five sealed rounds over 100 synthesized node states and an
+        eight-miner market: node 7 lags and claims a twin block at
+        every head, so the scan must come back with equivocation
+        evidence and active anomalies — a silently empty scan at fleet
+        scale cannot pass."""
+        from cess_tpu.obs.chainwatch import TAIL
+
+        def state(i, rnd):
+            h = (i * 2654435761 + rnd * 40503) & 0xFFFF
+            head = rnd * 3 + (h % 2)
+            finalized = max(0, head - (6 if i == 7 else h % 3))
+            blocks = [[f"v{i % 4}", head, f"b{i % 5}-{head}"]]
+            if i == 7:
+                blocks.append([f"v{i % 4}", head, f"b-twin-{head}"])
+            return _state(
+                head, finalized, slot=head + 1, era=head // 10,
+                forks=h % 3, blocks=blocks,
+                locks=[["acct", max(0, head - 2)]],
+                tail={str(n): f"{i % 5}-{n}"
+                      for n in range(max(0, head - TAIL), head + 1)})
+
+        def market(rnd):
+            return {
+                "miners": {f"m{j}": {"idle": 1 << 28,
+                                     "service": j << 23, "lock": 0,
+                                     "state": "positive",
+                                     "audited": j << 23}
+                           for j in range(8)},
+                "verdicts": {f"m{j}": [int((j + k + rnd) % 4 != 0)
+                                       for k in range(8)]
+                             for j in range(8)},
+                "restoral": {"open": rnd % 2, "claimed": 0,
+                             "generated": rnd, "claims": rnd,
+                             "completed": rnd}}
+
+        w = ChainWatch("fleet")
+        for rnd in range(6):
+            for i in range(100):
+                w.ingest_state(f"n{i:03d}", state(i, rnd))
+            w.ingest_market(market(rnd))
+            w.seal_round()
+        snap = w.snapshot()
+        assert w.metrics()["cess_chain_nodes"] == 100.0
+        assert len(snap["consensus"]["equivocations"]) >= 1
+        assert snap["anomalies"]["anomalies"] >= 1
+        assert len(snap["market"]["miners"]) == 8
+        assert any(k.startswith("v3@")
+                   for k in w.anomalies.active()["equivocation"])
+
     def test_ingest_frame_survives_hostile_peers(self):
         w = ChainWatch("probe")
         for frame in (None, 42, ("inst",), ("inst", None, "not-json"),

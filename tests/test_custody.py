@@ -238,6 +238,37 @@ class TestPlaneSealing:
         json.dumps(plane.snapshot())
 
 
+    def test_hundred_miner_fold_keeps_its_floor(self):
+        """128 RS(4, 4) segments through the real record_* seams over
+        100 miners, three of them dead; segment 0 sits on the dead
+        three, so the fold's floor is margin 1: the at-risk detector
+        holds a real edge through every round and nothing is lost. (A
+        fold that loses or invents healthy fragments moves the floor.)"""
+        k, m, n_miners, segments = 4, 4, 100, 128
+        plane = CustodyPlane("fold", fragment_cap=segments * (k + m))
+        for s in range(segments):
+            file_hex = f"{s:064x}"
+            frags = tuple(f"{s:060x}{r:04x}" for r in range(k + m))
+            plane.ledger.record_dispatch("fold", file_hex, k, m,
+                                         [(f"{s:063x}f", frags)])
+            for r, fh in enumerate(frags):
+                miner = f"m{(r if s == 0 else s * (k + m) + r) % n_miners}"
+                plane.ledger.record_transfer(miner, file_hex, r, (fh,))
+                plane.ledger.record_verdict(miner, s, True, True, (fh,))
+        alive = {f"m{j}": j >= 3 for j in range(n_miners)}
+        for _ in range(3):
+            plane.observe_alive(alive)
+            plane.observe_restorals(())
+            plane.seal_round()
+        margins = plane.margins()
+        assert len(margins) == segments
+        assert min(margins.values()) == 1 == AT_RISK_MARGIN
+        assert margins[f"{0:064x}:0"] == 1
+        snap = plane.snapshot()
+        assert len(snap["at_risk"]) >= 1 and len(snap["lost"]) == 0
+        assert plane.detector.active().get("lost", []) == []
+
+
 # -- MarketWatch cross-check (satellite) --------------------------------------
 class TestMarketDivergence:
     def _held_plane(self, miner, service):

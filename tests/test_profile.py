@@ -13,9 +13,9 @@
 - zero-cost-when-off: a disarmed engine holds no profile plane, the
   program cache times nothing, and no ``cess_profile_*`` key reaches
   GET /metrics;
-- baseline loaders parse driver ``BENCH_r*.json`` round wrappers and
-  the ``bench_diff --baseline-out`` artifact (fixture under
-  tests/data/), and an unanchored watchdog stays inert;
+- ``load_baseline`` reads the baseline artifact (fixture under
+  tests/data/) and refuses anything else, and an unanchored plane has
+  no watchdog;
 - wire-up: the ``cess_profileDump`` RPC, the ``node.cli --profile``
   flag (requires ``--engine``), and ``Scenario.profile=True`` riding
   ``SimReport``.
@@ -52,64 +52,12 @@ def make_pipe():
     return StoragePipeline(PipelineConfig(k=K, m=M, segment_size=SEG))
 
 
-def write_round_record(directory, name="BENCH_r05.json") -> str:
-    """A driver round wrapper (metric lines in ``tail``) holding the
-    checked-in baseline fixture's values, written under ``directory``
-    — the repo root carries no bench record of its own."""
-    vals = profile.load_baseline(BASELINE_FIXTURE)
-    path = os.path.join(str(directory), name)
-    with open(path, "w") as f:
-        json.dump({"n": 5, "cmd": "python bench.py", "rc": 0,
-                   "tail": "\n".join(
-                       json.dumps({"metric": m, "value": v})
-                       for m, v in sorted(vals.items()))}, f)
-    return path
-
-
 # -- baseline loading --------------------------------------------------------
 class TestBaselineLoaders:
-    def test_parse_round_wrapper(self, tmp_path):
-        vals = profile.parse_bench_record(write_round_record(tmp_path))
-        assert ENCODE_METRIC in vals and vals[ENCODE_METRIC] > 0
-
-    def test_parse_raw_jsonl_skips_garbage(self, tmp_path):
-        p = tmp_path / "rec.jsonl"
-        p.write_text("warming up...\n"
-                     + json.dumps({"metric": "a_GiBps",
-                                   "value": 2.5}) + "\n"
-                     + "{truncated\n"
-                     + json.dumps({"metric": "bad",
-                                   "value": "nan"}) + "\n"
-                     + json.dumps({"note": "no metric"}) + "\n")
-        assert profile.parse_bench_record(str(p)) == {"a_GiBps": 2.5}
-
-    def test_latest_picks_newest_round(self, tmp_path):
-        for rnd_, val in (("r01", 1.0), ("r10", 7.0)):
-            (tmp_path / f"BENCH_{rnd_}.json").write_text(json.dumps(
-                {"n": 1, "cmd": "bench", "rc": 0,
-                 "tail": json.dumps({"metric": "x_GiBps",
-                                     "value": val})}))
-        assert profile.latest_bench_baseline(str(tmp_path)) \
-            == {"x_GiBps": 7.0}
-        # no records at all: an unanchored (inert) watchdog, not a guess
-        assert profile.latest_bench_baseline(str(tmp_path / "empty")) \
-            == {}
-
-    def test_fixture_anchors_the_default_tracked_metric(self, tmp_path):
+    def test_fixture_anchors_the_default_tracked_metric(self):
         base = profile.load_baseline(BASELINE_FIXTURE)
         assert base[ENCODE_METRIC] > 0
         assert profile.TRACKED_DEFAULT["encode"] == ENCODE_METRIC
-        # a record beside the process anchors the default lookup too
-        write_round_record(tmp_path)
-        assert profile.latest_bench_baseline(str(tmp_path)) == base
-
-    def test_checked_in_artifact_matches_the_bench_record(self, tmp_path):
-        # the fixture has the shape of a bench_diff --baseline-out
-        # artifact — what --profile=PATH loads — and round-trips
-        # through a driver round wrapper value for value
-        base = profile.load_baseline(BASELINE_FIXTURE)
-        assert base == profile.parse_bench_record(
-            write_round_record(tmp_path))
 
     def test_load_baseline_rejects_non_artifact(self, tmp_path):
         p = tmp_path / "not_an_artifact.json"
@@ -537,15 +485,20 @@ class TestCliFlag:
             main(["--dev", "--blocks", "1", "--profile"])
         assert "requires --engine" in str(ei.value)
 
-    def test_cli_engine_builds_an_anchored_plane(self):
+    @staticmethod
+    def _cli_engine(profile_spec):
+        """What ``--engine cpu --profile[=SPEC]`` builds ("" = bare)."""
         import argparse
 
         from cess_tpu.node.chain_spec import dev_spec
         from cess_tpu.node.cli import _make_cli_engine
 
-        args = argparse.Namespace(engine="cpu", resilience="off",
-                                  profile=BASELINE_FIXTURE)
-        eng = _make_cli_engine(args, dev_spec())
+        return _make_cli_engine(
+            argparse.Namespace(engine="cpu", resilience="off",
+                               profile=profile_spec), dev_spec())
+
+    def test_cli_engine_builds_an_anchored_plane(self):
+        eng = self._cli_engine(BASELINE_FIXTURE)
         try:
             assert eng.profile is not None
             wd = eng.profile.watchdog
@@ -554,6 +507,37 @@ class TestCliFlag:
                 == profile.load_baseline(BASELINE_FIXTURE)
         finally:
             eng.close()
+
+    def test_cli_bare_profile_builds_an_unanchored_plane(self, capsys):
+        from cess_tpu.node.cli import _finish_cli_profile
+
+        eng = self._cli_engine("")
+        try:
+            assert eng.profile is not None
+            assert eng.profile.watchdog is None
+            _finish_cli_profile(eng)
+        finally:
+            eng.close()
+        assert "watchdog off (no baseline)" in capsys.readouterr().err
+
+    def test_help_says_what_bare_profile_does(self, capsys):
+        # argparse %-formats every help string: a bare "%" in any one
+        # of them makes --help raise instead of print
+        from cess_tpu.node.cli import main
+
+        with pytest.raises(SystemExit) as ei:
+            main(["--help"])
+        assert ei.value.code == 0
+        text = " ".join(capsys.readouterr().out.split())
+        assert "Bare --profile has no baseline" in text
+        assert "watchdog off" in text
+        assert "err=1%;encode" in text
+
+    def test_cli_refuses_a_file_that_is_no_artifact(self, tmp_path):
+        p = tmp_path / "not_an_artifact.json"
+        p.write_text(json.dumps({"metric": "x", "value": 1.0}))
+        with pytest.raises(ValueError, match="not a bench baseline"):
+            self._cli_engine(str(p))
 
 
 class TestSimScenario:

@@ -2,8 +2,10 @@
 
 Byte-exact determinism between the CPU default path and the device path
 is a protocol invariant — fragment hashes go on chain (SURVEY.md §7
-hard part 4). Runs on the virtual CPU mesh; the same code path runs on
-TPU hardware via bench.py.
+hard part 4). Runs on the virtual CPU mesh; on the chip the same code
+is held to the references by chip_smoke.py and by every cell of the
+benchmark (benchmark/run.py decides ``correct`` against
+benchmark/reference/).
 """
 import numpy as np
 import pytest

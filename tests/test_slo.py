@@ -337,6 +337,31 @@ class TestAdaptiveBatchPolicy:
                                       pol.max_batch_requests,
                                       pol.max_batch_rows)
 
+    def test_static_window_alone_exceeds_a_tight_target(self):
+        """Why a static policy cannot protect verify: with
+        encode-friendly constants a
+        lone verify waits out the whole coalescing window, and that
+        window alone exceeds a 100 ms verify target — arithmetic over
+        AdmissionPolicy, no timing. From the SAME constants the
+        adaptive policy brings verify's window under the target after
+        one evaluation of over-target observations, and encode keeps
+        its coalescing. (That the adaptive engine's measured p99 then
+        beats the static one's is a wall-clock claim no test holds.)"""
+        target = 0.100
+        pol = AdmissionPolicy(max_delay=0.25, queue_cap=4096,
+                              max_batch_requests=64)
+        assert pol.max_delay > target
+        board = SloBoard((SloTarget("verify", target),))
+        ad = AdaptiveBatchPolicy(pol, board=board, update_every=4,
+                                 window=64, shrink=0.35,
+                                 occupancy_target=1.0)
+        for _ in range(4):      # a lone verify: window + its own work
+            ad.note("verify", pol.max_delay + 0.05, occupancy=1)
+            ad.note("encode", 0.001, occupancy=4)
+        assert ad.knobs("verify")[0] == pytest.approx(0.25 * 0.35)
+        assert ad.knobs("verify")[0] < target
+        assert ad.knobs("encode")[0] == pol.max_delay
+
     def test_board_supplies_targets(self):
         board = SloBoard((SloTarget("verify", 0.07),))
         ad = AdaptiveBatchPolicy(board=board)
