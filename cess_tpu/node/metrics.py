@@ -57,6 +57,12 @@ def collect(node) -> dict[str, float]:
     engine = getattr(node, "engine", None)
     if engine is not None:
         m.update(engine.stats_metrics())
+    # the gateway's upload counters (node/offchain.py OssGateway): rows
+    # hashed and stored from host memory against rows fetched from the
+    # device, when the node's process runs a gateway
+    gateway = getattr(node, "gateway", None)
+    if gateway is not None:
+        m.update(gateway.metrics())
     # telemetry-stream delivery counters (satellite: drops and sends
     # were previously silent — a dead collector looked identical to a
     # healthy one from the node's own metrics)

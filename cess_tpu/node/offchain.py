@@ -8,7 +8,10 @@ agents around a Node, driving the TPU data plane
 (cess_tpu.models.pipeline / cess_tpu.ops.podr2) for the heavy math:
 
 - OssGateway.upload(): segments the file, RS-encodes + PoDR2-tags the
-  whole batch on device, declares on chain, serves fragments.
+  whole batch on device, declares on chain, serves fragments. The
+  systematic rows never come back from the device: they are hashed
+  and stored from the user's bytes while the device encodes, and only
+  parity is fetched.
 - MinerAgent: fetches assigned fragments, reports transfer, computes
   aggregated (mu, sigma) proofs over its REAL stored bytes each
   challenge round (drop its ``store`` entries to simulate data loss),
@@ -33,11 +36,15 @@ so it takes no engine.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import functools
 import hashlib
+import os
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,6 +62,35 @@ from ..ops import podr2
 from .network import Node
 
 
+# ceiling of a gateway's hash workers: an upload at RS(2,1) x 4
+# segments is sixteen jobs, and a row's copy into ``bytes`` holds the
+# GIL (only its SHA-256 runs beside the others), so a wider pool would
+# mostly queue for it
+_HASH_WORKERS_MAX = 8
+
+
+@functools.partial(jax.jit, static_argnames="k")
+def _parity_rows(frags, k: int):
+    """The parity rows of an encode result ``[segments, k+m, n]`` as
+    ``segments * m`` 1-D arrays, segment by segment: the only bytes of
+    an upload that the device made, in the shape in which bytes leave
+    it (a dense ``u8[n]``; serve/engine.py ``_linear_rows`` has the
+    reason and the same index forms, never a ``reshape`` that moves
+    bytes between dimensions). The data rows are the user's own bytes
+    and stay where the host has them."""
+    return tuple(frags[i, j] for i in range(frags.shape[0])
+                 for j in range(k, frags.shape[1]))
+
+
+def _hashed_copy(row) -> tuple[bytes, bytes]:
+    """A worker's job for one fragment: the ``bytes`` that the store
+    will hold, copied once from the row's memory (a view of the
+    upload's input, or a fetched parity row), and their identity,
+    hashed from that same copy."""
+    blob = bytes(row)
+    return blob, fragment_hash(blob)
+
+
 class OssGateway:
     """The user-facing gateway: chunk -> encode -> tag -> declare.
 
@@ -63,7 +99,16 @@ class OssGateway:
     tagged with the uploading OWNER's account, so the exposition's
     ``cess_tenant_*`` series and the batcher's weighted-fair dequeue
     see the user behind the bytes — not just the one shared gateway
-    account. Free when the engine has no SLO board."""
+    account. Free when the engine has no SLO board.
+
+    The code is systematic, so of an upload's ``k+m`` rows a segment
+    only the ``m`` parity rows are made on the device. The gateway
+    treats the ``k`` data rows as what they are, host data: they and
+    the segments are hashed (and the data rows copied into the store's
+    ``bytes``) by the gateway's worker threads, from the memory the
+    user handed in, while the device encodes; only parity comes back
+    over the link, and each parity row is hashed as it lands.
+    ``counters()`` says how many rows took which way."""
 
     def __init__(self, node: Node, account: str,
                  pipeline: StoragePipeline):
@@ -72,44 +117,95 @@ class OssGateway:
         self.pipeline = pipeline
         self.fragment_store: dict[bytes, bytes] = {}   # hash -> bytes
         self.tag_store: dict[bytes, np.ndarray] = {}   # hash -> [blocks] u32
+        # SHA-256 (hashlib drops the GIL) off the upload's own thread.
+        # As wide as the machine, up to the ceiling; the executor
+        # starts a thread only when a job finds none idle, so never
+        # more than an upload has rows in flight
+        self._hashers = concurrent.futures.ThreadPoolExecutor(
+            max_workers=min(os.cpu_count() or 1, _HASH_WORKERS_MAX),
+            thread_name_prefix=f"gateway-hash-{account}")
+        self._mu = threading.Lock()
+        self._counters = dict.fromkeys(
+            ("uploads", "rows_from_host", "rows_fetched",
+             "bytes_fetched", "hash_jobs"), 0)
+
+    def close(self) -> None:
+        """Stop the hash workers (idle ones also stop when the gateway
+        is collected). An upload after this raises."""
+        self._hashers.shutdown()
+
+    def counters(self) -> dict[str, int]:
+        """Totals over this gateway's completed uploads: ``uploads``;
+        ``rows_from_host`` (data rows hashed and stored from the
+        input, never fetched) and ``rows_fetched`` (parity rows);
+        ``bytes_fetched`` (everything that came down from the device:
+        the parity rows and the tags); ``hash_jobs`` (SHA-256 handed to
+        the workers: every fragment and every segment)."""
+        with self._mu:
+            return dict(self._counters)
+
+    def metrics(self) -> dict[str, float]:
+        """``counters()`` as ``cess_gateway_*_total`` series: merged
+        into GET /metrics when the node carries ``node.gateway = gw``
+        (node/metrics.py collect())."""
+        return {f"cess_gateway_{name}_total": float(value)
+                for name, value in self.counters().items()}
 
     def upload(self, owner: str, bucket: str, file_name: str,
                data: bytes) -> bytes:
         """Segment + encode + tag on device; declare on chain; keep
         fragments ready for miners to fetch. Returns the file hash."""
         cfg = self.pipeline.config
-        seg_size = cfg.segment_size
+        k, m, seg_size, n = cfg.k, cfg.m, cfg.segment_size, \
+            cfg.fragment_size
+        rows = k + m
         padded = data + b"\0" * ((-len(data)) % seg_size)
         n_segs = len(padded) // seg_size
         segments = np.frombuffer(padded, dtype=np.uint8).reshape(n_segs, seg_size)
-        frag_hashes = [
-            [fragment_hash(b"pending")] * (cfg.k + cfg.m)
-            for _ in range(n_segs)]
+        host = memoryview(padded)
+        hashers = self._hashers
         # one stage each per upload (obs.trace.stage): the upload and
         # its six stages are cess:offchain.upload / cess:gateway.* in
-        # any profiler trace, and spans of an armed tracer
+        # any profiler trace, and spans of an armed tracer. All of them
+        # are on this thread; the workers emit none
         with trace.stage("offchain.upload", sys="offchain",
                          file=file_name, segments=n_segs,
                          size=len(data)):
-            # hash fragments first (ids feed the tag PRF), then tag on
-            # device. The device-resident fragments feed tag_step
-            # DIRECTLY (zero-copy engine handoff): the hashing fetch is
-            # the only D2H, and the fragment bytes are never
-            # re-uploaded for tagging
             with trace.stage("gateway.encode"):
+                # what needs nothing from the device starts now, on
+                # zero-copy views of the input, and runs behind the
+                # copy up, the encode and the parity's way down
+                seg_jobs = [hashers.submit(
+                    fragment_hash, host[i * seg_size:(i + 1) * seg_size])
+                    for i in range(n_segs)]
+                frag_jobs = [[hashers.submit(
+                    _hashed_copy, host[i * seg_size + j * n:
+                                       i * seg_size + (j + 1) * n])
+                    for j in range(k)] for i in range(n_segs)]
                 frags_dev = self.pipeline.encode_step(
                     jnp.asarray(segments), tenant=owner)
-            with trace.stage("gateway.fetch"):
-                out_frags = np.asarray(frags_dev)
+            with trace.stage("gateway.fetch", rows=n_segs * m,
+                             bytes=n_segs * m * n):
+                # the device-resident fragments feed tag_step DIRECTLY
+                # (zero-copy engine handoff) and stay whole; only the
+                # parity rows come down, all on their way at once, each
+                # handed on as it lands
+                parity = _parity_rows(frags_dev, k=k)
+                for row in parity:
+                    row.copy_to_host_async()
+                for at, row in enumerate(parity):
+                    frag_jobs[at // m].append(hashers.submit(
+                        _hashed_copy, np.asarray(row)))
             with trace.stage("gateway.hash"):
-                ids = np.zeros((n_segs, cfg.k + cfg.m, 2), dtype=np.uint32)
-                for i in range(n_segs):
-                    for j in range(cfg.k + cfg.m):
-                        h = fragment_hash(out_frags[i, j].tobytes())
-                        frag_hashes[i][j] = h
-                        ids[i, j] = podr2.fragment_id_from_hash(h)
-                seg_hashes = [fragment_hash(segments[i].tobytes())
-                              for i in range(n_segs)]
+                # ids feed the tag PRF, so the tags wait for every hash
+                # of the batch; a failed hash or fetch fails the upload
+                # here, before anything is stored or declared
+                frags = [[job.result() for job in seg]
+                         for seg in frag_jobs]
+                seg_hashes = [job.result() for job in seg_jobs]
+                ids = np.array([[podr2.fragment_id_from_hash(h)
+                                 for _, h in seg] for seg in frags],
+                               dtype=np.uint32)
             with trace.stage("gateway.tag"):
                 tags_dev = self.pipeline.tag_step(frags_dev,
                                                   jnp.asarray(ids),
@@ -118,12 +214,13 @@ class OssGateway:
                 tags = np.asarray(tags_dev)
             with trace.stage("gateway.store"):
                 for i in range(n_segs):
-                    for j in range(cfg.k + cfg.m):
-                        h = frag_hashes[i][j]
-                        self.fragment_store[h] = out_frags[i, j].tobytes()
+                    for j in range(rows):
+                        blob, h = frags[i][j]
+                        self.fragment_store[h] = blob
                         self.tag_store[h] = tags[i, j]
             with trace.stage("gateway.declare"):
-                seg_list = [(seg_hashes[i], tuple(frag_hashes[i]))
+                seg_list = [(seg_hashes[i],
+                             tuple(h for _, h in frags[i]))
                             for i in range(n_segs)]
                 file_hash = fragment_hash(b"".join(h for _, fs in seg_list
                                                    for h in fs))
@@ -138,6 +235,13 @@ class OssGateway:
                 _flight.note("custody", "dispatch", owner=owner,
                              file=file_hash, k=cfg.k, m=cfg.m,
                              segments=seg_list)
+            with self._mu:
+                c = self._counters
+                c["uploads"] += 1
+                c["rows_from_host"] += n_segs * k
+                c["rows_fetched"] += n_segs * m
+                c["bytes_fetched"] += n_segs * m * n + tags.nbytes
+                c["hash_jobs"] += n_segs * (rows + 1)
             return file_hash
 
 

@@ -1,5 +1,6 @@
 """The main path's Pallas kernels, the engine's two audit programs, its
-flatten of a byte result into linear rows and the pooled stream step
+flatten of a byte result into linear rows, the gateway's parity-rows
+program and the pooled stream step
 over four chips, compiled at protocol widths for a
 DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
 refuses here what it would refuse on the chip — a kernel Mosaic cannot
@@ -28,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 
 from cess_tpu import constants
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
+from cess_tpu.node import offchain
 from cess_tpu.ops import gf, podr2, podr2_pallas, rs_pallas, rs_xor, \
     target, xor_sched
 from cess_tpu.parallel import mesh as pmesh
@@ -226,6 +228,29 @@ def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, packed):
     # dense rows: their logical bytes, plus the table of a tuple result
     assert 0 <= mem.output_size_in_bytes - rows * r * n < 4096, mem
     assert mem.argument_size_in_bytes == packed * rows * r * n, mem
+
+
+@pytest.mark.parametrize("shape,k", [
+    pytest.param((4, 3, 8 * MiB), 2, id="upload-rs2p1-4-segments"),
+    pytest.param((1, 12, 4 * MiB), 4, id="upload-rs4p8-1-segment")])
+def test_parity_rows_compile_for_v5e(one_chip, for_tpu, shape, k):
+    """The gateway's parity fetch (node/offchain.py _parity_rows) at an
+    upload's encode results: only the ``m`` parity rows of each segment
+    come out, each 1-D and dense, and the program compiles in seconds
+    (index forms only, no relayouting reshape). The encode result stays
+    whole on the device: the argument is not donated."""
+    segs, rows, n = shape
+    t0 = time.perf_counter()
+    compiled = offchain._parity_rows.lower(
+        jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip),
+        k=k).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [o.shape for o in outs] == [(n,)] * (segs * (rows - k))
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.output_size_in_bytes - segs * (rows - k) * n < 4096, mem
+    assert mem.alias_size_in_bytes == 0, mem
+    assert "reshape" not in compiled.as_text()
 
 
 def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):
