@@ -89,7 +89,7 @@ class PipelineConfig:
 class StoragePipeline:
     """Batched segment->fragment encode + PoDR2 tag program.
 
-    Unlike TPUCodec (a generic codec front with per-pattern caches),
+    Unlike TPUCodec (a generic codec front for any erasure pattern),
     this is a single fused forward step meant to be jitted/pjitted as
     one program over a segment batch. The tag step plays the
     reference's TEE role (SURVEY.md §3.2 step "TEE worker computes

@@ -517,13 +517,21 @@ class MinerAgent:
     # -- restoral servicing -------------------------------------------------------
     def warm_restoral(self) -> None:
         """Pre-compile + pre-stage the restoral market's reconstruct
-        programs — one per lost row, with the k lowest surviving rows
-        (exactly the survivor set try_repair assembles when every peer
-        holds its fragment) — so a claimed order pays kernel time, not
-        first-call compile + table staging. With an engine, the
-        engine's repair program cache is warmed under the keys its
-        batcher will hit; without one, the codec's AOT warm path is
-        used directly (no-op on the NumPy reference codec)."""
+        program for the SHAPE of a restoral repair — one lost row
+        rebuilt from k survivors of ``fragment_size`` bytes — so a
+        claimed order pays kernel time, not first-call compile. The
+        program takes the erasure pattern's matrix as an operand
+        (ops/rs.py), so it serves whichever k holders answer
+        ``try_repair``, not only the k lowest surviving rows. Those
+        patterns — one per lost row, the survivor set try_repair
+        assembles when every peer holds its fragment — are what is
+        handed over: their matrices are built and staged as well, which
+        at the protocol's RS(2,1) is every pattern there is; any other
+        helper set costs its matrix (a fraction of a millisecond on the
+        host) and nothing else. With an engine, the engine's repair
+        program cache is warmed under the keys its batcher will hit;
+        without one, the codec's own warm path is used directly (no-op
+        on the NumPy reference codec)."""
         cfg = self.pipeline.config
         rows = cfg.k + cfg.m
         patterns = []
@@ -532,10 +540,9 @@ class MinerAgent:
             patterns.append((present, (row,)))
         if self.engine is not None and self.engine.codec is not None:
             # restoral repairs are single-order blocking submits, so
-            # only the 1-row bucket's programs are ever dispatched —
-            # warming bucket 2 as well would double the AOT compile
-            # sweep (per pattern x per lane) for programs a repair
-            # never hits
+            # only the 1-row bucket's shape is ever dispatched —
+            # warming bucket 2 as well would double the compiles
+            # (per shape x per lane) for programs a repair never hits
             self.engine.warm_repair(patterns, cfg.fragment_size,
                                     buckets=(1,))
             return
@@ -612,9 +619,11 @@ class MinerAgent:
                               present: tuple[int, ...],
                               holders: dict[int, "MinerAgent"],
                               cfg: PipelineConfig) -> bytes:
-        """Whole-fragment dispatch: ingress k survivor rows and
+        """Whole-fragment dispatch: ingress k survivor rows — from
+        whichever k holders ``try_repair`` found, in row order — and
         reconstruct (engine repair queue when attached, direct codec
-        otherwise)."""
+        otherwise). The program is the warmed shape's whatever the
+        helper set; the set picks the matrix it is called with."""
         survivors = [np.frombuffer(
             holders[j].store[seg.fragment_hashes[j]], dtype=np.uint8)
             for j in present]

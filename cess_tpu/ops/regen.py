@@ -29,8 +29,9 @@ Device surfaces live behind the existing ``ErasureCodec`` gate
 subclasses TPUCodec, swaps every decode/repair matrix construction for
 the closed form, and adds the batched symbol fold
 (``fold_symbol`` — a [1, 2] GF matmul over (accumulator, fragment) row
-pairs via the same gather/bitmatrix/pallas lowerings) with per-pattern
-warm/AOT programs that ride ``engine.warm_repair``'s per-lane cache.
+pairs via the same gather/bitmatrix/pallas lowerings) through the
+per-shape programs of ops/rs.py (the coefficient an operand), warmed by
+``engine.warm_repair`` base and per lane.
 ``RegenReference`` is the NumPy twin serving as the byte-exact oracle
 and the engine's CPU-degraded fallback.
 
@@ -48,7 +49,7 @@ from __future__ import annotations
 import numpy as np
 
 from . import gf
-from .rs import TPUCodec, _MatrixApply, _placement_device
+from .rs import TPUCodec
 from .rs_ref import ReferenceCodec
 
 __all__ = [
@@ -242,56 +243,37 @@ class RegenCodec(TPUCodec):
     """TPUCodec with the regenerating-repair surfaces: every decode /
     repair matrix comes from the closed-form Cauchy construction, and
     ``fold_symbol`` runs the helper partial-sum hop as a batched device
-    matmul. Warm/AOT machinery (``warm_reconstruct``, ``warm_hits``,
-    the per-device program keys) is inherited unchanged, so
-    ``engine.warm_repair``'s per-lane cache serves regen patterns the
-    same way it serves plain reconstructs."""
+    matmul. The warm path (``warm_reconstruct``, a program per shape
+    and placement; ``warm_hits`` under ``xor`` / ``auto``) is inherited
+    unchanged, so ``engine.warm_repair`` serves regen patterns the same
+    way it serves plain reconstructs."""
 
-    def _matrix_for(self, kind: str, present: tuple[int, ...],
-                    missing: tuple[int, ...] = ()) -> _MatrixApply:
-        key = (kind, present, missing)
-        if key not in self._cache:
-            if kind == "decode":
-                mat = decode_matrix(self.k, self.m, present)
-            elif kind == "symbol":
-                mat = _symbol_matrix(present[0])
-            else:
-                mat = repair_matrix(self.k, self.m, present, missing)
-            self._cache[key] = _MatrixApply(mat, self.strategy)
-        return self._cache[key]
+    def _build_matrix(self, kind: str, present: tuple[int, ...],
+                      missing: tuple[int, ...]) -> np.ndarray:
+        if kind == "decode":
+            return decode_matrix(self.k, self.m, present)
+        if kind == "symbol":
+            return _symbol_matrix(present[0])
+        return repair_matrix(self.k, self.m, present, missing)
 
     # -- the symbol fold ---------------------------------------------------
-    def _symbol_key(self, coeff: int):
-        # a warm-dict key that can never collide with reconstruct keys
-        # (their first element is a tuple of int rows)
-        return ("symbol", int(coeff))
-
     def warm_fold(self, coeff: int, shape, device=None):
-        """Pre-compile + pre-stage the symbol fold for one coefficient
-        and exact pair shape, per device — the regen leg of
-        ``engine.warm_repair``. Same placement-keyed contract as
-        ``warm_reconstruct``."""
-        key = (self._symbol_key(coeff), (), tuple(shape),
-               _placement_device() if device is None else device)
-        if key not in self._warm:
-            self._warm[key] = self._matrix_for(
-                "symbol", (int(coeff),)).aot(shape, device=device)
-        return self._warm[key]
+        """Pre-compile + pre-stage the symbol fold for one exact pair
+        shape, per device, and stage this coefficient's operands — the
+        regen leg of ``engine.warm_repair``. Same contract as
+        ``warm_reconstruct``: under the dense strategies the
+        coefficient is an operand and one program folds them all."""
+        self._warm_program(("symbol", (int(coeff),), ()), shape, device)
 
-    def fold_symbol(self, pairs, coeff: int):
+    def fold_symbol(self, pairs, coeff: int, *, sink: dict | None = None):
         """pairs [..., 2, n] uint8 (accumulator, fragment) rows ->
         [..., 1, n]: acc ^ coeff*fragment, batched on device.
-        Dispatches the pre-staged AOT executable when warmed for this
-        placement (``warm_hits`` proves it, as for reconstruct)."""
+        Compiles nothing when warmed for this shape and placement.
+        ``sink`` as for ``reconstruct``."""
         import jax.numpy as jnp
 
-        pairs = jnp.asarray(pairs, dtype=jnp.uint8)
-        warm = self._warm.get((self._symbol_key(coeff), (),
-                               tuple(pairs.shape), _placement_device()))
-        if warm is not None:
-            self.warm_hits += 1
-            return warm(pairs)
-        return self._matrix_for("symbol", (int(coeff),))(pairs)
+        return self._apply(("symbol", (int(coeff),), ()),
+                           jnp.asarray(pairs, dtype=jnp.uint8), sink)
 
     def repair_coeffs(self, present: tuple[int, ...],
                       missing: tuple[int, ...]) -> tuple[int, ...]:

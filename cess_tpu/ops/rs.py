@@ -28,17 +28,34 @@ RS(2,1), /root/reference/runtime/src/lib.rs:1026-1027; BASELINE.json
 targets RS(4,8)). Decode/repair matrices for a given erasure pattern
 are built host-side (tiny Gauss-Jordan) and applied with the same
 batched device kernels.
+
+One program per SHAPE, the pattern's matrix an operand. The dense
+lowerings (gather, bitmatrix, pallas) take the matrix's tables as
+arguments of the device program, so a repair program compiled for one
+``(q, r, n, batch)`` serves every ``(present, missing)`` of that shape:
+RS(10,4) has 4,004 single-loss patterns once the repairer takes
+whichever ten helpers answer, and none of them compiles anything after
+the shape is warm (``TPUCodec.warm_reconstruct``). What a pattern costs
+is its matrix (0.27 ms on the host at (10,4)) and the put of its
+operands (under 1 KiB); the codec keeps the newest
+``TPUCodec.MATRICES`` of them, operands placed, and rebuilds the rest.
+``xor`` / ``auto`` compile the matrix INTO the program by nature and
+stay one program per pattern.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
+import math
+import threading
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace
 from ..resilience import faults
 from . import gf
 
@@ -123,10 +140,21 @@ def _apply_bitmatrix(bmat: jax.Array, data: jax.Array) -> jax.Array:
     return out.astype(jnp.uint8)
 
 
-def _pallas_apply(bmat_np: np.ndarray, data: jax.Array) -> jax.Array:
+@jax.jit
+def _apply_pallas(bmat: jax.Array, data: jax.Array) -> jax.Array:
+    """GF apply via the fused Pallas kernel (ops/rs_pallas.py): bmat is
+    ``rs_pallas.operand_np`` for this batch's group."""
     from . import rs_pallas  # local import: pallas only needed on this path
 
-    return rs_pallas.apply_bitmatrix(bmat_np, data)
+    return rs_pallas.apply_operand(bmat, data)
+
+
+# The dense lowerings: (matrix operands..., data) -> result, each one
+# module-level jit. The matrix's VALUES are arguments, so the program
+# jit compiles for one (matrix shape, data shape, placement) serves
+# every matrix of that shape.
+_DENSE = {"gather": _apply_gather, "bitmatrix": _apply_bitmatrix,
+          "pallas": _apply_pallas}
 
 
 # ---------------------------------------------------------------------------
@@ -135,20 +163,19 @@ def _pallas_apply(bmat_np: np.ndarray, data: jax.Array) -> jax.Array:
 
 
 class _MatrixApply:
-    """A GF matrix baked into device tables, applied with a chosen strategy."""
+    """A GF matrix as the operands of a chosen strategy's program (the
+    dense strategies), or compiled into it (``xor`` / ``auto``)."""
 
     def __init__(self, mat: np.ndarray, strategy: Strategy):
         self.mat = np.asarray(mat, dtype=np.uint8)
         self.strategy = strategy
+        # the dense strategies' operands on the device, put once per
+        # (placement, kernel group) and kept with the matrix
+        self._placed: dict[tuple, tuple] = {}
         if strategy == "gather":
-            lo, hi = nibble_tables(self.mat)
-            self._lo = jnp.asarray(lo)
-            self._hi = jnp.asarray(hi)
-        elif strategy == "bitmatrix":
-            self._bmat_np = gf.expand_bitmatrix(self.mat)
-            self._bmat = jnp.asarray(self._bmat_np, dtype=jnp.bfloat16)
-        elif strategy == "pallas":
-            self._bmat_np = gf.expand_bitmatrix(self.mat)
+            self._host = nibble_tables(self.mat)
+        elif strategy in ("bitmatrix", "pallas"):
+            self._host = (gf.expand_bitmatrix(self.mat),)
         elif strategy == "xor":
             from . import xor_sched  # local: default strategies never pay it
 
@@ -166,6 +193,39 @@ class _MatrixApply:
             self._auto_base = _MatrixApply(self.mat, default_strategy())
         else:
             raise ValueError(f"unknown strategy {strategy!r}")
+
+    @property
+    def baked(self) -> bool:
+        """True where the matrix is compiled into the program (one
+        program per matrix), False where it is the program's operand
+        (one program per shape)."""
+        return self.strategy not in _DENSE
+
+    def operands(self, shape) -> tuple:
+        """The matrix as the device operands of this strategy's program
+        for data of ``shape``, on the device a dispatch issued now lands
+        on: put there on first use (the one host -> device copy a new
+        matrix costs, under 1 KiB) and kept."""
+        group = 0
+        if self.strategy == "pallas":
+            from . import rs_pallas
+
+            # the kernel's operand is block-diagonal over the segments
+            # of one grid step, which follow from the batch
+            group = rs_pallas.group_for(math.prod(shape[:-2]))
+        key = (_placement_device(), group)
+        placed = self._placed.get(key)
+        if placed is None:
+            host = self._host
+            if self.strategy == "pallas":
+                host = (rs_pallas.operand_np(host[0], group),)
+            dtype = jnp.bfloat16 if self.strategy == "bitmatrix" else None
+            placed = tuple(jnp.asarray(t, dtype=dtype) for t in host)
+            # under a jit trace the operands may be the trace's own:
+            # never keep those
+            if not any(isinstance(t, jax.core.Tracer) for t in placed):
+                self._placed[key] = placed
+        return placed
 
     def _decide(self, shape) -> dict:
         """Cost-model verdict for one data shape (strategy="auto")."""
@@ -206,32 +266,31 @@ class _MatrixApply:
             raise ValueError(
                 f"expected {self.mat.shape[1]} shard rows, got {data.shape[-2]}"
             )
-        if self.strategy == "gather":
-            return _apply_gather(self._lo, self._hi, data)
-        if self.strategy == "pallas":
-            return _pallas_apply(self._bmat_np, data)
         if self.strategy == "xor":
             return self._apply_xor(data)
         if self.strategy == "auto":
             if self._decide(data.shape)["chosen"] == "xor":
                 return self._apply_xor(data)
             return self._auto_base(data)
-        return _apply_bitmatrix(self._bmat, data)
+        return _DENSE[self.strategy](*self.operands(data.shape), data)
 
     def aot(self, shape, dtype=jnp.uint8, device=None):
-        """AOT-compile this apply for one exact input shape: the
-        tables/matrices are baked into the executable as constants
-        (pre-staged) and calls skip the jit dispatch/tracing machinery
-        entirely — the repair warm path (TPUCodec.warm_reconstruct).
-        ``device`` pins which device the executable is compiled and
-        staged for (None = the current default device); the compiled
-        program is bound to that one device. Returns the compiled
-        callable (data) -> result."""
-        fn = jax.jit(self.__call__)
-        with contextlib.nullcontext() if device is None \
-                else jax.default_device(device):
-            return fn.lower(
+        """AOT-compile a baked apply (``xor`` / ``auto``: the schedule
+        IS the program, one executable a matrix) for one exact input
+        shape; calls of the executable skip the jit dispatch/tracing
+        machinery entirely (TPUCodec.warm_reconstruct). ``device`` pins
+        which device the executable is compiled and staged for (None =
+        the current default device); the compiled program is bound to
+        that one device."""
+        with _placed_on(device):
+            return jax.jit(self.__call__).lower(
                 jax.ShapeDtypeStruct(tuple(shape), dtype)).compile()
+
+
+def _placed_on(device):
+    """The placement scope of ``device`` (None: whatever is active)."""
+    return contextlib.nullcontext() if device is None \
+        else jax.default_device(device)
 
 
 def _placement_device():
@@ -239,8 +298,9 @@ def _placement_device():
     active ``jax.default_device`` scope's device (the pool's per-lane
     placement, serve/engine.py ``_lane_placement``), or None when no
     scope is active — JAX's backend default. This is the device
-    component of the AOT warm-program cache key: an executable is
-    bound to the device it was compiled for, so a warm hit compiled
+    component of the keys of what is kept per device (a matrix's
+    placed operands, a baked pattern's AOT executable): an executable
+    is bound to the device it was compiled for, so a warm hit compiled
     under device 0's scope must never be dispatched inside device 3's
     (the one-device-assumption bug this key component fixes)."""
     return jax.config.jax_default_device
@@ -262,8 +322,16 @@ class TPUCodec:
     Same surface as rs_ref.ReferenceCodec (encode / encode_parity /
     reconstruct / decode_data); shards are uint8 [..., rows, n] with
     arbitrary leading batch dims — vmap is implicit via batched shapes.
-    Decode matrices per erasure pattern are cached.
+
+    What it keeps, and how much: the newest ``MATRICES`` decode /
+    repair matrices (an LRU of ``_MatrixApply``, each with its device
+    operands: a matrix takes a fraction of a millisecond to rebuild).
+    The dense strategies' programs are jit's to keep, one per (matrix
+    shape, data shape, placement); under ``xor`` / ``auto`` the codec
+    keeps one AOT executable per warmed (pattern, data shape, device).
     """
+
+    MATRICES = 64
 
     def __init__(self, k: int, m: int, strategy: Strategy | None = None):
         if k < 1 or m < 0 or k + m > gf.FIELD:
@@ -272,11 +340,17 @@ class TPUCodec:
         self.m = m
         self.strategy = strategy or default_strategy()
         self._parity_apply = _MatrixApply(gf.cauchy_parity_matrix(k, m), self.strategy)
-        self._cache: dict[tuple, _MatrixApply] = {}
-        self._warm: dict[tuple, Callable] = {}   # AOT repair programs
-        # observable warm-path dispatches: lets callers (the tests)
-        # PROVE the warm program ran rather than a silent fallback to
-        # the cold jit path
+        # (kind, present, missing) -> _MatrixApply, newest last; shared
+        # by the engine's batcher, its pool lanes and warm-path callers
+        self._cache: "collections.OrderedDict[tuple, _MatrixApply]" = \
+            collections.OrderedDict()
+        self._mu = threading.Lock()
+        # xor / auto only: (pattern, data shape, device) -> the AOT
+        # executable with the pattern's schedule baked in, and its
+        # observable dispatches: lets callers (the tests) PROVE the
+        # warm program ran rather than a silent fallback to the cold
+        # jit path
+        self._warm: dict[tuple, Callable] = {}
         self.warm_hits = 0
 
     # -- encode -------------------------------------------------------------
@@ -298,83 +372,132 @@ class TPUCodec:
         return jnp.concatenate([data, self.encode_parity(data)], axis=-2)
 
     # -- decode -------------------------------------------------------------
+    def _build_matrix(self, kind: str, present: tuple[int, ...],
+                      missing: tuple[int, ...]) -> np.ndarray:
+        if kind == "decode":
+            return gf.decode_matrix(self.k, self.m, present)
+        return gf.repair_matrix(self.k, self.m, present, missing)
+
     def _matrix_for(self, kind: str, present: tuple[int, ...],
-                    missing: tuple[int, ...] = ()) -> _MatrixApply:
+                    missing: tuple[int, ...] = (), shape=None,
+                    sink: dict | None = None) -> _MatrixApply:
+        """The pattern's matrix from the LRU. One the codec does not
+        hold is built now, under the stage ``repair.matrix``
+        (obs.trace.stage: ``cess:repair.matrix`` in a profiler trace,
+        ``[count, seconds]`` into ``sink``): the host's Gauss-Jordan
+        and table expansion (``repair.matrix.build`` inside it) and,
+        given the data ``shape``, the put of its device operands."""
         key = (kind, present, missing)
-        if key not in self._cache:
-            if kind == "decode":
-                mat = gf.decode_matrix(self.k, self.m, present)
-            else:
-                mat = gf.repair_matrix(self.k, self.m, present, missing)
-            self._cache[key] = _MatrixApply(mat, self.strategy)
-        return self._cache[key]
+        with self._mu:
+            apply_ = self._cache.get(key)
+            if apply_ is not None:
+                self._cache.move_to_end(key)
+                return apply_
+        with trace.stage("repair.matrix", sink):
+            with trace.stage("repair.matrix.build", sink):
+                apply_ = _MatrixApply(
+                    self._build_matrix(kind, present, missing),
+                    self.strategy)
+            if shape is not None and not apply_.baked:
+                apply_.operands(shape)
+        with self._mu:
+            apply_ = self._cache.setdefault(key, apply_)
+            while len(self._cache) > self.MATRICES:
+                self._cache.popitem(last=False)
+        return apply_
 
-    def warm_reconstruct(self, present, missing=None, shape=None,
-                         device=None):
-        """Pre-compile + pre-stage the reconstruct program for ONE
-        erasure pattern and exact survivor shape (the restoral-market
-        warm path): the decode matrix is built AND baked into an AOT
-        executable now, so a later ``reconstruct`` with this pattern
-        and shape dispatches the compiled program directly — no jit
-        cache lookup, no tracing, no first-call compile in the latency
-        budget (the benchmark's repair cell warms its three patterns
-        in set-up and counts 0 compilations in its window).
-
-        ``device`` pins the device the executable is compiled for
-        (the device-pool path warms once per lane); None warms for
-        the CURRENT placement — the active jax.default_device scope,
-        else the backend default. The warm cache is keyed by that
-        placement too: a ``reconstruct`` only hits a warm program
-        compiled for the placement it is dispatching under, never an
-        executable bound to a different chip (tests/test_pool.py pins
-        the two-device case). Returns the compiled callable."""
+    def _pattern(self, present, missing) -> tuple[tuple, tuple]:
         present = tuple(present)
         if missing is None:
             missing = tuple(i for i in range(self.k + self.m)
                             if i not in present)
-        missing = tuple(missing)
+        return present, tuple(missing)
+
+    def _warm_program(self, pattern: tuple, shape, device) -> None:
+        """Compile, once, the program that serves ``pattern`` at
+        ``shape`` on ``device`` (None: the current placement), and
+        stage this pattern's operands there. Dense strategies: one run
+        of the strategy's jitted program over zeros — jit keeps one
+        executable per (matrix shape, data shape, placement), and every
+        later matrix of that shape is only its argument. Baked ones: an
+        AOT executable of the pattern's own, kept in ``_warm``."""
+        apply_ = self._matrix_for(*pattern)
+        with _placed_on(device):
+            if not apply_.baked:
+                jax.block_until_ready(apply_(jnp.zeros(shape, jnp.uint8)))
+                return
+            key = (pattern, tuple(shape), _placement_device())
+        if key not in self._warm:
+            self._warm[key] = apply_.aot(shape, device=device)
+
+    def _apply(self, pattern: tuple, data: jax.Array,
+               sink: dict | None = None) -> jax.Array:
+        """Apply the pattern's matrix to ``data``: the strategy's
+        jitted program with the matrix as its operands or, under
+        ``xor`` / ``auto``, the pattern's warmed executable for this
+        shape and placement when there is one (the key carries the
+        CURRENT placement: under a pool lane's default_device scope
+        only that lane's executable can hit)."""
+        apply_ = self._matrix_for(*pattern, shape=data.shape, sink=sink)
+        if apply_.baked:
+            warm = self._warm.get(
+                (pattern, tuple(data.shape), _placement_device()))
+            if warm is not None:
+                self.warm_hits += 1
+                return warm(data)
+        return apply_(data)
+
+    def warm_reconstruct(self, present, missing=None, shape=None,
+                         device=None):
+        """Pre-compile + pre-stage the reconstruct program for the
+        SHAPE of one erasure pattern — ``(len(present), len(missing))``
+        rows — and one exact survivor shape (the restoral-market warm
+        path): a later ``reconstruct`` of ANY pattern of that shape at
+        that survivor shape under the same placement runs the program
+        compiled here, its matrix an argument — no tracing, no compile
+        in the latency budget (the benchmark's repair cells warm their
+        shapes in set-up and count 0 compilations in their windows).
+        The named pattern's matrix is built and its operands staged
+        too. Under ``xor`` / ``auto`` the matrix is the program: there
+        this warms the named pattern alone, as an AOT executable that
+        ``warm_hits`` counts the dispatches of.
+
+        ``device`` pins the device the program is compiled for (the
+        device-pool path warms once per lane); None warms for the
+        CURRENT placement — the active jax.default_device scope, else
+        the backend default. A program is bound to the placement it
+        was compiled under: a ``reconstruct`` under another device's
+        scope never runs an executable bound to a different chip
+        (tests/test_pool.py pins the two-device case)."""
         if shape is None:
             raise ValueError("warm_reconstruct needs the exact "
                              "survivor shape, e.g. (k, fragment_size)")
-        key = (present, missing, tuple(shape),
-               _placement_device() if device is None else device)
-        if key not in self._warm:
-            self._warm[key] = self._matrix_for(
-                "repair", present, missing).aot(shape, device=device)
-        return self._warm[key]
+        self._warm_program(
+            ("repair",) + self._pattern(present, missing), shape, device)
 
     def reconstruct(self, survivors: jax.Array, present: tuple[int, ...],
-                    missing: tuple[int, ...] | None = None) -> jax.Array:
+                    missing: tuple[int, ...] | None = None, *,
+                    sink: dict | None = None) -> jax.Array:
         """Recover missing shards from any k survivors.
 
         survivors: [..., k, n] rows ordered as ``present``; returns
         [..., len(missing), n] (missing defaults to all absent rows).
-        Dispatches a pre-compiled executable when the exact
-        (pattern, shape) has been warmed (see warm_reconstruct).
+        Compiles nothing when the exact shape has been warmed (see
+        warm_reconstruct). ``sink``: an
+        obs.trace.stage sink that learns of a pattern the codec held no
+        matrix for (``_matrix_for``).
         """
         faults.inject("rs.reconstruct")
-        present = tuple(present)
-        if missing is None:
-            missing = tuple(i for i in range(self.k + self.m) if i not in present)
-        missing = tuple(missing)
-        survivors = jnp.asarray(survivors, dtype=jnp.uint8)
-        # the warm key carries the CURRENT placement (see
-        # warm_reconstruct): under a pool lane's default_device scope
-        # only that lane's executable can hit
-        warm = self._warm.get((present, missing,
-                               tuple(survivors.shape),
-                               _placement_device()))
-        if warm is not None:
-            self.warm_hits += 1
-            return warm(survivors)
-        apply_ = self._matrix_for("repair", present, missing)
-        return apply_(survivors)
+        return self._apply(
+            ("repair",) + self._pattern(present, missing),
+            jnp.asarray(survivors, dtype=jnp.uint8), sink)
 
-    def decode_data(self, survivors: jax.Array, present: tuple[int, ...]) -> jax.Array:
+    def decode_data(self, survivors: jax.Array, present: tuple[int, ...],
+                    *, sink: dict | None = None) -> jax.Array:
         """Recover the k data shards from any k survivors."""
         faults.inject("rs.decode")
-        apply_ = self._matrix_for("decode", tuple(present))
-        return apply_(jnp.asarray(survivors, dtype=jnp.uint8))
+        return self._apply(("decode", tuple(present), ()),
+                           jnp.asarray(survivors, dtype=jnp.uint8), sink)
 
     def program_meta(self, kind: str, present=(), missing=(),
                      shape=()) -> tuple:

@@ -114,55 +114,63 @@ def _apply_3d(bmat: jax.Array, packmat: jax.Array, q: int, r: int, g: int,
 
 
 @functools.lru_cache(maxsize=64)
-def _matrices_np(bmat_key, g: int, r: int,
-                 use_int8: bool) -> tuple[np.ndarray, np.ndarray]:
+def _pack_np(r: int, g: int) -> np.ndarray:
     # cache NUMPY only: a jnp array created inside a jit trace would be
-    # a tracer, and caching a tracer leaks it across traces
-    bmat_np = np.frombuffer(bmat_key[2], dtype=np.uint8).reshape(bmat_key[:2])
-    big = np.kron(np.eye(g, dtype=np.uint8), bmat_np)
-    big = big.astype(np.int8) if use_int8 else big.astype(np.float32)
+    # a tracer, and caching a tracer leaks it across traces.
     # pack matrix [rg, 8rg]: row i selects its 8 bit-rows with weights
-    pack = np.kron(np.eye(r * g, dtype=np.int8),
+    return np.kron(np.eye(r * g, dtype=np.int8),
                    np.asarray(PACK_W, dtype=np.int8)[None, :])
-    return big, pack
 
 
-def apply_bitmatrix(bmat_np: np.ndarray, data: jax.Array,
-                    tile_n: int = DEFAULT_TILE_N, use_int8: bool = True,
-                    group: int = DEFAULT_GROUP,
-                    subtiles: int = DEFAULT_SUBTILES,
-                    mxu_pack: bool = True) -> jax.Array:
-    """Apply an expanded (8r x 8q) GF(2) bit-matrix to [..., q, n] uint8 data.
+def group_for(batch: int, group: int = DEFAULT_GROUP) -> int:
+    """Segments per grid step for a flattened batch of ``batch``:
+    ``group`` degraded to its largest power-of-two divisor of it."""
+    g = group
+    while batch % g:
+        g //= 2
+    return g
+
+
+def operand_np(bmat_np: np.ndarray, g: int,
+               use_int8: bool = True) -> np.ndarray:
+    """The kernel's matrix operand on the host: the expanded (8r x 8q)
+    bit-matrix as the block-diagonal ``kron(I_g, bmat)`` the grid step
+    applies to ``g`` segments, in the MXU operand's dtype. The one part
+    of a call that depends on the matrix's VALUES: everything else in
+    ``apply_operand`` follows from shapes, so a program compiled for
+    one ``(q, r, n, batch)`` serves every matrix of that shape."""
+    big = np.kron(np.eye(g, dtype=np.uint8), bmat_np.astype(np.uint8))
+    return big.astype(np.int8) if use_int8 else big.astype(np.float32)
+
+
+def apply_operand(bmat: jax.Array, data: jax.Array,
+                  tile_n: int = DEFAULT_TILE_N, use_int8: bool = True,
+                  group: int = DEFAULT_GROUP,
+                  subtiles: int = DEFAULT_SUBTILES,
+                  mxu_pack: bool = True) -> jax.Array:
+    """Apply the matrix operand ``bmat`` (``operand_np`` for this
+    batch's group, on the device or traced) to [..., q, n] uint8 data.
 
     Returns [..., r, n] uint8. n is padded to a multiple of tile_n if
-    needed (zero columns encode to zero parity — harmless, stripped);
-    ``group`` degrades to the largest divisor of the flattened batch.
+    needed (zero columns encode to zero parity — harmless, stripped).
     """
-    r8, q8 = bmat_np.shape
-    q, r = q8 // 8, r8 // 8
     data = jnp.asarray(data, dtype=jnp.uint8)
-    *lead, q_in, n = data.shape
-    assert q_in == q, f"data rows {q_in} != matrix cols {q}"
+    *lead, q, n = data.shape
     pad = (-n) % tile_n
     if pad:
         data = jnp.pad(data, [(0, 0)] * len(lead) + [(0, 0), (0, pad)])
     flat = data.reshape(-1, q, data.shape[-1])  # [B, q, n_pad]
-    g = group
-    while flat.shape[0] % g:
-        g //= 2
+    g = group_for(flat.shape[0], group)
+    r = bmat.shape[0] // (8 * g)
+    assert bmat.shape == (8 * r * g, 8 * q * g), \
+        f"matrix operand {bmat.shape} for {g} x {q} data rows"
     sub = subtiles
     while tile_n % sub:
         sub //= 2
-    bmat_u8 = np.ascontiguousarray(bmat_np.astype(np.uint8))
-    big_np, pack_np = _matrices_np(
-        (bmat_u8.shape[0], bmat_u8.shape[1], bmat_u8.tobytes()), g, r,
-        use_int8)
-    bmat = jnp.asarray(big_np,
-                       dtype=jnp.int8 if use_int8 else jnp.bfloat16)
-    packmat = jnp.asarray(pack_np)
-    out = _apply_3d(bmat, packmat, q, r, g, tile_n, sub, use_int8, flat,
-                    mxu_pack)
+    out = _apply_3d(bmat, jnp.asarray(_pack_np(r, g)), q, r, g, tile_n,
+                    sub, use_int8, flat, mxu_pack)
     out = out.reshape(*lead, r, data.shape[-1])
     if pad:
         out = out[..., :n]
     return out
+

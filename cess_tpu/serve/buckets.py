@@ -11,9 +11,15 @@ small set of shape-bucketed device programs). The engine therefore
   only ever sees O(log max_rows) distinct shapes per op, and
 - memoizes the bound device callable per (op, bucket shape, aux key)
   in :class:`ProgramCache`, so bucket reuse is visible in the stats
-  (``programs_built`` vs ``programs_reused``) and table builds
-  (nibble tables, bit-matrix expansion, decode-matrix Gauss-Jordan)
-  happen once per key rather than per call.
+  (``programs_built`` vs ``programs_reused``). A key names a SHAPE:
+  what varies from call to call within one shape — a repair's erasure
+  pattern (its matrix), an audit round's challenge — is an argument of
+  the program, never part of its key, so the number of programs does
+  not grow with the number of patterns (RS(10,4) repaired from
+  whichever ten helpers answer has 4,004 single-loss patterns and one
+  program a shape). The pattern's tables (nibble tables, bit-matrix
+  expansion, decode-matrix Gauss-Jordan) are the codec's to keep
+  (ops/rs.py ``TPUCodec.MATRICES``).
 
 Padding is with zero rows and is sliced off after the op; every engine
 op is row-independent (vmap / per-row matrix apply), so padded results
@@ -52,15 +58,17 @@ class ProgramCache:
     (b) the engine can report compile-vs-reuse counts, and (c) the
     bucket policy has one place to be enforced.
 
-    Bounded: the keys that do name data — one repair program per
-    erasure pattern (present, missing), and the per-fragment
-    verify_batch closure, whose key still carries its round's digest —
-    are hot for a while and dead afterwards, and an unbounded dict
-    would be a slow leak of closures and what they captured. LRU with
-    a generous capacity keeps everything live resident and lets the
-    dead fall out. The stacked audit programs (prove, verify_agg) take
-    the round and the PoDR2 key as operands: one entry per batch
-    shape, the same round after round.
+    Bounded: the keys that do name data — the per-fragment
+    verify_batch closure, whose key still carries its round's digest,
+    and a repair under the ``xor`` / ``auto`` strategies, whose key
+    carries the schedule's cost-model meta — are hot for a while and
+    dead afterwards, and an unbounded dict would be a slow leak of
+    closures and what they captured. LRU with a generous capacity
+    keeps everything live resident and lets the dead fall out. The
+    repair class (one entry per ``(q, r, n, bucket)``, the pattern an
+    argument) and the stacked audit programs (prove, verify_agg: the
+    round and the PoDR2 key are operands) hold one entry per shape,
+    the same call after call.
     """
 
     CAPACITY = 256

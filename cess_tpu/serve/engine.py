@@ -622,77 +622,68 @@ class SubmissionEngine:
     # ------------------------------------------------------------------
     def warm_repair(self, patterns, n: int, buckets=(1, 2)) -> None:
         """Pre-compile + pre-stage the repair-class programs for the
-        given erasure patterns so a restoral-market claim pays kernel
-        time, never compile/staging time (the warm path behind the
-        fragment_repair_warm_p99_ms bench metric).
+        SHAPES the given erasure patterns imply, so a restoral-market
+        claim pays kernel time, never compile/staging time.
 
         patterns: iterable of (present, missing) row tuples;
-        n: shard byte width; buckets: row-bucket sizes to warm. The
-        default covers a solo claim (bucket 1) AND two same-pattern
-        claims coalescing in the batching window (bucket 2) — wider
-        coalescence pads to a bucket that was never warmed and pays
-        one cold compile; pass more buckets when many miners race the
-        same restoral order (each warmed bucket costs one AOT compile
-        per pattern at warm time).
+        n: shard byte width; buckets: row-bucket sizes to warm. A
+        repair program is compiled per shape — ``(len(present),
+        len(missing), n, bucket)`` — with the pattern's matrix as its
+        operand, so what is warmed is every pattern of those shapes,
+        the ones never named here included: after this call such a
+        pattern compiles nothing, builds no program and takes the same
+        dispatch path as a named one. The named patterns' matrices are
+        built and staged too (the codec keeps the newest
+        ``TPUCodec.MATRICES``), so a caller with a few patterns, like
+        the protocol's RS(2,1) miner, never builds one under a claim.
+        The default buckets cover a solo claim (bucket 1) AND two
+        same-pattern claims coalescing in the batching window
+        (bucket 2) — wider coalescence pads to a bucket that was never
+        warmed and pays one cold compile; pass more buckets when many
+        miners race the same restoral order.
 
         Populates the engine program cache under the exact keys
-        ``_op_repair`` will look up, and — when the codec supports it
-        (TPUCodec.warm_reconstruct) — AOT-compiles the underlying
-        reconstruct program with its decode matrix baked in. The
-        flatten a host claim's result leaves the device through
+        ``_op_repair`` will look up, base and per pool lane, and — when
+        the codec supports it (TPUCodec.warm_reconstruct) —
+        compiles the underlying reconstruct program per device. A
+        codec whose strategy compiles the matrix into the program
+        (``xor`` / ``auto``) is warmed for the named patterns alone.
+        The flatten a host claim's result leaves the device through
         (_fetch_linear) is warmed for the same shapes and devices, by
         one run over zeros."""
         self._need_codec()
         warm = getattr(self.codec, "warm_reconstruct", None)
-        pool = self.pool
-        lanes = pool.lanes if pool is not None else ()
-
-        def warm_fetch(shape, lane=None):
-            with self._lane_placement(lane, False):
-                jax.block_until_ready(self._linear_rows_program(
-                    shape, lane)(jnp.zeros(shape, jnp.uint8)))
+        # (lane, its device): the base programs, then every lane's
+        lanes = self.pool.lanes if self.pool is not None else ()
+        placements = [(None, None)] + [(lane, lane.device)
+                                       for lane in lanes]
+        patterns = [(tuple(p), tuple(mi)) for p, mi in patterns]
 
         for present, missing in patterns:
-            present, missing = tuple(present), tuple(missing)
             for b in buckets:
                 bucket = bucket_rows(b)
-                # the warm key must carry the same cost-model meta
-                # _op_repair's lookup appends, or the warmed entry
-                # never hits
-                meta = self._codec_meta(self.codec, "repair", present,
-                                        missing,
-                                        (bucket, len(present), n))
-                if warm is not None:
-                    warm(present, missing,
-                         (bucket, len(present), n))
-                self.programs.get(
-                    ("repair", present, missing, n, bucket) + meta,
-                    lambda p=present, mi=missing:
-                        (lambda a: self.codec.reconstruct(a, p, mi)))
-                warm_fetch((bucket, len(missing), n))
-                # pool path: pre-populate EVERY lane's slice of the
-                # cache under the device-component keys _op_repair
-                # will look up, and AOT-compile per lane device — a
-                # repair storm fans out across lanes without any lane
-                # paying compile/staging time (and a program warmed
-                # for device 0 is never handed a lane-3 batch)
-                for lane in lanes:
+                aux = {"present": present, "missing": missing}
+                # base first, then EVERY lane's slice of the cache and
+                # its device's program — a repair storm fans out
+                # across lanes without any lane paying compile/staging
+                # time (and a program warmed for device 0 is never
+                # handed a lane-3 batch)
+                for lane, device in placements:
                     if warm is not None:
-                        warm(present, missing,
-                             (bucket, len(present), n),
-                             device=lane.device)
-                    self.programs.get(
-                        self._key(("repair", present, missing, n,
-                                   bucket), False, lane) + meta,
-                        lambda p=present, mi=missing:
-                            (lambda a: self.codec.reconstruct(a, p,
-                                                              mi)))
-                    warm_fetch((bucket, len(missing), n), lane)
+                        warm(present, missing, (bucket, len(present), n),
+                             device=device)
+                    self._repair_program(self.codec, "reconstruct", aux,
+                                         n, bucket, False, lane)
+                    with self._lane_placement(lane, False):
+                        shape = (bucket, len(missing), n)
+                        jax.block_until_ready(self._linear_rows_program(
+                            shape, lane)(jnp.zeros(shape, jnp.uint8)))
         # regen leg: when the codec carries the symbol surface
-        # (RegenCodec.warm_fold), warm the helper-fold programs for
-        # every coefficient the single-missing patterns can ask for —
-        # same base + per-lane key discipline as the reconstructs, so
-        # a symbol chain fanned across lanes never pays compile time
+        # (RegenCodec.warm_fold), warm the helper-fold program and
+        # stage every coefficient the single-missing patterns can ask
+        # for — same base + per-lane key discipline as the
+        # reconstructs, so a symbol chain fanned across lanes never
+        # pays compile time
         warm_fold = getattr(self.codec, "warm_fold", None)
         if warm_fold is None:
             return
@@ -700,7 +691,6 @@ class SubmissionEngine:
 
         coeffs: set[int] = set()
         for present, missing in patterns:
-            present, missing = tuple(present), tuple(missing)
             if len(missing) != 1:
                 continue
             coeffs.update(regen.repair_coeffs(
@@ -709,20 +699,11 @@ class SubmissionEngine:
         for c in sorted(coeffs):
             for b in buckets:
                 bucket = bucket_rows(b)
-                meta = self._codec_meta(self.codec, "symbol", (c,), (),
-                                        (bucket, 2, n))
-                warm_fold(c, (bucket, 2, n))
-                self.programs.get(
-                    ("symbol", c, n, bucket) + meta,
-                    lambda cc=c:
-                        (lambda a: self.codec.fold_symbol(a, cc)))
-                for lane in lanes:
-                    warm_fold(c, (bucket, 2, n), device=lane.device)
-                    self.programs.get(
-                        self._key(("symbol", c, n, bucket), False,
-                                  lane) + meta,
-                        lambda cc=c:
-                            (lambda a: self.codec.fold_symbol(a, cc)))
+                for lane, device in placements:
+                    warm_fold(c, (bucket, 2, n), device=device)
+                    self._repair_program(self.codec, "symbol",
+                                         {"coeff": c}, n, bucket, False,
+                                         lane)
 
     def attach_stream(self, stream_stats) -> None:
         """Register a streaming driver's StreamStats so its per-stage
@@ -1585,7 +1566,7 @@ class SubmissionEngine:
         """Degraded programs cache under their own keys — a breaker
         flip must never hand a device program a CPU batch or vice
         versa. On the pool path the key grows a device component for
-        the same reason: a program compiled (AOT-warmed) for lane 0's
+        the same reason: a program compiled (warmed) for lane 0's
         device must never be handed a batch placed on lane 3
         (degraded keys stay device-free — the CPU fallback program is
         one program, shared by every lane)."""
@@ -1623,50 +1604,74 @@ class SubmissionEngine:
             n = surv.shape[2]
             surv = _pad_axis0(surv, bucket)
         with self._stage("repair", "dispatch"):
-            out = self._repair_program(codec, kind, aux, n, bucket,
-                                       degraded, lane)(surv)[:total]
+            prog, pattern = self._repair_program(codec, kind, aux, n,
+                                                 bucket, degraded, lane)
+            out = prog(surv, *pattern)[:total]
         return self._split_rows(batch, out, lane), bucket
 
     def _repair_program(self, codec, kind: str, aux: dict, n: int,
                         bucket: int, degraded: bool, lane):
-        """The repair class's cached program for one (kind, pattern,
-        shape bucket)."""
+        """The repair class's cached program for one (kind, SHAPE,
+        row bucket) and the pattern it is called with: ``program(
+        survivors, *pattern)``. The shape is the pattern's row counts
+        ``(q, r)``; the pattern itself (which rows, which coefficient)
+        is an argument, so a pattern never seen before builds no
+        program. Only the cost-model meta of a codec whose strategy
+        compiles the matrix into its program (``xor`` / ``auto``,
+        _codec_meta) still reads the pattern."""
         if kind == "reconstruct":
             present, missing = aux["present"], aux["missing"]
             meta = self._codec_meta(codec, "repair", present, missing,
                                     (bucket, len(present), n))
-            prog = self.programs.get(
-                self._key(("repair", present, missing, n, bucket),
-                          degraded, lane) + meta,
-                lambda: (lambda a: codec.reconstruct(a, present,
-                                                     missing)))
+            key = ("repair", len(present), len(missing), n, bucket)
+            call, pattern = codec.reconstruct, (present, missing)
         elif kind == "symbol":
-            coeff = aux["coeff"]
-            fold = getattr(codec, "fold_symbol", None)
-            if fold is None:
+            pattern = (aux["coeff"],)
+            call = getattr(codec, "fold_symbol", None)
+            if call is None:
                 # breaker-degraded (or plain-reference fallback) codec:
                 # serve the fold from the host twin — the chain stays
                 # bit-identical, only the placement degrades
                 from ..ops import regen
 
-                fold = regen.fold_symbol_pairs
+                call = regen.fold_symbol_pairs
                 meta = ()
             else:
-                meta = self._codec_meta(codec, "symbol", (coeff,), (),
+                meta = self._codec_meta(codec, "symbol", pattern, (),
                                         (bucket, 2, n))
-            prog = self.programs.get(
-                self._key(("symbol", coeff, n, bucket), degraded,
-                          lane) + meta,
-                lambda f=fold, c=coeff: (lambda a: f(a, c)))
+            key = ("symbol", n, bucket)
         else:
-            present = aux["present"]
-            meta = self._codec_meta(codec, "decode", present, (),
-                                    (bucket, len(present), n))
-            prog = self.programs.get(
-                self._key(("decode", present, n, bucket), degraded,
-                          lane) + meta,
-                lambda: (lambda a: codec.decode_data(a, present)))
-        return prog
+            pattern = (aux["present"],)
+            meta = self._codec_meta(codec, "decode", pattern[0], (),
+                                    (bucket, len(pattern[0]), n))
+            key = ("decode", len(pattern[0]), n, bucket)
+            call = codec.decode_data
+        prog = self.programs.get(self._key(key, degraded, lane) + meta,
+                                 lambda: self._counting_matrices(codec,
+                                                                 call))
+        return prog, pattern
+
+    def _counting_matrices(self, codec, call):
+        """``call`` (``codec``'s reconstruct / decode_data /
+        fold_symbol) as the repair class's program. A device codec
+        builds the matrix of a pattern it does not hold inside the call
+        and says so through an obs.trace.stage sink
+        (TPUCodec._matrix_for): counted here as ``patterns_new`` /
+        ``matrix_build_s``. The host codecs (the CPU fallback) keep no
+        matrices and are called as they are."""
+        if not hasattr(codec, "warm_reconstruct"):
+            return call
+
+        def counted(survivors, *pattern):
+            sink: dict = {}
+            out = call(survivors, *pattern, sink=sink)
+            if sink:
+                with self._lock:
+                    st = self.stats.classes["repair"]
+                    st.patterns_new += sink["repair.matrix"][0]
+                    st.matrix_build_s += sink["repair.matrix.build"][1]
+            return out
+        return counted
 
     def _op_tag(self, batch, degraded=False, lane=None):
         audit = self._audit_backend(degraded, lane)

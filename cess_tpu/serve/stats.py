@@ -45,7 +45,8 @@ class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
                  "saturated", "shed", "batches", "batched_requests",
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
-                 "latencies", "hist", "stage_n", "stage_s")
+                 "patterns_new", "matrix_build_s", "latencies", "hist",
+                 "stage_n", "stage_s")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -68,6 +69,15 @@ class ClassStats:
         # _fetch_linear); device submitters' and non-byte results
         # (tags, verdicts) leave it 0
         self.linear_fetches = 0
+        # repair class, device codec: batches whose erasure pattern the
+        # codec held no matrix for (engine.py _counting_matrices), and
+        # the host seconds their matrices took to build (GF
+        # Gauss-Jordan + table expansion; the put of the operands is in
+        # the ``cess:repair.matrix`` span around it, inside dispatch).
+        # A program is NOT built for such a pattern: programs_built
+        # counts programs, one per shape
+        self.patterns_new = 0
+        self.matrix_build_s = 0.0
         self.latencies = collections.deque(maxlen=LATENCY_WINDOW)
         # real Prometheus histogram of the same submit->resolve
         # latencies: unlike the sliding-window percentiles above this
@@ -230,6 +240,8 @@ class EngineStats:
                 "pad_waste": round(st.pad_waste, 4),
                 "operand_bytes": st.operand_bytes,
                 "linear_fetches": st.linear_fetches,
+                "patterns_new": st.patterns_new,
+                "matrix_build_s": st.matrix_build_s,
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
                 "stages": {stage: {"n": st.stage_n[stage],

@@ -11,12 +11,33 @@ import os
 import sys
 
 import jax
+import jax.monitoring
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
+
+_COMPILES = [0]
+
+
+def _count_compile(event, secs, **_):
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILES[0] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+@pytest.fixture
+def compiles():
+    """``compiles()``: the XLA compilations this process has made so
+    far. A warmed path is proved by differencing it to 0 (the dense RS
+    strategies keep no executable of their own to count hits of: their
+    programs are jit's)."""
+    return lambda: _COMPILES[0]
 
 
 def pytest_configure(config):

@@ -64,7 +64,9 @@ def _pallas_names(fn, *args) -> list[str]:
 def test_rs_kernel_call_carries_the_name():
     bmat = gf.expand_bitmatrix(gf.cauchy_parity_matrix(2, 1))
     data = jnp.zeros((2, 2, rs_pallas.DEFAULT_TILE_N), jnp.uint8)
-    assert _pallas_names(lambda d: rs_pallas.apply_bitmatrix(bmat, d),
+    operand = jnp.asarray(
+        rs_pallas.operand_np(bmat, rs_pallas.group_for(data.shape[0])))
+    assert _pallas_names(lambda d: rs_pallas.apply_operand(operand, d),
                          data) == [rs_pallas.KERNEL_NAME]
 
 

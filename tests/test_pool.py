@@ -177,49 +177,58 @@ def test_chaos_drill_journals_the_drain():
 
 # -- warm programs are device-keyed (the one-device key bugfix) -------------
 
-def test_warm_reconstruct_hits_only_its_own_device():
+@pytest.mark.parametrize("strategy", ["gather", "xor"])
+def test_warm_reconstruct_hits_only_its_own_device(strategy, compiles):
     devs = jax.devices()
-    assert len(devs) >= 2       # conftest: 8 virtual CPU devices
-    codec = rs.TPUCodec(K, M)
-    data = rnd((K, 256), 11)
+    assert len(devs) >= 3       # conftest: 8 virtual CPU devices
+    codec = rs.TPUCodec(K, M, strategy=strategy)
+    baked = strategy == "xor"   # an AOT executable a pattern, counted
+    data = rnd((K, 264), 11)    # a width no other test compiles
     coded = np.asarray(codec.encode(data))
     surv, present, missing = coded[[1, 2]], (1, 2), (0,)
     codec.warm_reconstruct(present, missing, surv.shape,
-                           device=devs[0])
-    # under a DIFFERENT device's placement scope the dev-0 executable
-    # must not hit (pre-fix, the device-free key dispatched a program
-    # staged on the wrong chip); the cold path still serves correctly
-    with jax.default_device(devs[1]):
-        out = np.asarray(codec.reconstruct(surv, present, missing))
-    assert codec.warm_hits == 0
-    assert np.array_equal(out[0], data[0])
-    # warming FOR that placement makes the same call hit
-    codec.warm_reconstruct(present, missing, surv.shape,
                            device=devs[1])
+    # under a DIFFERENT device's placement scope the dev-1 program
+    # must not run (pre-fix, the device-free key dispatched a program
+    # staged on the wrong chip); the cold path still serves correctly
+    compiled = compiles()
+    with jax.default_device(devs[2]):
+        out = np.asarray(codec.reconstruct(surv, present, missing))
+    assert codec.warm_hits == 0 and compiles() > compiled
+    assert np.array_equal(out[0], data[0])
+    # warmed FOR its placement the same call compiles nothing
+    compiled = compiles()
     with jax.default_device(devs[1]):
         out2 = np.asarray(codec.reconstruct(surv, present, missing))
-    assert codec.warm_hits == 1
+    assert codec.warm_hits == baked and compiles() == compiled
     assert np.array_equal(out2, out)
     # no scope + no device keeps the PR-2 single-device contract
     codec.warm_reconstruct(present, missing, surv.shape)
+    compiled = compiles()
     np.asarray(codec.reconstruct(surv, present, missing))
-    assert codec.warm_hits == 2
+    assert codec.warm_hits == 2 * baked and compiles() == compiled
 
 
-def test_engine_warm_repair_warms_every_lane():
+def test_engine_warm_repair_warms_every_lane(compiles):
     eng = _pool_engine(n=2)
     try:
         eng.warm_repair([((1, 2), (0,))], 256, buckets=(1,))
         # one device-free program + one per lane, all under the exact
         # keys _op_repair looks up
-        keys = {("repair", (1, 2), (0,), 256, 1),
-                ("repair", (1, 2), (0,), 256, 1, ("device", 0)),
-                ("repair", (1, 2), (0,), 256, 1, ("device", 1))}
+        # (the shape's: 2 survivors, 1 lost row; the pattern is the
+        # program's argument)
+        keys = {("repair", 2, 1, 256, 1),
+                ("repair", 2, 1, 256, 1, ("device", 0)),
+                ("repair", 2, 1, 256, 1, ("device", 1))}
         assert keys <= set(eng.programs._programs)
-        # the codec's AOT warm dict holds one executable per device
-        warm_devices = {k[-1] for k in eng.codec._warm}
-        assert {d for d in warm_devices if d is not None} \
-            == {eng.pool.lanes[0].device, eng.pool.lanes[1].device}
+        # and the codec's program is compiled for every lane's device
+        compiled = compiles()
+        surv = rnd((1, 2, 256), 12)
+        for lane in eng.pool.lanes:
+            with jax.default_device(lane.device):
+                jax.block_until_ready(
+                    eng.codec.reconstruct(surv, (0, 2), (1,)))
+        assert compiles() == compiled
     finally:
         eng.close()
 

@@ -243,36 +243,46 @@ class TestRegenCodec:
                 np.asarray(codec.fold_symbol(pairs, coeff)),
                 regen.fold_symbol_pairs(pairs, coeff))
 
-    def test_warm_fold_hits(self):
-        codec = regen.RegenCodec(2, 1)
-        pairs = rnd((2, 2, 64), 5)
-        out_cold = np.asarray(codec.fold_symbol(pairs, 7))
-        assert codec.warm_hits == 0
+    @pytest.mark.parametrize("strategy", ["gather", "xor"])
+    def test_warm_fold_hits(self, strategy, compiles):
+        codec = regen.RegenCodec(2, 1, strategy=strategy)
+        baked = strategy == "xor"   # an AOT executable a coefficient
+        pairs = rnd((2, 2, 72), 5)  # a width no other test compiles
         codec.warm_fold(7, pairs.shape)
+        compiled = compiles()
         out_warm = np.asarray(codec.fold_symbol(pairs, 7))
-        assert codec.warm_hits == 1
-        assert np.array_equal(out_warm, out_cold)
-        # a different coefficient or shape stays cold
-        np.asarray(codec.fold_symbol(pairs, 8))
-        np.asarray(codec.fold_symbol(rnd((3, 2, 64), 6), 7))
-        assert codec.warm_hits == 1
+        assert codec.warm_hits == baked and compiles() == compiled
+        assert np.array_equal(out_warm,
+                              regen.fold_symbol_pairs(pairs, 7))
+        # gather: the coefficient is an operand of the warmed program,
+        # so another one runs it too, bit-exact (xor: the coefficient
+        # is the program); another shape stays cold
+        out_8 = np.asarray(codec.fold_symbol(pairs, 8))
+        assert (compiles() == compiled) == (not baked)
+        assert np.array_equal(out_8, regen.fold_symbol_pairs(pairs, 8))
+        compiled = compiles()
+        np.asarray(codec.fold_symbol(rnd((3, 2, 72), 6), 7))
+        assert codec.warm_hits == baked and compiles() > compiled
 
-    def test_warm_fold_hits_only_its_own_device(self):
+    @pytest.mark.parametrize("strategy", ["gather", "xor"])
+    def test_warm_fold_hits_only_its_own_device(self, strategy, compiles):
         # mirror of the reconstruct device-key pin (test_pool): a fold
-        # warmed for dev-0 must not dispatch under dev-1's placement
+        # warmed for dev-1 must not dispatch under dev-2's placement
         devs = jax.devices()
-        assert len(devs) >= 2           # conftest: 8 virtual devices
-        codec = regen.RegenCodec(2, 1)
-        pairs = rnd((2, 2, 64), 8)
-        codec.warm_fold(5, pairs.shape, device=devs[0])
-        with jax.default_device(devs[1]):
-            out = np.asarray(codec.fold_symbol(pairs, 5))
-        assert codec.warm_hits == 0
-        assert np.array_equal(out, regen.fold_symbol_pairs(pairs, 5))
+        assert len(devs) >= 3           # conftest: 8 virtual devices
+        codec = regen.RegenCodec(2, 1, strategy=strategy)
+        pairs = rnd((2, 2, 88), 8)      # a width no other test compiles
         codec.warm_fold(5, pairs.shape, device=devs[1])
+        compiled = compiles()
+        with jax.default_device(devs[2]):
+            out = np.asarray(codec.fold_symbol(pairs, 5))
+        assert codec.warm_hits == 0 and compiles() > compiled
+        assert np.array_equal(out, regen.fold_symbol_pairs(pairs, 5))
+        compiled = compiles()
         with jax.default_device(devs[1]):
             out2 = np.asarray(codec.fold_symbol(pairs, 5))
-        assert codec.warm_hits == 1
+        assert codec.warm_hits == (strategy == "xor")
+        assert compiles() == compiled
         assert np.array_equal(out2, out)
 
 
@@ -302,7 +312,7 @@ class TestEngineSymbols:
         finally:
             eng.close()
 
-    def test_warm_repair_warms_fold_programs_per_lane(self):
+    def test_warm_repair_warms_fold_programs_per_lane(self, compiles):
         eng = make_engine(2, 1, rs_backend="regen",
                           policy=AdmissionPolicy(max_delay=0.002),
                           pool=DevicePool(n=2))
@@ -312,23 +322,25 @@ class TestEngineSymbols:
             coeffs.discard(0)
             assert coeffs
             keys = set(eng.programs._programs)
-            for c in coeffs:
-                # base + one per lane, under the exact keys _op_repair
-                # looks up — same discipline as the reconstructs
-                assert ("symbol", c, 256, 1) in keys
-                assert ("symbol", c, 256, 1, ("device", 0)) in keys
-                assert ("symbol", c, 256, 1, ("device", 1)) in keys
-            # the codec warm dict carries a fold executable per device
-            fold_devs = {k[-1] for k in eng.codec._warm
-                         if k[0][0] == "symbol"}
-            assert {d for d in fold_devs if d is not None} == \
-                {eng.pool.lanes[0].device, eng.pool.lanes[1].device}
-            # and the warmed fold actually hits through the engine
-            before = eng.codec.warm_hits
+            # base + one per lane, under the exact keys _op_repair
+            # looks up — same discipline as the reconstructs; the
+            # coefficient is the program's argument, not in its key
+            assert ("symbol", 256, 1) in keys
+            assert ("symbol", 256, 1, ("device", 0)) in keys
+            assert ("symbol", 256, 1, ("device", 1)) in keys
+            # the fold's program — the [1, 2] matrix's at [1, 2, 256],
+            # at RS(2,1) the one-row repair's too — is compiled for
+            # every lane's device
             pairs = rnd((1, 2, 256), 2)
+            compiled = compiles()
+            for lane in eng.pool.lanes:
+                with jax.default_device(lane.device):
+                    jax.block_until_ready(
+                        eng.codec.fold_symbol(pairs, sorted(coeffs)[0]))
+            assert compiles() == compiled
+            # and the fold through the engine runs it
             out = np.asarray(eng.repair_symbol(
                 pairs, sorted(coeffs)[0], timeout=60))
-            assert eng.codec.warm_hits > before
             assert np.array_equal(
                 out, regen.fold_symbol_pairs(pairs, sorted(coeffs)[0]))
         finally:

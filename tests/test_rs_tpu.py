@@ -7,6 +7,7 @@ is held to the references by chip_smoke.py and by every cell of the
 benchmark (benchmark/run.py decides ``correct`` against
 benchmark/reference/).
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -66,6 +67,30 @@ def test_reconstruct_all_erasure_patterns(k, m, strategy):
 
 
 @pytest.mark.parametrize("strategy", STRATEGIES)
+def test_reconstruct_10p4_from_helpers_that_are_not_the_lowest(strategy):
+    """The archival tier's repair: ten helpers that are NOT the k lowest
+    survivors (parity rows among them, data rows among the lost), one to
+    four rows lost; every pattern after the first of a shape reuses its
+    jitted program with another matrix."""
+    k, m = 10, 4
+    ref = ReferenceCodec(k, m)
+    tpu = TPUCodec(k, m, strategy=strategy)
+    shards = ref.encode(rand((2, k, 128), seed=104))
+    for present, missing in [
+            ((0, 1, 2, 4, 5, 7, 8, 10, 12, 13), (3,)),
+            ((1, 2, 3, 4, 5, 6, 8, 9, 11, 13), (0,)),
+            ((0, 2, 3, 5, 6, 7, 9, 10, 11, 13), (1, 12)),
+            ((0, 1, 3, 4, 6, 7, 9, 10, 11, 12), (2, 5, 13)),
+            ((1, 2, 3, 5, 6, 8, 10, 11, 12, 13), (0, 4, 7, 9))]:
+        survivors = shards[:, list(present), :]
+        got = np.asarray(tpu.reconstruct(survivors, present, missing))
+        np.testing.assert_array_equal(got, shards[:, list(missing), :])
+        np.testing.assert_array_equal(
+            got, ref.reconstruct(survivors, present, missing))
+    assert len(tpu._cache) == 5 and not tpu._warm
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
 def test_segment_sized_shards(strategy):
     """One real-geometry shard column count (scaled-down fragment)."""
     k, m = 4, 8
@@ -87,14 +112,16 @@ def test_make_codec_backends():
 @pytest.mark.parametrize("use_int8", [True, False])
 def test_pallas_kernel_matches_oracle(use_int8):
     """Fused Pallas kernel (interpret mode on CPU) vs oracle, incl. padding."""
-    from cess_tpu.ops.rs_pallas import apply_bitmatrix
+    from cess_tpu.ops.rs_pallas import apply_operand, group_for, operand_np
 
     k, m = 4, 8
     ref = ReferenceCodec(k, m)
-    bmat = gf.expand_bitmatrix(ref.parity)
+    bmat = jnp.asarray(
+        operand_np(gf.expand_bitmatrix(ref.parity), group_for(2), use_int8),
+        dtype=jnp.int8 if use_int8 else jnp.bfloat16)
     for n in (512, 700):  # 700 exercises the pad-to-tile path
         data = rand((2, k, n), seed=n)
-        got = np.asarray(apply_bitmatrix(bmat, data, tile_n=512, use_int8=use_int8))
+        got = np.asarray(apply_operand(bmat, data, tile_n=512, use_int8=use_int8))
         np.testing.assert_array_equal(got, ref.encode_parity(data))
 
 

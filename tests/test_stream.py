@@ -244,19 +244,26 @@ def test_stream_run_validates_eagerly():
 
 # -- repair warm path -------------------------------------------------------
 
-def test_warm_reconstruct_bit_exact_and_cached():
-    codec = rs.TPUCodec(K, M, strategy="gather")
-    data = rnd((K, 512), 60)
+@pytest.mark.parametrize("strategy", ["gather", "xor"])
+def test_warm_reconstruct_bit_exact_and_cached(strategy, compiles):
+    codec = rs.TPUCodec(K, M, strategy=strategy)
+    data = rnd((K, 520), 60)          # a width no other test compiles
     coded = np.asarray(codec.encode(data))
     surv = coded[[1, 2]]
-    prog = codec.warm_reconstruct((1, 2), (0,), surv.shape)
-    assert codec.warm_reconstruct((1, 2), (0,), surv.shape) is prog
+    codec.warm_reconstruct((1, 2), (0,), surv.shape)
+    compiled = compiles()
+    codec.warm_reconstruct((1, 2), (0,), surv.shape)      # once
     rec = np.asarray(codec.reconstruct(surv, (1, 2), (0,)))
+    assert compiles() == compiled
     assert np.array_equal(rec[0], coded[0])
-    # non-warmed pattern still takes the jit path, same result
+    # gather: the program is jit's, and the pattern its argument, so a
+    # pattern never warmed runs it too; xor: the pattern IS the
+    # program, warmed as an AOT executable of its own
+    assert codec.warm_hits == (strategy == "xor")
     surv2 = coded[[0, 2]]
     rec2 = np.asarray(codec.reconstruct(surv2, (0, 2), (1,)))
     assert np.array_equal(rec2[0], coded[1])
+    assert (compiles() == compiled) == (strategy == "gather")
 
 
 def test_engine_warm_repair_prepopulates_programs():
@@ -280,9 +287,9 @@ def test_engine_warm_repair_prepopulates_programs():
 
 
 def test_miner_warm_restoral_smoke():
-    """warm_restoral enumerates the restoral patterns without error on
-    both the engine and the direct-codec path (the NumPy reference
-    codec is a documented no-op)."""
+    """warm_restoral warms the restoral shape without error on both
+    the engine and the direct-codec path (the NumPy reference codec is
+    a documented no-op): one program for every lost row."""
     from cess_tpu.node.chain_spec import dev_spec
     from cess_tpu.node.network import Node
     from cess_tpu.node.offchain import MinerAgent
@@ -294,6 +301,15 @@ def test_miner_warm_restoral_smoke():
                       policy=AdmissionPolicy(max_delay=0.005))
     try:
         MinerAgent(node, "m2", [], pipe, engine=eng).warm_restoral()
-        assert eng.stats_snapshot()["programs_built"] >= ROWS
+        built = eng.stats_snapshot()["programs_built"]
+        assert built >= 1
+        n = pipe.config.fragment_size
+        coded = np.asarray(eng.codec.encode(rnd((1, K, n), 62)))
+        for row in range(ROWS):
+            present = tuple(j for j in range(ROWS) if j != row)[:K]
+            rec = eng.reconstruct(coded[:, list(present)], present,
+                                  (row,))
+            assert np.array_equal(np.asarray(rec)[:, 0], coded[:, row])
+        assert eng.stats_snapshot()["programs_built"] == built
     finally:
         eng.close()

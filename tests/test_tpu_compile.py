@@ -30,7 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 from cess_tpu import constants
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
 from cess_tpu.node import offchain
-from cess_tpu.ops import gf, podr2, podr2_pallas, rs_pallas, rs_xor, \
+from cess_tpu.ops import gf, podr2, podr2_pallas, rs, rs_pallas, rs_xor, \
     target, xor_sched
 from cess_tpu.parallel import mesh as pmesh
 from cess_tpu.serve import engine
@@ -82,16 +82,27 @@ def for_tpu(monkeypatch):
     jax.clear_caches()
 
 
+def _rs_constant(mat):
+    # the matrix a constant of the program, as the fused ingest has it
+    bmat = gf.expand_bitmatrix(mat)
+    return lambda d: rs_pallas.apply_operand(
+        jnp.asarray(rs_pallas.operand_np(
+            bmat, rs_pallas.group_for(d.shape[0]))), d)
+
+
 def _rs_encode(k, m):
-    bmat = gf.expand_bitmatrix(gf.cauchy_parity_matrix(k, m))
-    return lambda d: rs_pallas.apply_bitmatrix(bmat, d)
+    return _rs_constant(gf.cauchy_parity_matrix(k, m))
 
 
 def _rs_repair_one_row():
     # RS(4,8), row 0 lost, rebuilt from the 4 lowest survivors
-    bmat = gf.expand_bitmatrix(
-        gf.repair_matrix(4, 8, (1, 2, 3, 4), (0,)))
-    return lambda d: rs_pallas.apply_bitmatrix(bmat, d)
+    return _rs_constant(gf.repair_matrix(4, 8, (1, 2, 3, 4), (0,)))
+
+
+def _rs_operand():
+    # the repair class's program since PR 31: the pattern's bit-matrix
+    # is an ARGUMENT (int8 [8r, 8q] at batch 1), one program a shape
+    return rs._DENSE["pallas"]
 
 
 def _xor_encode(k, m):
@@ -133,6 +144,13 @@ CASES = [
      [((8, 2, 8 * MiB), jnp.uint8)], (RS,)),
     ("rs_pallas-repair-one-row", _rs_repair_one_row,
      [((1, 4, 8 * MiB), jnp.uint8)], (RS,)),
+    # the archival tier (RS(10,4)): ten 8 MiB helpers -> r lost rows,
+    # and the protocol's one-row repair through the same program
+    *[(f"rs_pallas-repair-10p4-r{r}", _rs_operand,
+       [((8 * r, 80), jnp.int8), ((1, 10, 8 * MiB), jnp.uint8)], (RS,))
+      for r in (1, 2, 3, 4)],
+    ("rs_pallas-repair-2p1-operand", _rs_operand,
+     [((8, 16), jnp.int8), ((1, 2, 8 * MiB), jnp.uint8)], (RS,)),
     ("podr2_pallas-tags", _podr2_tags,
      [((8, 2, 16384), jnp.uint32), ((8, 16384, 512), jnp.uint8)],
      (TAGS,)),

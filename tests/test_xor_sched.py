@@ -239,7 +239,7 @@ def test_engine_warm_repair_keys_carry_cost_model_meta():
         assert dict(meta)["strategy"].startswith("auto:")
         # one device-free program + one per lane, all under the exact
         # meta-extended keys _op_repair looks up
-        base = ("repair", (1, 2), (0,), 256, 1)
+        base = ("repair", 2, 1, 256, 1)
         keys = {base + meta,
                 base + (("device", 0),) + meta,
                 base + (("device", 1),) + meta}
