@@ -623,14 +623,16 @@ class MinerAgent:
         whichever k holders ``try_repair`` found, in row order — and
         reconstruct (engine repair queue when attached, direct codec
         otherwise). The program is the warmed shape's whatever the
-        helper set; the set picks the matrix it is called with."""
+        helper set; the set picks the matrix it is called with. The
+        engine takes the rows as they lie in the holders' stores (views
+        of their ``bytes``): it puts each on the device from there and
+        stacks them there, so no host copy of the k fragments is made."""
         survivors = [np.frombuffer(
             holders[j].store[seg.fragment_hashes[j]], dtype=np.uint8)
             for j in present]
         self.repair_ingress_bytes += sum(s.nbytes for s in survivors)
         if self.engine is not None and self.engine.codec is not None:
-            rec = self.engine.reconstruct(np.stack(survivors),
-                                          present, (row,),
+            rec = self.engine.reconstruct(survivors, present, (row,),
                                           tenant=self.account)
         else:
             from ..ops.rs import make_codec

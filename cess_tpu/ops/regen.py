@@ -266,14 +266,12 @@ class RegenCodec(TPUCodec):
         self._warm_program(("symbol", (int(coeff),), ()), shape, device)
 
     def fold_symbol(self, pairs, coeff: int, *, sink: dict | None = None):
-        """pairs [..., 2, n] uint8 (accumulator, fragment) rows ->
-        [..., 1, n]: acc ^ coeff*fragment, batched on device.
+        """pairs [..., 2, n] uint8 (accumulator, fragment) rows, or
+        the same as ``rs.LinearRows`` -> [..., 1, n]: acc ^
+        coeff*fragment, batched on device.
         Compiles nothing when warmed for this shape and placement.
         ``sink`` as for ``reconstruct``."""
-        import jax.numpy as jnp
-
-        return self._apply(("symbol", (int(coeff),), ()),
-                           jnp.asarray(pairs, dtype=jnp.uint8), sink)
+        return self._apply(("symbol", (int(coeff),), ()), pairs, sink)
 
     def repair_coeffs(self, present: tuple[int, ...],
                       missing: tuple[int, ...]) -> tuple[int, ...]:

@@ -49,7 +49,7 @@ import contextlib
 import functools
 import math
 import threading
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -157,6 +157,52 @@ _DENSE = {"gather": _apply_gather, "bitmatrix": _apply_bitmatrix,
           "pallas": _apply_pallas}
 
 
+class LinearRows(NamedTuple):
+    """Data ``u8[B, q, n]`` still as its ``B * q`` linear rows on the
+    device: row j of request i is ``rows[i * q + j]``, a 1-D ``u8[n]``.
+    A host array reaches the device fastest in this shape: the TPU
+    packs four rows of the second-minor dimension into each 32-bit
+    word, and a host -> device put of ``u8[1, 2, n]`` takes 8.7 ms
+    where the same 16 MiB as two ``u8[n]`` take 2.5 (PERF.md section 5,
+    the link probe). The codec's decode-side calls (``reconstruct`` /
+    ``decode_data`` / ``fold_symbol``) take one in place of the array
+    and stack it on the device, inside the program that applies the
+    matrix (``_apply_rows``)."""
+    rows: tuple
+    q: int
+
+    @property
+    def shape(self) -> tuple:
+        return (len(self.rows) // self.q, self.q) + self.rows[0].shape
+
+
+def _stack_rows(rows, q: int) -> jax.Array:
+    """``rows`` (``LinearRows.rows``, traced) as ``u8[B, q, n]``.
+    ``stack`` forms only: a ``reshape`` that moves bytes between
+    dimensions compiles in time proportional to the array on this
+    libtpu (models/pipeline.py split_rows); this compiles in under a
+    second at q = 10, n = 8 MiB, and runs there in 3.2 ms (0.97 ms at
+    q = 2), twice as fast as ``dynamic_update_slice`` into zeros
+    (PERF.md, PR 32: each row is relayouted on its own, then the rows
+    are concatenated)."""
+    return jnp.stack([jnp.stack(rows[i:i + q])
+                      for i in range(0, len(rows), q)])
+
+
+@functools.partial(jax.jit, static_argnames=("strategy", "q"))
+def _apply_rows(operands, rows, *, strategy: Strategy, q: int):
+    """The dense lowerings over linear rows: stack, then apply, one
+    program per (strategy, matrix shape, row count, n, placement). The
+    inner program is traced into this one, so the Pallas kernel keeps
+    its name (``_apply_3d``) in the compiled text and in a trace."""
+    return _DENSE[strategy](*operands, _stack_rows(rows, q))
+
+
+# the stack alone, for the strategies whose program is the matrix
+# (``xor`` / ``auto``): their executables take the stacked array
+_STACK_ROWS = jax.jit(_stack_rows, static_argnames=("q",))
+
+
 # ---------------------------------------------------------------------------
 # Codec front-end
 # ---------------------------------------------------------------------------
@@ -261,7 +307,9 @@ class _MatrixApply:
 
         return rs_xor.apply_schedule(self._sched, data)
 
-    def __call__(self, data: jax.Array) -> jax.Array:
+    def __call__(self, data) -> jax.Array:
+        """Apply to ``data``: ``u8[..., q, n]`` or, under the dense
+        strategies, ``LinearRows`` (stacked inside the program)."""
         if data.shape[-2] != self.mat.shape[1]:
             raise ValueError(
                 f"expected {self.mat.shape[1]} shard rows, got {data.shape[-2]}"
@@ -272,6 +320,9 @@ class _MatrixApply:
             if self._decide(data.shape)["chosen"] == "xor":
                 return self._apply_xor(data)
             return self._auto_base(data)
+        if isinstance(data, LinearRows):
+            return _apply_rows(self.operands(data.shape), data.rows,
+                               strategy=self.strategy, q=data.q)
         return _DENSE[self.strategy](*self.operands(data.shape), data)
 
     def aot(self, shape, dtype=jnp.uint8, device=None):
@@ -353,6 +404,12 @@ class TPUCodec:
         self._warm: dict[tuple, Callable] = {}
         self.warm_hits = 0
 
+    @property
+    def baked(self) -> bool:
+        """True under ``xor`` / ``auto``: a pattern's matrix is
+        compiled into its program, so warming is per pattern."""
+        return self._parity_apply.baked
+
     # -- encode -------------------------------------------------------------
     def encode_parity(self, data: jax.Array) -> jax.Array:
         """[..., k, n] uint8 -> [..., m, n] parity shards."""
@@ -430,16 +487,22 @@ class TPUCodec:
         if key not in self._warm:
             self._warm[key] = apply_.aot(shape, device=device)
 
-    def _apply(self, pattern: tuple, data: jax.Array,
+    def _apply(self, pattern: tuple, data,
                sink: dict | None = None) -> jax.Array:
-        """Apply the pattern's matrix to ``data``: the strategy's
-        jitted program with the matrix as its operands or, under
-        ``xor`` / ``auto``, the pattern's warmed executable for this
-        shape and placement when there is one (the key carries the
-        CURRENT placement: under a pool lane's default_device scope
-        only that lane's executable can hit)."""
+        """Apply the pattern's matrix to ``data`` (an array, or
+        ``LinearRows`` already on the device): the strategy's jitted
+        program with the matrix as its operands or, under ``xor`` /
+        ``auto``, the pattern's warmed executable for this shape and
+        placement when there is one (the key carries the CURRENT
+        placement: under a pool lane's default_device scope only that
+        lane's executable can hit). Those executables take the array:
+        linear rows are stacked for them by a program of its own."""
+        if not isinstance(data, LinearRows):
+            data = jnp.asarray(data, dtype=jnp.uint8)
         apply_ = self._matrix_for(*pattern, shape=data.shape, sink=sink)
         if apply_.baked:
+            if isinstance(data, LinearRows):
+                data = _STACK_ROWS(data.rows, q=data.q)
             warm = self._warm.get(
                 (pattern, tuple(data.shape), _placement_device()))
             if warm is not None:
@@ -475,12 +538,13 @@ class TPUCodec:
         self._warm_program(
             ("repair",) + self._pattern(present, missing), shape, device)
 
-    def reconstruct(self, survivors: jax.Array, present: tuple[int, ...],
+    def reconstruct(self, survivors, present: tuple[int, ...],
                     missing: tuple[int, ...] | None = None, *,
                     sink: dict | None = None) -> jax.Array:
         """Recover missing shards from any k survivors.
 
-        survivors: [..., k, n] rows ordered as ``present``; returns
+        survivors: [..., k, n] rows ordered as ``present``, or the same
+        as ``LinearRows``; returns
         [..., len(missing), n] (missing defaults to all absent rows).
         Compiles nothing when the exact shape has been warmed (see
         warm_reconstruct). ``sink``: an
@@ -489,15 +553,13 @@ class TPUCodec:
         """
         faults.inject("rs.reconstruct")
         return self._apply(
-            ("repair",) + self._pattern(present, missing),
-            jnp.asarray(survivors, dtype=jnp.uint8), sink)
+            ("repair",) + self._pattern(present, missing), survivors, sink)
 
-    def decode_data(self, survivors: jax.Array, present: tuple[int, ...],
+    def decode_data(self, survivors, present: tuple[int, ...],
                     *, sink: dict | None = None) -> jax.Array:
         """Recover the k data shards from any k survivors."""
         faults.inject("rs.decode")
-        return self._apply(("decode", tuple(present), ()),
-                           jnp.asarray(survivors, dtype=jnp.uint8), sink)
+        return self._apply(("decode", tuple(present), ()), survivors, sink)
 
     def program_meta(self, kind: str, present=(), missing=(),
                      shape=()) -> tuple:

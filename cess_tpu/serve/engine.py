@@ -24,7 +24,13 @@ Zero-copy handoff: submits accept ``jax.Array`` payloads and keep
 them ON DEVICE — coalescing concatenates resident inputs with
 ``jnp.concatenate``, padding pads with device zeros, and each
 request's result slice comes back as a ``jax.Array``. Host (numpy)
-submitters keep getting numpy back, even when a batch mixes both. So
+submitters keep getting numpy back, even when a batch mixes both. A
+repair batch of host submitters alone crosses the link in the shape
+the link is fast in, both ways: the survivors go up as linear ``u8[n]``
+rows put from the callers' own memory and are stacked on the device
+inside the program (``_put_rows``, PR 32), the result comes down as
+linear rows (``_fetch_linear``, PR 28); nothing is copied on the host
+on the way up. So
 ``StoragePipeline -> engine -> device`` is one H2D copy total for the
 concat-coalesced classes (encode / repair / tag / verify), provided
 the payloads live on the backend's device. The stacked classes
@@ -202,6 +208,30 @@ def _pad_axis0(arr, rows: int):
         return jnp.concatenate([arr, pad], axis=0)
     pad = np.zeros((rows - arr.shape[0],) + arr.shape[1:], arr.dtype)
     return np.concatenate([arr, pad], axis=0)
+
+
+class _HostRows(tuple):
+    """One repair request handed in as its ``q`` linear ``u8[n]`` rows,
+    still wherever the caller holds them (a miner's fetched fragments):
+    what ``[q, n]`` would have cost a host copy to build. It answers
+    ``shape`` / ``nbytes`` as that ``[1, q, n]`` array would."""
+
+    @property
+    def shape(self) -> tuple:
+        return (1, len(self)) + self[0].shape
+
+    @property
+    def nbytes(self) -> int:
+        return sum(row.nbytes for row in self)
+
+
+def _row_views(surv) -> list:
+    """A host request's survivors (``[B, q, n]`` or ``_HostRows``) as
+    ``B * q`` contiguous 1-D views: no byte is copied."""
+    if isinstance(surv, _HostRows):
+        return list(surv)
+    return [surv[i, j] for i in range(surv.shape[0])
+            for j in range(surv.shape[1])]
 
 
 def _check_round(idx, nu, num_blocks: int) -> tuple:
@@ -406,13 +436,16 @@ class SubmissionEngine:
                            timeout: float | None = None,
                            tenant: str | None = None) -> EngineFuture:
         """survivors [B, k, n] (or [k, n]) rows ordered as ``present``
-        -> future of the recovered [B, len(missing), n] shards."""
+        -> future of the recovered [B, len(missing), n] shards. One
+        request may also be a list or tuple of its k 1-D ``u8[n]``
+        NumPy rows (answered as ``[k, n]`` is): they go to the device
+        from where they lie, never stacked on the host."""
         self._need_codec()
         present = tuple(present)
         if missing is None:
             missing = tuple(i for i in range(self.codec.k + self.codec.m)
                             if i not in present)
-        survivors, squeeze = self._norm_shards(survivors, len(present))
+        survivors, squeeze = self._norm_survivors(survivors, len(present))
         key = ("repair", "reconstruct", present, tuple(missing),
                survivors.shape[2])
         return self._submit("repair", key, survivors.shape[0],
@@ -430,9 +463,11 @@ class SubmissionEngine:
     def submit_decode_data(self, survivors, present,
                            timeout: float | None = None,
                            tenant: str | None = None) -> EngineFuture:
+        """survivors as for ``submit_reconstruct`` -> future of the k
+        data shards [B, k, n]."""
         self._need_codec()
         present = tuple(present)
-        survivors, squeeze = self._norm_shards(survivors, len(present))
+        survivors, squeeze = self._norm_survivors(survivors, len(present))
         key = ("repair", "decode", present, (), survivors.shape[2])
         return self._submit("repair", key, survivors.shape[0],
                             {"survivors": survivors},
@@ -643,25 +678,48 @@ class SubmissionEngine:
         miners race the same restoral order.
 
         Populates the engine program cache under the exact keys
-        ``_op_repair`` will look up, base and per pool lane, and — when
-        the codec supports it (TPUCodec.warm_reconstruct) —
-        compiles the underlying reconstruct program per device. A
-        codec whose strategy compiles the matrix into the program
-        (``xor`` / ``auto``) is warmed for the named patterns alone.
-        The flatten a host claim's result leaves the device through
-        (_fetch_linear) is warmed for the same shapes and devices, by
-        one run over zeros."""
+        ``_op_repair`` will look up, base and per pool lane, and warms
+        what a HOST claim runs, per device, by one run over zero rows:
+        the program that stacks the survivors' linear rows and applies
+        the matrix (ops/rs.py ``_apply_rows``), then the flatten its
+        result leaves the device through (_fetch_linear). A claim
+        whose survivors are already on the device runs the codec's
+        program for the stacked array, which is NOT warmed here: the
+        first such batch of a shape compiles it. A codec whose
+        strategy compiles the matrix into the program (``xor`` /
+        ``auto``) is warmed for the named patterns alone, through
+        ``TPUCodec.warm_reconstruct``; a host codec has nothing to
+        warm."""
         self._need_codec()
-        warm = getattr(self.codec, "warm_reconstruct", None)
+        codec = self.codec
         # (lane, its device): the base programs, then every lane's
         lanes = self.pool.lanes if self.pool is not None else ()
         placements = [(None, None)] + [(lane, lane.device)
                                        for lane in lanes]
         patterns = [(tuple(p), tuple(mi)) for p, mi in patterns]
 
+        def run(kind, aux, q, bucket, lane, device):
+            """One (kind, shape, bucket, placement): the cache entry,
+            and on a device codec one run of a host claim's way over
+            zeros (after the codec's own warm call of the kind, for
+            the strategies that need one)."""
+            prog, pattern = self._repair_program(codec, kind, aux, n,
+                                                 bucket, False, lane)
+            if not self._on_device(codec):
+                return
+            if codec.baked:
+                warm = codec.warm_fold if kind == "symbol" \
+                    else codec.warm_reconstruct
+                warm(*pattern, (bucket, q, n), device=device)
+            with self._lane_placement(lane, False):
+                # the codec's own call: patterns_new counts batches
+                out = prog.__wrapped__(self._put_rows([], q, bucket, n),
+                                       *pattern)
+                jax.block_until_ready(
+                    self._linear_rows_program(out.shape, lane)(out))
+
         for present, missing in patterns:
             for b in buckets:
-                bucket = bucket_rows(b)
                 aux = {"present": present, "missing": missing}
                 # base first, then EVERY lane's slice of the cache and
                 # its device's program — a repair storm fans out
@@ -669,23 +727,15 @@ class SubmissionEngine:
                 # time (and a program warmed for device 0 is never
                 # handed a lane-3 batch)
                 for lane, device in placements:
-                    if warm is not None:
-                        warm(present, missing, (bucket, len(present), n),
-                             device=device)
-                    self._repair_program(self.codec, "reconstruct", aux,
-                                         n, bucket, False, lane)
-                    with self._lane_placement(lane, False):
-                        shape = (bucket, len(missing), n)
-                        jax.block_until_ready(self._linear_rows_program(
-                            shape, lane)(jnp.zeros(shape, jnp.uint8)))
+                    run("reconstruct", aux, len(present), bucket_rows(b),
+                        lane, device)
         # regen leg: when the codec carries the symbol surface
         # (RegenCodec.warm_fold), warm the helper-fold program and
         # stage every coefficient the single-missing patterns can ask
         # for — same base + per-lane key discipline as the
         # reconstructs, so a symbol chain fanned across lanes never
         # pays compile time
-        warm_fold = getattr(self.codec, "warm_fold", None)
-        if warm_fold is None:
+        if not hasattr(codec, "warm_fold"):
             return
         from ..ops import regen
 
@@ -698,12 +748,9 @@ class SubmissionEngine:
         coeffs.discard(0)
         for c in sorted(coeffs):
             for b in buckets:
-                bucket = bucket_rows(b)
                 for lane, device in placements:
-                    warm_fold(c, (bucket, 2, n), device=device)
-                    self._repair_program(self.codec, "symbol",
-                                         {"coeff": c}, n, bucket, False,
-                                         lane)
+                    run("symbol", {"coeff": c}, 2, bucket_rows(b), lane,
+                        device)
 
     def attach_stream(self, stream_stats) -> None:
         """Register a streaming driver's StreamStats so its per-stage
@@ -849,6 +896,20 @@ class SubmissionEngine:
             raise ValueError(f"expected [B, {rows}, n] shards, got "
                              f"{arr.shape}")
         return arr, squeeze
+
+    @classmethod
+    def _norm_survivors(cls, data, rows: int):
+        """_norm_shards for the repair class, which also takes one
+        request as a sequence of its ``rows`` 1-D NumPy rows and keeps
+        it so (``_HostRows``: ``np.asarray`` of such a list would be a
+        fresh stacked copy)."""
+        if isinstance(data, (list, tuple)) and data and all(
+                isinstance(r, np.ndarray) and r.ndim == 1 for r in data):
+            if len(data) != rows or len({r.shape for r in data}) != 1:
+                raise ValueError(f"expected {rows} rows of one length, "
+                                 f"got {[r.shape for r in data]}")
+            return _HostRows(_norm(r, np.uint8) for r in data), True
+        return cls._norm_shards(data, rows)
 
     def _tracer_now(self):
         """The tracer serving this call: the engine's pinned one, else
@@ -1539,6 +1600,14 @@ class SubmissionEngine:
         return self.audit
 
     @staticmethod
+    def _on_device(codec) -> bool:
+        """A device codec (ops/rs.py TPUCodec and its kin): it keeps
+        its patterns' matrices and takes survivors as ``LinearRows``.
+        The host codecs (``rs_backend="cpu"`` / ``"native"``, the
+        breaker's fallback) take host arrays."""
+        return hasattr(codec, "warm_reconstruct")
+
+    @staticmethod
     def _lane_placement(lane, degraded: bool):
         """Device scope for a batch dispatch: the lane's device on the
         pool path, JAX's default placement otherwise (and always for
@@ -1594,20 +1663,57 @@ class SubmissionEngine:
         return self._split_rows(batch, out, lane), bucket
 
     def _op_repair(self, batch, degraded=False, lane=None):
+        """All three kinds (reconstruct, decode, symbol: they differ in
+        the matrix). What the batch holds decides the survivors' way to
+        the device: with a device-resident contributor they are
+        concatenated there (no copy of what is resident); on a host
+        codec (``rs_backend="cpu"``, the breaker's fallback) they stay
+        a host array; every other batch goes up as its ``total * q``
+        linear rows, put from the callers' own memory and stacked on
+        the device inside the program (``_put_rows``)."""
         codec = self._rs_backend(degraded)
         kind = batch[0].key[1]
         aux = batch[0].aux
+        survs = [r.arrays["survivors"] for r in batch]
+        total = sum(r.rows for r in batch)
+        bucket = bucket_rows(total)
+        _, q, n = survs[0].shape
+        as_rows = self._on_device(codec) \
+            and not any(r.device for r in batch)
         with self._stage("repair", "assemble"):
-            surv = _concat_rows([r.arrays["survivors"] for r in batch])
-            total = surv.shape[0]
-            bucket = bucket_rows(total)
-            n = surv.shape[2]
-            surv = _pad_axis0(surv, bucket)
+            if as_rows:
+                surv = [row for a in survs for row in _row_views(a)]
+            else:
+                surv = _pad_axis0(_concat_rows(
+                    [np.stack(a)[None] if isinstance(a, _HostRows) else a
+                     for a in survs]), bucket)
         with self._stage("repair", "dispatch"):
             prog, pattern = self._repair_program(codec, kind, aux, n,
                                                  bucket, degraded, lane)
+            if as_rows:
+                surv = self._put_rows(surv, q, bucket, n)
+                with self._lock:
+                    self.stats.classes["repair"].linear_puts += 1
             out = prog(surv, *pattern)[:total]
         return self._split_rows(batch, out, lane), bucket
+
+    @staticmethod
+    def _put_rows(rows: list, q: int, bucket: int, n: int):
+        """A host batch's survivors on their way up: each ``u8[n]`` row
+        put as it lies (one ``device_put`` of the list, on the device
+        of the placement scope the batch runs under), padded to the
+        bucket's ``bucket * q`` rows with a zero row made on the device
+        (all of them, for warm_repair's run), for the codec to stack
+        inside its program (ops/rs.py LinearRows). The callers' rows
+        stay referenced by their requests until the batch resolves,
+        after ``wait`` has seen the result ready."""
+        from ..ops.rs import LinearRows
+
+        placed = jax.device_put(rows)
+        if len(placed) < bucket * q:
+            placed += [jnp.zeros((n,), jnp.uint8)] * (bucket * q
+                                                      - len(placed))
+        return LinearRows(tuple(placed), q)
 
     def _repair_program(self, codec, kind: str, aux: dict, n: int,
                         bucket: int, degraded: bool, lane):
@@ -1659,9 +1765,10 @@ class SubmissionEngine:
         (TPUCodec._matrix_for): counted here as ``patterns_new`` /
         ``matrix_build_s``. The host codecs (the CPU fallback) keep no
         matrices and are called as they are."""
-        if not hasattr(codec, "warm_reconstruct"):
+        if not self._on_device(codec):
             return call
 
+        @functools.wraps(call)
         def counted(survivors, *pattern):
             sink: dict = {}
             out = call(survivors, *pattern, sink=sink)

@@ -45,8 +45,8 @@ class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
                  "saturated", "shed", "batches", "batched_requests",
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
-                 "patterns_new", "matrix_build_s", "latencies", "hist",
-                 "stage_n", "stage_s")
+                 "linear_puts", "patterns_new", "matrix_build_s",
+                 "latencies", "hist", "stage_n", "stage_s")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -69,6 +69,12 @@ class ClassStats:
         # _fetch_linear); device submitters' and non-byte results
         # (tags, verdicts) leave it 0
         self.linear_fetches = 0
+        # repair class: batches whose survivors went up as linear rows
+        # put from the callers' own memory and stacked on the device
+        # (engine.py _put_rows): every all-host batch on a device
+        # codec; a batch with a device-resident contributor, and one
+        # served by a host codec (the breaker's fallback), leave it 0
+        self.linear_puts = 0
         # repair class, device codec: batches whose erasure pattern the
         # codec held no matrix for (engine.py _counting_matrices), and
         # the host seconds their matrices took to build (GF
@@ -240,6 +246,7 @@ class EngineStats:
                 "pad_waste": round(st.pad_waste, 4),
                 "operand_bytes": st.operand_bytes,
                 "linear_fetches": st.linear_fetches,
+                "linear_puts": st.linear_puts,
                 "patterns_new": st.patterns_new,
                 "matrix_build_s": st.matrix_build_s,
                 "latency_p50": round(st.percentile(0.50), 6),

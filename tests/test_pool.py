@@ -221,14 +221,74 @@ def test_engine_warm_repair_warms_every_lane(compiles):
                 ("repair", 2, 1, 256, 1, ("device", 0)),
                 ("repair", 2, 1, 256, 1, ("device", 1))}
         assert keys <= set(eng.programs._programs)
-        # and the codec's program is compiled for every lane's device
+        # and the codec's program — a host claim's: linear rows in,
+        # stacked on the device (PR 32) — is compiled for every lane's
+        # device, whichever pattern of the shape it is called with
         compiled = compiles()
         surv = rnd((1, 2, 256), 12)
         for lane in eng.pool.lanes:
             with jax.default_device(lane.device):
+                rows = rs.LinearRows(tuple(jax.device_put(list(surv[0]))),
+                                     2)
                 jax.block_until_ready(
-                    eng.codec.reconstruct(surv, (0, 2), (1,)))
+                    eng.codec.reconstruct(rows, (0, 2), (1,)))
         assert compiles() == compiled
+    finally:
+        eng.close()
+
+
+def test_host_rows_and_result_stay_on_the_batch_lane(compiles, monkeypatch):
+    """A host claim's rows are put on the device of the lane that runs
+    the batch, stacked and repaired there by that lane's program, and
+    the result comes down from there: with lane 0's dispatches failing
+    the same claim drains to lane 1 and is served by lane 1's program,
+    never lane 0's. Nothing compiles after warm_repair, on either."""
+    eng = _pool_engine(n=2, res=ResilienceConfig())
+    n = 328                         # a width no other test compiles
+    seen = []       # a batch: its program's key, rows' devices, result's
+    put, split, get = eng._put_rows, eng._split_rows, eng.programs.get
+
+    def programs_get(key, build):
+        if key[0] == "repair":          # _op_repair looks it up first
+            seen.append([key])
+        return get(key, build)
+
+    def put_rows(rows, q, bucket, n):
+        placed = put(rows, q, bucket, n)
+        seen[-1].append({d for row in placed.rows for d in row.devices()})
+        return placed
+
+    def split_rows(batch, out, lane=None):
+        seen[-1] += [out.devices(), lane.index]
+        return split(batch, out, lane)
+
+    try:
+        eng.warm_repair([((1, 2), (0,))], n, buckets=(1,))
+        monkeypatch.setattr(eng, "_put_rows", put_rows)
+        monkeypatch.setattr(eng.programs, "get", programs_get)
+        monkeypatch.setattr(eng, "_split_rows", split_rows)
+        coded = rs.make_codec(K, M, backend="cpu").encode(rnd((1, K, n), 13))
+        compiled = compiles()
+        built = eng.stats_snapshot()["programs_built"]
+        # a pattern warm_repair never named, as host rows
+        rows, present, missing = [coded[0, 0], coded[0, 2]], (0, 2), (1,)
+        out = eng.reconstruct(rows, present, missing, timeout=60)
+        assert np.array_equal(out[0], coded[0, 1])
+        plan = FaultPlan.seeded(b"lane0", {"engine.dispatch.d0":
+                                           (1.0, "raise")}, horizon=64)
+        with faults.armed(plan):
+            out = eng.reconstruct(rows, present, missing, timeout=60)
+        assert plan.fired_log()
+        assert np.array_equal(out[0], coded[0, 1])
+        assert compiles() == compiled
+        assert eng.stats_snapshot()["programs_built"] == built
+        lanes = eng.pool.lanes
+        assert [s[3] for s in seen] == [0, 1]
+        for key, devices, result, index in seen:
+            assert devices == result == {lanes[index].device}
+            assert key == ("repair", 2, 1, n, 1, ("device", index))
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["linear_puts"] == st["batches"] == 2
     finally:
         eng.close()
 

@@ -328,15 +328,18 @@ class TestEngineSymbols:
             assert ("symbol", 256, 1) in keys
             assert ("symbol", 256, 1, ("device", 0)) in keys
             assert ("symbol", 256, 1, ("device", 1)) in keys
-            # the fold's program — the [1, 2] matrix's at [1, 2, 256],
-            # at RS(2,1) the one-row repair's too — is compiled for
-            # every lane's device
+            # the fold's program — the [1, 2] matrix's over two linear
+            # rows of 256 (a host hop's way up since PR 32), at RS(2,1)
+            # the one-row repair's too — is compiled for every lane's
+            # device
             pairs = rnd((1, 2, 256), 2)
             compiled = compiles()
             for lane in eng.pool.lanes:
                 with jax.default_device(lane.device):
+                    rows = rs.LinearRows(
+                        tuple(jax.device_put(list(pairs[0]))), 2)
                     jax.block_until_ready(
-                        eng.codec.fold_symbol(pairs, sorted(coeffs)[0]))
+                        eng.codec.fold_symbol(rows, sorted(coeffs)[0]))
             assert compiles() == compiled
             # and the fold through the engine runs it
             out = np.asarray(eng.repair_symbol(

@@ -72,7 +72,15 @@ def test_reconstruct_equals_reference_for_random_helpers(k, m, e):
         assert lost_kinds == helper_kinds == {True, False}
 
 
-def test_unseen_patterns_build_no_program_and_compile_nothing(compiles):
+@pytest.mark.parametrize("form", ["array", "rows"])
+def test_unseen_patterns_build_no_program_and_compile_nothing(form,
+                                                              compiles):
+    """``form``: the survivors as ``[q, n]``, or as the list of their q
+    host rows (a miner's fetched fragments, PR 32): either way they go
+    up as linear rows, through the one program the shape was warmed
+    with."""
+    from cess_tpu.ops import rs
+
     k, m = 10, 4
     rng = np.random.default_rng(31)
     coded = ReferenceCodec(k, m).encode(
@@ -87,20 +95,26 @@ def test_unseen_patterns_build_no_program_and_compile_nothing(compiles):
         # four shapes: a repair program and a flatten each
         assert warmed["programs_built"] == len(eng.programs) == 8
         compiled = compiles()
+        stackers = rs._apply_rows._cache_size()
         seen = set()
         while len(seen) < 300:
             helpers, lost = _draw(rng, k, m, int(rng.integers(1, 5)))
             if (helpers, lost) in seen or helpers[0] == len(lost):
                 continue            # a new pattern, none of the warmed
             seen.add((helpers, lost))
-            got = np.asarray(eng.reconstruct(coded[list(helpers)],
-                                             helpers, lost))
+            surv = coded[list(helpers)]
+            got = np.asarray(eng.reconstruct(
+                list(surv) if form == "rows" else surv, helpers, lost))
             assert np.array_equal(got, coded[list(lost)])
         eng.flush()
         snap = eng.stats_snapshot()
         assert snap["programs_built"] == warmed["programs_built"]
-        # every one of them ran a warmed shape's program
+        # every one of them ran a warmed shape's program: the one that
+        # stacks the rows and applies the matrix, one a shape
         assert compiles() == compiled
+        assert rs._apply_rows._cache_size() == stackers
+        assert snap["classes"]["repair"]["linear_puts"] \
+            == snap["classes"]["repair"]["batches"] == 300
         # the bounds: 8 programs and the codec's newest MATRICES
         # matrices; the gather strategy keeps no executable of its own
         assert len(eng.programs) == 8
@@ -144,11 +158,11 @@ def test_unservable_pattern_is_refused(present, missing, match):
         eng.close()
 
 
-def test_try_repair_at_10p4_with_three_holders_silent():
-    """The ten helpers are whichever peers hold their row: with rows 1,
-    4 and 7 silent the set is not the k lowest survivors, and the warmed
-    shape's program serves it (nothing is built under the claim)."""
-    k, m = 10, 4
+def _restoral_world(eng, k, m, lost_row, silent):
+    """One (k, m) stripe on k + m peer miners, ``lost_row`` lost and
+    the ``silent`` rows' holders empty-handed, a restoral order open,
+    and a rescuer on ``eng``: (rescuer, peers, hashes, blobs, cfg,
+    extrinsics sent)."""
     cfg = PipelineConfig(k=k, m=m, segment_size=k * 1024)
     pipe = StoragePipeline(cfg)
     rng = np.random.default_rng(5)
@@ -156,7 +170,6 @@ def test_try_repair_at_10p4_with_three_holders_silent():
         rng.integers(0, 256, (k, cfg.fragment_size), dtype=np.uint8))
     blobs = [row.tobytes() for row in coded]
     hashes = [fragment_hash(b) for b in blobs]
-    lost_row, silent = 2, (1, 4, 7)
     seg = types.SimpleNamespace(fragment_hashes=hashes)
     bank = types.SimpleNamespace(
         restoral_order=lambda h: types.SimpleNamespace(file_hash=b"f"),
@@ -165,15 +178,26 @@ def test_try_repair_at_10p4_with_three_holders_silent():
     node = types.SimpleNamespace(
         runtime=types.SimpleNamespace(file_bank=bank),
         submit_extrinsic=lambda *a: sent.append(a[1]))
+    peers = []
+    for j in range(k + m):
+        peer = MinerAgent(node, f"h{j}", [], pipe)
+        if j != lost_row and j not in silent:
+            peer.store[hashes[j]] = blobs[j]
+        peers.append(peer)
+    rescuer = MinerAgent(node, "rescuer", [], pipe, engine=eng)
+    return rescuer, peers, hashes, blobs, cfg, sent
+
+
+def test_try_repair_at_10p4_with_three_holders_silent():
+    """The ten helpers are whichever peers hold their row: with rows 1,
+    4 and 7 silent the set is not the k lowest survivors, and the warmed
+    shape's program serves it (nothing is built under the claim)."""
+    k, m = 10, 4
+    lost_row = 2
     eng = _engine(k, m)
     try:
-        peers = []
-        for j in range(k + m):
-            peer = MinerAgent(node, f"h{j}", [], pipe)
-            if j != lost_row and j not in silent:
-                peer.store[hashes[j]] = blobs[j]
-            peers.append(peer)
-        rescuer = MinerAgent(node, "rescuer", [], pipe, engine=eng)
+        rescuer, peers, hashes, blobs, cfg, sent = _restoral_world(
+            eng, k, m, lost_row, silent=(1, 4, 7))
         rescuer.warm_restoral()
         built = eng.stats_snapshot()["programs_built"]
         assert rescuer.try_repair(hashes[lost_row], peers)
@@ -188,5 +212,54 @@ def test_try_repair_at_10p4_with_three_holders_silent():
         # named (0 only if another test of this process left the one
         # (10,4) codec make_codec hands out holding it)
         assert snap["classes"]["repair"]["patterns_new"] <= 1
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("k,m", [(2, 1), (10, 4)])
+def test_repair_via_fragments_hands_the_holders_rows_over(k, m, monkeypatch,
+                                                          compiles):
+    """``_repair_via_fragments`` reaches the engine with the k
+    fragments as they lie in the holders' stores (read-only views of
+    their ``bytes``: no stacked array in between), they go up as linear
+    rows through the warmed program, and the repaired fragment
+    re-hashes to its on-chain identity."""
+    lost_row = 1
+    eng = _engine(k, m)
+    try:
+        rescuer, peers, hashes, blobs, cfg, _ = _restoral_world(
+            eng, k, m, lost_row, silent=())
+        rescuer.warm_restoral()
+        handed = []
+        submit = eng.submit_reconstruct
+
+        def submit_reconstruct(survivors, present, missing=None, **kw):
+            handed.append((survivors, present, missing))
+            return submit(survivors, present, missing, **kw)
+
+        monkeypatch.setattr(eng, "submit_reconstruct", submit_reconstruct)
+
+        def no_stack(*a, **kw):
+            raise AssertionError("np.stack between the stores and the "
+                                 "engine")
+
+        built = eng.stats_snapshot()["programs_built"]
+        compiled = compiles()
+        with monkeypatch.context() as mp:
+            mp.setattr(np, "stack", no_stack)
+            assert rescuer.try_repair(hashes[lost_row], peers)
+        (survivors, present, missing), = handed
+        assert missing == (lost_row,) and len(survivors) == k
+        for row, j in zip(survivors, present):
+            assert row.ndim == 1 and not row.flags.writeable
+            assert row.base is peers[j].store[hashes[j]]     # a view
+        assert fragment_hash(rescuer.store[hashes[lost_row]]) \
+            == hashes[lost_row]
+        assert rescuer.store[hashes[lost_row]] == blobs[lost_row]
+        eng.flush()
+        snap = eng.stats_snapshot()
+        assert snap["programs_built"] == built and compiles() == compiled
+        assert snap["classes"]["repair"]["linear_puts"] \
+            == snap["classes"]["repair"]["batches"] == 1
     finally:
         eng.close()

@@ -7,6 +7,7 @@ BIT-IDENTICAL to the direct ErasureCodec / AuditBackend calls —
 the engine decides WHEN and HOW BATCHED device work runs, never what
 it computes (protocol determinism, like the codec gate itself).
 """
+import contextlib
 import threading
 
 import numpy as np
@@ -303,6 +304,230 @@ def test_warm_repair_warms_the_linear_fetch():
             st["classes"]["repair"]["batches"] == 2
         assert st["programs_built"] == built
         assert engine_mod._linear_rows._cache_size() == flattens
+    finally:
+        eng.close()
+
+
+# -- linear put: a host batch's survivors go up as linear rows, put from
+# the callers' own memory and stacked on the device (PERF.md, PR 32) -------
+
+def _repair_kind(kind, k, m, n, seed):
+    """One request of ``kind`` at RS(k, m): (engine method name,
+    ``[1, q, n]`` host payload, the call's other arguments, the bytes
+    the reference gives for it)."""
+    from cess_tpu.ops import regen
+    from cess_tpu.ops.rs_ref import ReferenceCodec
+
+    if kind == "repair_symbol":
+        pairs = rnd((1, 2, n), seed)
+        return pairs, (29,), regen.fold_symbol_pairs(pairs, 29)
+    ref = ReferenceCodec(k, m)
+    coded = ref.encode(rnd((1, k, n), seed))
+    present = tuple(range(1, k + 1))            # row 0 lost
+    surv = np.ascontiguousarray(coded[:, list(present)])
+    if kind == "decode_data":
+        return surv, (present,), ref.decode_data(surv, present)
+    return surv, (present, (0,)), ref.reconstruct(surv, present, (0,))
+
+
+@contextlib.contextmanager
+def _no_host_copy_on_the_way_up(eng, monkeypatch, n):
+    """np.concatenate / np.stack of a row's ``n`` bytes or more raise
+    while ``eng._op_repair`` runs (a pattern's matrix, a few hundred
+    bytes, is built with them), up to where it hands its result to
+    _split_rows (the way down puts a multi-row result back together
+    with np.stack, PR 28)."""
+    guard = threading.local()
+
+    def forbidding(name, real):
+        def copy(arrays, *args, **kwargs):
+            if getattr(guard, "on", False) \
+                    and sum(np.asarray(a).nbytes for a in arrays) >= n:
+                raise AssertionError(f"np.{name} on a repair's way up")
+            return real(arrays, *args, **kwargs)
+        return copy
+
+    for name in ("concatenate", "stack"):
+        monkeypatch.setattr(np, name, forbidding(name, getattr(np, name)))
+    op, split = eng._op_repair, eng._split_rows
+
+    def op_repair(*args, **kwargs):
+        guard.on = True
+        try:
+            return op(*args, **kwargs)
+        finally:
+            guard.on = False
+
+    def split_rows(*args, **kwargs):
+        guard.on = False
+        return split(*args, **kwargs)
+
+    monkeypatch.setattr(eng, "_op_repair", op_repair)
+    monkeypatch.setattr(eng, "_split_rows", split_rows)
+    yield
+    monkeypatch.undo()
+
+
+@pytest.mark.parametrize("claims", [1, 2, 3])
+@pytest.mark.parametrize("k,m", [(2, 1), (10, 4)])
+@pytest.mark.parametrize("kind", ["reconstruct", "decode_data",
+                                  "repair_symbol"])
+def test_host_repairs_go_up_as_linear_rows(kind, k, m, claims, monkeypatch):
+    """Every kind of the repair class, alone and coalesced (three
+    claims pad to a bucket of four): bytes equal to the reference's,
+    ``linear_puts == batches``, and no host copy of the survivors
+    between the submit and the device."""
+    n = 320
+    eng = make_engine(k, m, rs_backend="regen",
+                      policy=AdmissionPolicy(max_delay=0.25))
+    try:
+        cases = [_repair_kind(kind, k, m, n, 80 + c) for c in range(claims)]
+        with _no_host_copy_on_the_way_up(eng, monkeypatch, n):
+            futs = [getattr(eng, "submit_" + kind)(payload, *args)
+                    for payload, args, _ in cases]
+            outs = [f.result(timeout=60) for f in futs]
+        for out, (_, _, want) in zip(outs, cases):
+            assert isinstance(out, np.ndarray) and out.dtype == np.uint8
+            assert out.shape == want.shape
+            assert out.tobytes() == np.asarray(want).tobytes()
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["batches"] == 1 and st["batch_occupancy"] == claims
+        assert (st["pad_waste"] > 0) == (claims == 3)
+        assert st["linear_puts"] == st["batches"] == st["linear_fetches"]
+        assert eng.stats_metrics()["cess_engine_repair_linear_puts"] == 1
+    finally:
+        eng.close()
+
+
+def test_warmed_bucket_pads_with_device_zeros():
+    """Three claims in a warmed bucket of four: the fourth request's
+    rows are zeros made on the device, and the rows program is the one
+    the warm-up compiled (one a shape, whatever the number of claims;
+    what a padded batch still compiles is on the way down: the slice
+    off the pad and the flatten of three rows, as before PR 32). A
+    host engine's warm_repair has nothing to run."""
+    from cess_tpu.ops.rs_ref import ReferenceCodec
+
+    k, m, n = 2, 1, 704                 # a width no other test compiles
+    eng = make_engine(k, m, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.25))
+    try:
+        eng.warm_repair([((1, 2), (0,))], n, buckets=(4,))
+        stackers = rs._apply_rows._cache_size()
+        built = eng.stats_snapshot()["programs_built"]
+        ref = ReferenceCodec(k, m)
+        coded = ref.encode(rnd((3, k, n), 61))
+        futs = [eng.submit_reconstruct(coded[c, [0, 2]], (0, 2), (1,))
+                for c in range(3)]          # a pattern never named
+        for c, f in enumerate(futs):
+            assert np.array_equal(f.result(timeout=60)[0], coded[c, 1])
+        st = eng.stats_snapshot()
+        assert st["classes"]["repair"]["batches"] == 1
+        assert st["classes"]["repair"]["pad_waste"] == 0.25
+        assert st["classes"]["repair"]["linear_puts"] == 1
+        assert rs._apply_rows._cache_size() == stackers
+        # the flatten of the three rows left after the slice
+        assert st["programs_built"] == built + 1
+    finally:
+        eng.close()
+    host = make_engine(k, m, rs_backend="cpu",
+                       policy=AdmissionPolicy(max_delay=0.001))
+    try:
+        host.warm_repair([((1, 2), (0,))], n)
+        rec = host.reconstruct(list(coded[0, [1, 2]]), (1, 2), (0,))
+        assert np.array_equal(rec[0], coded[0, 0])
+        assert host.stats_snapshot()["classes"]["repair"][
+            "linear_puts"] == 0
+    finally:
+        host.close()
+
+
+@pytest.mark.parametrize("kind", ["reconstruct", "decode_data"])
+def test_request_given_as_rows_equals_the_stacked_request(kind, monkeypatch):
+    """One request as a list of its q 1-D rows: the answer ``[q, n]``
+    would get, with the rows never stacked on the host; a read-only
+    view of ``bytes`` (a miner's store) is taken as it is."""
+    k, m, n = 10, 4, 192
+    surv, args, want = _repair_kind(kind, k, m, n, 91)
+    rows = [np.frombuffer(surv[0, j].tobytes(), np.uint8) for j in range(k)]
+    eng = make_engine(k, m, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.001))
+    try:
+        stacked = getattr(eng, kind)(surv[0], *args)
+        with _no_host_copy_on_the_way_up(eng, monkeypatch, n):
+            for seq in (rows, tuple(rows)):
+                got = getattr(eng, kind)(seq, *args)
+                assert isinstance(got, np.ndarray)
+                assert got.shape == stacked.shape == want.shape[1:]
+                assert got.tobytes() == stacked.tobytes() \
+                    == np.asarray(want)[0].tobytes()
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["linear_puts"] == st["batches"] == 3
+        for bad in (rows[:-1], rows[:-1] + [rows[-1][:-1]]):
+            with pytest.raises(ValueError, match="rows"):
+                getattr(eng, kind)(bad, *args)
+    finally:
+        eng.close()
+
+
+def test_device_resident_repair_keeps_its_path():
+    """A device submitter's survivors are already where they must be:
+    jax.Array out, no linear put; coalesced with a host claim the batch
+    takes the same on-device path and both get their own bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    k, m, n = 2, 1, 448
+    eng = make_engine(k, m, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.25))
+    try:
+        (s0, args, w0), (s1, _, w1) = (
+            _repair_kind("reconstruct", k, m, n, 95 + c) for c in range(2))
+        out = eng.reconstruct(jnp.asarray(s0), *args)
+        assert isinstance(out, jax.Array)
+        assert np.asarray(out).tobytes() == w0.tobytes()
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["batches"] == 1 and st["linear_puts"] == 0
+        f_dev = eng.submit_reconstruct(jnp.asarray(s0), *args)
+        f_host = eng.submit_reconstruct(list(s1[0]), *args)   # as rows
+        assert isinstance(f_dev.result(timeout=60), jax.Array)
+        assert np.asarray(f_dev.result()).tobytes() == w0.tobytes()
+        got = f_host.result(timeout=60)
+        assert isinstance(got, np.ndarray)
+        assert got.tobytes() == w1.tobytes()
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["batches"] == 2 and st["batch_occupancy"] == 1.5
+        assert st["linear_puts"] == 0
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("as_rows", [False, True])
+def test_degraded_repair_keeps_a_host_array(as_rows):
+    """Every device dispatch fails: the batch is served by the CPU
+    reference codec from a host array (built with one np.stack when
+    the request came as rows): the same bytes, no linear put."""
+    from cess_tpu.resilience import ResilienceConfig, faults
+    from cess_tpu.resilience.faults import FaultPlan
+
+    k, m, n = 10, 4, 192
+    res = ResilienceConfig()
+    eng = make_engine(k, m, rs_backend="jax", resilience=res,
+                      policy=AdmissionPolicy(max_delay=0.001))
+    plan = FaultPlan.seeded(b"rows", {"engine.dispatch": (1.0, "raise")},
+                            horizon=64)
+    try:
+        surv, args, want = _repair_kind("reconstruct", k, m, n, 97)
+        with faults.armed(plan):
+            got = eng.reconstruct(list(surv[0]) if as_rows else surv,
+                                  *args, timeout=60)
+        assert plan.fired_log()
+        assert got.tobytes() == want.tobytes()
+        assert got.shape == (want.shape[1:] if as_rows else want.shape)
+        snap = res.stats.snapshot()
+        assert snap["fallback_batches"].get("repair", 0) == 1
+        st = eng.stats_snapshot()["classes"]["repair"]
+        assert st["completed"] == 1 and st["linear_puts"] == 0
     finally:
         eng.close()
 

@@ -248,6 +248,39 @@ def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, packed):
     assert mem.argument_size_in_bytes == packed * rows * r * n, mem
 
 
+@pytest.mark.parametrize("k,m,r,bucket", [
+    pytest.param(2, 1, 1, 1, id="one-claim-rs2p1"),
+    pytest.param(2, 1, 1, 2, id="two-claims-rs2p1"),
+    pytest.param(10, 4, 1, 1, id="one-claim-rs10p4"),
+    pytest.param(10, 4, 3, 1, id="three-rows-lost-rs10p4")])
+def test_rows_program_compiles_for_v5e(one_chip, for_tpu, k, m, r, bucket):
+    """A host claim's way up since PR 32 (ops/rs.py _apply_rows): the
+    survivors as ``bucket * k`` linear ``u8[8 MiB]`` rows, stacked and
+    repaired by ONE program, the pattern's matrix its operand. It
+    compiles in seconds (``stack`` forms only, no relayouting reshape
+    of the whole), holds the Pallas kernel under its pinned name, and
+    its arguments are the rows' logical bytes: dense 1-D rows, where
+    the stacked ``u8[1, 2, n]`` operand was twice its bytes."""
+    n = 8 * MiB
+    bmat = rs_pallas.operand_np(
+        gf.expand_bitmatrix(gf.repair_matrix(
+            k, m, tuple(range(r, r + k)), tuple(range(r)))),
+        rs_pallas.group_for(bucket))
+    rows = tuple(jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
+                 for _ in range(bucket * k))
+    t0 = time.perf_counter()
+    compiled = rs._apply_rows.lower(
+        (jax.ShapeDtypeStruct(bmat.shape, bmat.dtype, sharding=one_chip),),
+        rows, strategy="pallas", q=k).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    assert re.search(rf"%{RS}\.\d+ = [^\n]* custom-call\(",
+                     compiled.as_text())
+    assert compiled.out_info.shape == (bucket, r, n)
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.argument_size_in_bytes - bucket * k * n < 65536, mem
+    _fits_hbm(compiled)
+
+
 @pytest.mark.parametrize("shape,k", [
     pytest.param((4, 3, 8 * MiB), 2, id="upload-rs2p1-4-segments"),
     pytest.param((1, 12, 4 * MiB), 4, id="upload-rs4p8-1-segment")])

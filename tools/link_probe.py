@@ -9,7 +9,11 @@ d2h: median of REPS ``np.asarray`` calls, each on a fresh array that
 copy: a second fetch would time nothing). "as rows": the same bytes as that
 many ``u8[N]``. "flatten + fetch": the engine's path, ``[rows, r, N]``
 through one jitted program that returns its rows 1-D, then those fetched.
-h2d: ``jax.device_put`` + ``block_until_ready``. A CPU run says nothing.
+h2d: ``jax.device_put`` + ``block_until_ready``. "+ stack": a repair's
+survivors as the engine sends them up since PR 32, q linear ``u8[N]`` rows
+put one by one and stacked into the kernel's ``u8[1, q, N]`` operand by a
+jitted program on the device (``ops/rs.py _stack_rows``' form), against the
+same bytes put as one ``u8[1, q, N]`` host array. A CPU run says nothing.
 """
 import statistics
 import time
@@ -73,6 +77,15 @@ def put(*shapes):
         [jax.device_put(h) for h in hs]))
 
 
+def put_stacked(q):
+    """q host rows -> q device rows -> ``u8[1, q, N]`` on the device."""
+    stack = jax.jit(lambda *rows: jnp.stack([jnp.stack(rows)]))
+    rng = np.random.default_rng(32)
+    host = [rng.integers(0, 256, (N,), dtype=np.uint8) for _ in range(q)]
+    return _median_ms(lambda i: host, lambda hs: jax.block_until_ready(
+        stack(*jax.device_put(hs))))
+
+
 def main():
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}; "
@@ -89,7 +102,11 @@ def main():
              ("d2h 12 x u8[N] as rows", 12, as_rows(12)),
              ("d2h u8[4,3,N] flatten + fetch", 12, flat(4, 3, N)),
              ("h2d u8[1,2,N]", 2, put((1, 2, N))),
-             ("h2d 2 x u8[N]", 2, put((N,), (N,)))]
+             ("h2d 2 x u8[N]", 2, put((N,), (N,))),
+             ("h2d 2 x u8[N] + stack u8[1,2,N]", 2, put_stacked(2)),
+             ("h2d u8[1,10,N]", 10, put((1, 10, N))),
+             ("h2d 10 x u8[N]", 10, put(*[(N,)] * 10)),
+             ("h2d 10 x u8[N] + stack u8[1,10,N]", 10, put_stacked(10))]
     print(f"{'what':<34}{'MiB':>5}{'median':>10}{'min':>10}{'GiB/s':>8}")
     for what, frags, (med, low) in table:
         print(f"{what:<34}{frags * N >> 20:>5}{med:>10.3f}{low:>10.3f}"
