@@ -815,7 +815,21 @@ def build_proof(seed: bytes, owed: list[bytes],
 
 class TeeAgent:
     """Holds the PoDR2 secret; certifies fillers and verifies queued
-    proofs on device."""
+    proofs on device.
+
+    A round's verify missions are judged TOGETHER (``on_block`` ->
+    ``judge_round`` -> ``verify_round``, the one entry point): every
+    proof's wire bytes are decoded and held to the deployment's widths
+    on the host, the owed sets' fragment hashes become ids in one
+    vectorised pass, and what goes to the device — through the
+    engine's verify class where an engine is configured — is FLAT: one
+    row an owed fragment (its id and its mission's index), the proofs
+    [missions, sectors + limbs], the round's challenge and its two
+    aggregation key words. One compiled program a mission bucket (8,
+    64, 512 up to the protocol's VerifyMissionMax) folds the rows a
+    fixed number at a time and derives r itself; ``warm_verify`` loads
+    them, after which a round of any sizes compiles nothing. What the
+    verifier holds is held per mission (``verify_round``)."""
 
     def __init__(self, node: Node, controller: str, key: podr2.Podr2Key,
                  blocks_per_fragment: int, bls_seed: bytes | None = None,
@@ -911,23 +925,32 @@ class TeeAgent:
         ch = rt.audit.challenge()
         if not missions or ch is None:
             return
-        seed = b"".join(ch.net.randoms)
-        # challenge derivation is round-constant: hoist out of _verify
-        idx, nu = podr2.gen_challenge(seed, self.blocks)
-        for mission in missions:
-            if (mission.miner, ch.start) in self._submitted:
-                continue  # result already queued, not yet applied
-            snap = mission.snapshot   # owed sets frozen at round start
+        # a result already queued, not yet applied, is not judged again
+        todo = [m for m in missions
+                if (m.miner, ch.start) not in self._submitted]
+        if todo:
+            self.judge_round(node, todo, b"".join(ch.net.randoms),
+                             ch.start)
+
+    def judge_round(self, node: Node, missions, seed: bytes,
+                    round_start: int) -> None:
+        """Judge a round's missions together (``verify_round``: every
+        service proof and every idle proof in one call, against the
+        owed sets frozen at round start) and submit one sealed
+        ``audit.submit_verify_result`` a mission."""
+        count = len(missions)
+        verdicts = self.verify_round(
+            [m.service_proof for m in missions]
+            + [m.idle_proof for m in missions],
+            [m.snapshot.service_frags for m in missions]
+            + [m.snapshot.fillers for m in missions], seed)
+        for mission, service_ok, idle_ok in zip(
+                missions, verdicts[:count], verdicts[count:]):
             with trace.span("offchain.verify", sys="offchain",
                             tee=self.controller, miner=mission.miner,
-                            round=ch.start) as vspan:
-                service_ok = self._verify(mission.service_proof,
-                                          list(snap.service_frags), seed,
-                                          idx, nu)
-                idle_ok = self._verify(mission.idle_proof,
-                                       list(snap.fillers), seed, idx, nu)
-                vspan.set(service_ok=service_ok, idle_ok=idle_ok)
-                self._submitted.add((mission.miner, ch.start))
+                            round=round_start, service_ok=service_ok,
+                            idle_ok=idle_ok):
+                self._submitted.add((mission.miner, round_start))
                 bls_sig = b""
                 if self.bls_sk is not None:
                     from ..chain import audit as audit_mod
@@ -943,44 +966,128 @@ class TeeAgent:
                 # custody verdict: the frozen owed set is exactly the
                 # fragment list the audit outcome covers
                 _flight.note("custody", "verdict", miner=mission.miner,
-                             round=ch.start, service=service_ok,
-                             idle=idle_ok, frags=snap.service_frags)
+                             round=round_start, service=service_ok,
+                             idle=idle_ok,
+                             frags=mission.snapshot.service_frags)
 
-    def _verify(self, blob, owed: list[bytes], seed: bytes,
-                idx, nu) -> bool:
-        """Decode the (untrusted) aggregated proof bytes and check them
-        against the snapshot owed set — the miner proves exactly its
-        obligations, or fails. Malformed bytes are a failed audit,
-        never an exception."""
+    def warm_verify(self, missions: int = constants.VERIFY_MISSION_MAX
+                    ) -> None:
+        """Load every program a round of up to ``missions`` missions can
+        meet (the engine's, where one is configured), so that a round
+        of any sizes compiles nothing."""
+        count = len(podr2.gen_challenge(b"", self.blocks)[0])
+        engine = getattr(self, "engine", None)
+        if engine is not None and engine.audit is not None:
+            engine.warm_verify(count, missions)
+            return
+        key_ops = podr2.key_operands(self.key)
+        for bucket in podr2.mission_buckets(missions):
+            jax.block_until_ready(podr2.warm_round(key_ops, count, bucket))
+
+    def _decode_proof(self, blob) -> "Proof | None":
+        """The (untrusted) aggregated proof bytes as a well-shaped
+        Proof of this deployment's widths, or None: malformed bytes
+        are a failed audit, never an exception."""
         try:
             proof = codec.decode(blob)
         except (codec.CodecError, TypeError, ValueError):
-            return False
-        if not (isinstance(proof, Proof) and isinstance(proof.mu, np.ndarray)
-                and proof.mu.shape == (podr2.SECTORS,)
-                and proof.mu.dtype == np.uint32
-                and isinstance(proof.sigma, np.ndarray)
-                and proof.sigma.shape == (self.key.limbs,)
-                and proof.sigma.dtype == np.uint32
-                and bool((proof.sigma < pf.P).all())):
-            return False
-        if not owed:
-            return not proof.sigma.any() and not proof.mu.any()
-        ids = np.stack([podr2.fragment_id_from_hash(h) for h in owed])
-        r = podr2.aggregate_coeffs(seed, ids)
-        # getattr: tests construct partial TeeAgents via __new__
-        engine = getattr(self, "engine", None)
-        if engine is not None and engine.audit is not None:
-            return engine.verify_aggregate(
-                ids, self.blocks, np.asarray(idx), np.asarray(nu),
-                np.asarray(r), np.asarray(proof.mu),
-                np.asarray(proof.sigma, dtype=np.uint32),
-                tenant=self.controller)
-        ok = podr2.verify_aggregate(self.key, jnp.asarray(ids), self.blocks,
-                                    idx, nu, r,
-                                    jnp.asarray(proof.mu),
-                                    jnp.asarray(proof.sigma, dtype=jnp.uint32))
-        return bool(np.asarray(ok))
+            return None
+        if isinstance(proof, Proof) and isinstance(proof.mu, np.ndarray) \
+                and proof.mu.shape == (podr2.SECTORS,) \
+                and proof.mu.dtype == np.uint32 \
+                and isinstance(proof.sigma, np.ndarray) \
+                and proof.sigma.shape == (self.key.limbs,) \
+                and proof.sigma.dtype == np.uint32 \
+                and bool((proof.sigma < pf.P).all()):
+            return proof
+        return None
+
+    def verify_round(self, proofs, owed_sets, seed: bytes,
+                     challenge=None) -> list[bool]:
+        """THE verifier's entry point: a round's missions judged
+        together. ``proofs[i]`` are mission i's aggregated proof as
+        wire bytes, ``owed_sets[i]`` the fragment hashes the chain says
+        it owes, ``seed`` the round's randomness -> one verdict a
+        mission. The miner proves exactly its obligations, or fails.
+
+        Held per mission, never for the round: undecodable or
+        mis-shaped bytes and ``sigma >= p`` are a failed audit, not an
+        exception; an empty owed set passes only under the all-zero
+        proof; a bad mission does not fail its neighbours. No verdict
+        is remembered: every call computes every mission's equation,
+        both limbs, from the bytes handed in.
+
+        The missions' owed fragments go to the device FLAT (ids in one
+        vectorised pass, a row a fragment with its mission's index) and
+        one program a mission bucket folds them, r derived there from
+        the round's aggregation key words: through the engine's verify
+        class where one is configured (``submit_verify_round``), else
+        by the same programs directly (``podr2.round_dispatch``). A
+        profiler trace holds ``cess:tee.round`` with ``.decode``,
+        ``.ids``, ``.challenge``, ``.submit`` and ``.gather`` inside
+        it."""
+        with trace.stage("tee.round"):
+            with trace.stage("tee.round.decode"):
+                # equal bytes decode once a call (every fillerless
+                # miner sends the same all-zero idle proof); nothing is
+                # kept from one call to the next
+                once: dict = {}
+                decoded = []
+                for blob in proofs:
+                    if type(blob) is not bytes:
+                        decoded.append(self._decode_proof(blob))
+                        continue
+                    if blob not in once:
+                        once[blob] = self._decode_proof(blob)
+                    decoded.append(once[blob])
+            verdicts = [False] * len(decoded)
+            live = []
+            for i, (proof, owed) in enumerate(zip(decoded, owed_sets)):
+                if proof is None:
+                    continue
+                if len(owed):
+                    live.append(i)
+                else:
+                    verdicts[i] = not proof.sigma.any() \
+                        and not proof.mu.any()
+            if not live:
+                return verdicts
+            with trace.stage("tee.round.ids"):
+                sizes = [len(owed_sets[i]) for i in live]
+                ids = np.concatenate([podr2.fragment_ids_from_hashes(
+                    owed_sets[i]) for i in live])
+                mu = np.stack([decoded[i].mu for i in live])
+                sigma = np.stack([decoded[i].sigma for i in live])
+                words = podr2.aggregate_words(seed)
+            with trace.stage("tee.round.challenge"):
+                # challenge derivation is round-constant: one a round
+                idx, nu = challenge if challenge is not None \
+                    else podr2.gen_challenge(seed, self.blocks)
+                idx, nu = np.asarray(idx), np.asarray(nu)
+            with trace.stage("tee.round.submit"):
+                # getattr: tests construct partial TeeAgents via __new__
+                engine = getattr(self, "engine", None)
+                if engine is not None and engine.audit is not None:
+                    pending = engine.submit_verify_round(
+                        ids, sizes, self.blocks, idx, nu, words, mu, sigma,
+                        tenant=self.controller)
+                else:
+                    pending = podr2.round_dispatch(
+                        podr2.key_operands(self.key),
+                        podr2.round_rows(ids, sizes, mu, sigma), idx, nu,
+                        words)
+            with trace.stage("tee.round.gather"):
+                ok = pending.result() if hasattr(pending, "result") \
+                    else np.asarray(pending)
+            for i, good in zip(live, ok):
+                verdicts[i] = bool(good)
+            return verdicts
+
+    def _verify(self, blob, owed: list[bytes], seed: bytes,
+                idx, nu) -> bool:
+        """One mission through ``verify_round`` (idx, nu: the round's
+        challenge, derived by the caller)."""
+        return self.verify_round([blob], [owed], seed, (idx, nu))[0]
 
 
 class ValidatorOcw:

@@ -152,6 +152,20 @@ def fragment_id_from_hash(fragment_hash: bytes) -> np.ndarray:
     return np.array([v & 0xFFFFFFFF, v >> 32], dtype=np.uint32)
 
 
+def fragment_ids_from_hashes(hashes) -> np.ndarray:
+    """``fragment_id_from_hash`` of a whole owed list in one pass ->
+    [F, 2] uint32 (an audit round at the protocol's caps names 100,000
+    fragments: one join and one view, not a Python call a hash)."""
+    hashes = list(hashes)
+    width = len(hashes[0]) if hashes else 0
+    if width < 8 or width % 4 or set(map(len, hashes)) != {width}:
+        return np.stack([fragment_id_from_hash(h) for h in hashes]) \
+            if hashes else np.zeros((0, 2), np.uint32)
+    words = np.frombuffer(b"".join(hashes), dtype="<u4")
+    return np.ascontiguousarray(
+        words.reshape(len(hashes), width // 4)[:, :2], dtype=np.uint32)
+
+
 def _fragment_key(prf_key, fragment_id):
     """Per-fragment PRF key: fragment_id (possibly 64-bit) folds in as
     two 32-bit words (x32 mode cannot carry 64-bit scalars)."""
@@ -315,6 +329,31 @@ def prove_batch(fragments, tags, idx, nu, sectors: int = SECTORS):
     return jax.vmap(lambda d, t: prove(d, t, idx, nu, sectors))(fragments, tags)
 
 
+def aggregate_words(seed_bytes: bytes) -> np.ndarray:
+    """The round seed's two aggregation key words [2] uint32: all of
+    ``aggregate_coeffs`` that is not arithmetic. A verifier that derives
+    r on the device (``round_fold``) ships these eight bytes a round."""
+    import hashlib
+
+    digest = hashlib.sha256(b"cess-podr2-agg:" + seed_bytes).digest()
+    return np.frombuffer(digest[:8], dtype="<u4").astype(np.uint32)
+
+
+def _aggregate_key(words):
+    """The aggregation PRF key from ``aggregate_words`` (host words or
+    traced ones: the same two operations either way)."""
+    return jax.random.fold_in(jax.random.key(words[0]), words[1])
+
+
+def _coeffs(agg_key, ids):
+    """r [F] for ids [F, 2] under the round's aggregation key."""
+    def one(fid):
+        k = jax.random.fold_in(jax.random.fold_in(agg_key, fid[0]), fid[1])
+        return pf.to_field(jax.random.bits(k, (), jnp.uint32))
+
+    return jax.vmap(one)(ids)
+
+
 def aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
     """Per-fragment random linear-combination coefficients r[F] for
     cross-fragment proof aggregation, PRF-derived from the round seed
@@ -334,19 +373,8 @@ def aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
     authoritative statement at PROOF_BYTES, framed total computed by
     node/offchain.py proof_wire_bytes).
     """
-    import hashlib
-
-    digest = hashlib.sha256(b"cess-podr2-agg:" + seed_bytes).digest()
-    w0 = int.from_bytes(digest[:4], "little")
-    w1 = int.from_bytes(digest[4:8], "little")
-    key = jax.random.fold_in(jax.random.key(np.uint32(w0)), np.uint32(w1))
-    ids = jnp.asarray(fragment_ids).reshape(-1, 2)
-
-    def one(fid):
-        k = jax.random.fold_in(jax.random.fold_in(key, fid[0]), fid[1])
-        return pf.to_field(jax.random.bits(k, (), jnp.uint32))
-
-    return jax.vmap(one)(ids)
+    return _coeffs(_aggregate_key(aggregate_words(seed_bytes)),
+                   jnp.asarray(fragment_ids).reshape(-1, 2))
 
 
 def _fold_proofs(mu_f, sigma_f, r):
@@ -387,6 +415,174 @@ def verify_aggregate(key: Podr2Key, fragment_ids, num_blocks: int,
     lhs = pf.addmod(pf.dotmod(r[:, None], lhs_f, axis=0),
                     pf.dotmod(key.alpha, mu[:, None], axis=0))
     return jnp.all(lhs == jnp.asarray(sigma))
+
+
+# -- a round's missions judged together ----------------------------------
+#
+# A TEE worker's verify queue holds up to VERIFY_MISSION_MAX = 500
+# missions a round (runtime/src/lib.rs:990), their owed sets ragged by
+# miner size (29 to 14,721 fragments in the benchmark's round of
+# 100,000). Stacked [missions, F-bucket] they would need a program per
+# pair of buckets and a PRF intermediate of gigabytes; here the owed
+# fragments of every mission lie FLAT, one row each with the index of
+# its mission, and one program folds them into [missions, limbs] a
+# fixed number of rows at a time. Its shape knows the mission bucket
+# and nothing of how the sizes spread.
+ROUND_ROWS = 16384      # flat rows handed to one call of the fold
+ROUND_SUB = 512         # rows per loop step: bounds the PRF intermediate
+                        # ([512, 753, 2] uint32 = 3 MiB at the protocol's)
+
+
+def mission_buckets(missions: int):
+    """The mission counts a round's programs are shaped for, up to the
+    one that holds ``missions``: 8, 64, 512, 4096... (three shapes up
+    to the protocol's cap). A pad mission costs a row of the fold's
+    mask and one zero equation."""
+    b = 8
+    while b < missions:
+        yield b
+        b <<= 3
+    yield b
+
+
+def mission_bucket(missions: int) -> int:
+    """The bucket a round of ``missions`` missions runs in."""
+    *_, bucket = mission_buckets(missions)
+    return bucket
+
+
+def round_fold(key: Podr2Key, agg_words, ids, seg, steps, acc, idx, nu):
+    """acc [missions, limbs] += per mission m:
+    sum_{rows f: seg[f] == m} r_f * sum_i nu_i * f_k(id_f, I_i)
+    over the first ``steps * ROUND_SUB`` of the flat rows ids [rows, 2].
+
+    ``seg`` [rows] int32 is each row's mission; pad rows carry -1 and
+    are in none. ``steps`` is an operand (a loop bound, not a shape),
+    so a short round pays for its own rows only. r_f is derived HERE
+    from the round's aggregation key words (``aggregate_words``) and
+    the row's id, bit for bit ``aggregate_coeffs``: the verifier needs
+    no r from outside. Every row's PRF is evaluated at every challenged
+    block, both limbs, whatever its mission's size."""
+    agg_key = _aggregate_key(agg_words)
+    slots = jnp.arange(acc.shape[0], dtype=jnp.int32)
+
+    def step(i, acc):
+        ids_s = jax.lax.dynamic_slice_in_dim(ids, i * ROUND_SUB, ROUND_SUB)
+        seg_s = jax.lax.dynamic_slice_in_dim(seg, i * ROUND_SUB, ROUND_SUB)
+        f_i = jax.vmap(lambda fid: prf_elems_at(
+            key.prf_key, fid, idx, key.limbs))(ids_s)    # [sub, c, limbs]
+        lhs_f = jax.vmap(
+            lambda f: pf.dotmod(nu[:, None], f, axis=0))(f_i)
+        v = pf.mulmod(_coeffs(agg_key, ids_s)[:, None], lhs_f)
+        mine = seg_s[:, None] == slots[None, :]         # [sub, missions]
+        part = pf.summod(jnp.where(mine[:, :, None], v[:, None, :],
+                                   jnp.uint32(0)), axis=0)
+        return pf.addmod(acc, part)
+
+    return jax.lax.fori_loop(0, steps, step, acc)
+
+
+def round_close(alpha, acc, mu, sigma):
+    """bool [missions]: acc + sum_j alpha_j * mu_j == sigma, BOTH limb
+    equations, for proofs mu [missions, sectors], sigma [missions,
+    limbs]. A pad mission (all zeros) holds; the caller slices it off."""
+    rhs = jax.vmap(lambda u: pf.dotmod(alpha, u[:, None], axis=0))(mu)
+    return jnp.all(pf.addmod(acc, rhs) == sigma, axis=-1)
+
+
+def _round_fold_program(ids, seg, steps, acc, idx, nu, agg_words, alpha,
+                        prf_key_data, *, prf_impl: str):
+    key = Podr2Key(alpha, jax.random.wrap_key_data(prf_key_data,
+                                                   impl=prf_impl))
+    return round_fold(key, agg_words, ids, seg, steps, acc, idx, nu)
+
+
+# jitted once for the process: the round (idx, nu, the aggregation
+# words), the chunk and the key are operands, so one executable a
+# mission bucket serves every round, key and spread of sizes
+ROUND_FOLD = jax.jit(_round_fold_program, static_argnames=("prf_impl",))
+ROUND_CLOSE = jax.jit(round_close)
+
+
+@dataclasses.dataclass(frozen=True)
+class RoundRows:
+    """A round's missions laid out for the fold (host arrays)."""
+    ids: np.ndarray         # [calls * ROUND_ROWS, 2] uint32, zero pad
+    seg: np.ndarray         # [calls * ROUND_ROWS] int32, -1 on pad rows
+    steps: tuple            # loop steps of each call
+    mu: np.ndarray          # [bucket, sectors] uint32, zero pad
+    sigma: np.ndarray       # [bucket, limbs] uint32, zero pad
+    missions: int           # real missions (the first of the bucket)
+    rows: int               # real rows
+
+    @property
+    def rows_issued(self) -> int:
+        """Rows whose PRF the fold evaluates: the real ones and the
+        pad of each call's last step."""
+        return sum(self.steps) * ROUND_SUB
+
+
+def round_rows(ids, sizes, mu, sigma) -> RoundRows:
+    """Lay out missions for the fold: ids [T, 2] in mission order,
+    sizes [M] (every one >= 1, summing to T), proofs mu [M, sectors]
+    and sigma [M, limbs]."""
+    sizes = np.asarray(sizes, dtype=np.int64)
+    missions, total = len(sizes), int(sizes.sum())
+    if missions < 1 or sizes.min() < 1 or total != len(ids):
+        raise ValueError("expected ids [T, 2] and sizes [M] >= 1 "
+                         "summing to T")
+    calls = -(-total // ROUND_ROWS)
+    ids_pad = np.zeros((calls * ROUND_ROWS, 2), dtype=np.uint32)
+    ids_pad[:total] = ids
+    seg = np.full(calls * ROUND_ROWS, -1, dtype=np.int32)
+    seg[:total] = np.repeat(np.arange(missions, dtype=np.int32), sizes)
+    steps = tuple(-(-min(ROUND_ROWS, total - c * ROUND_ROWS) // ROUND_SUB)
+                  for c in range(calls))
+    bucket = mission_bucket(missions)
+    mu_pad = np.zeros((bucket,) + mu.shape[1:], dtype=np.uint32)
+    mu_pad[:missions] = mu
+    sigma_pad = np.zeros((bucket,) + sigma.shape[1:], dtype=np.uint32)
+    sigma_pad[:missions] = sigma
+    return RoundRows(ids_pad, seg, steps, mu_pad, sigma_pad, missions,
+                     total)
+
+
+def key_operands(key: Podr2Key) -> tuple:
+    """The key as the round programs take it: (alpha, the PRF key's raw
+    words) as host arrays and the PRF's implementation name. Host
+    words, so the call is placed wherever its caller places it."""
+    return (np.asarray(key.alpha),
+            np.asarray(jax.random.key_data(key.prf_key)),
+            str(jax.random.key_impl(key.prf_key)))
+
+
+def round_dispatch(key_ops: tuple, rows: RoundRows, idx, nu, agg_words):
+    """Enqueue the round: one fold a ROUND_ROWS rows, then the close.
+    Returns the device's bool [bucket]; nothing is waited for."""
+    alpha, prf_key_data, prf_impl = key_ops
+    acc = np.zeros(rows.sigma.shape, dtype=np.uint32)
+    for c, steps in enumerate(rows.steps):
+        at = slice(c * ROUND_ROWS, (c + 1) * ROUND_ROWS)
+        acc = ROUND_FOLD(rows.ids[at], rows.seg[at], np.int32(steps), acc,
+                         idx, nu, agg_words, alpha, prf_key_data,
+                         prf_impl=prf_impl)
+    return ROUND_CLOSE(alpha, acc, rows.mu, rows.sigma)
+
+
+def warm_round(key_ops: tuple, challenged: int, bucket: int):
+    """Run the fold and the close of one mission bucket over zeros, for
+    rounds of ``challenged`` blocks: after it such a round compiles
+    nothing, whatever its sizes. Returns the device's result."""
+    alpha = key_ops[0]
+    rows = RoundRows(
+        ids=np.zeros((ROUND_ROWS, 2), np.uint32),
+        seg=np.full(ROUND_ROWS, -1, np.int32), steps=(1,),
+        mu=np.zeros((bucket, alpha.shape[0]), np.uint32),
+        sigma=np.zeros((bucket, alpha.shape[1]), np.uint32),
+        missions=bucket, rows=0)
+    return round_dispatch(key_ops, rows, np.zeros((challenged,), np.int32),
+                          np.zeros((challenged,), np.uint32),
+                          np.zeros((2,), np.uint32))
 
 
 def verify_from_f(alpha, f, idx, nu, mu, sigma):

@@ -40,7 +40,17 @@ rounds: the round (idx, nu) and the PoDR2 key are operands, never
 constants. Their callers are host agents: a miner's fragments stay in
 host memory, and ``assemble`` gathers the challenged blocks there
 (4.6% of the set at protocol widths), so only what the round reads
-travels to the device; verify ships KiB-scale proofs.
+travels to the device; verify ships KiB-scale proofs. A TEE's whole
+round (verify_round, PR 33: up to 500 missions, owed sets ragged from
+tens to ten thousands of fragments) is not stacked but FLAT: what
+ships is a row a owed fragment (its 8-byte id and its mission's
+index), the proofs [missions, sectors + limbs], the round (idx, nu)
+and its two aggregation key words — r is derived on the device — and
+one program a mission bucket (8, 64, 512) folds the rows
+ops/podr2.py ROUND_ROWS at a time into [missions, limbs], so the
+number of programs does not grow with the spread of the missions'
+sizes, pad is the last loop step's only, and requests of one round
+coalesce row-wise (``submit_verify_round``, ``warm_verify``).
 
 Protocol determinism is the hard constraint: engine-mediated results
 are bit-identical to the direct calls. That falls out of two facts —
@@ -307,7 +317,8 @@ class SubmissionEngine:
     # op class -> which backend's health breaker gates it
     _BACKEND_OF = {"encode": "codec", "repair": "codec", "decode": "codec",
                    "tag": "audit", "verify_batch": "audit",
-                   "verify_agg": "audit", "prove": "audit"}
+                   "verify_agg": "audit", "verify_round": "audit",
+                   "prove": "audit"}
 
     def __init__(self, codec=None, audit=None,
                  policy: AdmissionPolicy | None = None,
@@ -652,6 +663,53 @@ class SubmissionEngine:
             num_blocks, idx, nu, r, mu, sigma, timeout=timeout,
             tenant=tenant))
 
+    def submit_verify_round(self, fragment_ids, sizes, num_blocks, idx,
+                            nu, agg_words, mu, sigma,
+                            timeout: float | None = None,
+                            tenant: str | None = None) -> EngineFuture:
+        """A round's missions judged together (TeeAgent.verify_round):
+        the missions' owed fragments FLAT, ids [T, 2] in mission order
+        with sizes [M] (each >= 1, summing to T), their proofs mu
+        [M, sectors] and sigma [M, limbs], the round (idx, nu) and its
+        aggregation key words (podr2.aggregate_words: r is derived on
+        the device) -> future of bool [M]. Requests of one round
+        coalesce row-wise: a mission is rows and an index, so ragged
+        owed sets cost no pad beyond the last loop step's and no
+        program of their own (ops/podr2.py ``round_fold``)."""
+        self._need_audit()
+        ids = np.ascontiguousarray(np.asarray(fragment_ids,
+                                              dtype=np.uint32)).reshape(-1, 2)
+        sizes = np.ascontiguousarray(np.asarray(sizes, dtype=np.int64))
+        mu = np.ascontiguousarray(np.asarray(mu, dtype=np.uint32))
+        sigma = np.ascontiguousarray(np.asarray(sigma, dtype=np.uint32))
+        words = np.ascontiguousarray(np.asarray(agg_words,
+                                                dtype=np.uint32))
+        if sizes.ndim != 1 or mu.ndim != 2 or sigma.ndim != 2 \
+                or not len(sizes) == len(mu) == len(sigma) \
+                or words.shape != (2,) \
+                or (len(sizes) and sizes.min() < 1) \
+                or int(sizes.sum()) != len(ids):
+            raise ValueError("expected ids [T, 2], sizes [M] >= 1 "
+                             "summing to T, mu [M, s], sigma [M, limbs] "
+                             "and two aggregation key words")
+        idx, nu = _check_round(idx, nu, num_blocks)
+        key = ("verify_round", num_blocks, mu.shape[1], sigma.shape[1],
+               hashlib.sha256(_round_digest(num_blocks, idx, nu)
+                              + words.tobytes()).digest()[:16])
+        return self._submit("verify", key, ids.shape[0],
+                            {"ids": ids, "sizes": sizes, "mu": mu,
+                             "sigma": sigma},
+                            {"idx": idx, "nu": nu, "agg_words": words},
+                            timeout, tenant=tenant)
+
+    def verify_round(self, fragment_ids, sizes, num_blocks, idx, nu,
+                     agg_words, mu, sigma, timeout: float | None = None,
+                     tenant: str | None = None) -> np.ndarray:
+        return self._blocking(
+            "verify", self.submit_verify_round, fragment_ids, sizes,
+            num_blocks, idx, nu, agg_words, mu, sigma, timeout=timeout,
+            tenant=tenant)
+
     # ------------------------------------------------------------------
     # lifecycle / introspection
     # ------------------------------------------------------------------
@@ -751,6 +809,23 @@ class SubmissionEngine:
                 for lane, device in placements:
                     run("symbol", {"coeff": c}, 2, bucket_rows(b), lane,
                         device)
+
+    def warm_verify(self, challenged: int, missions: int = 512) -> None:
+        """Load the verify class's round programs for every shape a
+        round of up to ``missions`` missions can meet: one fold and one
+        close a mission bucket (8, 64, 512...), base and per pool lane,
+        run once over zeros. ``challenged`` is the round's block count
+        c (a shape; which blocks is an operand). After it a round of
+        any sizes compiles nothing."""
+        from ..ops import podr2
+
+        self._need_audit()
+        sectors, limbs = self.audit.key.alpha.shape
+        lanes = self.pool.lanes if self.pool is not None else ()
+        for bucket in podr2.mission_buckets(missions):
+            for lane in (None, *lanes):
+                self._round_program(challenged, sectors, limbs, bucket,
+                                    False, lane, warm=True)
 
     def attach_stream(self, stream_stats) -> None:
         """Register a streaming driver's StreamStats so its per-stage
@@ -1879,6 +1954,7 @@ class SubmissionEngine:
             prog = self._stacked_program(batch, fb, rb, degraded, lane,
                                          bind)
             out = prog(ids, rs, mu, sigma, aux["idx"], aux["nu"])
+            self._count_verify(len(batch), 1, rb * fb * len(aux["idx"]))
         with self._stage("verify", "wait"):
             # the fetch below would block on the device anyway: the
             # wait is named, nothing is added to the path
@@ -1887,6 +1963,72 @@ class SubmissionEngine:
             out = np.asarray(out)
             results = [bool(out[i]) for i in range(len(batch))]
         return results, rb * fb
+
+    def _count_verify(self, missions: int, calls: int, evals: int) -> None:
+        with self._lock:
+            st = self.stats.classes["verify"]
+            st.missions += missions
+            st.device_calls += calls
+            st.prf_evals += evals
+
+    def _round_program(self, challenged: int, sectors: int, limbs: int,
+                       bucket: int, degraded: bool, lane,
+                       warm: bool = False):
+        """The verify class's cached program for rounds of one mission
+        bucket: ops/podr2.py ``round_dispatch`` with the backend's key
+        read once as host words, placed on the backend's device.
+        ``warm``: a new entry runs once over zeros as it is built."""
+        from ..ops import podr2
+
+        audit = self._audit_backend(degraded, lane)
+
+        def build():
+            key_ops = podr2.key_operands(audit.key)
+
+            def placed(rows, idx, nu, agg_words):
+                with jax.default_device(audit.device):
+                    return podr2.round_dispatch(key_ops, rows, idx, nu,
+                                                agg_words)
+            if warm:
+                with jax.default_device(audit.device):
+                    jax.block_until_ready(
+                        podr2.warm_round(key_ops, challenged, bucket))
+            return placed
+
+        return self.programs.get(
+            self._key(("verify_round", challenged, sectors, limbs, bucket),
+                      degraded, lane), build)
+
+    def _op_verify_round(self, batch, degraded=False, lane=None):
+        from ..ops import podr2
+
+        aux = batch[0].aux
+        with self._stage("verify", "assemble"):
+            if len(batch) == 1:
+                arrays = batch[0].arrays
+            else:
+                arrays = {k: np.concatenate([r.arrays[k] for r in batch])
+                          for k in ("ids", "sizes", "mu", "sigma")}
+            rows = podr2.round_rows(arrays["ids"], arrays["sizes"],
+                                    arrays["mu"], arrays["sigma"])
+        challenged = len(aux["idx"])
+        with self._stage("verify", "dispatch"):
+            prog = self._round_program(
+                challenged, rows.mu.shape[1], rows.sigma.shape[1],
+                len(rows.mu), degraded, lane)
+            out = prog(rows, aux["idx"], aux["nu"], aux["agg_words"])
+            self._count_verify(rows.missions, len(rows.steps) + 1,
+                               rows.rows_issued * challenged)
+        with self._stage("verify", "wait"):
+            jax.block_until_ready(out)
+        with self._stage("verify", "fetch"):
+            out = np.asarray(out)
+            results, at = [], 0
+            for r in batch:
+                n = len(r.arrays["sizes"])
+                results.append(out[at:at + n])
+                at += n
+        return results, rows.rows_issued
 
     def _op_prove(self, batch, degraded=False, lane=None):
         aux = batch[0].aux

@@ -73,7 +73,8 @@ _ONE_SHOT = frozenset(("file-offence",))
 # (read from the bound engine when one is attached)
 _CLASS_BACKEND = {"encode": "codec", "decode": "codec",
                   "repair": "codec", "tag": "audit", "prove": "audit",
-                  "verify_batch": "audit", "verify_agg": "audit"}
+                  "verify_batch": "audit", "verify_agg": "audit",
+                  "verify_round": "audit"}
 
 # detector notes folded into the evidence map (snapshot context for
 # humans; never actions by themselves)

@@ -46,6 +46,7 @@ class ClassStats:
                  "saturated", "shed", "batches", "batched_requests",
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
                  "linear_puts", "patterns_new", "matrix_build_s",
+                 "missions", "device_calls", "prf_evals",
                  "latencies", "hist", "stage_n", "stage_s")
 
     def __init__(self):
@@ -84,6 +85,15 @@ class ClassStats:
         # counts programs, one per shape
         self.patterns_new = 0
         self.matrix_build_s = 0.0
+        # verify class, aggregated proofs (engine.py _op_verify_agg,
+        # _op_verify_round): missions judged, device programs called
+        # for them, and PRF evaluations those programs issue (rows on
+        # the device x challenged blocks, pad rows included: what the
+        # device is asked for; ``rows`` / ``padded_rows`` say how much
+        # of it a mission owed)
+        self.missions = 0
+        self.device_calls = 0
+        self.prf_evals = 0
         self.latencies = collections.deque(maxlen=LATENCY_WINDOW)
         # real Prometheus histogram of the same submit->resolve
         # latencies: unlike the sliding-window percentiles above this
@@ -244,11 +254,16 @@ class EngineStats:
                 "batches": st.batches,
                 "batch_occupancy": round(st.occupancy, 4),
                 "pad_waste": round(st.pad_waste, 4),
+                "rows": st.rows,
+                "padded_rows": st.padded_rows,
                 "operand_bytes": st.operand_bytes,
                 "linear_fetches": st.linear_fetches,
                 "linear_puts": st.linear_puts,
                 "patterns_new": st.patterns_new,
                 "matrix_build_s": st.matrix_build_s,
+                "missions": st.missions,
+                "device_calls": st.device_calls,
+                "prf_evals": st.prf_evals,
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
                 "stages": {stage: {"n": st.stage_n[stage],

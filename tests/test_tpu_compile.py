@@ -223,6 +223,36 @@ def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
                 + mem.output_size_in_bytes) < HBM_BYTES, mem
 
 
+@pytest.mark.parametrize("missions", [8, 512])
+def test_round_fold_compiles_for_v5e(one_chip, for_tpu, missions):
+    """The TEE's round programs (ops/podr2.py ROUND_FOLD / ROUND_CLOSE)
+    at the protocol's widths: 16,384 flat rows a call, 753 challenged
+    blocks, the smallest and the cap's mission bucket. The PRF
+    intermediate is a loop step's, whatever the rows."""
+    from cess_tpu.ops import podr2
+
+    u32, i32 = jnp.uint32, jnp.int32
+    fold = [((podr2.ROUND_ROWS, 2), u32), ((podr2.ROUND_ROWS,), i32),
+            ((), i32), ((missions, 2), u32), ((753,), i32), ((753,), u32),
+            ((2,), u32), ((256, 2), u32), ((2,), u32)]
+    close = [((256, 2), u32), ((missions, 2), u32), ((missions, 256), u32),
+             ((missions, 2), u32)]
+    programs = (
+        (functools.partial(podr2._round_fold_program,
+                           prf_impl="threefry2x32"), fold),
+        (podr2.round_close, close))
+    for fn, shapes in programs:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert time.perf_counter() - t0 < COMPILE_SECONDS
+        mem = compiled.memory_analysis()
+        print(f"temp {mem.temp_size_in_bytes / MiB:.1f} MiB, arguments "
+              f"{mem.argument_size_in_bytes / MiB:.1f} MiB")  # pytest -s
+        assert mem.temp_size_in_bytes < 256 * MiB, mem
+
+
 @pytest.mark.parametrize("shape,packed", [
     pytest.param((1, 1, 8 * MiB), 4, id="one-claim-rs2p1"),
     pytest.param((2, 1, 8 * MiB), 2, id="two-claims-rs2p1"),
