@@ -40,8 +40,14 @@ class AuditBackend:
             return fn(*args)
 
     # -- TEE: tag generation ------------------------------------------------
+    @functools.cached_property
+    def _tag_operands(self) -> tuple:
+        return podr2.tag_operands(self.key)
+
     def tag_fragments(self, fragment_ids, fragments):
-        return self._on("tag", podr2.tag_fragments, self.key,
+        """One compiled program a batch shape (podr2.TAG_PROGRAM), the
+        key its operands: a call is one enqueue on this device."""
+        return self._on("tag", podr2.tag_dispatch, self._tag_operands,
                         fragment_ids, fragments)
 
     # -- round: challenge derivation ----------------------------------------

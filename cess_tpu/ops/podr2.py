@@ -242,27 +242,75 @@ def tag_fragment(key: Podr2Key, fragment_id, fragment) -> jax.Array:
                                                m.shape[0], key.limbs), m)
 
 
+def _tag_batch(key: Podr2Key, fragment_ids, fragments, weights):
+    """THE one definition of batched tag-gen, traced by every caller
+    (eager, the fused ingest step, TAG_PROGRAM). ``weights``: the fused
+    kernel's weight limbs of ``key.alpha`` (podr2_pallas.weight_limbs)
+    or None — they are host arithmetic on a concrete alpha, so a caller
+    whose key is traced brings them. The lowering follows the shape:
+    the Pallas kernel inside its envelope, the plain-jnp MAC outside it
+    (and without weights): identical results either way."""
+    from . import podr2_pallas
+
+    sectors = key.alpha.shape[0]
+    blocks = fragments.shape[-1] // (sectors * pf.BYTES_PER_ELEM)
+    if weights is not None and podr2_pallas.supported(sectors, blocks):
+        prf = jax.vmap(
+            lambda i: prf_elems(key.prf_key, i, blocks,
+                                key.limbs))(fragment_ids)
+        return podr2_pallas.tag_fragments_fused(weights, prf, fragments)
+    return jax.vmap(lambda i, d: tag_fragment(key, i, d))(fragment_ids,
+                                                          fragments)
+
+
 def tag_fragments(key: Podr2Key, fragment_ids, fragments) -> jax.Array:
     """Batched tag-gen: ids [F], fragments [F, fragment_bytes] ->
     [F, blocks, limbs]. Routes through the fused Pallas kernel
     (ops/podr2_pallas.py) when the shape envelope allows — identical
-    results, one VMEM pass instead of materialised pack/MAC stages."""
+    results, one VMEM pass instead of materialised pack/MAC stages.
+    Eager, or inside the caller's own trace with the key its constants
+    (models/pipeline.py fused_step); a tag batch a call, with the key
+    as operands, is ``tag_dispatch``."""
     from . import podr2_pallas
 
-    fragments = jnp.asarray(fragments)
-    sectors = key.alpha.shape[0]
-    blocks = fragments.shape[-1] // (sectors * pf.BYTES_PER_ELEM)
     # a TRACED alpha (key passed as a jit argument) cannot feed the
     # kernel's host-side weight precompute; the jnp path traces fine
     alpha_concrete = not isinstance(key.alpha, jax.core.Tracer)
-    if alpha_concrete and podr2_pallas.supported(sectors, blocks):
-        prf = jax.vmap(
-            lambda i: prf_elems(key.prf_key, i, blocks,
-                                key.limbs))(fragment_ids)
-        return podr2_pallas.tag_fragments_fused(key.alpha, prf,
-                                                fragments)
-    return jax.vmap(lambda i, d: tag_fragment(key, i, d))(fragment_ids,
-                                                          fragments)
+    return _tag_batch(key, fragment_ids, jnp.asarray(fragments),
+                      podr2_pallas.weight_limbs(key.alpha)
+                      if alpha_concrete else None)
+
+
+def _tag_program(fragment_ids, fragments, alpha, prf_key_data, weights, *,
+                 prf_impl: str):
+    key = Podr2Key(alpha, jax.random.wrap_key_data(prf_key_data,
+                                                   impl=prf_impl))
+    return _tag_batch(key, fragment_ids, fragments, weights)
+
+
+# jitted once for the process, as the round programs below: the key
+# and the kernel's weights are operands, so one executable a batch
+# shape (ids, fragments) and device serves every key and every backend
+TAG_PROGRAM = jax.jit(_tag_program, static_argnames=("prf_impl",))
+
+
+def tag_operands(key: Podr2Key) -> tuple:
+    """The key as TAG_PROGRAM takes it: ``key_operands`` plus the fused
+    kernel's weight limbs, all host arrays, made once a key."""
+    from . import podr2_pallas
+
+    alpha, prf_key_data, prf_impl = key_operands(key)
+    return (alpha, prf_key_data, podr2_pallas.weight_limbs(alpha),
+            prf_impl)
+
+
+def tag_dispatch(tag_ops: tuple, fragment_ids, fragments) -> jax.Array:
+    """``tag_fragments`` as ONE enqueue: TAG_PROGRAM over
+    ``tag_operands(key)``, placed wherever its caller places it. Tags
+    bit-identical to ``tag_fragments(key, ...)``."""
+    alpha, prf_key_data, weights, prf_impl = tag_ops
+    return TAG_PROGRAM(fragment_ids, fragments, alpha, prf_key_data,
+                       weights, prf_impl=prf_impl)
 
 
 def gen_challenge(seed_bytes: bytes | int, num_blocks: int,

@@ -162,16 +162,24 @@ def _weight_limbs(alpha_key) -> tuple[np.ndarray, np.ndarray]:
     return ((w & 0xFFFF).astype(np.int32), (w >> 16).astype(np.int32))
 
 
-def tag_fragments_fused(alpha: jax.Array, prf: jax.Array,
+def weight_limbs(alpha) -> tuple[np.ndarray, np.ndarray]:
+    """The kernel's weights (w0, w1) for a CONCRETE alpha [sectors,
+    limbs]: host arithmetic on the key's own words, so a caller whose
+    alpha is traced (podr2.TAG_PROGRAM) makes them once a key and hands
+    them in as operands."""
+    alpha_np = np.asarray(jax.device_get(alpha), dtype=np.uint32)
+    return _weight_limbs(alpha_np.shape + (alpha_np.tobytes(),))
+
+
+def tag_fragments_fused(weights, prf: jax.Array,
                         fragments: jax.Array) -> jax.Array:
     """fragments [F, bytes] uint8, prf [F, blocks, limbs] ->
-    tags [F, blocks, limbs] (the tag_from_elems contract, fused)."""
+    tags [F, blocks, limbs] (the tag_from_elems contract, fused).
+    ``weights``: ``weight_limbs(alpha)``, host arrays or traced."""
     fcount, nbytes = fragments.shape
-    sectors, limbs = alpha.shape
-    lanes = 2 * sectors
+    w0, w1 = weights
+    limbs, lanes = w0.shape
     blocks = nbytes // lanes
-    alpha_np = np.asarray(jax.device_get(alpha), dtype=np.uint32)
-    w0, w1 = _weight_limbs((sectors, limbs, alpha_np.tobytes()))
     tile = min(blocks, DEFAULT_BLOCK_TILE)
     out = _tags_3d(jnp.asarray(w0), jnp.asarray(w1),
                    jnp.moveaxis(prf, -1, 1),

@@ -1866,6 +1866,9 @@ class SubmissionEngine:
             ids = _pad_axis0(ids, bucket)
             frags = _pad_axis0(frags, bucket)
         with self._stage("tag", "dispatch"):
+            # one enqueue: the backend calls ops/podr2.py TAG_PROGRAM
+            # (one executable a batch shape and device, the key its
+            # operands) under its own fault seam and device scope
             prog = self.programs.get(self._key(("tag", nbytes, bucket),
                                                degraded, lane),
                                      lambda: audit.tag_fragments)

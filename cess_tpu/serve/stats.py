@@ -27,8 +27,8 @@ LATENCY_WINDOW = 512     # per-class sliding window for percentiles
 #   assemble  host-side coalescing: concatenate/pad, or the stacked
 #             classes' zero-fill + copy loops
 #   dispatch  the program call returns: the program and its implicit
-#             host->device copies are ENQUEUED (the un-jitted vmaps
-#             issue op by op here), the result slice with them
+#             host->device copies are ENQUEUED (verify_batch's un-jitted
+#             vmap issues op by op here), the result slice with them
 #   wait      jax.block_until_ready on the result: the device works,
 #             the host waits
 #   fetch     np.asarray of the result and the per-request slicing

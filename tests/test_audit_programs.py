@@ -27,11 +27,16 @@ def rnd(shape, seed):
                                                 dtype=np.uint8)
 
 
-def _mission(key, f, seed, round_seed=b"round", frag_bytes=FRAG):
-    """One miner's set of f fragments with its ids, tags and r."""
-    frags = rnd((f, frag_bytes), seed)
+def _tag_inputs(f, frag_bytes, seed):
+    """f seeded fragments and their (lo, hi) ids."""
     ids = np.stack([podr2.fragment_id_from_hash(bytes([seed % 251, j]) * 16)
                     for j in range(f)])
+    return ids, rnd((f, frag_bytes), seed)
+
+
+def _mission(key, f, seed, round_seed=b"round", frag_bytes=FRAG):
+    """One miner's set of f fragments with its ids, tags and r."""
+    ids, frags = _tag_inputs(f, frag_bytes, seed)
     tags = np.asarray(podr2.tag_fragments(key, ids, frags))
     r = np.asarray(podr2.aggregate_coeffs(round_seed, ids))
     return frags, ids, tags, r
@@ -260,3 +265,125 @@ def test_one_program_per_shape_across_rounds_and_operands_are_what_a_round_reads
         assert metrics["cess_engine_encode_operand_bytes"] == 0
     finally:
         eng.close()
+
+
+# -- the tag batch as one compiled program (PR 34) ---------------------------
+#
+# ops/podr2.py TAG_PROGRAM behind AuditBackend.tag_fragments: the key
+# and the kernel's weights are operands, the lowering follows the shape.
+
+def _plain_tags(key, ids, frags):
+    """The plain-jnp MAC, a fragment at a time: no kernel, no batch."""
+    return np.stack([np.asarray(podr2.tag_fragment(key, i, d))
+                     for i, d in zip(ids, frags)])
+
+
+# case -> (limbs, blocks, fragments of each coalesced request, engine
+# kwargs). 8 blocks lie inside the kernel's envelope, 192 outside it
+# (192 % 128 != 0: the plain-jnp MAC under the same program).
+TAG_CASES = {
+    "kernel": (2, 8, (4,), {}),
+    "kernel-limbs3": (3, 8, (2,), {}),
+    "jnp-outside-envelope": (2, 192, (2,), {}),
+    "padded-bucket": (2, 8, (3,), {}),
+    "ragged-coalesced": (2, 8, (1, 2, 3), {}),
+    "degraded-cpu": (2, 8, (3,), {"resilience": ResilienceConfig()}),
+    "pool-lane": (2, 8, (2,), {"pool": 2}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TAG_CASES))
+def test_engine_tags_bit_identical_to_direct(case):
+    from cess_tpu.ops import podr2_pallas
+
+    limbs, blocks, sizes, kwargs = TAG_CASES[case]
+    assert podr2_pallas.supported(podr2.SECTORS, blocks) \
+        == (case != "jnp-outside-envelope")
+    key = podr2.Podr2Key.generate(34, podr2.Podr2Params(limbs=limbs))
+    reqs = [_tag_inputs(f, blocks * podr2.BLOCK_BYTES, 60 + i)
+            for i, f in enumerate(sizes)]
+    eng = make_engine(podr2_key=key,
+                      policy=AdmissionPolicy(max_delay=0.25), **kwargs)
+    plan = FaultPlan.seeded(b"tags", {"engine.dispatch": (1.0, "raise")}) \
+        if case == "degraded-cpu" else FaultPlan({})
+    try:
+        with faults.armed(plan):
+            futs = [eng.submit_tag(ids, frags) for ids, frags in reqs]
+            got = [f.result(timeout=120) for f in futs]
+        for (ids, frags), tags in zip(reqs, got):
+            # rows beyond the request's own never come back
+            assert tags.shape == (len(frags), blocks, limbs)
+            assert tags.dtype == np.uint32
+            assert np.array_equal(
+                tags, np.asarray(podr2.tag_fragments(key, ids, frags)))
+            assert np.array_equal(tags, _plain_tags(key, ids, frags))
+        snap = eng.stats_snapshot()
+        if case == "ragged-coalesced":
+            assert snap["classes"]["tag"]["batches"] == 1
+            assert snap["classes"]["tag"]["padded_rows"] == 2   # 6 of 8
+        if case == "padded-bucket":
+            assert snap["classes"]["tag"]["padded_rows"] == 1   # 3 of 4
+        if case == "degraded-cpu":
+            assert plan.fired_log()
+            res = snap["resilience"]
+            assert res["fallback_batches"].get("tag", 0) \
+                + res["degraded_batches"].get("tag", 0) >= 1
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("pairs", [True, False], ids=["pairs", "scalars"])
+@pytest.mark.parametrize("blocks", [8, 192], ids=["kernel", "jnp"])
+def test_tag_dispatch_is_tag_fragments(blocks, pairs):
+    """On raw arrays, without an engine: the program over the key's
+    operands against the eager call and the plain MAC, for (lo, hi) id
+    pairs and for scalar ids, host and device-resident inputs."""
+    import jax.numpy as jnp
+
+    key = podr2.Podr2Key.generate(9)
+    ids, frags = _tag_inputs(3, blocks * podr2.BLOCK_BYTES, 21)
+    if not pairs:
+        ids = ids[:, 0].copy()
+    ops = podr2.tag_operands(key)
+    assert all(isinstance(a, np.ndarray) for a in ops[:2] + ops[2])
+    want = np.asarray(podr2.tag_fragments(key, ids, frags))
+    assert np.array_equal(want, _plain_tags(key, ids, frags))
+    got = podr2.tag_dispatch(ops, ids, frags)
+    assert np.array_equal(np.asarray(got), want)
+    got_dev = podr2.tag_dispatch(ops, jnp.asarray(ids), jnp.asarray(frags))
+    assert np.array_equal(np.asarray(got_dev), want)
+
+
+def test_one_tag_program_per_shape_across_keys_and_batches(compiles):
+    """The mechanism: after one warm batch a second of the same shape
+    compiles nothing and builds no program, and a second KEY (another
+    engine, another backend) runs the same trace: the key is an
+    operand. Its tags are its own."""
+    key_a, key_b = podr2.Podr2Key.generate(1), podr2.Podr2Key.generate(2)
+    eng_a = make_engine(podr2_key=key_a,
+                        policy=AdmissionPolicy(max_delay=0.002))
+    eng_b = make_engine(podr2_key=key_b,
+                        policy=AdmissionPolicy(max_delay=0.002))
+    try:
+        ids, frags = _tag_inputs(4, FRAG, 5)
+        first = eng_a.tag_fragments(ids, frags)
+        eng_a.flush()
+        built = eng_a.stats_snapshot()["programs_built"]
+        traced, compiled = podr2.TAG_PROGRAM._cache_size(), compiles()
+        ids2, frags2 = _tag_inputs(4, FRAG, 6)
+        second = eng_a.tag_fragments(ids2, frags2)
+        other = eng_b.tag_fragments(ids, frags)
+        eng_a.flush()
+        assert compiles() == compiled
+        assert podr2.TAG_PROGRAM._cache_size() == traced
+        snap = eng_a.stats_snapshot()
+        assert snap["programs_built"] == built == 1
+        assert snap["programs_reused"] == 1
+        assert np.array_equal(
+            second, np.asarray(podr2.tag_fragments(key_a, ids2, frags2)))
+        assert np.array_equal(
+            other, np.asarray(podr2.tag_fragments(key_b, ids, frags)))
+        assert not np.array_equal(first, other)
+    finally:
+        eng_a.close()
+        eng_b.close()

@@ -270,6 +270,42 @@ def test_abandon_when_budget_exhausted():
         eng.close()
 
 
+def test_tag_seam_fires_on_every_engine_tag_batch(pkey):
+    """``podr2.tag.<platform>`` is crossed once per engine tag batch,
+    the batches after the first included: they run a compiled program
+    (ops/podr2.py TAG_PROGRAM) that jit already holds, and the seam
+    must stay outside it. A delay changes nothing, a raise rejects that
+    batch alone (no resilience layer here to hide it)."""
+    import jax
+
+    site = f"podr2.tag.{jax.devices('cpu')[0].platform}"
+    eng = make_engine(podr2_key=pkey,
+                      policy=AdmissionPolicy(max_delay=0.002))
+    plan = FaultPlan({site: {0: FaultSpec("delay", delay_s=0.001),
+                             1: FaultSpec("delay", delay_s=0.001),
+                             2: FaultSpec("raise"),
+                             3: FaultSpec("delay", delay_s=0.001)}})
+    ids = np.stack([podr2.fragment_id_from_hash(bytes([j]) * 32)
+                    for j in range(2)])
+    try:
+        with faults.armed(plan):
+            for batch in range(4):
+                frags = rnd((2, FRAG), 70 + batch)
+                if batch == 2:
+                    with pytest.raises(FaultInjected):
+                        eng.tag_fragments(ids, frags, timeout=60)
+                    continue
+                assert np.array_equal(
+                    eng.tag_fragments(ids, frags, timeout=60),
+                    np.asarray(podr2.tag_fragments(pkey, ids, frags)))
+        assert plan.fired_log() == ((site, 0, "delay"), (site, 1, "delay"),
+                                    (site, 2, "raise"), (site, 3, "delay"))
+        st = eng.stats_snapshot()["classes"]["tag"]
+        assert st["completed"] == 3 and st["failed"] == 1
+    finally:
+        eng.close()
+
+
 # -- streaming + transfer seams ---------------------------------------------
 
 def test_stream_staging_fault_seams(pkey):

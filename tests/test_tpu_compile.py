@@ -1,6 +1,6 @@
-"""The main path's Pallas kernels, the engine's two audit programs, its
-flatten of a byte result into linear rows, the gateway's parity-rows
-program and the pooled stream step
+"""The main path's Pallas kernels, the engine's two audit programs and
+its tag program, its flatten of a byte result into linear rows, the
+gateway's parity-rows program and the pooled stream step
 over four chips, compiled at protocol widths for a
 DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
 refuses here what it would refuse on the chip — a kernel Mosaic cannot
@@ -113,9 +113,7 @@ def _xor_encode(k, m):
 
 def _podr2_tags():
     key = podr2.Podr2Key.generate(0)
-    w0, w1 = podr2_pallas._weight_limbs(
-        (key.alpha.shape[0], key.limbs,
-         np.asarray(key.alpha, dtype=np.uint32).tobytes()))
+    w0, w1 = podr2_pallas.weight_limbs(key.alpha)
     lanes = 2 * key.alpha.shape[0]
 
     def run(prf, data):
@@ -221,6 +219,38 @@ def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
               f"{mem.argument_size_in_bytes / MiB:.1f} MiB")  # pytest -s
         assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes) < HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("bucket,n", [
+    pytest.param(16, 8 * MiB, id="upload-rs2p1-16x8MiB"),
+    pytest.param(16, 4 * MiB, id="upload-rs4p8-16x4MiB")])
+def test_tag_program_compiles_for_v5e(one_chip, for_tpu, bucket, n):
+    """The engine's tag batch since PR 34 (ops/podr2.py TAG_PROGRAM):
+    one program a batch shape, the key and the kernel's weights its
+    operands, at the two shapes uploads send. The fragments are an
+    ARGUMENT here (an intermediate in the fused ingest step), so the
+    relayouting view u8[F, n] -> [F, blocks, 512] in front of the
+    kernel must still compile in seconds (PERF.md, PR 22); the kernel
+    keeps its pinned name and nothing calls back to the host."""
+    u32, i32 = jnp.uint32, jnp.int32
+    shapes = [((bucket, 2), u32), ((bucket, n), jnp.uint8),
+              ((256, 2), u32), ((2,), u32)]
+    ids, frags, alpha, key_data = (
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes)
+    weights = tuple(jax.ShapeDtypeStruct((2, 512), i32, sharding=one_chip)
+                    for _ in range(2))
+    t0 = time.perf_counter()
+    compiled = podr2.TAG_PROGRAM.lower(
+        ids, frags, alpha, key_data, weights,
+        prf_impl="threefry2x32").compile()
+    took = time.perf_counter() - t0
+    print(f"compiled in {took:.1f} s")                     # pytest -s
+    assert took < COMPILE_SECONDS
+    text = compiled.as_text()
+    assert re.search(rf"%{TAGS}\.\d+ = [^\n]* custom-call\(", text)
+    assert not re.search(r"callback|host_compute|outfeed|infeed", text)
+    assert compiled.out_info.shape == (bucket, n // 512, 2)
+    _fits_hbm(compiled)
 
 
 @pytest.mark.parametrize("missions", [8, 512])
