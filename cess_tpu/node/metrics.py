@@ -63,6 +63,13 @@ def collect(node) -> dict[str, float]:
     gateway = getattr(node, "gateway", None)
     if gateway is not None:
         m.update(gateway.metrics())
+    # the process's PoDR2 round derivations (ops/podr2.py gen_challenge
+    # / aggregate_coeffs: host seconds and calls), where its agents
+    # hold the device path
+    if engine is not None or gateway is not None:
+        from ..ops import podr2
+
+        m.update(podr2.stage_metrics())
     # telemetry-stream delivery counters (satellite: drops and sends
     # were previously silent — a dead collector looked identical to a
     # healthy one from the node's own metrics)

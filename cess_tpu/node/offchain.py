@@ -82,13 +82,34 @@ def _parity_rows(frags, k: int):
                  for j in range(k, frags.shape[1]))
 
 
-def _hashed_copy(row) -> tuple[bytes, bytes]:
+def _worker_sink(sinks: dict) -> dict:
+    """This worker thread's stage sink for the upload whose jobs share
+    ``sinks`` (thread id -> sink): obs.trace.stage's sinks are unlocked,
+    so every writer thread has its own; the upload merges them once its
+    jobs are done."""
+    return sinks.setdefault(threading.get_ident(), {})
+
+
+def _hashed(data, sinks: dict, span) -> bytes:
+    """A worker's job for one segment, and the second half of one for a
+    fragment: the SHA-256 of ``data``, one ``gateway.worker.hash`` stage
+    a job on the worker's thread (``span``: the upload's, handed across
+    the thread boundary)."""
+    with trace.stage("gateway.worker.hash", _worker_sink(sinks),
+                     parent=span):
+        return fragment_hash(data)
+
+
+def _hashed_copy(row, sinks: dict, span) -> tuple[bytes, bytes]:
     """A worker's job for one fragment: the ``bytes`` that the store
     will hold, copied once from the row's memory (a view of the
-    upload's input, or a fetched parity row), and their identity,
-    hashed from that same copy."""
-    blob = bytes(row)
-    return blob, fragment_hash(blob)
+    upload's input, or a fetched parity row; the ``gateway.worker.copy``
+    stage, which holds the GIL), and their identity, hashed from that
+    same copy (``gateway.worker.hash``, which does not)."""
+    with trace.stage("gateway.worker.copy", _worker_sink(sinks),
+                     parent=span):
+        blob = bytes(row)
+    return blob, _hashed(blob, sinks, span)
 
 
 class OssGateway:
@@ -108,7 +129,13 @@ class OssGateway:
     ``bytes``) by the gateway's worker threads, from the memory the
     user handed in, while the device encodes; only parity comes back
     over the link, and each parity row is hashed as it lands.
-    ``counters()`` says how many rows took which way."""
+    ``counters()`` says how many rows took which way, and how long each
+    stage of the uploads took: every stage of an upload goes through
+    obs.trace.stage with a sink, the upload thread's (``offchain.upload``,
+    the seven ``gateway.*`` stages and ``gateway.encode``'s three
+    children) and each worker's own (``gateway.worker.copy`` /
+    ``gateway.worker.hash``, one a job), merged when the upload counts
+    itself."""
 
     def __init__(self, node: Node, account: str,
                  pipeline: StoragePipeline):
@@ -128,28 +155,48 @@ class OssGateway:
         self._counters = dict.fromkeys(
             ("uploads", "rows_from_host", "rows_fetched",
              "bytes_fetched", "hash_jobs"), 0)
+        # stage name -> [count, seconds] over completed uploads
+        self._stages: dict[str, list] = {}
 
     def close(self) -> None:
         """Stop the hash workers (idle ones also stop when the gateway
         is collected). An upload after this raises."""
         self._hashers.shutdown()
 
-    def counters(self) -> dict[str, int]:
+    def counters(self) -> dict:
         """Totals over this gateway's completed uploads: ``uploads``;
         ``rows_from_host`` (data rows hashed and stored from the
         input, never fetched) and ``rows_fetched`` (parity rows);
         ``bytes_fetched`` (everything that came down from the device:
         the parity rows and the tags); ``hash_jobs`` (SHA-256 handed to
-        the workers: every fragment and every segment)."""
+        the workers: every fragment and every segment); and, by stage
+        name, ``stage_count`` and ``stage_seconds`` (raw, unrounded:
+        the upload thread's stages once an upload, the workers' once a
+        job, so their seconds are worker-seconds and may pass the
+        upload's own)."""
         with self._mu:
-            return dict(self._counters)
+            out = dict(self._counters)
+            out["stage_count"] = {k: v[0] for k, v in self._stages.items()}
+            out["stage_seconds"] = {k: v[1]
+                                    for k, v in self._stages.items()}
+        return out
 
     def metrics(self) -> dict[str, float]:
-        """``counters()`` as ``cess_gateway_*_total`` series: merged
-        into GET /metrics when the node carries ``node.gateway = gw``
-        (node/metrics.py collect())."""
-        return {f"cess_gateway_{name}_total": float(value)
-                for name, value in self.counters().items()}
+        """``counters()`` as ``cess_gateway_*_total`` series and, a
+        stage, ``cess_gateway_stage_<name>_seconds`` / ``_count``
+        (``<name>``: the stage's without its first part, dots as
+        underscores: ``upload``, ``encode_put``, ``worker_copy``):
+        merged into GET /metrics when the node carries ``node.gateway
+        = gw`` (node/metrics.py collect())."""
+        c = self.counters()
+        counts, seconds = c.pop("stage_count"), c.pop("stage_seconds")
+        out = {f"cess_gateway_{name}_total": float(value)
+               for name, value in c.items()}
+        for stage, n in counts.items():
+            name = stage.partition(".")[2].replace(".", "_")
+            out[f"cess_gateway_stage_{name}_seconds"] = seconds[stage]
+            out[f"cess_gateway_stage_{name}_count"] = float(n)
+        return out
 
     def upload(self, owner: str, bucket: str, file_name: str,
                data: bytes) -> bytes:
@@ -164,27 +211,45 @@ class OssGateway:
         segments = np.frombuffer(padded, dtype=np.uint8).reshape(n_segs, seg_size)
         host = memoryview(padded)
         hashers = self._hashers
-        # one stage each per upload (obs.trace.stage): the upload and
-        # its six stages are cess:offchain.upload / cess:gateway.* in
-        # any profiler trace, and spans of an armed tracer. All of them
-        # are on this thread; the workers emit none
-        with trace.stage("offchain.upload", sys="offchain",
+        # one stage each per upload (obs.trace.stage): the upload, its
+        # seven stages and the three children of gateway.encode are
+        # cess:offchain.upload / cess:gateway.* in any profiler trace,
+        # counted in ``sink``, and spans of an armed tracer; all of
+        # those are on this thread. The workers' jobs are stages too
+        # (gateway.worker.copy / .hash, one a job), each on its
+        # worker's thread and in its worker's own sink of ``sinks``,
+        # children of the upload's span
+        sink: dict = {}
+        sinks: dict = {}
+        with trace.stage("offchain.upload", sink, sys="offchain",
                          file=file_name, segments=n_segs,
                          size=len(data)):
-            with trace.stage("gateway.encode"):
-                # what needs nothing from the device starts now, on
-                # zero-copy views of the input, and runs behind the
-                # copy up, the encode and the parity's way down
-                seg_jobs = [hashers.submit(
-                    fragment_hash, host[i * seg_size:(i + 1) * seg_size])
-                    for i in range(n_segs)]
-                frag_jobs = [[hashers.submit(
-                    _hashed_copy, host[i * seg_size + j * n:
-                                       i * seg_size + (j + 1) * n])
-                    for j in range(k)] for i in range(n_segs)]
-                frags_dev = self.pipeline.encode_step(
-                    jnp.asarray(segments), tenant=owner)
-            with trace.stage("gateway.fetch", rows=n_segs * m,
+            span = trace.current_span()
+            with trace.stage("gateway.encode", sink):
+                with trace.stage("gateway.encode.jobs", sink):
+                    # what needs nothing from the device starts now,
+                    # on zero-copy views of the input, and runs behind
+                    # the copy up, the encode and the parity's way down
+                    seg_jobs = [hashers.submit(
+                        _hashed, host[i * seg_size:(i + 1) * seg_size],
+                        sinks, span) for i in range(n_segs)]
+                    frag_jobs = [[hashers.submit(
+                        _hashed_copy, host[i * seg_size + j * n:
+                                           i * seg_size + (j + 1) * n],
+                        sinks, span)
+                        for j in range(k)] for i in range(n_segs)]
+                with trace.stage("gateway.encode.put", sink):
+                    # the upload's host -> device copy: the call, as
+                    # the host sees it (the bytes' own time is in the
+                    # device's line of a trace)
+                    segments_dev = jnp.asarray(segments)
+                with trace.stage("gateway.encode.step", sink):
+                    frags_dev = self.pipeline.encode_step(segments_dev,
+                                                          tenant=owner)
+                # the fragments hold the same bytes: 64 MiB of device
+                # memory that the tag step's program would peak over
+                del segments_dev
+            with trace.stage("gateway.fetch", sink, rows=n_segs * m,
                              bytes=n_segs * m * n):
                 # the device-resident fragments feed tag_step DIRECTLY
                 # (zero-copy engine handoff) and stay whole; only the
@@ -195,8 +260,8 @@ class OssGateway:
                     row.copy_to_host_async()
                 for at, row in enumerate(parity):
                     frag_jobs[at // m].append(hashers.submit(
-                        _hashed_copy, np.asarray(row)))
-            with trace.stage("gateway.hash"):
+                        _hashed_copy, np.asarray(row), sinks, span))
+            with trace.stage("gateway.hash", sink):
                 # ids feed the tag PRF, so the tags wait for every hash
                 # of the batch; a failed hash or fetch fails the upload
                 # here, before anything is stored or declared
@@ -206,19 +271,19 @@ class OssGateway:
                 ids = np.array([[podr2.fragment_id_from_hash(h)
                                  for _, h in seg] for seg in frags],
                                dtype=np.uint32)
-            with trace.stage("gateway.tag"):
+            with trace.stage("gateway.tag", sink):
                 tags_dev = self.pipeline.tag_step(frags_dev,
                                                   jnp.asarray(ids),
                                                   tenant=owner)
-            with trace.stage("gateway.fetch"):
+            with trace.stage("gateway.fetch", sink):
                 tags = np.asarray(tags_dev)
-            with trace.stage("gateway.store"):
+            with trace.stage("gateway.store", sink):
                 for i in range(n_segs):
                     for j in range(rows):
                         blob, h = frags[i][j]
                         self.fragment_store[h] = blob
                         self.tag_store[h] = tags[i, j]
-            with trace.stage("gateway.declare"):
+            with trace.stage("gateway.declare", sink):
                 seg_list = [(seg_hashes[i],
                              tuple(h for _, h in frags[i]))
                             for i in range(n_segs)]
@@ -235,14 +300,21 @@ class OssGateway:
                 _flight.note("custody", "dispatch", owner=owner,
                              file=file_hash, k=cfg.k, m=cfg.m,
                              segments=seg_list)
-            with self._mu:
-                c = self._counters
-                c["uploads"] += 1
-                c["rows_from_host"] += n_segs * k
-                c["rows_fetched"] += n_segs * m
-                c["bytes_fetched"] += n_segs * m * n + tags.nbytes
-                c["hash_jobs"] += n_segs * (rows + 1)
-            return file_hash
+        # every job of this upload has handed in its result, so no
+        # worker writes its sink of this upload any more
+        with self._mu:
+            c = self._counters
+            c["uploads"] += 1
+            c["rows_from_host"] += n_segs * k
+            c["rows_fetched"] += n_segs * m
+            c["bytes_fetched"] += n_segs * m * n + tags.nbytes
+            c["hash_jobs"] += n_segs * (rows + 1)
+            for part in (sink, *sinks.values()):
+                for name, (count, seconds) in part.items():
+                    acc = self._stages.setdefault(name, [0, 0.0])
+                    acc[0] += count
+                    acc[1] += seconds
+        return file_hash
 
 
 def filler_bytes(miner: str, index: int, size: int) -> bytes:

@@ -412,11 +412,13 @@ _ANNOTATION = None        # jax.profiler.TraceAnnotation, bound at first use
 
 
 class _Stage:
-    """The context object :func:`stage` returns; ``seconds`` holds the
-    stage's duration once the block has been left."""
+    """The context object :func:`stage` returns; ``t0`` holds the
+    ``time.perf_counter()`` read at entry and ``seconds`` the stage's
+    duration once the block has been left (``t0 + seconds``: the read
+    at exit)."""
 
-    __slots__ = ("name", "sink", "parent", "sys", "attrs", "seconds",
-                 "_ann", "_span", "_t0")
+    __slots__ = ("name", "sink", "parent", "sys", "attrs", "t0",
+                 "seconds", "_ann", "_span")
 
     def __init__(self, name, sink, parent, sys, attrs):
         self.name = name
@@ -424,7 +426,7 @@ class _Stage:
         self.parent = parent
         self.sys = sys
         self.attrs = attrs
-        self.seconds = 0.0
+        self.t0 = self.seconds = 0.0
         self._span = NOOP_SPAN
 
     def __enter__(self) -> "_Stage":
@@ -452,11 +454,11 @@ class _Stage:
             self._span = tracer.start(
                 self.name, sys=self.sys or self.name.partition(".")[0],
                 parent=parent, current=True, **self.attrs)
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self.seconds = dt = time.perf_counter() - self._t0
+        self.seconds = dt = time.perf_counter() - self.t0
         sink = self.sink
         if sink is not None:
             acc = sink.get(self.name)
@@ -475,7 +477,12 @@ def stage(name: str, sink: dict | None = None, *, parent=None,
           sys: str = "", **attrs) -> _Stage:
     """The ``with``-style hook for one STAGE of a unit of device work
     (an engine batch, a gateway upload, a streamed batch) — the only
-    way the program writes into a profiler trace. The block
+    way the program writes into a profiler trace. Its callers are the
+    threads the work passes through: the engine's batcher and a pool
+    lane's worker (a batch's stages), the thread that called the engine
+    (``engine.<cls>.submit`` / ``.result``), the gateway's upload thread
+    and its hash workers (``gateway.worker.*``, a job each), the stream
+    driver, the TEE's round and the PoDR2 challenge. The block
 
     - runs under ``jax.profiler.TraceAnnotation("cess:" + name)``,
       ALWAYS: whenever a profiler session is live the stage is in its
@@ -484,16 +491,21 @@ def stage(name: str, sink: dict | None = None, *, parent=None,
     - is timed with ``time.perf_counter()`` at both ends: the seconds
       land on the returned object (``.seconds``) and, when a ``sink``
       dict is given, ``sink[name]`` accumulates ``[count, seconds]``
-      (unlocked: give each writer thread its own sink and merge);
+      (unlocked, on purpose: EVERY WRITER THREAD HAS ITS OWN SINK, and
+      whoever owns the totals merges the sinks under its own lock once
+      their writers are done with them);
     - when tracing is on, is a child :class:`Span` too, so
       ``cess_traceDump`` shows the same stages per request. ``parent``
       is the explicit parent Span (its tracer serves; passing
       :data:`NOOP_SPAN` means "no span": the caller's own span
       machinery is off, or already covers this extent); without one
       the span is a child of the context's current span, on that
-      span's tracer or the armed one.
+      span's tracer or the armed one. Contexts do not cross threads:
+      a stage on another thread than its unit of work (a gateway
+      worker's job) is handed the unit's span as ``parent``.
 
-    One stage per unit of work, never per row or per fragment."""
+    One stage per unit of work (a batch, a request's submit, a worker's
+    job), never per row of a batch or per fragment of a job."""
     return _Stage(name, sink, parent, sys, attrs)
 
 

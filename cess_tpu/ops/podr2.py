@@ -51,12 +51,14 @@ the byte/block axis shards across the mesh with psum aggregation
 from __future__ import annotations
 
 import dataclasses
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import constants
+from ..obs import trace
 from . import pfield as pf
 
 SECTORS = 256                       # field elements per block
@@ -313,13 +315,66 @@ def tag_dispatch(tag_ops: tuple, fragment_ids, fragments) -> jax.Array:
                        weights, prf_impl=prf_impl)
 
 
+# the round's host-side derivations, counted for the process: stage
+# name -> [calls, seconds] (stage_counters). Their callers are agents'
+# threads, so the account has its own lock and the stage no sink.
+_STAGE_MU = threading.Lock()
+_STAGES = {"podr2.challenge": [0, 0.0], "podr2.coeffs": [0, 0.0]}
+
+
+def _staged(name: str, derive, *args):
+    """``derive(*args)`` as one stage of a round, one a call
+    (obs.trace.stage: ``cess:<name>`` in a profiler trace, a child of
+    the caller's span, ``[calls, seconds]`` in ``stage_counters()``).
+    Only the EAGER entry is a stage: reached while JAX traces a caller
+    (``jit``, ``vmap``: ``trace_ctx`` is not at its top level) the call
+    is a piece of that program, made once a trace and timing nothing
+    of a round, and runs bare."""
+    if not jax.core.trace_ctx.is_top_level():
+        return derive(*args)
+    with trace.stage(name) as stage:
+        out = derive(*args)
+    with _STAGE_MU:
+        acc = _STAGES[name]
+        acc[0] += 1
+        acc[1] += stage.seconds
+    return out
+
+
+def stage_counters() -> dict:
+    """``{"podr2.challenge": {"n", "s"}, "podr2.coeffs": {"n", "s"}}``:
+    the eager calls of ``gen_challenge`` / ``aggregate_coeffs`` in this
+    process and the host seconds they took (the calls as the host sees
+    them: both issue their operations one by one and return device
+    arrays that may still be in flight)."""
+    with _STAGE_MU:
+        return {name: {"n": n, "s": s} for name, (n, s) in _STAGES.items()}
+
+
+def stage_metrics() -> dict[str, float]:
+    """``stage_counters()`` as ``cess_podr2_challenge_seconds`` /
+    ``_count`` and ``cess_podr2_coeffs_...`` (node/metrics.py)."""
+    out = {}
+    for name, acc in stage_counters().items():
+        short = name.partition(".")[2]
+        out[f"cess_podr2_{short}_seconds"] = acc["s"]
+        out[f"cess_podr2_{short}_count"] = float(acc["n"])
+    return out
+
+
 def gen_challenge(seed_bytes: bytes | int, num_blocks: int,
                   count: int | None = None):
     """Derive (indices [c], nu [c]) from round randomness.
 
     Coverage mirrors audit's 46/1000 of chunks (SURVEY.md §3.3); the
     reference draws 20-byte randoms per index, here nu in F_p.
+    An eager call is the stage ``podr2.challenge`` (``_staged``).
     """
+    return _staged("podr2.challenge", _gen_challenge, seed_bytes,
+                   num_blocks, count)
+
+
+def _gen_challenge(seed_bytes, num_blocks: int, count: int | None):
     if count is None:
         count = max(1, num_blocks * constants.CHALLENGE_RATE_NUM
                     // constants.CHALLENGE_RATE_DEN)
@@ -420,7 +475,13 @@ def aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
     (PROOF_BYTES raw payload + constant codec framing; see the
     authoritative statement at PROOF_BYTES, framed total computed by
     node/offchain.py proof_wire_bytes).
+    An eager call is the stage ``podr2.coeffs`` (``_staged``).
     """
+    return _staged("podr2.coeffs", _aggregate_coeffs, seed_bytes,
+                   fragment_ids)
+
+
+def _aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
     return _coeffs(_aggregate_key(aggregate_words(seed_bytes)),
                    jnp.asarray(fragment_ids).reshape(-1, 2))
 

@@ -17,8 +17,10 @@ the serving layer between all of them and the ``ErasureCodec`` /
   bucket (buckets.py: compile-once program cache), launches it, and
   slices results back per request;
 - everything observable lands in stats.py (queue depth, batch
-  occupancy, pad waste, per-class latency percentiles), exported via
-  node/metrics.py and the ``cess_engineStats`` RPC.
+  occupancy, pad waste, per-class latency percentiles, the six stages
+  of a batch and, per request, the caller's side: its ``submit`` and
+  the hand-back of its result), exported via node/metrics.py and the
+  ``cess_engineStats`` RPC.
 
 Zero-copy handoff: submits accept ``jax.Array`` payloads and keep
 them ON DEVICE — coalescing concatenates resident inputs with
@@ -128,14 +130,28 @@ from .stats import EngineStats
 
 class EngineFuture:
     """Result handle for a submitted request (threading-based: the
-    engine serves plain synchronous agents, not an event loop)."""
+    engine serves plain synchronous agents, not an event loop).
 
-    __slots__ = ("_event", "_value", "_exc")
+    A future of an engine also times the caller's side of the
+    hand-back: ``result()`` runs under the stage
+    ``engine.<cls>.result`` (the caller's whole blocked extent, on the
+    caller's thread: a ``cess:`` event in a profiler trace, a child of
+    the caller's span when it has one), and counts once, under the
+    class's ``caller.handoff``, how much of it came after ``_resolve``
+    / ``_reject`` stamped the result (stats.py CALLER). A bare
+    ``EngineFuture()`` counts nothing."""
 
-    def __init__(self):
+    __slots__ = ("_event", "_value", "_exc", "_engine", "_cls",
+                 "_resolved_t")
+
+    def __init__(self, engine=None, cls: str = ""):
         self._event = threading.Event()
         self._value: Any = None
         self._exc: BaseException | None = None
+        # who counts the hand-back; None once it is counted
+        self._engine = engine
+        self._cls = cls
+        self._resolved_t: float | None = None   # time.perf_counter()
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -144,6 +160,25 @@ class EngineFuture:
         """Block until resolved. Raises the request's failure
         (EngineTimeout on deadline cancellation, the op's error on a
         batch failure) or EngineTimeout if ``timeout`` elapses first."""
+        engine = self._engine
+        if engine is None:
+            return self._wait(timeout)
+        try:
+            with trace.stage(f"engine.{self._cls}.result",
+                             parent=trace.current_span()) as stage:
+                return self._wait(timeout)
+        finally:
+            done_t = self._resolved_t
+            if done_t is not None:
+                # resolved or rejected: the one hand-back this future
+                # has. A caller that came after it waited for nothing.
+                self._engine = None
+                engine._count_caller(
+                    self._cls, "handoff",
+                    0.0 if done_t <= stage.t0
+                    else max(stage.t0 + stage.seconds - done_t, 0.0))
+
+    def _wait(self, timeout: float | None):
         if not self._event.wait(timeout):
             raise EngineTimeout(f"no result within {timeout}s")
         if self._exc is not None:
@@ -153,10 +188,12 @@ class EngineFuture:
     # engine-internal
     def _resolve(self, value) -> None:
         self._value = value
+        self._resolved_t = time.perf_counter()
         self._event.set()
 
     def _reject(self, exc: BaseException) -> None:
         self._exc = exc
+        self._resolved_t = time.perf_counter()
         self._event.set()
 
 
@@ -170,6 +207,10 @@ class _Request:
     enqueue_t: float
     deadline: float | None
     future: EngineFuture
+    # the instant the drain trigger of its batch tripped (_tripped),
+    # stamped at the drain: where the queue stage's ``coalesce`` half
+    # ends and its ``wake`` half begins (stats.py QUEUE_PARTS)
+    trip_t: float = 0.0
     squeeze: bool = False    # 2-D submit: drop the batch axis on return
     device: bool = False     # jax.Array payload: result stays on device
     # request-scoped trace span (cess_tpu/obs): covers queue-wait ->
@@ -309,6 +350,34 @@ def _linear_rows(out):
                  for j in range(out.shape[1]))
 
 
+def _caller_submit(cls: str):
+    """Decorator of a public ``submit_*`` method of class ``cls``: the
+    call runs under the stage ``engine.<cls>.submit`` on the caller's
+    thread (entry -> the request is queued and the method returns), and
+    a request that was queued counts it under the class's
+    ``caller.submit`` (stats.py CALLER). The stage is a child of the
+    caller's current span and makes no span where there is none: a root
+    of its own would read as a trace of its own. The request's span
+    stays a child of the caller's span, not of this stage
+    (``_calling``)."""
+    name = f"engine.{cls}.submit"
+
+    def wrap(method):
+        @functools.wraps(method)
+        def submit(self, *args, **kwargs):
+            outer = trace.current_span()
+            self._calling.span = None if outer is trace.NOOP_SPAN else outer
+            try:
+                with trace.stage(name, parent=outer) as stage:
+                    fut = method(self, *args, **kwargs)
+            finally:
+                self._calling.span = None     # keep no span alive
+            self._count_caller(cls, "submit", stage.seconds)
+            return fut
+        return submit
+    return wrap
+
+
 class SubmissionEngine:
     """See module docstring. Construct via :func:`make_engine` or pass
     an ``ErasureCodec`` (ops/rs.py gate) and optionally an
@@ -412,10 +481,18 @@ class SubmissionEngine:
         # lane's worker): its stage sink and its batch span, for the
         # op runners' stage hooks (_stage)
         self._running = threading.local()
+        # the span that was current on THIS thread when it entered a
+        # public submit_* (_caller_submit): the request span's parent,
+        # which inside the submit stage's span is no longer the current
+        # one (None: the stage made no span, the current one serves)
+        self._calling = threading.local()
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._closed = False
         self._flushing = 0       # active flush() calls force draining
+        # when the flush or close that forces draining now began: a
+        # drain trigger's instant like a deadline's (_tripped)
+        self._forced_t = 0.0
         self._inflight = 0
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="cess-submission-engine")
@@ -427,6 +504,7 @@ class SubmissionEngine:
     # ------------------------------------------------------------------
 
     # -- encode (ErasureCodec) ----------------------------------------
+    @_caller_submit("encode")
     def submit_encode(self, data, timeout: float | None = None,
                       tenant: str | None = None) -> EngineFuture:
         """data [B, k, n] (or [k, n]) uint8 -> future of [B, k+m, n]."""
@@ -443,6 +521,7 @@ class SubmissionEngine:
                               timeout=timeout, tenant=tenant)
 
     # -- decode / repair (ErasureCodec) --------------------------------
+    @_caller_submit("repair")
     def submit_reconstruct(self, survivors, present, missing=None,
                            timeout: float | None = None,
                            tenant: str | None = None) -> EngineFuture:
@@ -471,6 +550,7 @@ class SubmissionEngine:
                               survivors, present, missing,
                               timeout=timeout, tenant=tenant)
 
+    @_caller_submit("repair")
     def submit_decode_data(self, survivors, present,
                            timeout: float | None = None,
                            tenant: str | None = None) -> EngineFuture:
@@ -492,6 +572,7 @@ class SubmissionEngine:
                               survivors, present, timeout=timeout,
                               tenant=tenant)
 
+    @_caller_submit("repair")
     def submit_repair_symbol(self, pairs, coeff: int,
                              timeout: float | None = None,
                              tenant: str | None = None) -> EngineFuture:
@@ -521,6 +602,7 @@ class SubmissionEngine:
                               tenant=tenant)
 
     # -- tag (AuditBackend, TEE role) ----------------------------------
+    @_caller_submit("tag")
     def submit_tag(self, fragment_ids, fragments,
                    timeout: float | None = None,
                    tenant: str | None = None) -> EngineFuture:
@@ -544,6 +626,7 @@ class SubmissionEngine:
                               fragments, timeout=timeout, tenant=tenant)
 
     # -- prove (miner role) --------------------------------------------
+    @_caller_submit("prove")
     def submit_prove_aggregate(self, fragments, tags, idx, nu, r,
                                sectors: int | None = None,
                                timeout: float | None = None,
@@ -592,6 +675,7 @@ class SubmissionEngine:
                               timeout=timeout, tenant=tenant)
 
     # -- verify (TEE role) ---------------------------------------------
+    @_caller_submit("verify")
     def submit_verify_batch(self, fragment_ids, num_blocks, idx, nu,
                             mu, sigma,
                             timeout: float | None = None,
@@ -624,6 +708,7 @@ class SubmissionEngine:
                               fragment_ids, num_blocks, idx, nu, mu,
                               sigma, timeout=timeout, tenant=tenant)
 
+    @_caller_submit("verify")
     def submit_verify_aggregate(self, fragment_ids, num_blocks, idx, nu,
                                 r, mu, sigma,
                                 timeout: float | None = None,
@@ -663,6 +748,7 @@ class SubmissionEngine:
             num_blocks, idx, nu, r, mu, sigma, timeout=timeout,
             tenant=tenant))
 
+    @_caller_submit("verify")
     def submit_verify_round(self, fragment_ids, sizes, num_blocks, idx,
                             nu, agg_words, mu, sigma,
                             timeout: float | None = None,
@@ -883,6 +969,7 @@ class SubmissionEngine:
         timeout elapses first; queued work keeps draining regardless."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._cond:
+            self._force_drain_locked()
             self._flushing += 1
             self._cond.notify_all()
             try:
@@ -906,6 +993,7 @@ class SubmissionEngine:
         the no-silent-drops contract extends to shutdown. A batch
         already in flight still resolves if the process lives on."""
         with self._cond:
+            self._force_drain_locked()
             self._closed = True
             self._cond.notify_all()
         self._thread.join(timeout)
@@ -926,6 +1014,19 @@ class SubmissionEngine:
     # ------------------------------------------------------------------
     # internals
     # ------------------------------------------------------------------
+    def _force_drain_locked(self) -> None:
+        """A flush or a close begins: unless one is forcing the drain
+        already, this is the instant from which queued requests wait on
+        the batcher and no longer on policy."""
+        if not (self._closed or self._flushing):
+            self._forced_t = time.monotonic()
+
+    def _count_caller(self, cls: str, account: str, seconds: float) -> None:
+        """One request's ``submit`` or ``handoff`` seconds (stats.py
+        CALLER), from the caller's thread."""
+        with self._lock:
+            self.stats.classes[cls].add_caller(account, seconds)
+
     def _need_codec(self) -> None:
         if self.codec is None:
             raise ValueError("engine has no ErasureCodec configured")
@@ -1021,14 +1122,16 @@ class SubmissionEngine:
                 if tracer is not None:
                     with tracer.start(f"engine.{cls}", sys="engine",
                                       cls=cls, rows=rows, op=key[0],
-                                      outcome="shed",
-                                      reason=reason) as sp:
+                                      outcome="shed", reason=reason,
+                                      parent=getattr(
+                                          self._calling, "span",
+                                          None)) as sp:
                         if tenant is not None:
                             sp.set(tenant=tenant)
                 _flight.note("engine", "shed", cls=cls, reason=reason,
                              tenant=tenant)
                 raise EngineShed(f"{cls} request shed: {reason}")
-        fut = EngineFuture()
+        fut = EngineFuture(self, cls)
         device = any(isinstance(a, jax.Array) for a in arrays.values())
         req = _Request(cls=cls, key=key, rows=rows, arrays=arrays,
                        aux=aux, enqueue_t=now,
@@ -1042,7 +1145,8 @@ class SubmissionEngine:
             # can own it — every exit path below closes it explicitly
             req.span = tracer.start(  # cesslint: disable=span-balance — finished at resolve/reject/expire/close (cross-thread span)
                 f"engine.{cls}", sys="engine", cls=cls, rows=rows,
-                op=key[0])
+                op=key[0],
+                parent=getattr(self._calling, "span", None))
             if tenant is not None:
                 req.span.set(tenant=tenant)
         saturated = False
@@ -1079,9 +1183,9 @@ class SubmissionEngine:
                     self._expire(now, breaches)
                     if breaches:
                         break
-                    cls = self._ready_class(now)
-                    if cls is not None:
-                        batch = self._drain(cls)
+                    ready = self._ready_class(now)
+                    if ready is not None:
+                        batch = self._drain(*ready)
                         self._inflight += 1
                         break
                     if self._closed:
@@ -1180,8 +1284,9 @@ class SubmissionEngine:
             q.clear()
             q.extend(keep)
 
-    def _ready_class(self, now: float) -> str | None:
-        """Class to drain now, or None to keep waiting.
+    def _ready_class(self, now: float) -> tuple[str, float] | None:
+        """(class to drain now, the instant its trigger tripped), or
+        None to keep waiting.
 
         A drain happens when ANY class trips a trigger — size
         (requests or rows), deadline (oldest waited its class's
@@ -1199,13 +1304,37 @@ class SubmissionEngine:
                 continue
             if first_nonempty is None:
                 first_nonempty = cls
-            max_delay, max_reqs, max_rows = self._knobs(cls)
-            if (self._closed or self._flushing
-                    or len(q) >= max_reqs
-                    or q[0].enqueue_t + max_delay <= now
-                    or sum(r.rows for r in q) >= max_rows):
-                return first_nonempty
+            trip = self._tripped(q, now, *self._knobs(cls))
+            if trip is not None:
+                return first_nonempty, trip
         return None
+
+    def _tripped(self, q, now: float, max_delay: float, max_reqs: int,
+                 max_rows: int) -> float | None:
+        """The instant a non-empty queue's drain trigger tripped, None
+        while none has (lock held): the earliest of the start of the
+        flush or close that forces the drain, the enqueue of the
+        request that filled the request budget or the row budget, and
+        the oldest request's enqueue + ``max_delay``. Up to it a member
+        waits because policy says so, from it on for the batcher
+        (``_open_stages``: the queue stage's two halves)."""
+        trip = None
+        if self._closed or self._flushing:
+            trip = self._forced_t
+        if len(q) >= max_reqs:
+            t = q[max_reqs - 1].enqueue_t
+            trip = t if trip is None else min(trip, t)
+        t = q[0].enqueue_t + max_delay
+        if t <= now:
+            trip = t if trip is None else min(trip, t)
+        rows = 0
+        for r in q:
+            rows += r.rows
+            if rows >= max_rows:
+                trip = r.enqueue_t if trip is None \
+                    else min(trip, r.enqueue_t)
+                break
+        return trip
 
     def _wake_timeout(self, now: float) -> float | None:
         wake = None
@@ -1267,13 +1396,16 @@ class SubmissionEngine:
             return OVERFLOW
         return t
 
-    def _drain(self, cls: str) -> list[_Request]:
+    def _drain(self, cls: str, trip: float = 0.0) -> list[_Request]:
         """Pop one coalescible batch (lock held): take queued requests
         sharing the ANCHOR request's key up to the size budgets;
         others stay queued in order. The anchor is the oldest request
         (or the fair-queued tenant's oldest — _anchor_index). Expired
         requests are already gone (_expire runs under the same lock
-        hold)."""
+        hold). Every member is stamped with ``trip``, the instant the
+        drain's trigger tripped (here and not in ``_run``: a name bound
+        to a request in the batcher's own frame would keep that
+        request's payload alive until the next drain)."""
         q = self._queues[cls]
         if not q:
             return []
@@ -1300,6 +1432,8 @@ class SubmissionEngine:
                 rest.append(r)
         q.clear()
         q.extend(rest)
+        for r in batch:
+            r.trip_t = trip
         return batch
 
     # -- stage clock (obs.trace.stage; stats.STAGES) ----------------------
@@ -1307,11 +1441,21 @@ class SubmissionEngine:
                      span=trace.NOOP_SPAN) -> dict:
         """Start the stage sink of a batch this thread is about to
         run: the queue stage ends here (each member's enqueue -> now,
-        summed: a counter, no span), and the op runners' stages
-        (_stage) land in the returned sink, under ``span``."""
+        summed: a counter, no span), split at the instant the drain
+        trigger tripped into ``coalesce`` and ``wake`` (stats.py
+        QUEUE_PARTS; a member enqueued after the trip waited on no
+        policy), and the op runners' stages (_stage) land in the
+        returned sink, under ``span``."""
         now = time.monotonic()
-        sink = {f"engine.{batch[0].cls}.queue":
-                [1, sum(now - r.enqueue_t for r in batch)]}
+        coalesce = wake = 0.0
+        for r in batch:
+            trip = min(max(r.trip_t, r.enqueue_t), now)
+            coalesce += trip - r.enqueue_t
+            wake += now - trip
+        name = f"engine.{batch[0].cls}.queue"
+        sink = {name: [1, coalesce + wake],
+                name + ".coalesce": [1, coalesce],
+                name + ".wake": [1, wake]}
         self._running.batch = (sink, span)
         return sink
 

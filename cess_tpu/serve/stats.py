@@ -36,9 +36,44 @@ LATENCY_WINDOW = 512     # per-class sliding window for percentiles
 #             rows, their np.asarray and the host reassembly; prove:
 #             the release of the stacked host batch too)
 #   resolve   accounting + resolving the members' futures
-# For a class whose batches hold one request, the stage seconds sum to
-# its submit -> resolve latency, within the clock reads between them.
+# These six are the batcher's side of a request and stay six (the
+# readers of ``stages`` sum whatever it holds). The caller's side is
+# counted per request under keys of its own, CALLER below, and the
+# queue's seconds are split under QUEUE_PARTS. For a class whose batches
+# hold one request, ``caller.submit`` + the six stages' seconds +
+# ``caller.handoff`` sum to the blocking call's own extent (entry of
+# ``engine.reconstruct`` / ``prove_aggregate`` / ... -> its return),
+# within the clock reads between them: ``queue`` starts at the stamp
+# ``_submit`` takes, a few microseconds before ``submit`` ends, and the
+# hand-back starts inside ``resolve``.
 STAGES = ("queue", "assemble", "dispatch", "wait", "fetch", "resolve")
+
+# The caller's side of one request, on the thread that called the engine
+# (serve/engine.py, through the same hook):
+#   submit    entry of the public ``submit_*`` method -> the request is
+#             in its queue and the method returns: normalising the
+#             arguments (np.asarray, _norm_survivors, _check_round,
+#             _round_digest), admission, the request's span, the
+#             enqueue. Counted for requests that were queued.
+#   handoff   how long the caller stayed blocked in ``result()`` after
+#             its result existed: return of ``result()`` less the
+#             later of its entry and the stamp ``_resolve`` /
+#             ``_reject`` took. The wake-up of the caller's thread and
+#             its wait for the GIL; 0 for a caller that came late.
+#             Counted once a future.
+CALLER = ("submit", "handoff")
+
+# The two halves of ``queue``, summed over members like it:
+#   coalesce  each member's enqueue -> the instant the drain trigger
+#             tripped (the oldest member's enqueue + max_delay, the
+#             enqueue of the request that filled a size budget, the
+#             start of a flush or close): the wait policy asks for
+#   wake      from there until the batch starts to run: the batcher
+#             asleep, busy with another batch or waiting for the GIL
+#             (lane wait on the pool path)
+# ``coalesce + wake == queue``, exactly: the queue stage's total is
+# kept as the sum of the two accumulators (ClassStats.add_stages).
+QUEUE_PARTS = ("coalesce", "wake")
 
 
 class ClassStats:
@@ -47,7 +82,8 @@ class ClassStats:
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
                  "linear_puts", "patterns_new", "matrix_build_s",
                  "missions", "device_calls", "prf_evals",
-                 "latencies", "hist", "stage_n", "stage_s")
+                 "latencies", "hist", "stage_n", "stage_s",
+                 "caller_n", "caller_s", "queue_s")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -104,14 +140,31 @@ class ClassStats:
         # merged from each batch's own sink when the batch is done
         self.stage_n = dict.fromkeys(STAGES, 0)
         self.stage_s = dict.fromkeys(STAGES, 0.0)
+        # the caller's side, per request (CALLER), and the queue
+        # seconds' two halves, per batch (QUEUE_PARTS)
+        self.caller_n = dict.fromkeys(CALLER, 0)
+        self.caller_s = dict.fromkeys(CALLER, 0.0)
+        self.queue_s = dict.fromkeys(QUEUE_PARTS, 0.0)
 
     def add_stages(self, sink: dict) -> None:
         """Merge one batch's stage sink (``{"engine.<cls>.<stage>":
-        [count, seconds]}``, obs.trace.stage's shape)."""
+        [count, seconds]}``, obs.trace.stage's shape; the queue's halves
+        are ``engine.<cls>.queue.coalesce`` / ``.wake``)."""
         for name, (n, seconds) in sink.items():
-            stage = name.rpartition(".")[2]
-            self.stage_n[stage] += n
-            self.stage_s[stage] += seconds
+            stage = name.split(".", 2)[2]
+            if stage in self.stage_n:
+                self.stage_n[stage] += n
+                self.stage_s[stage] += seconds
+            else:
+                self.queue_s[stage.rpartition(".")[2]] += seconds
+        # float additions do not associate: the total is the halves'
+        self.stage_s["queue"] = self.queue_s["coalesce"] \
+            + self.queue_s["wake"]
+
+    def add_caller(self, account: str, seconds: float) -> None:
+        """Count one request's ``submit`` or ``handoff`` (CALLER)."""
+        self.caller_n[account] += 1
+        self.caller_s[account] += seconds
 
     # -- derived -----------------------------------------------------------
     @property
@@ -269,6 +322,12 @@ class EngineStats:
                 "stages": {stage: {"n": st.stage_n[stage],
                                    "s": st.stage_s[stage]}
                            for stage in STAGES},
+                "caller": {acct: {"n": st.caller_n[acct],
+                                  "s": st.caller_s[acct]}
+                           for acct in CALLER},
+                "queue": {part: {"n": st.stage_n["queue"],
+                                 "s": st.queue_s[part]}
+                          for part in QUEUE_PARTS},
             }
         if self.streams:
             out["streams"] = [s.snapshot() for s in self.streams]
@@ -294,6 +353,11 @@ class EngineStats:
             for stage, acc in st.pop("stages").items():
                 out[f"cess_engine_{cls}_stage_{stage}_seconds"] = acc["s"]
                 out[f"cess_engine_{cls}_stage_{stage}_count"] = acc["n"]
+            for acct, acc in st.pop("caller").items():
+                out[f"cess_engine_{cls}_caller_{acct}_seconds"] = acc["s"]
+                out[f"cess_engine_{cls}_caller_{acct}_count"] = acc["n"]
+            for part, acc in st.pop("queue").items():
+                out[f"cess_engine_{cls}_queue_{part}_seconds"] = acc["s"]
             for name, val in st.items():
                 out[f"cess_engine_{cls}_{name}"] = val
         if self.streams:

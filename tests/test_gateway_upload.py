@@ -148,10 +148,24 @@ def test_upload_equals_the_serial_reference(pipes, pkey, gateway, k, m,
     _same_stores(gw, frag_store, tag_store)
     # the mechanism engaged: parity and tags came down, no data row did
     segs = len(declaration[3])
-    assert gw.counters() == {
+    counters = gw.counters()
+    stage_count = counters.pop("stage_count")
+    stage_seconds = counters.pop("stage_seconds")
+    assert counters == {
         "uploads": 1, "rows_from_host": k * segs, "rows_fetched": m * segs,
         "bytes_fetched": m * segs * FRAG + tag_bytes,
         "hash_jobs": segs * (k + m + 1)}
+    # every stage of the upload is timed: once an upload on its thread
+    # (gateway.fetch twice), once a job on the workers'
+    assert stage_count == {
+        "offchain.upload": 1, "gateway.encode": 1, "gateway.encode.jobs": 1,
+        "gateway.encode.put": 1, "gateway.encode.step": 1,
+        "gateway.fetch": 2, "gateway.hash": 1, "gateway.tag": 1,
+        "gateway.store": 1, "gateway.declare": 1,
+        "gateway.worker.copy": segs * (k + m),
+        "gateway.worker.hash": segs * (k + m + 1)}
+    assert set(stage_seconds) == set(stage_count)
+    assert all(s >= 0.0 for s in stage_seconds.values())
 
 
 @pytest.mark.parametrize("k,m", GEOMETRIES)
@@ -265,11 +279,27 @@ def test_the_stages_stay_on_the_uploads_thread(pipes, gateway):
         gw.upload("alice", "photos", "f.bin", _file(k, 3.0))
     spans = tracer.finished()
     (upload,) = [s for s in spans if s["name"] == "offchain.upload"]
-    stages = [s for s in spans if s["name"].startswith("gateway.")]
+    workers = [s for s in spans if s["name"].startswith("gateway.worker.")]
+    stages = [s for s in spans if s["name"].startswith("gateway.")
+              and s["parent_id"] == upload["span_id"] and s not in workers]
     assert [s["name"] for s in stages] == [
         "gateway.encode", "gateway.fetch", "gateway.hash", "gateway.tag",
         "gateway.fetch", "gateway.store", "gateway.declare"]
-    assert {s["parent_id"] for s in stages} == {upload["span_id"]}
+    assert {s["tid"] for s in stages} == {upload["tid"]}
+    # gateway.encode is made of three, in order, on the same thread
+    (encode,) = [s for s in stages if s["name"] == "gateway.encode"]
+    inner = [s for s in spans if s["parent_id"] == encode["span_id"]
+             and s["name"].startswith("gateway.")]
+    assert [s["name"] for s in inner] == [
+        "gateway.encode.jobs", "gateway.encode.put", "gateway.encode.step"]
+    assert {s["tid"] for s in inner} == {upload["tid"]}
+    # the workers' jobs are children of the upload, none on its thread:
+    # a copy and a hash a fragment, a hash a segment
+    assert {s["parent_id"] for s in workers} == {upload["span_id"]}
+    assert upload["tid"] not in {s["tid"] for s in workers}
+    names = [s["name"] for s in workers]
+    assert names.count("gateway.worker.copy") == 3 * (k + m)
+    assert names.count("gateway.worker.hash") == 3 * (k + m + 1)
     parity, tags = [s for s in stages if s["name"] == "gateway.fetch"]
     assert parity["attrs"] == {"rows": 3 * m, "bytes": 3 * m * FRAG}
     assert tags["attrs"] == {}
@@ -294,6 +324,23 @@ def test_counters_ride_the_nodes_exposition(pipes, gateway):
     assert series["cess_gateway_hash_jobs_total"] == 3.0 * (k + m + 1)
     assert series["cess_gateway_bytes_fetched_total"] \
         == gw.counters()["bytes_fetched"]
+    # every stage's seconds and count, the workers' once a job; the
+    # process's challenge derivations ride along
+    seconds = gw.counters()["stage_seconds"]
+    assert series["cess_gateway_stage_upload_count"] == 1.0
+    assert series["cess_gateway_stage_upload_seconds"] \
+        == seconds["offchain.upload"]
+    assert series["cess_gateway_stage_encode_put_seconds"] \
+        == seconds["gateway.encode.put"]
+    assert series["cess_gateway_stage_fetch_count"] == 2.0
+    assert series["cess_gateway_stage_worker_copy_count"] == 3.0 * (k + m)
+    assert series["cess_gateway_stage_worker_hash_count"] \
+        == 3.0 * (k + m + 1)
+    assert series["cess_gateway_stage_worker_copy_seconds"] \
+        == seconds["gateway.worker.copy"]
+    assert {"cess_podr2_challenge_seconds", "cess_podr2_challenge_count",
+            "cess_podr2_coeffs_seconds", "cess_podr2_coeffs_count"} \
+        <= set(series)
     assert "# TYPE cess_gateway_rows_fetched_total counter" \
         in render_metrics(node)
 

@@ -4,12 +4,20 @@ time their stages through ONE hook, ``obs.trace.stage``, which counts them
 as ``cess:<name>`` events — no tracer, flag or argument needed — and makes
 them spans of an armed tracer.
 
+Since ISSUE 35 the same hook reaches the caller's side of a request
+(``engine.<cls>.submit`` / ``.result`` on the caller's thread, counted per
+request under ``caller``; the queue's seconds split under ``queue``), the
+gateway's hash workers (``gateway.worker.copy`` / ``.hash``, a job each)
+and the PoDR2 challenge (``podr2.challenge`` / ``podr2.coeffs``).
+
 No timing thresholds here: counts, names, nesting and the accounting
-identity (a batch's stages are pieces of its members' submit -> resolve
-latency).
+identities (a batch's stages are pieces of its members' submit -> resolve
+latency; ``coalesce + wake == queue``; submit + stages + hand-back is the
+blocking call).
 """
 import glob
 import os
+import time
 
 import numpy as np
 import pytest
@@ -22,8 +30,8 @@ from cess_tpu.node.offchain import OssGateway
 from cess_tpu.obs import trace
 from cess_tpu.ops import podr2
 from cess_tpu.serve import AdmissionPolicy, make_engine
-from cess_tpu.serve.policy import CLASSES
-from cess_tpu.serve.stats import STAGES
+from cess_tpu.serve.policy import CLASSES, EngineTimeout
+from cess_tpu.serve.stats import CALLER, QUEUE_PARTS, STAGES
 from cess_tpu.serve.stream import StreamingIngest
 
 K, M = 2, 1
@@ -119,6 +127,246 @@ def test_stage_metrics_are_flat_gauges(pkey, cls):
     # the flattening loop was never handed the nested dict
     assert all(isinstance(v, (int, float)) for v in metrics.values())
     assert f"cess_engine_{cls}_stages" not in metrics
+
+
+@pytest.mark.parametrize("cls", sorted(CLASSES))
+def test_caller_and_queue_accounts_sit_beside_the_six_stages(pkey, cls):
+    """The caller's side is counted per request and the queue's halves per
+    batch, under keys of their own: ``stages`` keeps its six names (its
+    readers sum whatever it holds)."""
+    eng = _engine(pkey)
+    try:
+        _drive(eng, pkey, cls)
+        eng.flush()
+        snap = eng.stats_snapshot()["classes"][cls]
+        metrics = eng.stats_metrics()
+    finally:
+        eng.close()
+    assert tuple(snap["stages"]) == DOCUMENTED
+    assert (CALLER, QUEUE_PARTS) == (("submit", "handoff"),
+                                     ("coalesce", "wake"))
+    assert tuple(snap["caller"]) == CALLER
+    assert tuple(snap["queue"]) == QUEUE_PARTS
+    # one submit and one hand-back a request, the halves once a batch
+    assert snap["submitted"] == snap["completed"] >= 2
+    for acct in CALLER:
+        assert snap["caller"][acct]["n"] == snap["completed"], acct
+        assert snap["caller"][acct]["s"] >= 0.0, acct
+    for part in QUEUE_PARTS:
+        assert snap["queue"][part]["n"] == snap["batches"], part
+        assert snap["queue"][part]["s"] >= 0.0, part
+    # exactly: the queue's total is kept as the sum of its halves
+    assert snap["queue"]["coalesce"]["s"] + snap["queue"]["wake"]["s"] \
+        == snap["stages"]["queue"]["s"]
+    # a lone client's request waits out max_delay and no more on policy
+    assert snap["queue"]["coalesce"]["s"] \
+        == pytest.approx(0.002 * snap["batches"], rel=1e-6)
+    # flat gauges beside the stages', and no nested dict among them
+    for acct in CALLER:
+        assert metrics[f"cess_engine_{cls}_caller_{acct}_count"] \
+            == snap["caller"][acct]["n"]
+        assert metrics[f"cess_engine_{cls}_caller_{acct}_seconds"] \
+            == snap["caller"][acct]["s"]
+    for part in QUEUE_PARTS:
+        assert metrics[f"cess_engine_{cls}_queue_{part}_seconds"] \
+            == snap["queue"][part]["s"]
+    assert all(isinstance(v, (int, float)) for v in metrics.values())
+
+
+def _lone(eng):
+    eng.encode(rnd((1, K, FRAG), 1))
+    return 1
+
+
+def _coalesced(eng):
+    futs = [eng.submit_encode(rnd((1, K, FRAG), s)) for s in range(4)]
+    for f in futs:
+        f.result(30)
+    return 4
+
+
+def _flushed(eng):
+    futs = [eng.submit_encode(rnd((1, K, FRAG), s)) for s in range(2)]
+    assert eng.flush(60)            # long before max_delay would trip
+    for f in futs:
+        f.result(30)
+    return 2
+
+
+@pytest.mark.parametrize("drive,max_delay", [
+    (_lone, 0.002), (_coalesced, 0.25), (_flushed, 600.0)],
+    ids=["lone", "coalesced", "flushed"])
+def test_queue_is_its_two_halves(drive, max_delay):
+    """``coalesce`` (enqueue -> the drain trigger trips) + ``wake`` (-> the
+    batch starts) == ``queue``, exactly, whatever tripped the trigger: the
+    oldest member's max_delay, or a flush."""
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=max_delay))
+    try:
+        n = drive(eng)
+        eng.flush()
+        snap = eng.stats_snapshot()["classes"]["encode"]
+    finally:
+        eng.close()
+    halves = snap["queue"]
+    assert snap["completed"] == n
+    assert halves["coalesce"]["s"] + halves["wake"]["s"] \
+        == snap["stages"]["queue"]["s"] > 0.0
+    assert halves["coalesce"]["s"] >= 0.0 and halves["wake"]["s"] >= 0.0
+    # no member waits on policy past max_delay: a flush cuts it short
+    assert 0.0 < halves["coalesce"]["s"] <= n * max_delay + 1e-9
+    if drive is _lone:
+        assert halves["coalesce"]["s"] == pytest.approx(max_delay, rel=1e-6)
+    if drive is _coalesced:
+        assert snap["batches"] < n
+
+
+BLOCKING = {"encode": ("encode",),
+            "repair": ("reconstruct", "decode_data"),
+            "tag": ("tag_fragments",), "prove": ("prove_aggregate",),
+            "verify": ("verify_aggregate",)}
+
+
+@pytest.mark.parametrize("cls", sorted(CLASSES))
+def test_submit_stages_and_handback_are_the_blocking_call(pkey, cls):
+    """The widened identity, for batches of one request: ``caller.submit``
+    + the six stages + ``caller.handoff`` is the blocking call's own extent
+    (entry of ``engine.reconstruct`` / ... -> its return), within the clock
+    reads between them."""
+    eng = _engine(pkey)
+    extent = [0.0]
+
+    def timed(call):
+        def wrapper(*args, **kw):
+            t0 = time.perf_counter()
+            try:
+                return call(*args, **kw)
+            finally:
+                extent[0] += time.perf_counter() - t0
+        return wrapper
+
+    for name in BLOCKING[cls]:
+        setattr(eng, name, timed(getattr(eng, name)))
+    try:
+        _drive(eng, pkey, cls)
+        eng.flush()
+        snap = eng.stats_snapshot()["classes"][cls]
+    finally:
+        eng.close()
+    assert snap["batches"] == snap["completed"] >= 2     # one a batch
+    named = snap["caller"]["submit"]["s"] + snap["caller"]["handoff"]["s"] \
+        + sum(acc["s"] for acc in snap["stages"].values())
+    # what lies between the accounts is code between two clock reads; a
+    # loaded box may park a thread there, so the room is wide, and still
+    # far below any account left out (the queue alone is 2 ms a request)
+    assert named == pytest.approx(extent[0], rel=0.15,
+                                  abs=1e-3 * snap["completed"])
+
+
+def _wait_done(fut, seconds=30.0):
+    end = time.monotonic() + seconds
+    while not fut.done():
+        assert time.monotonic() < end
+        time.sleep(0.002)
+
+
+def test_a_late_caller_counts_a_handback_of_zero_once():
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.002))
+    try:
+        fut = eng.submit_encode(rnd((1, K, FRAG), 5))
+        _wait_done(fut)
+        first = fut.result()
+        assert np.array_equal(fut.result(), first)      # and again
+        eng.flush()
+        snap = eng.stats_snapshot()["classes"]["encode"]
+    finally:
+        eng.close()
+    assert snap["caller"]["handoff"] == {"n": 1, "s": 0.0}
+    assert snap["caller"]["submit"]["n"] == 1
+
+
+def test_a_rejected_future_counts_its_handback_once():
+    """A request that times out in its queue: ``result()`` raises, counts
+    the one hand-back the future has, and closes its stage (the caller's
+    span is current again; every result span is finished)."""
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=600.0))
+    tracer = obs.Tracer()
+    try:
+        with obs.armed(tracer), obs.span("caller", sys="test") as outer:
+            fut = eng.submit_encode(rnd((1, K, FRAG), 6), timeout=0.05)
+            # the caller's own patience runs out first: no result yet,
+            # so no hand-back to count
+            with pytest.raises(EngineTimeout):
+                fut.result(timeout=0.0)
+            assert obs.current_span() is outer
+            pending = eng.stats_snapshot()["classes"]["encode"]
+            assert pending["caller"]["handoff"]["n"] == 0
+            for _ in range(2):
+                with pytest.raises(EngineTimeout):
+                    fut.result(30)
+                assert obs.current_span() is outer
+        snap = eng.stats_snapshot()["classes"]["encode"]
+    finally:
+        eng.close()
+    assert snap["timeouts"] == 1 and snap["completed"] == 0
+    assert snap["caller"]["handoff"]["n"] == 1
+    assert snap["caller"]["submit"]["n"] == 1
+    spans = tracer.finished()
+    results = [s for s in spans if s["name"] == "engine.encode.result"]
+    (caller,) = [s for s in spans if s["name"] == "caller"]
+    (submit,) = [s for s in spans if s["name"] == "engine.encode.submit"]
+    (request,) = [s for s in spans if s["name"] == "engine.encode"]
+    # the counted ones and the one that found nothing: all closed
+    assert len(results) == 2
+    assert {s["parent_id"] for s in results + [submit, request]} \
+        == {caller["span_id"]}
+
+
+def test_the_batcher_keeps_no_request_alive_past_its_batch():
+    """The stamps of the queue's halves are written where the batch is
+    drained: a name left bound to a request in the batcher's own frame
+    would hold that request's payload until the next drain, and free it
+    there, inside the next request's queue wait (found on the chip: 80 MiB
+    unmapped under the GIL, 5.9 ms of ``wake``)."""
+    import gc
+    import weakref
+
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.002))
+    try:
+        data = rnd((1, K, FRAG), 9)
+        ref = weakref.ref(data)
+        eng.encode(data)
+        assert eng.flush(60)
+        del data
+        end = time.monotonic() + 10.0
+        while ref() is not None and time.monotonic() < end:
+            gc.collect()
+            time.sleep(0.01)
+        assert ref() is None
+    finally:
+        eng.close()
+
+
+def test_caller_stages_make_no_root_span():
+    """Without a span of the caller's there is nothing to be a child of:
+    the stages are annotations and counters only, and the request's span
+    stays the root it was."""
+    tracer = obs.Tracer()
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=0.002))
+    try:
+        with obs.armed(tracer):
+            eng.encode(rnd((1, K, FRAG), 8))
+            eng.flush()
+    finally:
+        eng.close()
+    roots = [s["name"] for s in tracer.finished() if s["parent_id"] == 0]
+    assert roots == ["engine.encode"]
+    assert not [s for s in tracer.finished()
+                if s["name"].endswith((".submit", ".result"))]
 
 
 def test_coalesced_batch_counts_once_and_sums_its_members_queue(pkey):
@@ -272,6 +520,43 @@ def test_stream_stages_ride_the_batch_and_run_spans():
                                      for s in by("stream.put")) + 1e-4
 
 
+# -- the PoDR2 challenge -----------------------------------------------------
+def test_challenge_is_a_stage_of_its_caller_and_never_of_a_trace():
+    ids = np.stack([podr2.fragment_id_from_hash(bytes([i]) * 32)
+                    for i in range(3)])
+    tracer = obs.Tracer()
+    before = podr2.stage_counters()
+    with obs.armed(tracer):
+        with trace.stage("tee.round.challenge"):
+            idx, nu = podr2.gen_challenge(b"stage-seed", 64)
+            r = podr2.aggregate_coeffs(b"stage-seed", ids)
+    spans = {s["name"]: s for s in tracer.finished()}
+    outer = spans["tee.round.challenge"]
+    assert spans["podr2.challenge"]["parent_id"] == outer["span_id"]
+    assert spans["podr2.coeffs"]["parent_id"] == outer["span_id"]
+    after = podr2.stage_counters()
+    assert set(after) == {"podr2.challenge", "podr2.coeffs"}
+    for name in after:
+        assert after[name]["n"] == before[name]["n"] + 1, name
+        assert after[name]["s"] >= before[name]["s"], name
+    assert podr2.stage_metrics()["cess_podr2_challenge_count"] \
+        == after["podr2.challenge"]["n"]
+
+    # reached while JAX traces a caller, the call is a piece of that
+    # program: no stage, no count, the same values
+    @jax.jit
+    def traced(fragment_ids):
+        return (podr2.gen_challenge(b"stage-seed", 64),
+                podr2.aggregate_coeffs(b"stage-seed", fragment_ids))
+
+    with obs.armed(tracer):
+        (jidx, jnu), jr = traced(ids)
+    assert podr2.stage_counters() == after
+    assert len(tracer.finished()) == 3
+    assert np.array_equal(jidx, idx) and np.array_equal(jnu, nu)
+    assert np.array_equal(jr, r)
+
+
 # -- the profiler's trace ----------------------------------------------------
 class _Node:
     def __init__(self):
@@ -367,6 +652,47 @@ def test_profile_holds_the_stream_stages(profiled, name):
     mine = [e for e in profiled if e[1] == name]
     assert len(mine) >= 2          # 3 streamed rows, 2 a batch
     assert all(e[3] >= e[2] for e in mine)
+
+
+@pytest.mark.parametrize("cls", ["repair", "prove", "verify", "tag",
+                                 "encode"])
+def test_profile_holds_the_callers_side_on_the_callers_thread(profiled,
+                                                              cls):
+    """One ``submit`` and one ``result`` a request, on another thread than
+    the batch; the batcher starts to resolve while the caller is blocked
+    in ``result`` (the overlap that shows the hand-back)."""
+    batches = [e for e in profiled if e[1] == f"engine.{cls}.batch"]
+    submits = [e for e in profiled if e[1] == f"engine.{cls}.submit"]
+    results = [e for e in profiled if e[1] == f"engine.{cls}.result"]
+    assert len(submits) == len(results) == len(batches) >= 1
+    assert {e[0] for e in submits} == {e[0] for e in results}
+    assert not {e[0] for e in submits} & {e[0] for e in batches}
+    for resolve in (e for e in profiled
+                    if e[1] == f"engine.{cls}.resolve"):
+        assert any(r[2] <= resolve[2] <= r[3] for r in results)
+    # a request is submitted before its batch runs
+    assert min(e[3] for e in submits) <= min(e[2] for e in batches)
+    assert f"engine.{cls}.queue.coalesce" not in {e[1] for e in profiled}
+
+
+def test_profile_holds_the_workers_jobs_inside_the_upload(profiled):
+    """2 segments x 3 fragments: a copy and a hash a fragment, a hash a
+    segment, each on a worker's thread, inside the upload's extent."""
+    (upload,) = [e for e in profiled if e[1] == "offchain.upload"]
+    copies = [e for e in profiled if e[1] == "gateway.worker.copy"]
+    hashes = [e for e in profiled if e[1] == "gateway.worker.hash"]
+    assert (len(copies), len(hashes)) == (2 * (K + M), 2 * (K + M + 1))
+    for e in copies + hashes:
+        assert e[0] != upload[0]
+        assert upload[2] <= e[2] and e[3] <= upload[3]
+    for part in ("jobs", "put", "step"):
+        assert _inside(profiled, f"gateway.encode.{part}", "gateway.encode")
+
+
+def test_profile_holds_the_challenge(profiled):
+    names = [e[1] for e in profiled]
+    assert names.count("podr2.challenge") == 1      # one audit round
+    assert names.count("podr2.coeffs") == 1
 
 
 def test_profile_has_one_event_per_stage_per_batch(profiled):

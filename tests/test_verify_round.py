@@ -402,6 +402,16 @@ def test_a_round_is_one_span_with_its_stages_inside(ref, key, engine):
     for stage in ("decode", "ids", "challenge", "submit", "gather"):
         assert spans[f"tee.round.{stage}"]["parent_id"] == outer["span_id"]
     assert "engine.verify" in spans and "engine.verify.dispatch" in spans
+    # the round's derivation is the program's own stage inside the TEE's,
+    # and the caller's side of the request lies where the caller was
+    assert spans["podr2.challenge"]["parent_id"] \
+        == spans["tee.round.challenge"]["span_id"]
+    assert spans["engine.verify.submit"]["parent_id"] \
+        == spans["tee.round.submit"]["span_id"]
+    assert spans["engine.verify"]["parent_id"] \
+        == spans["tee.round.submit"]["span_id"]
+    assert spans["engine.verify.result"]["parent_id"] \
+        == spans["tee.round.gather"]["span_id"]
 
 
 # -- the chain path --------------------------------------------------------
