@@ -64,7 +64,9 @@ def collect(node) -> dict[str, float]:
     if gateway is not None:
         m.update(gateway.metrics())
     # the process's PoDR2 round derivations (ops/podr2.py gen_challenge
-    # / aggregate_coeffs: host seconds and calls), where its agents
+    # / aggregate_coeffs: host seconds, calls and, as
+    # cess_podr2_challenge_programs / cess_podr2_coeffs_programs, the
+    # shapes their compiled programs were built for), where its agents
     # hold the device path
     if engine is not None or gateway is not None:
         from ..ops import podr2

@@ -51,6 +51,7 @@ the byte/block axis shards across the mesh with psum aggregation
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import threading
 
 import jax
@@ -60,6 +61,7 @@ import numpy as np
 from .. import constants
 from ..obs import trace
 from . import pfield as pf
+from . import xor_sched
 
 SECTORS = 256                       # field elements per block
 BLOCK_BYTES = SECTORS * pf.BYTES_PER_ELEM   # 512
@@ -315,25 +317,27 @@ def tag_dispatch(tag_ops: tuple, fragment_ids, fragments) -> jax.Array:
                        weights, prf_impl=prf_impl)
 
 
-# the round's host-side derivations, counted for the process: stage
-# name -> [calls, seconds] (stage_counters). Their callers are agents'
-# threads, so the account has its own lock and the stage no sink.
+# the round's two derivations, counted for the process: stage name ->
+# [calls, seconds] (stage_counters). Their callers are agents' threads,
+# so the account has its own lock and the stage no sink.
 _STAGE_MU = threading.Lock()
 _STAGES = {"podr2.challenge": [0, 0.0], "podr2.coeffs": [0, 0.0]}
 
 
-def _staged(name: str, derive, *args):
-    """``derive(*args)`` as one stage of a round, one a call
+def _staged(name: str, body, dispatch, *args):
+    """One round derivation over ``args``, the seed's words first. At the
+    top level it is ``dispatch(*args)`` — the call of the derivation's
+    compiled program, one enqueue — as one stage of a round, one a call
     (obs.trace.stage: ``cess:<name>`` in a profiler trace, a child of
     the caller's span, ``[calls, seconds]`` in ``stage_counters()``).
-    Only the EAGER entry is a stage: reached while JAX traces a caller
+    Only that entry is a stage: reached while JAX traces a caller
     (``jit``, ``vmap``: ``trace_ctx`` is not at its top level) the call
     is a piece of that program, made once a trace and timing nothing
-    of a round, and runs bare."""
+    of a round, and runs its plain ``body(*args)`` inline and bare."""
     if not jax.core.trace_ctx.is_top_level():
-        return derive(*args)
+        return body(*args)
     with trace.stage(name) as stage:
-        out = derive(*args)
+        out = dispatch(*args)
     with _STAGE_MU:
         acc = _STAGES[name]
         acc[0] += 1
@@ -342,59 +346,81 @@ def _staged(name: str, derive, *args):
 
 
 def stage_counters() -> dict:
-    """``{"podr2.challenge": {"n", "s"}, "podr2.coeffs": {"n", "s"}}``:
-    the eager calls of ``gen_challenge`` / ``aggregate_coeffs`` in this
-    process and the host seconds they took (the calls as the host sees
-    them: both issue their operations one by one and return device
-    arrays that may still be in flight)."""
+    """``{"podr2.challenge": {"n", "s", "programs"}, "podr2.coeffs":
+    {...}}``: the top-level calls of ``gen_challenge`` /
+    ``aggregate_coeffs`` in this process, the host seconds they took
+    (the calls as the host sees them: each enqueues one compiled program
+    and returns device arrays that may still be in flight) and the
+    shapes their programs were compiled for (CHALLENGE_PROGRAM: one a
+    geometry; COEFFS_PROGRAM: one a power of two of F; either: one more
+    a device they were placed on). ``n`` rising while ``programs``
+    stands is the mechanism at work; ``programs`` rising with ``n`` is
+    a process that compiles in its rounds."""
     with _STAGE_MU:
-        return {name: {"n": n, "s": s} for name, (n, s) in _STAGES.items()}
+        return {name: {"n": n, "s": s,
+                       "programs": _PROGRAMS[name]._cache_size()}
+                for name, (n, s) in _STAGES.items()}
 
 
 def stage_metrics() -> dict[str, float]:
     """``stage_counters()`` as ``cess_podr2_challenge_seconds`` /
-    ``_count`` and ``cess_podr2_coeffs_...`` (node/metrics.py)."""
+    ``_count`` / ``_programs`` and ``cess_podr2_coeffs_...``
+    (node/metrics.py)."""
     out = {}
     for name, acc in stage_counters().items():
         short = name.partition(".")[2]
         out[f"cess_podr2_{short}_seconds"] = acc["s"]
         out[f"cess_podr2_{short}_count"] = float(acc["n"])
+        out[f"cess_podr2_{short}_programs"] = float(acc["programs"])
     return out
+
+
+def _seed_words(data: bytes) -> np.ndarray:
+    """64-bit fold of round randomness -> [2] uint32. jax.random.key
+    truncates its seed to 32 bits under x32, so a derivation seeds its
+    key with the first word and takes the second in via fold_in."""
+    return np.frombuffer(hashlib.sha256(data).digest()[:8],
+                         dtype="<u4").astype(np.uint32)
 
 
 def gen_challenge(seed_bytes: bytes | int, num_blocks: int,
                   count: int | None = None):
-    """Derive (indices [c], nu [c]) from round randomness.
+    """Derive (indices [c] int32, nu [c] uint32) from round randomness.
 
     Coverage mirrors audit's 46/1000 of chunks (SURVEY.md §3.3); the
     reference draws 20-byte randoms per index, here nu in F_p.
-    An eager call is the stage ``podr2.challenge`` (``_staged``).
+    The host keeps what is not arithmetic (the coverage rule, the
+    seed's two words); the rest is CHALLENGE_PROGRAM, one compiled
+    program a geometry — ``num_blocks`` and ``count`` its static
+    arguments, the words its operand, so a new seed never compiles.
+    A top-level call is the stage ``podr2.challenge`` (``_staged``).
     """
-    return _staged("podr2.challenge", _gen_challenge, seed_bytes,
-                   num_blocks, count)
-
-
-def _gen_challenge(seed_bytes, num_blocks: int, count: int | None):
     if count is None:
         count = max(1, num_blocks * constants.CHALLENGE_RATE_NUM
                     // constants.CHALLENGE_RATE_DEN)
     if isinstance(seed_bytes, bytes):
-        import hashlib
-
-        # 64-bit fold of the round randomness. jax.random.key truncates
-        # its seed to 32 bits under x32, so the second word goes in via
-        # fold_in rather than the seed.
-        digest = hashlib.sha256(seed_bytes).digest()
-        w0 = int.from_bytes(digest[:4], "little")
-        w1 = int.from_bytes(digest[4:8], "little")
+        words = _seed_words(seed_bytes)
     else:
-        w0 = int(seed_bytes) & 0xFFFFFFFF
-        w1 = (int(seed_bytes) >> 32) & 0xFFFFFFFF
-    key = jax.random.fold_in(jax.random.key(np.uint32(w0)), np.uint32(w1))
+        words = np.array([int(seed_bytes) & 0xFFFFFFFF,
+                          (int(seed_bytes) >> 32) & 0xFFFFFFFF], np.uint32)
+    return _staged("podr2.challenge", _gen_challenge, CHALLENGE_PROGRAM,
+                   words, num_blocks, count)
+
+
+def _gen_challenge(words, num_blocks: int, count: int):
+    """THE one definition of the challenge's arithmetic, over the seed's
+    two words (host words or traced ones)."""
+    key = jax.random.fold_in(jax.random.key(words[0]), words[1])
     k_idx, k_nu = jax.random.split(key)
     idx = jax.random.randint(k_idx, (count,), 0, num_blocks, dtype=jnp.int32)
     nu = pf.to_field(jax.random.bits(k_nu, (count,), jnp.uint32))
     return idx, nu
+
+
+# jitted once for the process, as TAG_PROGRAM above and the round
+# programs below: (words u32[2]) -> (idx i32[count], nu u32[count])
+CHALLENGE_PROGRAM = jax.jit(_gen_challenge,
+                            static_argnames=("num_blocks", "count"))
 
 
 def prove_at(blocks_i, tags_i, nu):
@@ -436,10 +462,7 @@ def aggregate_words(seed_bytes: bytes) -> np.ndarray:
     """The round seed's two aggregation key words [2] uint32: all of
     ``aggregate_coeffs`` that is not arithmetic. A verifier that derives
     r on the device (``round_fold``) ships these eight bytes a round."""
-    import hashlib
-
-    digest = hashlib.sha256(b"cess-podr2-agg:" + seed_bytes).digest()
-    return np.frombuffer(digest[:8], dtype="<u4").astype(np.uint32)
+    return _seed_words(b"cess-podr2-agg:" + seed_bytes)
 
 
 def _aggregate_key(words):
@@ -475,15 +498,38 @@ def aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
     (PROOF_BYTES raw payload + constant codec framing; see the
     authoritative statement at PROOF_BYTES, framed total computed by
     node/offchain.py proof_wire_bytes).
-    An eager call is the stage ``podr2.coeffs`` (``_staged``).
+    The host keeps ``aggregate_words``; the rest is COEFFS_PROGRAM over
+    the ids padded with zero rows to the next power of two (a miner
+    holds any F: one compiled program a power of two, not one an F),
+    and ``r[:F]`` comes back. r is a per-row PRF, so a real row's r
+    knows nothing of the pad.
+    A top-level call is the stage ``podr2.coeffs`` (``_staged``).
     """
-    return _staged("podr2.coeffs", _aggregate_coeffs, seed_bytes,
-                   fragment_ids)
+    return _staged("podr2.coeffs", _aggregate_coeffs, _coeffs_dispatch,
+                   aggregate_words(seed_bytes), fragment_ids)
 
 
-def _aggregate_coeffs(seed_bytes: bytes, fragment_ids) -> jax.Array:
-    return _coeffs(_aggregate_key(aggregate_words(seed_bytes)),
+def _aggregate_coeffs(words, fragment_ids) -> jax.Array:
+    return _coeffs(_aggregate_key(words),
                    jnp.asarray(fragment_ids).reshape(-1, 2))
+
+
+# jitted once for the process: (words u32[2], ids u32[F', 2]) -> r u32[F']
+COEFFS_PROGRAM = jax.jit(_aggregate_coeffs)
+# the round derivations' programs by stage (stage_counters)
+_PROGRAMS = {"podr2.challenge": CHALLENGE_PROGRAM,
+             "podr2.coeffs": COEFFS_PROGRAM}
+
+
+def _coeffs_dispatch(words, fragment_ids) -> jax.Array:
+    """COEFFS_PROGRAM over host ids [F, 2], zero rows up to the next
+    power of two; the first F of its r."""
+    ids = np.asarray(fragment_ids).reshape(-1, 2)
+    f = len(ids)
+    padded = np.zeros((xor_sched.rows_bucket(f), 2), np.uint32)
+    padded[:f] = ids
+    r = COEFFS_PROGRAM(words, padded)
+    return r if f == len(padded) else r[:f]
 
 
 def _fold_proofs(mu_f, sigma_f, r):

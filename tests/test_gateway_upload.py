@@ -339,7 +339,8 @@ def test_counters_ride_the_nodes_exposition(pipes, gateway):
     assert series["cess_gateway_stage_worker_copy_seconds"] \
         == seconds["gateway.worker.copy"]
     assert {"cess_podr2_challenge_seconds", "cess_podr2_challenge_count",
-            "cess_podr2_coeffs_seconds", "cess_podr2_coeffs_count"} \
+            "cess_podr2_challenge_programs", "cess_podr2_coeffs_seconds",
+            "cess_podr2_coeffs_count", "cess_podr2_coeffs_programs"} \
         <= set(series)
     assert "# TYPE cess_gateway_rows_fetched_total counter" \
         in render_metrics(node)

@@ -397,3 +397,121 @@ def test_fused_envelope_tracks_block_tile():
     assert pp.supported(256, tile // 2)      # sub-tile: tile == blocks
     assert not pp.supported(256, tile + tile // 2)   # ragged grid
     assert not pp.supported(256, 3 * tile // 2)
+
+
+# -- the round's two derivations as compiled programs ------------------------
+def _words(seed):
+    """The seed's two key words, the test's own arithmetic."""
+    import hashlib
+
+    if isinstance(seed, bytes):
+        digest = hashlib.sha256(seed).digest()
+        return np.array([int.from_bytes(digest[:4], "little"),
+                         int.from_bytes(digest[4:8], "little")], np.uint32)
+    return np.array([seed & 0xFFFFFFFF, seed >> 32], np.uint32)
+
+
+def _ids(f, seed=5):
+    return np.random.default_rng(seed).integers(
+        0, 2 ** 32, (f, 2), dtype=np.uint32)
+
+
+SEEDS = [b"", b"round-7", bytes(range(40)), (1 << 40) + 12345]
+# (blocks, count handed in, count that comes back); the protocol's
+# 16384 blocks by the 46/1000 coverage rule, an explicit count, a rule
+# that rounds down to its floor of one
+GEOMETRIES = [(16384, None, 753), (64, 5, 5), (16, None, 1)]
+
+
+@pytest.mark.parametrize("blocks,count,want_count", GEOMETRIES)
+@pytest.mark.parametrize("seed", SEEDS, ids=["empty", "short", "bytes40",
+                                             "int-above-2^32"])
+def test_challenge_program_is_the_plain_body(seed, blocks, count,
+                                             want_count):
+    """CHALLENGE_PROGRAM is bit for bit its body run primitive by
+    primitive, for every kind of seed."""
+    import jax
+
+    idx, nu = podr2.gen_challenge(seed, blocks, count)
+    with jax.disable_jit():
+        want_idx, want_nu = podr2._gen_challenge(_words(seed), blocks,
+                                                 want_count)
+    assert isinstance(idx, jax.Array) and isinstance(nu, jax.Array)
+    assert idx.shape == nu.shape == (want_count,)
+    assert idx.dtype == np.int32 and nu.dtype == np.uint32
+    assert np.array_equal(idx, want_idx) and np.array_equal(nu, want_nu)
+    assert 0 <= int(idx.min()) and int(idx.max()) < blocks
+    assert int(nu.max()) < pf.P
+
+
+@pytest.mark.parametrize("f", [1, 3, 32, 33, 100])
+def test_coeffs_program_is_the_plain_body_whatever_the_pad(f):
+    """COEFFS_PROGRAM over ids padded to a power of two hands back
+    exactly F values, each the plain per-row PRF's: the pad changes no
+    real row's r."""
+    import jax
+
+    seed, ids = b"agg-program", _ids(f)
+    r = podr2.aggregate_coeffs(seed, ids)
+    with jax.disable_jit():
+        want = podr2._coeffs(
+            podr2._aggregate_key(_words(b"cess-podr2-agg:" + seed)), ids)
+    assert isinstance(r, jax.Array)
+    assert r.shape == (f,) and r.dtype == np.uint32
+    assert np.array_equal(r, want)
+    # a list of id pairs and a device array are the same ids
+    assert np.array_equal(podr2.aggregate_coeffs(seed, list(ids)), want)
+    assert np.array_equal(podr2.aggregate_coeffs(seed, jnp.asarray(ids)),
+                          want)
+
+
+def _programs(stage):
+    return podr2.stage_counters()[stage]["programs"]
+
+
+@pytest.mark.parametrize("call,same,new", [
+    pytest.param(lambda: podr2.gen_challenge(b"a", 977, 13),
+                 lambda: podr2.gen_challenge(b"another seed", 977, 13),
+                 [lambda: podr2.gen_challenge(b"a", 977, 14),
+                  lambda: podr2.gen_challenge(b"a", 978, 13)],
+                 id="podr2.challenge"),
+    pytest.param(lambda: podr2.aggregate_coeffs(b"a", _ids(40)),
+                 lambda: podr2.aggregate_coeffs(b"b", _ids(33, seed=6)),
+                 [lambda: podr2.aggregate_coeffs(b"a", _ids(65))],
+                 id="podr2.coeffs")])
+def test_one_compile_a_shape(request, compiles, call, same, new):
+    """A new seed, or another F under the same power of two, runs the
+    program that is there; a new geometry or the next power of two is
+    one more. ``programs`` is the count."""
+    stage = request.node.callspec.id
+    podr2._PROGRAMS[stage].clear_cache()
+    assert _programs(stage) == 0
+    call()
+    assert _programs(stage) == 1
+    n = podr2.stage_counters()[stage]["n"]
+    before = compiles()
+    same()
+    assert _programs(stage) == 1
+    assert podr2.stage_counters()[stage]["n"] == n + 1
+    # the program compiles nothing; padded, r[:F] is one tiny slice
+    assert compiles() - before <= (stage == "podr2.coeffs")
+    for k, other in enumerate(new):
+        other()
+        assert _programs(stage) == 2 + k
+    assert podr2.stage_metrics()[
+        f"cess_podr2_{stage.partition('.')[2]}_programs"] == 1.0 + len(new)
+
+
+def test_stage_counters_carry_programs_beside_n_and_s():
+    podr2.gen_challenge(b"c", 16)
+    podr2.aggregate_coeffs(b"c", _ids(2))
+    counters = podr2.stage_counters()
+    assert set(counters) == {"podr2.challenge", "podr2.coeffs"}
+    metrics = podr2.stage_metrics()
+    for stage, acc in counters.items():
+        assert set(acc) == {"n", "s", "programs"}
+        assert acc["n"] >= 1 and acc["s"] > 0 and acc["programs"] >= 1
+        short = stage.partition(".")[2]
+        assert metrics[f"cess_podr2_{short}_programs"] == acc["programs"]
+        assert metrics[f"cess_podr2_{short}_count"] == acc["n"]
+        assert metrics[f"cess_podr2_{short}_seconds"] == acc["s"]

@@ -537,13 +537,17 @@ def test_challenge_is_a_stage_of_its_caller_and_never_of_a_trace():
     after = podr2.stage_counters()
     assert set(after) == {"podr2.challenge", "podr2.coeffs"}
     for name in after:
+        assert set(after[name]) == {"n", "s", "programs"}, name
         assert after[name]["n"] == before[name]["n"] + 1, name
         assert after[name]["s"] >= before[name]["s"], name
+        assert after[name]["programs"] >= 1, name
     assert podr2.stage_metrics()["cess_podr2_challenge_count"] \
         == after["podr2.challenge"]["n"]
+    assert podr2.stage_metrics()["cess_podr2_coeffs_programs"] \
+        == after["podr2.coeffs"]["programs"]
 
     # reached while JAX traces a caller, the call is a piece of that
-    # program: no stage, no count, the same values
+    # program: no stage, no count, no program of its own, the same values
     @jax.jit
     def traced(fragment_ids):
         return (podr2.gen_challenge(b"stage-seed", 64),

@@ -283,6 +283,33 @@ def test_round_fold_compiles_for_v5e(one_chip, for_tpu, missions):
         assert mem.temp_size_in_bytes < 256 * MiB, mem
 
 
+@pytest.mark.parametrize("program,static,shapes,out", [
+    pytest.param("CHALLENGE_PROGRAM", dict(num_blocks=16384, count=753),
+                 [((2,), jnp.uint32)], [(753,), (753,)], id="challenge"),
+    pytest.param("COEFFS_PROGRAM", {},
+                 [((2,), jnp.uint32), ((32, 2), jnp.uint32)], [(32,)],
+                 id="coeffs-32"),
+    pytest.param("COEFFS_PROGRAM", {},
+                 [((2,), jnp.uint32), ((16384, 2), jnp.uint32)], [(16384,)],
+                 id="coeffs-16384")])
+def test_round_derivations_compile_for_v5e(one_chip, for_tpu, program,
+                                           static, shapes, out):
+    """A round's challenge and its aggregation coefficients (ops/podr2.py
+    CHALLENGE_PROGRAM / COEFFS_PROGRAM since PR 37) at the protocol's
+    geometry, the audit cell's F and a miner's 16,384: the seed's words
+    are the operand, nothing of the host is called back."""
+    from cess_tpu.ops import podr2
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    t0 = time.perf_counter()
+    compiled = getattr(podr2, program).lower(*args, **static).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    assert not re.search(r"callback|host_compute|outfeed|infeed",
+                         compiled.as_text())
+    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == out
+
+
 @pytest.mark.parametrize("shape,packed", [
     pytest.param((1, 1, 8 * MiB), 4, id="one-claim-rs2p1"),
     pytest.param((2, 1, 8 * MiB), 2, id="two-claims-rs2p1"),
