@@ -379,6 +379,25 @@ def slow_filler_bytes(secret: bytes, index: int, size: int,
 
 
 class MinerAgent:
+    """A storage miner's off-chain side: it fetches the fragments of the
+    deals it is assigned into ``store`` (fragment hash -> ``bytes``,
+    their PoDR2 tags beside them in ``tags``), answers every audit
+    round over what it holds, and rebuilds lost fragments for restoral
+    orders.
+
+    A round is answered through ONE entry point, ``prove_round(seed,
+    owed)`` -> the aggregated proof's wire bytes, which ``on_block`` ->
+    ``_submit_proof`` calls for the service and the idle proof alike.
+    The fragments stay where they are held: what a round hands on are
+    views of the store's ``bytes`` and the tag arrays as they lie (one
+    largest deal's share is 1,000 fragments of 8 MiB, 7.8 GiB, and no
+    copy of it is made), the prover gathers the round's challenged
+    blocks from them ``podr2.PROVE_CHUNK`` fragments at a time, and
+    what crosses to the device is those chunks (24 MiB each at the
+    protocol's geometry, 373.5 MiB a round of 1,000 fragments) folded
+    into one running (mu, sigma) that comes back once. ``store[h]``
+    serves transfers, repairs and the tests that index it as before."""
+
     def __init__(self, node: Node, account: str, gateways: list[OssGateway],
                  pipeline: StoragePipeline, engine=None, retry=None,
                  clock=None):
@@ -500,7 +519,10 @@ class MinerAgent:
             blob = self._transfer(gw, frag_hash)
             if blob is not None:
                 self.store[frag_hash] = blob
-                self.tags[frag_hash] = gw.tag_store[frag_hash]
+                # the miner's own tag array, laid as a round reads it
+                # (the same object where it already is)
+                self.tags[frag_hash] = np.ascontiguousarray(
+                    gw.tag_store[frag_hash])
                 return True
         # repair path: reconstruct from peers (restoral flow fetches
         # survivor rows from other miners via the network harness)
@@ -570,21 +592,111 @@ class MinerAgent:
         (audit/src/lib.rs:430-479) with honest wire sizing."""
         seed = b"".join(ch.net.randoms)
         snap = next(s for s in ch.miners if s.miner == self.account)
-        limbs = self.pipeline.podr2_key.limbs
         with trace.span("offchain.prove", sys="offchain",
                         miner=self.account, round=ch.start,
                         service=len(snap.service_frags),
                         idle=len(snap.fillers)):
-            service = build_proof(seed, list(snap.service_frags),
-                                  self.store, self.tags, limbs=limbs,
-                                  engine=self.engine,
-                                  tenant=self.account)
-            idle = build_proof(seed, list(snap.fillers),
-                               self.filler_store, self.filler_tags,
-                               limbs=limbs, engine=self.engine,
-                               tenant=self.account)
+            service = self.prove_round(seed, snap.service_frags)
+            idle = self.prove_round(seed, snap.fillers, idle=True)
             node.submit_extrinsic(self.account, "audit.submit_proof",
                                   idle, service)
+
+    @classmethod
+    def custodian(cls, store: dict, tags: dict, *, limbs: int | None = None,
+                  engine=None, account: str | None = None) -> "MinerAgent":
+        """A miner reduced to what a round reads: its store, its tag
+        store, the deployment's limb width and an optional engine — no
+        node, no gateways, no pipeline. ``build_proof`` answers through
+        one; it holds the caller's dicts, not copies."""
+        agent = object.__new__(cls)
+        agent.account = account
+        agent.engine = engine
+        agent.pipeline = None
+        agent._limbs = limbs
+        agent.store, agent.tags = store, tags
+        agent.filler_store, agent.filler_tags = {}, {}
+        return agent
+
+    def prove_round(self, seed: bytes, owed, *, idle: bool = False
+                    ) -> bytes:
+        """THE miner's entry point: one round answered. ``seed`` is the
+        round's randomness and ``owed`` the fragment hashes frozen in
+        the challenge snapshot (service fragments, or with ``idle`` the
+        fillers) -> the aggregated proof (mu, sigma) as wire bytes,
+        constant in size. ``_submit_proof`` calls it for both proofs of
+        ``audit.submit_proof``.
+
+        A fragment the miner no longer holds simply does not contribute
+        — the fold then fails TEE verification (that's the audit); an
+        empty held set is the all-zero proof. Nothing is remembered
+        from an earlier round and nothing skipped: every challenged
+        block of every held owed fragment is read from the store's
+        bytes in this call, both limbs always.
+
+        The store is not copied. What goes to the prover is a view of
+        each held fragment's ``bytes`` and its tag array as they lie
+        (``np.frombuffer``: 1,000 fragments of 8 MiB are 7.8 GiB that
+        stay where they are); the ids come from the hashes in one pass
+        and r as host words from calls of fixed shapes
+        (``podr2.round_coeffs``). With an engine the request joins its
+        prove class, where miners answering the same round coalesce;
+        without one ``podr2.prove_held`` runs the same steps directly.
+        Either gathers the round's challenged blocks (4.6% of the set
+        at the protocol's geometry) ``podr2.PROVE_CHUNK`` fragments at
+        a time and folds them into a running (mu, sigma) on the device,
+        so only what the round reads travels, in pieces of one shape
+        however large the custody, and the bytes are the same whichever
+        way. A profiler trace holds ``cess:miner.round`` with ``.ids``,
+        ``.challenge``, ``.coeffs``, ``.submit`` and ``.encode`` inside
+        it (and, between the last two, the caller's wait for the
+        engine: ``cess:engine.prove.result``)."""
+        store, tags = (self.filler_store, self.filler_tags) if idle \
+            else (self.store, self.tags)
+        with trace.stage("miner.round"):
+            with trace.stage("miner.round.ids"):
+                held = [h for h in owed if h in store]
+                # the limb WIDTH is a deployment parameter: it comes
+                # from the PoDR2 key (hardwiring 2 broke limbs=3
+                # deployments; and an EMPTY tags map must not silently
+                # fall back to the module default — a fillerless miner
+                # in a limbs=3 deployment would emit a wrong-width zero
+                # sigma and fail an audit it should pass; both
+                # review-caught, r05)
+                limbs = self._limbs if self.pipeline is None \
+                    else self.pipeline.podr2_key.limbs
+                if limbs is None:
+                    limbs = next(iter(tags.values())).shape[-1] if tags \
+                        else podr2.LIMBS
+                if not held:
+                    return codec.encode(Proof(
+                        mu=np.zeros((podr2.SECTORS,), np.uint32),
+                        sigma=np.zeros((limbs,), np.uint32)))
+                frags = [np.frombuffer(store[h], dtype=np.uint8)
+                         for h in held]
+                tag_rows = [tags[h] for h in held]
+                ids = podr2.fragment_ids_from_hashes(held)
+            with trace.stage("miner.round.challenge"):
+                idx, nu = (np.asarray(a) for a in podr2.gen_challenge(
+                    seed, tag_rows[0].shape[0]))
+            with trace.stage("miner.round.coeffs"):
+                r = podr2.round_coeffs(seed, ids)
+            with trace.stage("miner.round.submit"):
+                engine = self.engine
+                if engine is not None and engine.audit is not None:
+                    # submission-engine path: miners answering the same
+                    # round coalesce in the engine's prove queue
+                    # (bit-identical fold)
+                    pending = engine.submit_prove_aggregate(
+                        frags, tag_rows, idx, nu, r, tenant=self.account)
+                else:
+                    pending = podr2.prove_held(frags, tag_rows, idx, nu, r)
+            mu, sigma = pending.result() if hasattr(pending, "result") \
+                else pending
+            with trace.stage("miner.round.encode"):
+                return codec.encode(Proof(
+                    mu=np.ascontiguousarray(np.asarray(mu, dtype=np.uint32)),
+                    sigma=np.ascontiguousarray(
+                        np.asarray(sigma, dtype=np.uint32))))
 
     # -- restoral servicing -------------------------------------------------------
     def warm_restoral(self) -> None:
@@ -843,46 +955,14 @@ def build_proof(seed: bytes, owed: list[bytes],
                 tags: dict[bytes, np.ndarray],
                 limbs: int | None = None, engine=None,
                 tenant: str | None = None) -> bytes:
-    """Miner-side: aggregated proof over the owed set, as wire bytes.
-    Fragments the miner no longer holds simply can't contribute — the
-    fold then fails TEE verification (that's the audit). ``tenant``
-    tags the engine submit (the proving miner's account) for
-    per-tenant accounting."""
-    held = [h for h in owed if h in store]
-    # the limb WIDTH is a deployment parameter: callers pass it from
-    # their PoDR2 key (hardwiring 2 broke limbs=3 deployments; and an
-    # EMPTY tags map must not silently fall back to the module default
-    # — a fillerless miner in a limbs=3 deployment would emit a
-    # wrong-width zero sigma and fail an audit it should pass; both
-    # review-caught, r05)
-    if limbs is None:
-        limbs = next(iter(tags.values())).shape[-1] if tags \
-            else podr2.LIMBS
-    if not held:
-        return codec.encode(Proof(
-            mu=np.zeros((podr2.SECTORS,), np.uint32),
-            sigma=np.zeros((limbs,), np.uint32)))
-    frags = np.stack([np.frombuffer(store[h], dtype=np.uint8)
-                      for h in held])
-    tag_arr = np.stack([tags[h] for h in held])
-    blocks = tag_arr.shape[1]
-    idx, nu = podr2.gen_challenge(seed, blocks)
-    ids = np.stack([podr2.fragment_id_from_hash(h) for h in held])
-    r = podr2.aggregate_coeffs(seed, ids)
-    if engine is not None and engine.audit is not None:
-        # submission-engine path: miners answering the same round
-        # coalesce in the engine's prove queue (bit-identical fold)
-        mu, sigma = engine.prove_aggregate(frags, tag_arr,
-                                           np.asarray(idx),
-                                           np.asarray(nu), np.asarray(r),
-                                           tenant=tenant)
-    else:
-        mu, sigma = podr2.prove_aggregate(jnp.asarray(frags),
-                                          jnp.asarray(tag_arr), idx, nu,
-                                          r)
-    return codec.encode(Proof(
-        mu=np.ascontiguousarray(np.asarray(mu, dtype=np.uint32)),
-        sigma=np.ascontiguousarray(np.asarray(sigma, dtype=np.uint32))))
+    """Miner-side: aggregated proof over the owed set, as wire bytes —
+    ``MinerAgent.prove_round`` over the two dicts handed in (a
+    ``MinerAgent.custodian`` of them). ``limbs`` is the deployment's
+    limb width (None: the tags' own, else the module default);
+    ``tenant`` tags the engine submit (the proving miner's account)
+    for per-tenant accounting."""
+    return MinerAgent.custodian(store, tags, limbs=limbs, engine=engine,
+                                account=tenant).prove_round(seed, owed)
 
 
 class TeeAgent:

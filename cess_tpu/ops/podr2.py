@@ -50,6 +50,7 @@ the byte/block axis shards across the mesh with psum aggregation
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import threading
@@ -554,6 +555,199 @@ def prove_aggregate_at(blocks_i, tags_i, nu, r):
     fragment, r [F] -> (mu [sectors], sigma [limbs])."""
     return _fold_proofs(*jax.vmap(
         lambda b, t: prove_at(b, t, nu))(blocks_i, tags_i), r)
+
+
+# -- a miner's round over what it holds -----------------------------------
+#
+# A storage miner answers a round over its whole frozen owed set: one
+# largest deal's share is SEGMENT_COUNT_MAX = 1,000 fragments of 8 MiB
+# (7.8 GiB of host memory), of which the round reads 753 blocks of 512 B
+# a fragment. The fragments stay where the miner holds them; what the
+# round reads is gathered on the host PROVE_CHUNK fragments at a time
+# into two reused buffers, put, and folded into a running (mu, sigma) on
+# the device, the gather of the next chunk running while the last one
+# goes up and folds. The fold is linear mod p over canonical residues,
+# so the chunks' sums are the whole set's, bit for bit. The chunk is the
+# only shape past PROVE_CHUNK fragments: custody that grows compiles
+# nothing new.
+PROVE_CHUNK = 64        # fragments a device step takes: 24 MiB of
+                        # challenged blocks at the protocol's geometry
+COEFF_ROWS = 1024       # ids a call of COEFFS_PROGRAM takes of a
+                        # miner's round past that many (round_coeffs)
+
+
+class HeldRows(tuple):
+    """A miner's fragments (or their tags) as they lie: one array a
+    fragment, each still wherever the miner holds it (a view of a
+    store's ``bytes``), where ``[F, ...]`` would have cost a copy of the
+    store to build. It answers ``shape`` / ``nbytes`` as that array
+    would."""
+
+    @property
+    def shape(self) -> tuple:
+        return (len(self),) + self[0].shape
+
+    @property
+    def nbytes(self) -> int:
+        return sum(row.nbytes for row in self)
+
+
+def held_rows(data, dtype, ndim: int):
+    """``data`` as the prover takes it: one contiguous array
+    ``[F, ...]`` of ``ndim`` dimensions, or — kept so, nothing stacked —
+    a non-empty sequence of F arrays of one shape (``HeldRows``)."""
+    if isinstance(data, (list, tuple)):
+        rows = HeldRows(np.ascontiguousarray(np.asarray(a, dtype=dtype))
+                        for a in data)
+        if not rows or rows[0].ndim != ndim - 1 \
+                or len({a.shape for a in rows}) != 1:
+            raise ValueError(f"expected a sequence of equal arrays of "
+                             f"{ndim - 1} dimensions")
+        return rows
+    arr = np.ascontiguousarray(np.asarray(data, dtype=dtype))
+    if arr.ndim != ndim:
+        raise ValueError(f"expected an array of {ndim} dimensions, got "
+                         f"{arr.shape}")
+    return arr
+
+
+def chunk_plan(rows: int) -> tuple[int, int]:
+    """(fragments a device step, steps) for a held set of ``rows``: the
+    power-of-two bucket in one step up to PROVE_CHUNK, whole chunks
+    past it."""
+    if rows <= PROVE_CHUNK:
+        return xor_sched.rows_bucket(rows), 1
+    return PROVE_CHUNK, -(-rows // PROVE_CHUNK)
+
+
+def gather_challenged(fragments, tags, lo: int, hi: int, idx,
+                      blocks_out, tags_out) -> int:
+    """The challenged blocks of fragments ``lo .. hi - 1`` and their tag
+    rows, read on the host from where the fragments lie into the first
+    ``hi - lo`` rows of ``blocks_out [n, c, sectors]`` uint16 (the
+    bytes read as little-endian field elements, pfield.pack_bytes'
+    embedding: a free view on a little-endian host) and ``tags_out
+    [n, c, limbs]``. ``idx`` was range-checked by the caller. Returns
+    the bytes gathered."""
+    n, sectors = hi - lo, blocks_out.shape[-1]
+    if isinstance(fragments, HeldRows):
+        for i in range(n):
+            np.take(fragments[lo + i].view("<u2").reshape(-1, sectors),
+                    idx, axis=0, out=blocks_out[i], mode="clip")
+    else:
+        # mode="clip": unbuffered writes into the batch
+        np.take(fragments[lo:hi].view("<u2").reshape(n, -1, sectors),
+                idx, axis=1, out=blocks_out[:n], mode="clip")
+    if isinstance(tags, HeldRows):
+        for i in range(n):
+            np.take(tags[lo + i], idx, axis=0, out=tags_out[i],
+                    mode="clip")
+    else:
+        np.take(tags[lo:hi], idx, axis=1, out=tags_out[:n], mode="clip")
+    return blocks_out[:n].nbytes + tags_out[:n].nbytes
+
+
+def fold_chunks(chunks: int, bufs, fill, call,
+                stage=lambda name: contextlib.nullcontext()):
+    """The host loop of a chunked prove. Step j fills ``bufs[j % 2]`` on
+    the host (``fill(j, buf)``: the gather) and calls the device program
+    on it (``call(acc, buf) -> acc``, ``acc`` None at the first step: an
+    enqueue, the buffer's put and the fold run behind it), so the gather
+    of chunk j + 1 runs while chunk j goes up and folds. A buffer is
+    filled again only once the step that read it has finished. Returns
+    the last ``acc``, still in flight; ``stage(name)`` wraps the
+    ``assemble`` / ``dispatch`` / ``wait`` parts of every step."""
+    acc, reader = None, [None] * len(bufs)
+    for j in range(chunks):
+        slot = j % len(bufs)
+        if reader[slot] is not None:
+            with stage("wait"):
+                jax.block_until_ready(reader[slot])
+        with stage("assemble"):
+            fill(j, bufs[slot])
+        with stage("dispatch"):
+            acc = call(acc, bufs[slot])
+        reader[slot] = acc
+    return acc
+
+
+def prove_buffers(shape: tuple, count: int) -> list:
+    """``count`` host buffers of one device step: (blocks ``[..., fb, c,
+    sectors]`` uint16, tags ``[..., fb, c, limbs]``, r ``[..., fb]``)
+    for ``shape = (*lead, fb, c, sectors, limbs)``."""
+    *lead, c, sectors, limbs = shape
+    return [(np.zeros((*lead, c, sectors), np.uint16),
+             np.zeros((*lead, c, limbs), np.uint32),
+             np.zeros(tuple(lead), np.uint32)) for _ in range(count)]
+
+
+def prove_step_at(mu, sigma, blocks_i, tags_i, nu, r):
+    """A later step of a chunked prove: the running (mu [sectors],
+    sigma [limbs]) plus ``prove_aggregate_at`` of one more chunk. The
+    sum is over canonical residues mod p, so the steps' total is the
+    one-step proof, bit for bit."""
+    mu_c, sigma_c = prove_aggregate_at(blocks_i, tags_i, nu, r)
+    return pf.addmod(mu, mu_c), pf.addmod(sigma, sigma_c)
+
+
+# the engine-less prover's two programs, one executable a chunk shape
+_HELD_FIRST = jax.jit(prove_aggregate_at)
+_HELD_STEP = jax.jit(prove_step_at)
+
+
+def prove_held(fragments, tags, idx, nu, r):
+    """``prove_aggregate`` over a held set without the engine and
+    without a copy of it: fragments ``[F, bytes]`` or a sequence of F
+    byte arrays, tags likewise, the challenge and r ``[F]`` as host
+    arrays -> (mu [sectors], sigma [limbs]) in flight. Bit-identical to
+    ``prove_aggregate``: only the challenged blocks go to the device,
+    ``chunk_plan`` fragments a step (pad rows carry r = 0: exact modular
+    zeros)."""
+    fragments = held_rows(fragments, np.uint8, 2)
+    tags = held_rows(tags, np.uint32, 3)
+    idx = np.asarray(idx)
+    nu = np.asarray(nu, dtype=np.uint32)
+    r = np.asarray(r, dtype=np.uint32)
+    fb, chunks = chunk_plan(len(r))
+    bufs = prove_buffers((fb, len(idx), fragments.shape[-1]
+                          // (tags.shape[1] * pf.BYTES_PER_ELEM),
+                          tags.shape[2]), min(chunks, 2))
+
+    def fill(j, buf):
+        lo, hi = j * fb, min((j + 1) * fb, len(r))
+        gather_challenged(fragments, tags, lo, hi, idx, buf[0], buf[1])
+        buf[2][:hi - lo] = r[lo:hi]
+        buf[2][hi - lo:] = 0
+
+    def call(acc, buf):
+        blocks_i, tags_i, rs = buf
+        if acc is None:
+            return _HELD_FIRST(blocks_i, tags_i, nu, rs)
+        return _HELD_STEP(*acc, blocks_i, tags_i, nu, rs)
+
+    return fold_chunks(chunks, bufs, fill, call)
+
+
+def round_coeffs(seed_bytes: bytes, fragment_ids) -> np.ndarray:
+    """``aggregate_coeffs`` for a miner's round, as host words [F]:
+    COEFFS_PROGRAM over the ids in calls of fixed shapes — the next
+    power of two up to COEFF_ROWS (the executables ``aggregate_coeffs``
+    uses), COEFF_ROWS ids a call past it — and the pad cut off on the
+    host, so a held set that grows compiles nothing past COEFF_ROWS and
+    no slice program at any F. One stage ``podr2.coeffs`` a call."""
+    ids = np.asarray(fragment_ids, dtype=np.uint32).reshape(-1, 2)
+
+    def dispatch(words, ids):
+        f = len(ids)
+        piece = min(xor_sched.rows_bucket(f), COEFF_ROWS)
+        padded = np.zeros((-(-f // piece) * piece, 2), np.uint32)
+        padded[:f] = ids
+        parts = [COEFFS_PROGRAM(words, padded[at:at + piece])
+                 for at in range(0, len(padded), piece)]
+        return np.concatenate([np.asarray(p) for p in parts])[:f]
+
+    return _staged("podr2.coeffs", _aggregate_coeffs, dispatch,
+                   aggregate_words(seed_bytes), ids)
 
 
 def verify_aggregate(key: Podr2Key, fragment_ids, num_blocks: int,

@@ -40,9 +40,18 @@ the payloads live on the backend's device. The stacked classes
 host-side and run ONE compiled program per batch shape, reused across
 rounds: the round (idx, nu) and the PoDR2 key are operands, never
 constants. Their callers are host agents: a miner's fragments stay in
-host memory, and ``assemble`` gathers the challenged blocks there
-(4.6% of the set at protocol widths), so only what the round reads
-travels to the device; verify ships KiB-scale proofs. A TEE's whole
+host memory — one array, or one buffer a fragment as its store holds
+them (``podr2.HeldRows``: a deal's share is 1,000 fragments, 7.8 GiB,
+and is never copied) — and ``assemble`` gathers the challenged blocks
+there (4.6% of the set at protocol widths), so only what the round
+reads travels to the device. A prove ships it in pieces of one shape
+(PR 38): ``podr2.PROVE_CHUNK`` fragments a device step ([R, 64, c,
+sectors] uint16 blocks, their tag rows and r: 24 MiB at the protocol's
+geometry), gathered into two reused host buffers and folded into a
+running (mu, sigma) on the device while the next step's gather runs;
+up to a chunk that is the one step of the bucket's program, past it
+the chunk is the only shape, so the programs do not grow with F, and
+(mu, sigma) come back once. Verify ships KiB-scale proofs. A TEE's whole
 round (verify_round, PR 33: up to 500 missions, owed sets ragged from
 tens to ten thousands of fragments) is not stacked but FLAT: what
 ships is a row a owed fragment (its 8-byte id and its mission's
@@ -311,6 +320,16 @@ def _prove_missions(blocks_i, tags_i, r, nu):
                     in_axes=(0, 0, None, 0))(blocks_i, tags_i, nu, r)
 
 
+def _prove_missions_step(mu, sigma, blocks_i, tags_i, r, nu):
+    """A later step of a chunked prove, one row per miner: the running
+    (mu [R, sectors], sigma [R, limbs]) plus the fold of one more chunk
+    of every miner's fragments (``podr2.prove_step_at``)."""
+    from ..ops import podr2
+
+    return jax.vmap(podr2.prove_step_at, in_axes=(0, 0, 0, 0, None, 0))(
+        mu, sigma, blocks_i, tags_i, nu, r)
+
+
 def _verify_missions(ids, r, mu, sigma, idx, nu, alpha, prf_key_data, *,
                      num_blocks: int, prf_impl: str):
     """The verify_agg class's device program, one row per mission: ids
@@ -330,6 +349,7 @@ def _verify_missions(ids, r, mu, sigma, idx, nu, alpha, prf_key_data, *,
 # program, so a shape compiles once however many rounds and engines
 # run it (the engine's ProgramCache counts the shapes it has met)
 _PROVE_PROGRAM = jax.jit(_prove_missions)
+_PROVE_STEP = jax.jit(_prove_missions_step)
 _VERIFY_PROGRAM = jax.jit(_verify_missions,
                           static_argnames=("num_blocks", "prf_impl"))
 
@@ -633,22 +653,25 @@ class SubmissionEngine:
                                tenant: str | None = None) -> EngineFuture:
         """One miner's aggregated proof over its held set: fragments
         [F, bytes], tags [F, blocks, limbs], coefficients r [F] ->
-        future of (mu [sectors], sigma [limbs]). The fragments stay
-        where they are (host memory, no copy of a contiguous uint8
-        array): the batch gathers the round's challenged blocks and
-        their tags from them, and only those go to the device. Requests
-        from miners answering the SAME round (same idx/nu) coalesce
-        into one batch [miners, F-bucket, challenged blocks, ...] for
-        one compiled program; r's zero padding contributes exact
-        modular zeros to the fold, so results are bit-identical."""
+        future of (mu [sectors], sigma [limbs]). Fragments and tags
+        may each be handed in as a sequence of F per-fragment arrays
+        (views of the ``bytes`` a miner's store holds): they are kept
+        so, never stacked. The fragments stay where they are (host
+        memory, no copy): the batch gathers the round's challenged
+        blocks and their tags from them, ``podr2.PROVE_CHUNK``
+        fragments a device step, and only those go to the device.
+        Requests from miners answering the SAME round (same idx/nu)
+        coalesce into one batch [miners, F-bucket, challenged blocks,
+        ...]; r's zero padding contributes exact modular zeros to the
+        fold, so results are bit-identical."""
         self._need_audit()
         from ..ops import podr2
 
-        frags = np.ascontiguousarray(np.asarray(fragments, dtype=np.uint8))
-        tag_arr = np.ascontiguousarray(np.asarray(tags, dtype=np.uint32))
+        frags = podr2.held_rows(fragments, np.uint8, 2)
+        tag_arr = podr2.held_rows(tags, np.uint32, 3)
         r_arr = np.ascontiguousarray(np.asarray(r, dtype=np.uint32))
         sectors = podr2.SECTORS if sectors is None else sectors
-        if frags.ndim != 2 or tag_arr.ndim != 3 or r_arr.ndim != 1 \
+        if r_arr.ndim != 1 \
                 or not frags.shape[0] == tag_arr.shape[0] == r_arr.shape[0]:
             raise ValueError("expected fragments [F, bytes], tags "
                              "[F, blocks, limbs], r [F]")
@@ -819,7 +842,9 @@ class SubmissionEngine:
         same-pattern claims coalescing in the batching window
         (bucket 2) — wider coalescence pads to a bucket that was never
         warmed and pays one cold compile; pass more buckets when many
-        miners race the same restoral order.
+        miners race the same restoral order: a warmed bucket is warmed
+        for every count of claims that pads to it (the slice and the
+        flatten of three claims in a bucket of four as well as four's).
 
         Populates the engine program cache under the exact keys
         ``_op_repair`` will look up, base and per pool lane, and warms
@@ -859,8 +884,12 @@ class SubmissionEngine:
                 # the codec's own call: patterns_new counts batches
                 out = prog.__wrapped__(self._put_rows([], q, bucket, n),
                                        *pattern)
-                jax.block_until_ready(
-                    self._linear_rows_program(out.shape, lane)(out))
+                # the bucket full, then every count of claims that pads
+                # to it: a padded batch's slice and its flatten
+                for total in range(bucket, bucket // 2, -1):
+                    part = out[:total]
+                    jax.block_until_ready(
+                        self._linear_rows_program(part.shape, lane)(part))
 
         for present, missing in patterns:
             for b in buckets:
@@ -1763,15 +1792,17 @@ class SubmissionEngine:
             if isinstance(out, jax.Array):
                 jax.block_until_ready(out)
         with self._stage(cls, "fetch"):
+            pieces = None
             if isinstance(out, jax.Array) \
                     and not any(r.device for r in batch):
                 if out.dtype == np.uint8 and out.ndim == 3:
-                    out = self._fetch_linear(cls, out, lane)
+                    pieces = self._fetch_linear(cls, out, lane, batch)
                 else:
                     out = np.asarray(out)
             results, off = [], 0
-            for r in batch:
-                piece = out[off:off + r.rows]
+            for i, r in enumerate(batch):
+                piece = out[off:off + r.rows] if pieces is None \
+                    else pieces[i]
                 if r.device and not isinstance(piece, jax.Array):
                     piece = jnp.asarray(piece)
                 elif not r.device and isinstance(piece, jax.Array):
@@ -1787,17 +1818,28 @@ class SubmissionEngine:
             self._key(("linear_rows",) + shape, False, lane),
             lambda: _linear_rows)
 
-    def _fetch_linear(self, cls: str, out: jax.Array, lane) -> np.ndarray:
+    def _fetch_linear(self, cls: str, out: jax.Array, lane,
+                      batch: list[_Request]) -> list[np.ndarray]:
         """Fetch a byte result ``[rows, r, n]`` as ``rows * r`` linear
-        rows (_linear_rows, on the device the result is on) and put the
-        C-contiguous ``np.uint8 [rows, r, n]`` back together on the
-        host: a view of the one row, else one ``memcpy`` a row."""
+        rows (_linear_rows, on the device the result is on) and hand
+        each request of the batch its own ``np.uint8 [rows_i, r, n]``:
+        a view of the one row where a request is one row, else one
+        ``memcpy`` a row of that request. The batch is never put back
+        together on the host: sixteen coalesced claims would be one
+        fresh allocation of 128 MiB a batch, faulted in page by page
+        (PERF.md, PR 38: 45 of a batch's 57 ms of fetch at five
+        claims)."""
         flat = [np.asarray(row)
                 for row in self._linear_rows_program(out.shape, lane)(out)]
         with self._lock:
             self.stats.classes[cls].linear_fetches += 1
-        whole = flat[0] if len(flat) == 1 else np.stack(flat)
-        return whole.reshape(out.shape)
+        per, pieces, at = out.shape[1], [], 0
+        for r in batch:
+            rows = flat[at:at + r.rows * per]
+            whole = rows[0] if len(rows) == 1 else np.stack(rows)
+            pieces.append(whole.reshape((r.rows,) + out.shape[1:]))
+            at += len(rows)
+        return pieces
 
     def _rs_backend(self, degraded: bool):
         """The ErasureCodec serving this batch: the configured device
@@ -2042,7 +2084,7 @@ class SubmissionEngine:
         return self._split_rows(batch, out, lane), bucket
 
     def _stacked_program(self, batch, fb: int, rb: int, degraded: bool,
-                         lane, bind):
+                         lane, bind, *which):
         """The stacked classes' cached program for one batch shape:
         the request key WITHOUT its round digest (the digest decides
         which requests coalesce, never which program runs) plus the
@@ -2051,7 +2093,9 @@ class SubmissionEngine:
         jitted program and the operands that are the backend's own
         (its key); the entry adds them to the batch's, counts what it
         hands over (``operand_bytes``) and places the call on the
-        audit backend's device, as every other audit op is."""
+        audit backend's device, as every other audit op is. ``which``
+        names a second program of the same shape (a chunked prove's
+        later steps)."""
         cls = batch[0].cls
         audit = self._audit_backend(degraded, lane)
 
@@ -2067,7 +2111,8 @@ class SubmissionEngine:
                     return fn(*operands)
             return placed
 
-        key = batch[0].key[:-1] + (len(batch[0].aux["idx"]), fb, rb)
+        key = batch[0].key[:-1] + (len(batch[0].aux["idx"]), fb, rb) \
+            + which
         return self.programs.get(self._key(key, degraded, lane), build)
 
     def _op_verify_agg(self, batch, degraded=False, lane=None):
@@ -2178,42 +2223,102 @@ class SubmissionEngine:
         return results, rows.rows_issued
 
     def _op_prove(self, batch, degraded=False, lane=None):
+        """A prove batch, ``podr2.chunk_plan`` fragments a device step:
+        a step's challenged blocks and tag rows are gathered where the
+        miners hold them into one of two reused host buffers (only what
+        the round reads travels; nothing is zero-filled a round: pad
+        rows carry r = 0, exact modular zeros, whatever lies under
+        them), put with the step's call and folded into the running
+        (mu, sigma) on the device; the next step's gather runs
+        meanwhile (``podr2.fold_chunks``). Up to PROVE_CHUNK fragments
+        that is one step of the bucket's program, as ever; past it the
+        chunk is the only shape, so the programs do not grow with F."""
+        from ..ops import podr2
+
         aux = batch[0].aux
-        idx, sectors = aux["idx"], aux["sectors"]
-        with self._stage("prove", "assemble"):
-            fb = bucket_rows(max(r.rows for r in batch))
-            rb = bucket_rows(len(batch))
-            blocks, limbs = batch[0].arrays["tags"].shape[1:]
-            # only what the round reads: the challenged blocks of every
-            # fragment, gathered where the miner holds them. Read as
-            # little-endian uint16 (pfield.pack_bytes' embedding; a free
-            # view on a little-endian host), so the device packs nothing
-            blocks_i = np.zeros((rb, fb, len(idx), sectors), np.uint16)
-            tags_i = np.zeros((rb, fb, len(idx), limbs), np.uint32)
-            rs = np.zeros((rb, fb), dtype=np.uint32)
+        idx, nu, sectors = aux["idx"], aux["nu"], aux["sectors"]
+        fb, chunks = podr2.chunk_plan(max(r.rows for r in batch))
+        rb = bucket_rows(len(batch))
+        limbs = batch[0].arrays["tags"].shape[2]
+        bufs = self._prove_buffers((rb, fb, len(idx), sectors, limbs),
+                                   min(chunks, 2))
+        gathered = 0
+
+        def fill(j, buf):
+            nonlocal gathered
+            blocks_i, tags_i, rs = buf
+            rs[:] = 0
+            lo = j * fb
             for i, r in enumerate(batch):
-                elems = r.arrays["fragments"].view("<u2").reshape(
-                    r.rows, blocks, sectors)
-                # mode="clip": unbuffered writes into the batch (idx
-                # was range-checked at submit)
-                np.take(elems, idx, axis=1, out=blocks_i[i, :r.rows],
-                        mode="clip")
-                np.take(r.arrays["tags"], idx, axis=1,
-                        out=tags_i[i, :r.rows], mode="clip")
-                rs[i, :r.rows] = r.arrays["r"]
-        with self._stage("prove", "dispatch"):
-            prog = self._stacked_program(
-                batch, fb, rb, degraded, lane,
-                lambda audit: (_PROVE_PROGRAM, ()))
-            mu, sigma = prog(blocks_i, tags_i, rs, aux["nu"])
-        with self._stage("prove", "wait"):
-            # as in _op_verify_agg: the fetch would block anyway
-            jax.block_until_ready((mu, sigma))
+                hi = min(r.rows, lo + fb)
+                if hi > lo:
+                    gathered += podr2.gather_challenged(
+                        r.arrays["fragments"], r.arrays["tags"], lo, hi,
+                        idx, blocks_i[i], tags_i[i])
+                    rs[i, :hi - lo] = r.arrays["r"][lo:hi]
+
+        first = self._stacked_program(
+            batch, fb, rb, degraded, lane,
+            lambda audit: (_PROVE_PROGRAM, ()))
+        step = first if chunks == 1 else self._stacked_program(
+            batch, fb, rb, degraded, lane,
+            lambda audit: (_PROVE_STEP, ()), "step")
+
+        def call(acc, buf):
+            if acc is None:
+                return first(*buf, nu)
+            return step(*acc, *buf, nu)
+
+        try:
+            mu, sigma = podr2.fold_chunks(
+                chunks, bufs, fill, call,
+                functools.partial(self._stage, "prove"))
+            with self._stage("prove", "wait"):
+                # as in _op_verify_agg: the fetch would block anyway
+                jax.block_until_ready((mu, sigma))
+        except BaseException:
+            # a step may still read them: the next batch takes new ones
+            self._running.prove_bufs = None
+            raise
+        sink = self._stages_once()
+        with self._lock:
+            st = self.stats.classes["prove"]
+            st.chunks += chunks
+            st.device_calls += chunks
+            st.gathered_bytes += gathered
+            st.gather_seconds += sink.get("engine.prove.assemble",
+                                          (0, 0.0))[1]
         with self._stage("prove", "fetch"):
             mu = np.asarray(mu)
             sigma = np.asarray(sigma)
             results = [(mu[i], sigma[i]) for i in range(len(batch))]
-        return results, rb * fb
+        return results, rb * fb * chunks
+
+    def _prove_buffers(self, shape: tuple, count: int) -> list:
+        """This thread's host buffers of a prove step (the batcher's, a
+        lane worker's): kept from batch to batch while the step's shape
+        stays, so a round touches no fresh pages. Free to fill again:
+        a batch ends with its last step waited for."""
+        from ..ops import podr2
+
+        held = getattr(self._running, "prove_bufs", None)
+        if held is None or held[0] != shape:
+            held = (shape, [])
+        if len(held[1]) < count:
+            held[1].extend(podr2.prove_buffers(shape,
+                                               count - len(held[1])))
+        self._running.prove_bufs = held
+        return held[1][:count]
+
+    def _stages_once(self) -> dict:
+        """The sink of the batch this thread is running, each stage
+        counted once: a batch that entered stages once a device step
+        still counts them once a batch (stats.py: a stage's ``n`` is
+        batches); the seconds are every entry's."""
+        sink = getattr(self._running, "batch", (None,))[0] or {}
+        for acc in sink.values():
+            acc[0] = 1
+        return sink
 
 
 def make_engine(k: int | None = None, m: int | None = None, *,

@@ -82,6 +82,7 @@ class ClassStats:
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
                  "linear_puts", "patterns_new", "matrix_build_s",
                  "missions", "device_calls", "prf_evals",
+                 "chunks", "gathered_bytes", "gather_seconds",
                  "latencies", "hist", "stage_n", "stage_s",
                  "caller_n", "caller_s", "queue_s")
 
@@ -130,6 +131,14 @@ class ClassStats:
         self.missions = 0
         self.device_calls = 0
         self.prf_evals = 0
+        # prove class (engine.py _op_prove): device steps its batches
+        # took (``device_calls`` counts them too: a step is a program
+        # call), the bytes of challenged blocks and tag rows gathered
+        # on the host for them, and the host seconds of those gathers
+        # (the batches' ``assemble`` stage)
+        self.chunks = 0
+        self.gathered_bytes = 0
+        self.gather_seconds = 0.0
         self.latencies = collections.deque(maxlen=LATENCY_WINDOW)
         # real Prometheus histogram of the same submit->resolve
         # latencies: unlike the sliding-window percentiles above this
@@ -317,6 +326,9 @@ class EngineStats:
                 "missions": st.missions,
                 "device_calls": st.device_calls,
                 "prf_evals": st.prf_evals,
+                "chunks": st.chunks,
+                "gathered_bytes": st.gathered_bytes,
+                "gather_seconds": st.gather_seconds,
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
                 "stages": {stage: {"n": st.stage_n[stage],

@@ -402,10 +402,11 @@ def test_host_repairs_go_up_as_linear_rows(kind, k, m, claims, monkeypatch):
 def test_warmed_bucket_pads_with_device_zeros():
     """Three claims in a warmed bucket of four: the fourth request's
     rows are zeros made on the device, and the rows program is the one
-    the warm-up compiled (one a shape, whatever the number of claims;
-    what a padded batch still compiles is on the way down: the slice
-    off the pad and the flatten of three rows, as before PR 32). A
-    host engine's warm_repair has nothing to run."""
+    the warm-up compiled (one a shape, whatever the number of claims).
+    Since PR 38 a warmed bucket is warmed for every count of claims
+    that pads to it, so the way down builds nothing either: the slice
+    off the pad and the flatten of three rows were loaded with the
+    bucket of four. A host engine's warm_repair has nothing to run."""
     from cess_tpu.ops.rs_ref import ReferenceCodec
 
     k, m, n = 2, 1, 704                 # a width no other test compiles
@@ -426,8 +427,9 @@ def test_warmed_bucket_pads_with_device_zeros():
         assert st["classes"]["repair"]["pad_waste"] == 0.25
         assert st["classes"]["repair"]["linear_puts"] == 1
         assert rs._apply_rows._cache_size() == stackers
-        # the flatten of the three rows left after the slice
-        assert st["programs_built"] == built + 1
+        # the flatten of the three rows left after the slice was
+        # warmed with the bucket
+        assert st["programs_built"] == built
     finally:
         eng.close()
     host = make_engine(k, m, rs_backend="cpu",

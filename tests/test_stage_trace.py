@@ -227,12 +227,10 @@ BLOCKING = {"encode": ("encode",),
             "verify": ("verify_aggregate",)}
 
 
-@pytest.mark.parametrize("cls", sorted(CLASSES))
-def test_submit_stages_and_handback_are_the_blocking_call(pkey, cls):
-    """The widened identity, for batches of one request: ``caller.submit``
-    + the six stages + ``caller.handoff`` is the blocking call's own extent
-    (entry of ``engine.reconstruct`` / ... -> its return), within the clock
-    reads between them."""
+def _blocking_accounts(pkey, cls) -> tuple[float, float, int]:
+    """One drive of ``cls`` on an engine of its own, every request alone in
+    its batch -> (the named seconds: ``caller.submit`` + the six stages +
+    ``caller.handoff``; the blocking calls' own extent; requests)."""
     eng = _engine(pkey)
     extent = [0.0]
 
@@ -256,11 +254,28 @@ def test_submit_stages_and_handback_are_the_blocking_call(pkey, cls):
     assert snap["batches"] == snap["completed"] >= 2     # one a batch
     named = snap["caller"]["submit"]["s"] + snap["caller"]["handoff"]["s"] \
         + sum(acc["s"] for acc in snap["stages"].values())
-    # what lies between the accounts is code between two clock reads; a
-    # loaded box may park a thread there, so the room is wide, and still
-    # far below any account left out (the queue alone is 2 ms a request)
-    assert named == pytest.approx(extent[0], rel=0.15,
-                                  abs=1e-3 * snap["completed"])
+    return named, extent[0], snap["completed"]
+
+
+@pytest.mark.parametrize("cls", sorted(CLASSES))
+def test_submit_stages_and_handback_are_the_blocking_call(pkey, cls):
+    """The widened identity, for batches of one request: ``caller.submit``
+    + the six stages + ``caller.handoff`` is the blocking call's own extent
+    (entry of ``engine.reconstruct`` / ... -> its return), within the clock
+    reads between them. A clock identity on a box that runs six test
+    workers: the best of three drives is held to the tolerance, since a
+    thread parked between two clock reads is the box's doing, and an
+    account left out would show in every drive."""
+    for _ in range(3):
+        named, extent, completed = _blocking_accounts(pkey, cls)
+        # what lies between the accounts is code between two clock
+        # reads; a loaded box may park a thread there, so the room is
+        # wide, and still far below any account left out (the queue
+        # alone is 2 ms a request)
+        close = pytest.approx(extent, rel=0.15, abs=1e-3 * completed)
+        if named == close:
+            break
+    assert named == close
 
 
 def _wait_done(fut, seconds=30.0):

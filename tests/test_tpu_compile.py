@@ -201,7 +201,8 @@ def _audit_shapes(f, c):
 
 @pytest.mark.parametrize("f,c,blocks", [
     pytest.param(32, 753, 16384, id="rs2p1-32x8MiB"),
-    pytest.param(16, 376, 8192, id="rs4p8-16x4MiB")])
+    pytest.param(16, 376, 8192, id="rs4p8-16x4MiB"),
+    pytest.param(64, 753, 16384, id="rs2p1-chunk-64x8MiB")])
 def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
     prove, verify = _audit_shapes(f, c)
     programs = (
@@ -219,6 +220,31 @@ def test_audit_programs_compile_for_v5e(one_chip, for_tpu, f, c, blocks):
               f"{mem.argument_size_in_bytes / MiB:.1f} MiB")  # pytest -s
         assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes) < HBM_BYTES, mem
+
+
+def test_prove_step_compiles_for_v5e(one_chip, for_tpu):
+    """A chunked prove's later steps (PR 38, serve/engine.py
+    _prove_missions_step): the running (mu, sigma) plus the fold of one
+    more chunk of ``podr2.PROVE_CHUNK`` fragments' challenged blocks.
+    The chunk is the only shape past a chunk, so this and the first
+    step's program (above, f = 64) are all a miner of any custody
+    runs; the products [F, c, sectors] are fused away, not held."""
+    f, c = podr2.PROVE_CHUNK, 753
+    assert f == 64
+    prove, _ = _audit_shapes(f, c)
+    shapes = [((1, 256), jnp.uint32), ((1, 2), jnp.uint32)] + prove
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    t0 = time.perf_counter()
+    compiled = jax.jit(engine._prove_missions_step).lower(*args).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    mem = compiled.memory_analysis()
+    print(f"temp {mem.temp_size_in_bytes / MiB:.1f} MiB, arguments "
+          f"{mem.argument_size_in_bytes / MiB:.1f} MiB")      # pytest -s
+    # the operands are a chunk's blocks as uint16 and its tags; the
+    # uint32 products of the whole chunk would be 47 MiB more
+    assert mem.temp_size_in_bytes < 40 * MiB, mem
+    _fits_hbm(compiled)
 
 
 @pytest.mark.parametrize("bucket,n", [
@@ -338,6 +364,8 @@ def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, packed):
 @pytest.mark.parametrize("k,m,r,bucket", [
     pytest.param(2, 1, 1, 1, id="one-claim-rs2p1"),
     pytest.param(2, 1, 1, 2, id="two-claims-rs2p1"),
+    pytest.param(2, 1, 1, 8, id="a-storm-of-eight-rs2p1"),
+    pytest.param(2, 1, 1, 16, id="a-storm-of-sixteen-rs2p1"),
     pytest.param(10, 4, 1, 1, id="one-claim-rs10p4"),
     pytest.param(10, 4, 3, 1, id="three-rows-lost-rs10p4")])
 def test_rows_program_compiles_for_v5e(one_chip, for_tpu, k, m, r, bucket):
