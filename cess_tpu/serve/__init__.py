@@ -3,8 +3,9 @@
 A dynamic micro-batching service between every off-chain client
 (OssGateway encode, MinerAgent proving, TeeAgent tagging/verification)
 and the ``ErasureCodec`` / ``AuditBackend`` device gates: bounded
-per-class queues, a size-or-deadline batcher that coalesces ragged
-requests into shape-bucketed device programs, explicit backpressure,
+per-class queues, a work-conserving batcher (a class drains the moment
+something can run its batch, or a size budget fills) that coalesces
+ragged requests into shape-bucketed device programs, explicit backpressure,
 and engine counters on the node metrics surface. See engine.py for
 the full design; the direct synchronous path stays the default
 everywhere an engine is not explicitly configured.

@@ -54,12 +54,19 @@ import threading
 from ..obs import flight as _flight
 from .policy import AdmissionPolicy
 
+# What the per-class delay is seeded from where the static policy has no
+# window (``AdmissionPolicy.max_delay`` None, its default): this
+# controller steers a NUMBER, so it starts from the 2 ms every static
+# policy had until PR 39 and caps at 8x it (delay_cap_s 0.016).
+SEED_DELAY_S = 0.002
+
 
 class AdaptiveBatchPolicy:
     """Per-class batching knobs, latency/occupancy-tuned. See module
     doc.
 
-    policy:        the static AdmissionPolicy supplying seeds + caps.
+    policy:        the static AdmissionPolicy supplying seeds + caps
+                   (a policy without a window seeds SEED_DELAY_S).
     board:         optional obs.SloBoard — classes with an SLO target
                    adapt toward (headroom * p99 objective); others
                    stay on the static constants.
@@ -93,8 +100,10 @@ class AdaptiveBatchPolicy:
         self.update_every = update_every
         self.window = window
         self.min_delay_s = min_delay_s
+        self.seed_delay_s = self.policy.max_delay \
+            if self.policy.max_delay is not None else SEED_DELAY_S
         self.delay_cap_s = delay_cap_s \
-            if delay_cap_s is not None else self.policy.max_delay * 8
+            if delay_cap_s is not None else self.seed_delay_s * 8
         self.shrink = shrink
         self.grow = grow
         self.headroom = headroom
@@ -120,7 +129,7 @@ class AdaptiveBatchPolicy:
         if st is None:
             pol = self.policy
             st = self._classes[cls] = {
-                "delay": pol.max_delay,
+                "delay": self.seed_delay_s,
                 "reqs": pol.max_batch_requests,
                 "rows": pol.max_batch_rows,
                 "lats": collections.deque(maxlen=self.window),

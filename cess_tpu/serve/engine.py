@@ -11,7 +11,12 @@ the serving layer between all of them and the ``ErasureCodec`` /
 - callers ``submit_*`` and get a future back; per-op-class bounded
   queues hold the requests (policy.py: explicit backpressure, class
   priority, deadlines);
-- one batcher thread drains a class on a size-or-deadline trigger,
+- one batcher thread drains a class the moment something can run its
+  batch — its own thread between batches, a free lane on the pool
+  path — or a size budget fills (``_tripped``): an idle engine never
+  makes a lone caller wait, and requests coalesce by arriving while
+  the executors are busy (a numeric ``AdmissionPolicy.max_delay``
+  holds the oldest request that long for companions instead). It
   coalesces coalescible requests (same op, geometry and round
   parameters) into a single device batch, pads the batch to a shape
   bucket (buckets.py: compile-once program cache), launches it, and
@@ -994,8 +999,9 @@ class SubmissionEngine:
 
     def flush(self, timeout: float | None = None) -> bool:
         """Force-drain everything queued and wait until it resolves
-        (no waiting out the coalescing delay). Returns False if the
-        timeout elapses first; queued work keeps draining regardless."""
+        (no waiting out a coalescing window or a busy lane). Returns
+        False if the timeout elapses first; queued work keeps draining
+        regardless."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._cond:
             self._force_drain_locked()
@@ -1214,7 +1220,9 @@ class SubmissionEngine:
                         break
                     ready = self._ready_class(now)
                     if ready is not None:
-                        batch = self._drain(*ready)
+                        cls, trip, trigger = ready
+                        batch = self._drain(cls, trip)
+                        self.stats.classes[cls].drains[trigger] += 1
                         self._inflight += 1
                         break
                     if self._closed:
@@ -1270,11 +1278,12 @@ class SubmissionEngine:
             self._inflight -= 1
             self._cond.notify_all()
 
-    def _knobs(self, cls: str) -> tuple[float, int, int]:
+    def _knobs(self, cls: str) -> tuple[float | None, int, int]:
         """(max_delay, max_batch_requests, max_batch_rows) for this
         class: the live AdaptiveBatchPolicy values when one is
-        configured, else the static policy constants — the one seam
-        through which adaptive control steers the batcher."""
+        configured (its window is always a number), else the static
+        policy constants — the one seam through which adaptive control
+        steers the batcher."""
         ad = self.adaptive
         if ad is not None:
             return ad.knobs(cls)
@@ -1313,19 +1322,21 @@ class SubmissionEngine:
             q.clear()
             q.extend(keep)
 
-    def _ready_class(self, now: float) -> tuple[str, float] | None:
-        """(class to drain now, the instant its trigger tripped), or
-        None to keep waiting.
+    def _ready_class(self, now: float) -> tuple[str, float, str] | None:
+        """(class to drain now, the instant its trigger tripped, the
+        trigger's name), or None to keep waiting.
 
-        A drain happens when ANY class trips a trigger — size
-        (requests or rows), deadline (oldest waited its class's
-        max_delay), an active flush, or engine shutdown (drain
-        everything). Once the device is going to be fed, the
-        HIGHEST-PRIORITY non-empty class goes first regardless of
-        which class tripped: a just-arrived challenge verification
-        preempts the bulk encode whose delay expired (policy.py).
-        Expired requests are gone already (_expire runs first), so
-        deadlines never trigger drains."""
+        A drain happens when ANY class trips a trigger (_tripped):
+        size (requests or rows), an active flush or engine shutdown
+        (drain everything), and the class's window — under the default
+        policy none: the class goes the moment an executor is free;
+        under a numeric ``max_delay``: the oldest waited that long.
+        Once the device is going to be fed, the HIGHEST-PRIORITY
+        non-empty class goes first regardless of which class tripped:
+        a just-arrived challenge verification preempts the bulk encode
+        whose delay expired (policy.py). Expired requests are gone
+        already (_expire runs first), so deadlines never trigger
+        drains."""
         first_nonempty = None
         for cls in CLASSES:               # priority order
             q = self._queues[cls]
@@ -1333,52 +1344,79 @@ class SubmissionEngine:
                 continue
             if first_nonempty is None:
                 first_nonempty = cls
-            trip = self._tripped(q, now, *self._knobs(cls))
-            if trip is not None:
-                return first_nonempty, trip
+            tripped = self._tripped(q, now, *self._knobs(cls))
+            if tripped is not None:
+                return (first_nonempty, *tripped)
         return None
 
-    def _tripped(self, q, now: float, max_delay: float, max_reqs: int,
-                 max_rows: int) -> float | None:
-        """The instant a non-empty queue's drain trigger tripped, None
-        while none has (lock held): the earliest of the start of the
-        flush or close that forces the drain, the enqueue of the
-        request that filled the request budget or the row budget, and
-        the oldest request's enqueue + ``max_delay``. Up to it a member
-        waits because policy says so, from it on for the batcher
-        (``_open_stages``: the queue stage's two halves)."""
-        trip = None
+    def _executor_free(self) -> bool:
+        """Can something run a batch drained now (lock held)? What the
+        engine observes of itself, not a setting: on the inline path
+        the batcher's thread is the one executor, and it asks only
+        between batches; on the pool path a lane has nothing placed on
+        it while fewer batches are in flight than there are lanes
+        (placement is least-loaded, so that lane gets the next one)."""
+        pool = self.pool
+        return self._inflight < (1 if pool is None else pool.n_devices)
+
+    def _tripped(self, q, now: float, max_delay: float | None,
+                 max_reqs: int, max_rows: int
+                 ) -> tuple[float, str] | None:
+        """(the instant a non-empty queue's drain trigger tripped, the
+        trigger: stats.py DRAIN_TRIGGERS), None while none has (lock
+        held). The earliest of: ``forced``, the start of the flush or
+        close that forces the drain; ``size``, the enqueue of the
+        request that filled the request budget or the row budget; and
+        the class's window. Without one (``max_delay`` None, the
+        default policy) that is ``idle``: the earliest enqueue in the
+        queue, as soon as an executor is free (_executor_free) — with every
+        executor busy the class gathers companions, and the batch
+        whose end frees one re-evaluates it (_run's finally,
+        _batch_done: a notify, no timer). With a numeric ``max_delay``
+        it is ``window``: the oldest request's enqueue + ``max_delay``,
+        whatever the device is doing. Of two triggers at one instant
+        the one named first here counts. Up to the instant a member
+        waits because policy says so, from it on for the batcher or a
+        lane (``_open_stages``: the queue stage's two halves)."""
+        trips = []
         if self._closed or self._flushing:
-            trip = self._forced_t
+            trips.append((self._forced_t, "forced"))
         if len(q) >= max_reqs:
-            t = q[max_reqs - 1].enqueue_t
-            trip = t if trip is None else min(trip, t)
-        t = q[0].enqueue_t + max_delay
-        if t <= now:
-            trip = t if trip is None else min(trip, t)
+            trips.append((q[max_reqs - 1].enqueue_t, "size"))
         rows = 0
         for r in q:
             rows += r.rows
             if rows >= max_rows:
-                trip = r.enqueue_t if trip is None \
-                    else min(trip, r.enqueue_t)
+                trips.append((r.enqueue_t, "size"))
                 break
-        return trip
+        if max_delay is None:
+            if self._executor_free():
+                # the earliest stamp, not q[0]'s: a request is stamped
+                # before it takes the lock, so two can queue out of
+                # order, and no member of an idle drain waited on policy
+                trips.append((min(r.enqueue_t for r in q), "idle"))
+        elif q[0].enqueue_t + max_delay <= now:
+            trips.append((q[0].enqueue_t + max_delay, "window"))
+        # min keeps the first of equals: the order above breaks ties
+        return min(trips, key=lambda trip: trip[0], default=None)
 
     def _wake_timeout(self, now: float) -> float | None:
-        wake = None
+        """Seconds until the batcher has to look again without being
+        notified (lock held), None for never: the earliest request
+        deadline and, for a class with a numeric window, the earliest
+        enqueue + ``max_delay``. A class without a window sets no
+        timer: a submit, a finished batch, a flush or a close notify."""
+        times = []
         for cls, q in self._queues.items():
             if not q:
                 continue
             max_delay = self._knobs(cls)[0]
             for r in q:
-                t = r.enqueue_t + max_delay
+                if max_delay is not None:
+                    times.append(r.enqueue_t + max_delay)
                 if r.deadline is not None:
-                    t = min(t, r.deadline)
-                wake = t if wake is None else min(wake, t)
-        if wake is None:
-            return None
-        return max(wake - now, 0.0)
+                    times.append(r.deadline)
+        return max(min(times) - now, 0.0) if times else None
 
     # ops that pad every request's OWN row axis to the batch-wide
     # bucket (stacked, not concatenated): cap the bucket spread so one

@@ -76,9 +76,19 @@ class AdmissionPolicy:
     max_batch_rows:     row budget per device batch (padding bucket
                         ceiling; requests beyond it wait for the next
                         batch).
-    max_delay:          deadline trigger, seconds — the oldest queued
-                        request never waits longer than this for
-                        companions before its batch launches.
+    max_delay:          the coalescing window. ``None`` (the default):
+                        no fixed window — a non-empty class drains
+                        the moment something can run its batch (the
+                        batcher's own thread on the inline path, a
+                        lane with nothing placed on it on the pool
+                        path) and gathers companions only while
+                        every executor is busy, so an idle engine
+                        never makes a lone caller wait. A float,
+                        seconds: the oldest queued request waits up
+                        to it for companions whatever the device is
+                        doing, then its batch launches (an operator
+                        or a test that wants requests held on an
+                        idle engine passes one).
     default_timeout:    deadline applied to requests submitted without
                         one (None = no deadline).
     """
@@ -86,10 +96,11 @@ class AdmissionPolicy:
     queue_cap: int = 256
     max_batch_requests: int = 32
     max_batch_rows: int = 512
-    max_delay: float = 0.002
+    max_delay: float | None = None
     default_timeout: float | None = None
 
     def __post_init__(self):
         if self.queue_cap < 1 or self.max_batch_requests < 1 \
-                or self.max_batch_rows < 1 or self.max_delay < 0:
+                or self.max_batch_rows < 1 \
+                or (self.max_delay is not None and self.max_delay < 0):
             raise ValueError("invalid admission policy bounds")

@@ -65,15 +65,32 @@ CALLER = ("submit", "handoff")
 
 # The two halves of ``queue``, summed over members like it:
 #   coalesce  each member's enqueue -> the instant the drain trigger
-#             tripped (the oldest member's enqueue + max_delay, the
-#             enqueue of the request that filled a size budget, the
-#             start of a flush or close): the wait policy asks for
+#             tripped (DRAIN_TRIGGERS below): the wait policy asks for.
+#             Under the default policy, which has no window, a member
+#             of an ``idle`` drain waited 0: the trigger trips at the
+#             oldest member's enqueue
 #   wake      from there until the batch starts to run: the batcher
 #             asleep, busy with another batch or waiting for the GIL
 #             (lane wait on the pool path)
 # ``coalesce + wake == queue``, exactly: the queue stage's total is
 # kept as the sum of the two accumulators (ClassStats.add_stages).
 QUEUE_PARTS = ("coalesce", "wake")
+
+# What tripped a drain (serve/engine.py _tripped), counted per class and
+# drain (``drains``; ``cess_engine_<cls>_drains_<trigger>_total``):
+#   idle    no window (``AdmissionPolicy.max_delay`` None, the default):
+#           an executor was free, so the class went at once; the instant
+#           is the oldest member's enqueue. Batches behind a busy
+#           executor count here too, when it comes free: their members
+#           gathered while it ran (their wait is ``wake``)
+#   window  a numeric ``max_delay``: the oldest member's enqueue + it
+#   size    the enqueue of the request that filled the request budget
+#           or the row budget
+#   forced  the start of the flush or close that forces the drain
+# The class drained is the highest-priority non-empty one, which need
+# not be the class whose trigger tripped (_ready_class): the count goes
+# to the class that was drained.
+DRAIN_TRIGGERS = ("idle", "window", "size", "forced")
 
 
 class ClassStats:
@@ -84,7 +101,7 @@ class ClassStats:
                  "missions", "device_calls", "prf_evals",
                  "chunks", "gathered_bytes", "gather_seconds",
                  "latencies", "hist", "stage_n", "stage_s",
-                 "caller_n", "caller_s", "queue_s")
+                 "caller_n", "caller_s", "queue_s", "drains")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -154,6 +171,8 @@ class ClassStats:
         self.caller_n = dict.fromkeys(CALLER, 0)
         self.caller_s = dict.fromkeys(CALLER, 0.0)
         self.queue_s = dict.fromkeys(QUEUE_PARTS, 0.0)
+        # drains of this class by what tripped them (DRAIN_TRIGGERS)
+        self.drains = dict.fromkeys(DRAIN_TRIGGERS, 0)
 
     def add_stages(self, sink: dict) -> None:
         """Merge one batch's stage sink (``{"engine.<cls>.<stage>":
@@ -340,6 +359,7 @@ class EngineStats:
                 "queue": {part: {"n": st.stage_n["queue"],
                                  "s": st.queue_s[part]}
                           for part in QUEUE_PARTS},
+                "drains": dict(st.drains),
             }
         if self.streams:
             out["streams"] = [s.snapshot() for s in self.streams]
@@ -370,6 +390,8 @@ class EngineStats:
                 out[f"cess_engine_{cls}_caller_{acct}_count"] = acc["n"]
             for part, acc in st.pop("queue").items():
                 out[f"cess_engine_{cls}_queue_{part}_seconds"] = acc["s"]
+            for trigger, n in st.pop("drains").items():
+                out[f"cess_engine_{cls}_drains_{trigger}_total"] = n
             for name, val in st.items():
                 out[f"cess_engine_{cls}_{name}"] = val
         if self.streams:

@@ -9,6 +9,7 @@ compile cache (cess_tpu/jaxcache.py) is NOT turned on for tests.
 """
 import os
 import sys
+import threading
 
 import jax
 import jax.monitoring
@@ -38,6 +39,53 @@ def compiles():
     strategies keep no executable of their own to count hits of: their
     programs are jit's)."""
     return lambda: _COMPILES[0]
+
+
+class _Gate:
+    """Holds an engine's runner of one op at its entry until it is
+    opened: a batch "runs", and its executor stays busy, for as long
+    as the test wants, whatever the box's speed. ``entered`` counts
+    the batches that reached the runner."""
+
+    def __init__(self, eng, op):
+        self.entered = threading.Semaphore(0)
+        self.opened = threading.Event()
+        real = getattr(eng, f"_op_{op}")
+
+        def runner(*args):
+            self.entered.release()
+            assert self.opened.wait(60)
+            return real(*args)
+
+        setattr(eng, f"_op_{op}", runner)
+
+    def running(self) -> bool:
+        """Wait until one more batch is inside the runner."""
+        return self.entered.acquire(timeout=60)
+
+    def open(self):
+        self.opened.set()
+
+
+@pytest.fixture
+def gate():
+    """``gate(eng, op)``: a _Gate on that engine's ``_op_<op>``. The
+    test opens it before it closes the engine (a close drains)."""
+    return _Gate
+
+
+@pytest.fixture
+def queue_accounts():
+    """``queue_accounts(eng, cls)``: the class's snapshot after a
+    flush, held to ``coalesce + wake == queue`` exactly (the queue's
+    total is kept as the sum of its halves: serve/stats.py)."""
+    def read(eng, cls):
+        eng.flush()
+        snap = eng.stats_snapshot()["classes"][cls]
+        assert snap["queue"]["coalesce"]["s"] + snap["queue"]["wake"]["s"] \
+            == snap["stages"]["queue"]["s"]
+        return snap
+    return read
 
 
 def pytest_configure(config):

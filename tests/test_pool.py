@@ -19,7 +19,8 @@ from cess_tpu.obs import flight
 from cess_tpu.ops import podr2, rs
 from cess_tpu.resilience import ResilienceConfig, faults
 from cess_tpu.resilience.faults import FaultPlan
-from cess_tpu.serve import AdmissionPolicy, DevicePool, make_engine
+from cess_tpu.serve import (AdmissionPolicy, DevicePool, EngineTimeout,
+                            make_engine)
 
 K, M = 2, 1
 FRAG = 1024
@@ -30,10 +31,10 @@ def rnd(shape, seed=0, dtype=np.uint8):
     return rng.integers(0, np.iinfo(dtype).max, shape, dtype=dtype)
 
 
-def _pool_engine(n=2, res=None, pkey=None):
+def _pool_engine(n=2, res=None, pkey=None,
+                 policy=AdmissionPolicy(max_delay=0.002)):
     return make_engine(K, M, rs_backend="jax", podr2_key=pkey,
-                       resilience=res,
-                       policy=AdmissionPolicy(max_delay=0.002),
+                       resilience=res, policy=policy,
                        pool=DevicePool(n=n))
 
 
@@ -291,6 +292,111 @@ def test_host_rows_and_result_stay_on_the_batch_lane(compiles, monkeypatch):
         assert st["linear_puts"] == st["batches"] == 2
     finally:
         eng.close()
+
+
+# -- the drain trigger on the pool path (PR 39) ------------------------------
+# (the ``gate`` fixture, tests/conftest.py, keeps a lane busy for as long
+# as a test wants; ``policy=None`` is the default policy: no window)
+
+def test_a_free_lane_drains_at_once_and_busy_lanes_gather(gate, queue_accounts):
+    """The default policy on the pool path: a request goes the moment a
+    lane has nothing placed on it; with every lane busy the class
+    gathers, and the batch whose end frees a lane takes all of it."""
+    codec = rs.make_codec(K, M, backend="cpu")
+    data = rnd((1, K, 256), 70)
+    eng = _pool_engine(2, policy=None)
+    held = gate(eng, "encode")
+    try:
+        a = eng.submit_encode(data)
+        assert held.running()               # lane 0 is busy
+        b = eng.submit_encode(data)
+        assert held.running()               # ...lane 1 took b at once
+        rest = [eng.submit_encode(data) for _ in range(3)]
+        # both lanes busy: no third batch reaches a runner, they gather
+        assert not held.entered.acquire(timeout=0.05)
+        assert eng.stats_snapshot()["classes"]["encode"]["queue_depth"] == 3
+        assert [row[4] for row in eng.pool.placement_log()] == [0, 1]
+        held.open()
+        for f in [a, b] + rest:
+            assert np.array_equal(f.result(timeout=60), codec.encode(data))
+        snap = queue_accounts(eng, "encode")
+        placed = eng.pool.placement_log()
+    finally:
+        held.open()
+        eng.close()
+    # a | b | the three that gathered, as one batch (no timer: the
+    # end of a batch re-evaluated the class)
+    assert (snap["completed"], snap["batches"]) == (5, 3)
+    assert [row[2] for row in placed] == [1, 1, 3]
+    assert snap["queue"]["coalesce"]["s"] == 0.0
+    assert snap["drains"] == {"idle": 3, "window": 0, "size": 0,
+                              "forced": 0}
+
+
+def test_a_deadline_expires_while_gathering_behind_busy_lanes(gate, queue_accounts):
+    eng = _pool_engine(1, policy=None)
+    held = gate(eng, "encode")
+    try:
+        a = eng.submit_encode(rnd((1, K, 256), 71))
+        assert held.running()
+        late = eng.submit_encode(rnd((1, K, 256), 72), timeout=0.05)
+        kept = eng.submit_encode(rnd((1, K, 256), 73))
+        with pytest.raises(EngineTimeout):
+            late.result(timeout=30)         # the batcher's own timer
+        assert not a.done() and not kept.done()
+        held.open()
+        a.result(timeout=60), kept.result(timeout=60)
+        snap = queue_accounts(eng, "encode")
+    finally:
+        held.open()
+        eng.close()
+    assert snap["timeouts"] == 1 and snap["completed"] == 2
+
+
+def test_a_higher_class_goes_first_when_the_lane_comes_free(gate):
+    pkey = podr2.Podr2Key.generate(26)
+    eng = _pool_engine(1, pkey=pkey, policy=None)
+    held = gate(eng, "encode")
+    order: list[str] = []
+    real_verify = eng._op_verify_batch
+    eng._op_verify_batch = lambda *a: (order.append("verify"),
+                                       real_verify(*a))[1]
+    try:
+        first = eng.submit_encode(rnd((1, K, 256), 74))
+        assert held.running()
+        f_enc = eng.submit_encode(rnd((1, K, 256), 75))     # gathers
+        blocks = FRAG // podr2.BLOCK_BYTES
+        idx, nu = podr2.gen_challenge(b"round-39", blocks)
+        f_ver = eng.submit_verify_batch(                    # LATER
+            np.zeros((1, 2), np.uint32), blocks, idx, nu,
+            np.zeros((1, podr2.SECTORS), np.uint32),
+            np.zeros((1, podr2.LIMBS), np.uint32))
+        real_encode, eng._op_encode = eng._op_encode, \
+            lambda *a: (order.append("encode"), real_encode(*a))[1]
+        held.open()
+        for f in (first, f_enc, f_ver):
+            f.result(timeout=60)
+    finally:
+        held.open()
+        eng.close()
+    assert order == ["verify", "encode"]
+
+
+def test_a_numeric_window_holds_a_request_before_free_lanes(gate, queue_accounts):
+    eng = _pool_engine(2, policy=AdmissionPolicy(max_delay=30.0))
+    held = gate(eng, "encode")
+    held.open()
+    try:
+        fut = eng.submit_encode(rnd((1, K, 256), 76))
+        assert not held.entered.acquire(timeout=0.05)
+        assert eng.stats_snapshot()["classes"]["encode"]["queue_depth"] == 1
+        assert eng.flush(60)
+        fut.result(timeout=60)
+        snap = queue_accounts(eng, "encode")
+    finally:
+        eng.close()
+    assert snap["drains"] == {"idle": 0, "window": 0, "size": 0,
+                              "forced": 1}
 
 
 # -- surfaces: zero-cost default, snapshot, metrics, lifecycle --------------

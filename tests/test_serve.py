@@ -7,15 +7,20 @@ BIT-IDENTICAL to the direct ErasureCodec / AuditBackend calls —
 the engine decides WHEN and HOW BATCHED device work runs, never what
 it computes (protocol determinism, like the codec gate itself).
 """
+import collections
 import contextlib
+import sys
 import threading
+import types
 
 import numpy as np
 import pytest
 
 from cess_tpu.ops import podr2, rs
-from cess_tpu.serve import (AdmissionPolicy, EngineClosed,
-                            EngineSaturated, EngineTimeout, make_engine)
+from cess_tpu.serve import (AdaptiveBatchPolicy, AdmissionPolicy,
+                            EngineClosed, EngineSaturated, EngineTimeout,
+                            make_engine)
+from cess_tpu.serve.engine import SubmissionEngine
 
 K, M = 2, 1
 FRAG = 1024               # bytes per fragment -> 2 PoDR2 blocks
@@ -925,6 +930,329 @@ def test_mixed_shapes_do_not_cross_coalesce(pkey):
         assert eng.stats_snapshot()["classes"]["encode"]["batches"] == 2
     finally:
         eng.close()
+
+
+# -- the drain trigger (PR 39): an idle engine does not make its caller wait -
+
+OP_OF = {"encode": "encode", "repair": "repair", "tag": "tag",
+         "prove": "prove", "verify": "verify_agg"}
+
+
+@pytest.fixture(scope="module")
+def round_(pkey):
+    """One request's worth of input for every class (same key every
+    call, so requests of one class coalesce)."""
+    frags = rnd((2, FRAG), 61)
+    ids = np.stack([podr2.fragment_id_from_hash(bytes([60 + i]) * 32)
+                    for i in range(2)])
+    tags = np.asarray(podr2.tag_fragments(pkey, ids, frags))
+    blocks = tags.shape[1]
+    idx, nu = (np.asarray(a)
+               for a in podr2.gen_challenge(b"round-39", blocks))
+    r = np.asarray(podr2.aggregate_coeffs(b"round-39", ids))
+    mu, sigma = (np.asarray(a) for a in
+                 podr2.prove_aggregate(frags, tags, idx, nu, r))
+    coded = rs.make_codec(K, M, backend="cpu").encode(rnd((1, K, 256), 62))
+    return {
+        "encode": lambda e: e.submit_encode(coded[:, :K]),
+        "repair": lambda e: e.submit_reconstruct(coded[:, 1:], (1, 2), (0,)),
+        "tag": lambda e: e.submit_tag(ids, frags),
+        "prove": lambda e: e.submit_prove_aggregate(frags, tags, idx, nu, r),
+        "verify": lambda e: e.submit_verify_aggregate(
+            ids, blocks, idx, nu, r, mu, sigma),
+    }
+
+
+@pytest.mark.parametrize("cls", sorted(OP_OF))
+def test_lone_request_on_an_idle_engine_drains_at_once(pkey, round_, cls,
+                                                       queue_accounts):
+    """The default policy has no window: a lone caller's request trips
+    at its own enqueue (``idle``), waits on no policy at all, and the
+    batcher sets no timer for it."""
+    eng = make_engine(K, M, podr2_key=pkey)
+    try:
+        assert eng.policy.max_delay is None
+        for _ in range(3):
+            round_[cls](eng).result(timeout=60)
+        snap = queue_accounts(eng, cls)
+        metrics = eng.stats_metrics()
+    finally:
+        eng.close()
+    assert snap["completed"] == snap["batches"] == 3
+    assert snap["queue"]["coalesce"]["s"] == 0.0
+    assert snap["queue"]["wake"]["s"] > 0.0
+    assert snap["drains"] == {"idle": 3, "window": 0, "size": 0,
+                              "forced": 0}
+    for trigger, n in snap["drains"].items():
+        assert metrics[f"cess_engine_{cls}_drains_{trigger}_total"] == n
+    assert f"cess_engine_{cls}_drains" not in metrics
+
+
+@pytest.mark.parametrize("cls", sorted(OP_OF))
+def test_requests_behind_a_running_batch_leave_together(
+        pkey, round_, cls, gate, queue_accounts):
+    """Companions are gathered while the executor is busy, not by a
+    timer: what arrives while a batch runs leaves as ONE batch the
+    moment the batcher is free, and none of it waited on policy."""
+    eng = make_engine(K, M, podr2_key=pkey)
+    held = gate(eng, OP_OF[cls])
+    try:
+        first = round_[cls](eng)
+        assert held.running()               # the batcher is busy now
+        rest = [round_[cls](eng) for _ in range(3)]
+        assert eng.stats_snapshot()["classes"][cls]["queue_depth"] == 3
+        held.open()
+        want = first.result(timeout=60)
+        for f in rest:
+            got = f.result(timeout=60)
+            assert all(np.array_equal(a, b) for a, b in zip(
+                got if isinstance(got, tuple) else (got,),
+                want if isinstance(want, tuple) else (want,)))
+        snap = queue_accounts(eng, cls)
+    finally:
+        held.open()
+        eng.close()
+    assert snap["completed"] == 4 and snap["batches"] == 2
+    assert snap["batch_occupancy"] == 2.0       # 1, then 3 together
+    assert snap["queue"]["coalesce"]["s"] == 0.0
+    assert snap["drains"]["idle"] == 2 and sum(snap["drains"].values()) == 2
+
+
+def test_gathered_requests_split_by_key_and_budget(gate, queue_accounts):
+    """What gathered behind a busy batcher still coalesces by key and
+    within the budgets: three of one geometry under a budget of two
+    leave as 2 + 1, the other geometry on its own."""
+    codec = rs.make_codec(K, M, backend="cpu")
+    eng = make_engine(K, M, policy=AdmissionPolicy(max_batch_requests=2))
+    held = gate(eng, "encode")
+    try:
+        a, b = rnd((1, K, 128), 1), rnd((1, K, 256), 2)
+        first = eng.submit_encode(a)
+        assert held.running()
+        futs = [eng.submit_encode(x) for x in (a, b, a, a)]
+        held.open()
+        for x, f in zip((a, a, b, a, a), [first] + futs):
+            assert np.array_equal(f.result(timeout=60), codec.encode(x))
+        snap = queue_accounts(eng, "encode")
+    finally:
+        held.open()
+        eng.close()
+    # a | a a | b | a
+    assert (snap["completed"], snap["batches"]) == (5, 4)
+    assert snap["queue"]["coalesce"]["s"] == 0.0
+    assert sum(snap["drains"].values()) == 4 and snap["drains"]["window"] == 0
+
+
+@pytest.mark.parametrize("max_delay,trigger", [(30.0, "forced"),
+                                               (0.01, "window")])
+def test_a_numeric_window_holds_a_request_on_an_idle_engine(
+        max_delay, trigger, gate, queue_accounts):
+    """A float keeps the behaviour it had: the oldest request waits up
+    to it whatever the device does — on an idle engine too — and a
+    flush cuts it short."""
+    eng = make_engine(K, M, policy=AdmissionPolicy(max_delay=max_delay))
+    held = gate(eng, "encode")
+    held.open()
+    try:
+        fut = eng.submit_encode(rnd((1, K, 64), 3))
+        if trigger == "forced":
+            # held: no batch reaches the runner, the request stays queued
+            assert not held.entered.acquire(timeout=0.05)
+            snap = eng.stats_snapshot()["classes"]["encode"]
+            assert snap["queue_depth"] == 1
+            assert not any(snap["drains"].values())
+            assert eng.flush(60)
+        fut.result(timeout=60)
+        snap = queue_accounts(eng, "encode")
+    finally:
+        eng.close()
+    assert snap["drains"] == {**dict.fromkeys(snap["drains"], 0),
+                              trigger: 1}
+    if trigger == "window":
+        assert snap["queue"]["coalesce"]["s"] \
+            == pytest.approx(max_delay, rel=1e-6)
+    else:
+        assert 0.0 < snap["queue"]["coalesce"]["s"] < max_delay
+
+
+def test_a_higher_class_that_arrives_while_a_lower_one_gathers_goes_first(
+        pkey, round_, gate):
+    """Priority across classes is untouched: with the batcher busy, an
+    encode gathers first and a verify arrives later, and the verify
+    goes to the device first."""
+    eng = make_engine(K, M, podr2_key=pkey)
+    held = gate(eng, "encode")
+    order: list[str] = []
+    real_verify = eng._op_verify_agg
+    eng._op_verify_agg = lambda *a: (order.append("verify"),
+                                     real_verify(*a))[1]
+    try:
+        first = round_["encode"](eng)
+        assert held.running()
+        f_enc = round_["encode"](eng)       # gathers behind the batch
+        f_ver = round_["verify"](eng)       # ...and arrives LATER
+        real_encode, eng._op_encode = eng._op_encode, \
+            lambda *a: (order.append("encode"), real_encode(*a))[1]
+        held.open()
+        for f in (first, f_enc, f_ver):
+            f.result(timeout=60)
+    finally:
+        held.open()
+        eng.close()
+    assert order == ["verify", "encode"]
+
+
+def test_many_closed_loops_on_the_default_policy(queue_accounts):
+    """More client threads than cores, each a closed loop, on an engine
+    without a window, under a short switch interval: every result is
+    the direct codec's, every drain is counted once under one trigger,
+    batches coalesce (they meet while a batch runs) and nobody waited
+    on policy."""
+    codec = rs.make_codec(K, M, backend="cpu")
+    eng = make_engine(K, M)
+    n_threads, rounds = 16, 12
+    datas = [rnd((1, K, 128), 200 + i) for i in range(n_threads)]
+    want = [codec.encode(d) for d in datas]
+    bad: list = []
+
+    def client(i):
+        for _ in range(rounds):
+            if not np.array_equal(eng.encode(datas[i], timeout=60), want[i]):
+                bad.append(i)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+        snap = queue_accounts(eng, "encode")
+    finally:
+        sys.setswitchinterval(interval)
+        eng.close()
+    assert not bad
+    assert snap["submitted"] == snap["completed"] == n_threads * rounds
+    assert sum(snap["drains"].values()) == snap["batches"] < snap["completed"]
+    assert snap["drains"]["window"] == snap["drains"]["forced"] == 0
+    assert snap["queue"]["coalesce"]["s"] == 0.0
+
+
+def _bare_engine(inflight=0, lanes=None, forced_t=None):
+    """The trigger's own inputs on an engine that runs no thread."""
+    eng = object.__new__(SubmissionEngine)
+    eng._closed, eng._flushing = False, int(forced_t is not None)
+    eng._forced_t = forced_t or 0.0
+    eng._inflight = inflight
+    eng.pool = None if lanes is None \
+        else types.SimpleNamespace(n_devices=lanes)
+    return eng
+
+
+@pytest.mark.parametrize("case,kw,max_delay,budgets,want", [
+    # the default policy: no window
+    ("inline, free", {}, None, (8, 8), (10.0, "idle")),
+    ("inline, busy", {"inflight": 1}, None, (8, 8), None),
+    ("pool, a lane free", {"inflight": 1, "lanes": 2}, None, (8, 8),
+     (10.0, "idle")),
+    ("pool, every lane busy", {"inflight": 2, "lanes": 2}, None, (8, 8),
+     None),
+    ("busy, flushed", {"inflight": 1, "forced_t": 10.7}, None, (8, 8),
+     (10.7, "forced")),
+    ("free, flushed later", {"forced_t": 10.7}, None, (8, 8),
+     (10.0, "idle")),
+    # a numeric window: as it was, whatever the executors do
+    ("window open", {}, 1.5, (8, 8), None),
+    ("window over", {}, 0.75, (8, 8), (10.75, "window")),
+    ("window over, busy", {"inflight": 1}, 0.75, (8, 8),
+     (10.75, "window")),
+    ("window open, flushed", {"forced_t": 10.7}, 1.5, (8, 8),
+     (10.7, "forced")),
+    ("zero window", {}, 0.0, (8, 8), (10.0, "window")),
+    # the size budgets (requests, rows) beside either
+    ("requests filled, busy", {"inflight": 1}, None, (2, 8),
+     (10.5, "size")),
+    ("rows filled, busy", {"inflight": 1}, None, (8, 2), (10.5, "size")),
+    ("rows filled before the window", {}, 1.5, (8, 2), (10.5, "size")),
+    ("the window before the rows", {}, 0.25, (8, 2), (10.25, "window")),
+    # one instant, two triggers: the one named first counts
+    ("one request over the budget", {}, None, (1, 8), (10.0, "size")),
+    ("filled, free: the oldest's enqueue", {}, None, (2, 8),
+     (10.0, "idle")),
+])
+def test_the_trigger_and_its_instant(case, kw, max_delay, budgets, want):
+    """_tripped over two queued requests of a row each (enqueued at
+    10.0 and 10.5, now 11.0): which trigger, and when."""
+    q = [types.SimpleNamespace(enqueue_t=t, rows=1) for t in (10.0, 10.5)]
+    assert _bare_engine(**kw)._tripped(q, 11.0, max_delay, *budgets) == want
+
+
+def test_an_idle_drain_trips_at_the_earliest_stamp_in_the_queue():
+    """A request is stamped before it takes the engine's lock, so two
+    can queue out of order; no member of an idle drain waited on
+    policy, so the instant is the earliest stamp, not q[0]'s (a numeric
+    window still counts from q[0], as it did)."""
+    q = [types.SimpleNamespace(enqueue_t=t, rows=1) for t in (10.5, 10.0)]
+    eng = _bare_engine()
+    assert eng._tripped(q, 11.0, None, 8, 8) == (10.0, "idle")
+    assert eng._tripped(q, 11.0, 0.25, 8, 8) == (10.75, "window")
+
+
+@pytest.mark.parametrize("max_delay,want", [(None, 0.25), (0.125, 0.125),
+                                            (30.0, 0.25)])
+def test_the_batcher_sets_a_timer_only_for_a_window_or_a_deadline(
+        max_delay, want):
+    """_wake_timeout: a class without a window wakes the batcher for
+    deadlines alone (None: it sleeps until it is notified)."""
+    eng = _bare_engine()
+    eng.adaptive = None
+    eng.policy = AdmissionPolicy(max_delay=max_delay)
+    eng._queues = {"encode": collections.deque(
+        [types.SimpleNamespace(enqueue_t=10.0, deadline=None)])}
+    if max_delay is None:
+        assert eng._wake_timeout(10.0) is None
+    eng._queues["verify"] = collections.deque(
+        [types.SimpleNamespace(enqueue_t=10.0, deadline=10.25)])
+    assert eng._wake_timeout(10.0) == want
+
+
+def test_adaptive_over_the_default_policy_seeds_and_caps_as_over_2ms():
+    """AdaptiveBatchPolicy steers a number: over a static policy
+    without a window it starts from 0.002 and caps at 0.016, and walks
+    the same way under the same observations."""
+    def walk(policy):
+        ad = AdaptiveBatchPolicy(policy, targets={"encode": 1.0},
+                                 update_every=4, window=8)
+        seeded = ad.knobs("encode")
+        for _ in range(64):             # headroom, under-occupied: grow
+            ad.note("encode", 0.001, occupancy=1)
+        return seeded, ad.delay_cap_s, ad.knobs("encode"), \
+            ad.adjustment_log()
+
+    default = walk(AdmissionPolicy())
+    assert default == walk(AdmissionPolicy(max_delay=0.002))
+    assert default[0] == (0.002, 32, 512) and default[1] == 0.016
+    assert default[2][0] == 0.016                   # grew to the cap
+    # an explicit window still seeds itself
+    assert walk(AdmissionPolicy(max_delay=0.005))[:2] \
+        == ((0.005, 32, 512), 0.04)
+
+
+def test_an_adaptive_engine_over_the_default_policy_keeps_a_window(
+        queue_accounts):
+    eng = make_engine(K, M, adaptive=AdaptiveBatchPolicy())
+    try:
+        assert eng.policy.max_delay is None
+        assert eng._knobs("encode") == (0.002, 32, 512)
+        eng.encode(rnd((1, K, 64), 5))
+        snap = queue_accounts(eng, "encode")
+    finally:
+        eng.close()
+    assert snap["drains"]["window"] == 1 and snap["drains"]["idle"] == 0
+    assert snap["queue"]["coalesce"]["s"] == pytest.approx(0.002, rel=1e-6)
 
 
 # -- observability surface ---------------------------------------------------
