@@ -63,6 +63,12 @@ def collect(node) -> dict[str, float]:
     gateway = getattr(node, "gateway", None)
     if gateway is not None:
         m.update(gateway.metrics())
+    # a miner's restoral counters (node/offchain.py MinerAgent): bytes
+    # that came in for its repairs against bytes recovered, fallbacks,
+    # and every repair stage's seconds, when the node's process runs one
+    miner = getattr(node, "miner", None)
+    if miner is not None:
+        m.update(miner.metrics())
     # the process's PoDR2 round derivations (ops/podr2.py gen_challenge
     # / aggregate_coeffs: host seconds, calls and, as
     # cess_podr2_challenge_programs / cess_podr2_coeffs_programs, the

@@ -112,6 +112,32 @@ def _hashed_copy(row, sinks: dict, span) -> tuple[bytes, bytes]:
     return blob, _hashed(blob, sinks, span)
 
 
+def _merge_sinks(stages: dict, *sinks: dict) -> None:
+    """obs.trace.stage sinks (``name -> [count, seconds]``) added into
+    an agent's totals; the caller holds the lock that guards them."""
+    for sink in sinks:
+        for name, (count, seconds) in sink.items():
+            acc = stages.setdefault(name, [0, 0.0])
+            acc[0] += count
+            acc[1] += seconds
+
+
+def _series(role: str, counters: dict) -> dict[str, float]:
+    """An agent's ``counters()`` as ``cess_<role>_<name>_total`` series
+    and, a stage, ``cess_<role>_stage_<name>_seconds`` / ``_count``
+    (``<name>``: the stage's without its first part, dots as
+    underscores)."""
+    counts = counters.pop("stage_count")
+    seconds = counters.pop("stage_seconds")
+    out = {f"cess_{role}_{name}_total": float(value)
+           for name, value in counters.items()}
+    for stage, n in counts.items():
+        name = stage.partition(".")[2].replace(".", "_")
+        out[f"cess_{role}_stage_{name}_seconds"] = seconds[stage]
+        out[f"cess_{role}_stage_{name}_count"] = float(n)
+    return out
+
+
 class OssGateway:
     """The user-facing gateway: chunk -> encode -> tag -> declare.
 
@@ -188,15 +214,7 @@ class OssGateway:
         underscores: ``upload``, ``encode_put``, ``worker_copy``):
         merged into GET /metrics when the node carries ``node.gateway
         = gw`` (node/metrics.py collect())."""
-        c = self.counters()
-        counts, seconds = c.pop("stage_count"), c.pop("stage_seconds")
-        out = {f"cess_gateway_{name}_total": float(value)
-               for name, value in c.items()}
-        for stage, n in counts.items():
-            name = stage.partition(".")[2].replace(".", "_")
-            out[f"cess_gateway_stage_{name}_seconds"] = seconds[stage]
-            out[f"cess_gateway_stage_{name}_count"] = float(n)
-        return out
+        return _series("gateway", self.counters())
 
     def upload(self, owner: str, bucket: str, file_name: str,
                data: bytes) -> bytes:
@@ -309,11 +327,7 @@ class OssGateway:
             c["rows_fetched"] += n_segs * m
             c["bytes_fetched"] += n_segs * m * n + tags.nbytes
             c["hash_jobs"] += n_segs * (rows + 1)
-            for part in (sink, *sinks.values()):
-                for name, (count, seconds) in part.items():
-                    acc = self._stages.setdefault(name, [0, 0.0])
-                    acc[0] += count
-                    acc[1] += seconds
+            _merge_sinks(self._stages, sink, *sinks.values())
         return file_hash
 
 
@@ -396,7 +410,13 @@ class MinerAgent:
     what crosses to the device is those chunks (24 MiB each at the
     protocol's geometry, 373.5 MiB a round of 1,000 fragments) folded
     into one running (mu, sigma) that comes back once. ``store[h]``
-    serves transfers, repairs and the tests that index it as before."""
+    serves transfers, repairs and the tests that index it as before.
+
+    A restoral is done through ONE entry point too,
+    ``restore_fragment(hashes, row, peers)``: everything ``try_repair``
+    does once the chain has named the segment and the lost row.
+    ``counters()`` / ``metrics()`` give its accounts and every stage's
+    count and seconds."""
 
     def __init__(self, node: Node, account: str, gateways: list[OssGateway],
                  pipeline: StoragePipeline, engine=None, retry=None,
@@ -440,6 +460,12 @@ class MinerAgent:
         self.repair_symbol_repairs = 0
         self.repair_whole_repairs = 0
         self.repair_fallbacks = 0
+        # stage name -> [count, seconds] over this miner's restorals
+        # and the hops it served as a helper (obs.trace.stage sinks,
+        # one a call, merged here under the lock: a helper is called on
+        # its rebuilder's thread, and several may ask at once)
+        self._stages: dict[str, list] = {}
+        self._stages_mu = threading.Lock()
         self.store: dict[bytes, bytes] = {}        # fragment hash -> bytes
         self.tags: dict[bytes, np.ndarray] = {}
         self.filler_store: dict[bytes, bytes] = {}  # filler hash -> bytes
@@ -750,29 +776,39 @@ class MinerAgent:
         or the transfer dropped (seam "offchain.symbol"). The outgoing
         aggregate rides the "offchain.symbol_bytes" corruption seam;
         integrity is the REBUILDER's hash check, exactly as for
-        whole-fragment transfers."""
+        whole-fragment transfers.
+
+        The aggregate comes in and goes out as a host array: the
+        helpers are machines of their own, so nothing of one hop stays
+        on the device for the next. With a regenerating engine the
+        fold is one request of the repair class, handed over as its
+        two rows where they lie (the accumulator that arrived, a view
+        of the held ``bytes``; the first hop's accumulator is a zero
+        row); a hop is the stage ``miner.symbol.hop``."""
         blob = self.store.get(frag_hash)
         if blob is None:
             return None
         if not faults.allow("offchain.symbol"):
             return None
-        frag = np.frombuffer(blob, dtype=np.uint8)
-        acc = np.zeros_like(frag) if acc is None \
-            else np.asarray(acc, dtype=np.uint8)
-        if self.engine is not None and self.engine.codec is not None \
-                and hasattr(self.engine.codec, "fold_symbol"):
-            out = self.engine.repair_symbol(np.stack([acc, frag]),
-                                            int(coeff),
-                                            tenant=self.account)
-            sym = np.asarray(out)[0]
-        else:
-            from ..ops import regen
+        sink: dict = {}
+        with trace.stage("miner.symbol.hop", sink):
+            frag = np.frombuffer(blob, dtype=np.uint8)
+            acc = np.zeros_like(frag) if acc is None \
+                else np.asarray(acc, dtype=np.uint8)
+            if self.engine is not None and self.engine.codec is not None \
+                    and hasattr(self.engine.codec, "fold_symbol"):
+                sym = self.engine.repair_symbol([acc, frag], int(coeff),
+                                                tenant=self.account)[0]
+            else:
+                from ..ops import regen
 
-            sym = regen.fold_symbol_host(acc, frag, int(coeff))
-        return np.asarray(faults.corrupt("offchain.symbol_bytes", sym),
-                          dtype=np.uint8)
+                sym = regen.fold_symbol_host(acc, frag, int(coeff))
+            sym = np.asarray(faults.corrupt("offchain.symbol_bytes", sym),
+                             dtype=np.uint8)
+        self._merge_stages(sink)
+        return sym
 
-    def _repair_via_symbols(self, seg, row: int,
+    def _repair_via_symbols(self, hashes, row: int,
                             present: tuple[int, ...],
                             holders: dict[int, "MinerAgent"],
                             cfg: PipelineConfig) -> bytes | None:
@@ -790,8 +826,7 @@ class MinerAgent:
             return None
         acc = None
         for j, coeff in zip(present, coeffs):
-            acc = holders[j].repair_symbol(seg.fragment_hashes[j],
-                                           int(coeff), acc)
+            acc = holders[j].repair_symbol(hashes[j], int(coeff), acc)
             if acc is None:
                 return None
         # the aggregate crossed the wire whether or not it hashes
@@ -799,21 +834,20 @@ class MinerAgent:
         self.repair_ingress_bytes += acc.nbytes
         return acc.tobytes()
 
-    def _repair_via_fragments(self, seg, row: int,
+    def _repair_via_fragments(self, hashes, row: int,
                               present: tuple[int, ...],
                               holders: dict[int, "MinerAgent"],
                               cfg: PipelineConfig) -> bytes:
         """Whole-fragment dispatch: ingress k survivor rows — from
-        whichever k holders ``try_repair`` found, in row order — and
-        reconstruct (engine repair queue when attached, direct codec
-        otherwise). The program is the warmed shape's whatever the
-        helper set; the set picks the matrix it is called with. The
+        whichever k holders ``restore_fragment`` found, in row order —
+        and reconstruct (engine repair queue when attached, direct
+        codec otherwise). The program is the warmed shape's whatever
+        the helper set; the set picks the matrix it is called with. The
         engine takes the rows as they lie in the holders' stores (views
         of their ``bytes``): it puts each on the device from there and
         stacks them there, so no host copy of the k fragments is made."""
-        survivors = [np.frombuffer(
-            holders[j].store[seg.fragment_hashes[j]], dtype=np.uint8)
-            for j in present]
+        survivors = [np.frombuffer(holders[j].store[hashes[j]],
+                                   dtype=np.uint8) for j in present]
         self.repair_ingress_bytes += sum(s.nbytes for s in survivors)
         if self.engine is not None and self.engine.codec is not None:
             rec = self.engine.reconstruct(survivors, present, (row,),
@@ -829,15 +863,10 @@ class MinerAgent:
     def try_repair(self, frag_hash: bytes, peers: list["MinerAgent"],
                    gateways: list[OssGateway] | None = None) -> bool:
         """Claim + repair a broken fragment from peer-held rows, then
-        report completion. ``repair_mode`` picks the dispatch:
-        "fragments" ingresses k whole survivor rows; "symbols" walks
-        the regenerating repair-symbol chain (ops/regen.py) and
-        ingresses one fragment-sized aggregate, falling back to the
-        whole-fragment path when a helper refuses or the aggregate
-        fails its hash (counted in ``repair_fallbacks`` and noted to
-        the flight recorder). EITHER WAY the repaired bytes must
-        re-hash to the on-chain identity before they are stored — a
-        bad decode is a failed repair, never poisoned storage."""
+        report completion: the chain's side of a restoral. The order,
+        its file and the segment that holds the fragment are read from
+        ``file_bank``; the repair itself — holders, dispatch, hash
+        check, store, both extrinsics — is ``restore_fragment``."""
         rt = self.node.runtime
         order = rt.file_bank.restoral_order(frag_hash)
         if order is None:
@@ -847,72 +876,156 @@ class MinerAgent:
             return False
         seg = next(s for s in f.segments if frag_hash in s.fragment_hashes)
         row = seg.fragment_hashes.index(frag_hash)
+        with trace.span("offchain.repair", sys="offchain",
+                        miner=self.account, row=row,
+                        mode=self.repair_mode):
+            return self.restore_fragment(seg.fragment_hashes, row, peers,
+                                         gateways)
+
+    def restore_fragment(self, hashes, row: int,
+                         peers: list["MinerAgent"],
+                         gateways: list[OssGateway] | None = None
+                         ) -> bool:
+        """THE miner's repair entry point: one lost fragment rebuilt.
+        ``hashes`` are the segment's ``k + m`` fragment hashes in row
+        order (their on-chain ids), ``row`` the lost one, ``peers`` the
+        miners to ask -> True once the fragment is stored and reported.
+        ``try_repair`` calls it after its chain lookups.
+
+        The helpers are the first k peers, in row order, that hold
+        their row. ``repair_mode`` picks the dispatch: "fragments"
+        ingresses k whole survivor rows; "symbols" walks the
+        regenerating repair-symbol chain (ops/regen.py) and ingresses
+        one fragment-sized aggregate, falling back to the
+        whole-fragment path when a helper refuses or the aggregate
+        fails its hash (counted in ``repair_fallbacks`` and noted to
+        the flight recorder). EITHER WAY the repaired bytes must
+        re-hash to the on-chain identity before they are stored — a
+        bad decode is a failed repair, never poisoned storage. Then
+        ``file_bank.claim_restoral_order`` and
+        ``file_bank.restoral_order_complete`` are submitted.
+
+        A profiler trace holds ``cess:miner.repair`` with ``.holders``,
+        ``.chain`` (symbols) or ``.fragments``, ``.hash``, ``.store``
+        and ``.report`` inside it (a fallback: ``.chain``, ``.hash``,
+        then ``.fragments`` and ``.hash`` again); ``counters()`` has
+        each one's count and seconds."""
+        sink: dict = {}
+        try:
+            with trace.stage("miner.repair", sink, sys="offchain",
+                             miner=self.account, row=row):
+                return self._restore(hashes, row, peers, gateways, sink)
+        finally:
+            self._merge_stages(sink)
+
+    def _restore(self, hashes, row: int, peers, gateways,
+                 sink: dict) -> bool:
+        """``restore_fragment`` inside its stage."""
+        frag_hash = hashes[row]
         cfg = self.pipeline.config
-        holders: dict[int, MinerAgent] = {}
-        for j, h in enumerate(seg.fragment_hashes):
-            if j == row:
-                continue
-            for peer in peers:
-                if h in peer.store:
-                    holders[j] = peer
+        with trace.stage("miner.repair.holders", sink):
+            holders: dict[int, MinerAgent] = {}
+            for j, h in enumerate(hashes):
+                if j == row:
+                    continue
+                for peer in peers:
+                    if h in peer.store:
+                        holders[j] = peer
+                        break
+                if len(holders) == cfg.k:
                     break
-            if len(holders) == cfg.k:
-                break
         if len(holders) < cfg.k:
             return False
         present = tuple(holders)
-        mode = self.repair_mode
         via_symbols = False
         ingress0 = self.repair_ingress_bytes
-        with trace.span("offchain.repair", sys="offchain",
-                        miner=self.account, row=row,
-                        survivors=len(present), mode=mode):
-            blob = None
-            if mode == "symbols":
-                blob = self._repair_via_symbols(seg, row, present,
+        blob = None
+        if self.repair_mode == "symbols":
+            with trace.stage("miner.repair.chain", sink):
+                blob = self._repair_via_symbols(hashes, row, present,
                                                 holders, cfg)
-                if blob is not None and fragment_hash(blob) == frag_hash:
-                    via_symbols = True
-                else:
-                    self.repair_fallbacks += 1
-                    _flight.note("repair", "fallback",
-                                 miner=self.account, row=row,
-                                 reason="broken-chain" if blob is None
-                                 else "bad-hash")
-                    blob = None
-            if blob is None:
-                blob = self._repair_via_fragments(seg, row, present,
+            with trace.stage("miner.repair.hash", sink):
+                via_symbols = blob is not None \
+                    and fragment_hash(blob) == frag_hash
+            if not via_symbols:
+                self.repair_fallbacks += 1
+                _flight.note("repair", "fallback",
+                             miner=self.account, row=row,
+                             reason="broken-chain" if blob is None
+                             else "bad-hash")
+        if not via_symbols:
+            with trace.stage("miner.repair.fragments", sink):
+                blob = self._repair_via_fragments(hashes, row, present,
                                                   holders, cfg)
-        if fragment_hash(blob) != frag_hash:
-            return False
-        self.store[frag_hash] = blob
-        self.repair_recovered_bytes += len(blob)
-        if via_symbols:
-            self.repair_symbol_repairs += 1
-        else:
-            self.repair_whole_repairs += 1
-        for peer in peers:
-            if frag_hash in peer.tags:
-                self.tags[frag_hash] = peer.tags[frag_hash]
-                break
-        else:
-            for gw in (gateways or self.gateways):
-                if frag_hash in gw.tag_store:
-                    self.tags[frag_hash] = gw.tag_store[frag_hash]
+            with trace.stage("miner.repair.hash", sink):
+                if fragment_hash(blob) != frag_hash:
+                    return False
+        with trace.stage("miner.repair.store", sink):
+            self.store[frag_hash] = blob
+            self.repair_recovered_bytes += len(blob)
+            if via_symbols:
+                self.repair_symbol_repairs += 1
+            else:
+                self.repair_whole_repairs += 1
+            for peer in peers:
+                if frag_hash in peer.tags:
+                    self.tags[frag_hash] = peer.tags[frag_hash]
                     break
-        self.node.submit_extrinsic(self.account,
-                                   "file_bank.claim_restoral_order",
-                                   frag_hash)
-        self.node.submit_extrinsic(self.account,
-                                   "file_bank.restoral_order_complete",
-                                   frag_hash)
-        # custody restoral: the fragment's custodian is this miner now
-        # (the ledger clears the loss and re-scores the margin)
-        _flight.note("custody", "repair", miner=self.account,
-                     frag=frag_hash,
-                     mode="symbols" if via_symbols else "fragments",
-                     ingress=self.repair_ingress_bytes - ingress0)
+            else:
+                for gw in (gateways or self.gateways):
+                    if frag_hash in gw.tag_store:
+                        self.tags[frag_hash] = gw.tag_store[frag_hash]
+                        break
+        with trace.stage("miner.repair.report", sink):
+            self.node.submit_extrinsic(self.account,
+                                       "file_bank.claim_restoral_order",
+                                       frag_hash)
+            self.node.submit_extrinsic(self.account,
+                                       "file_bank.restoral_order_complete",
+                                       frag_hash)
+            # custody restoral: the fragment's custodian is this miner
+            # now (the ledger clears the loss and re-scores the margin)
+            _flight.note("custody", "repair", miner=self.account,
+                         frag=frag_hash,
+                         mode="symbols" if via_symbols else "fragments",
+                         ingress=self.repair_ingress_bytes - ingress0)
         return True
+
+    def _merge_stages(self, sink: dict) -> None:
+        """One call's stage sink into this miner's totals."""
+        with self._stages_mu:
+            _merge_sinks(self._stages, sink)
+
+    def counters(self) -> dict:
+        """Totals over this miner's restorals: ``repairs`` (fragments
+        stored: ``repair_symbol_repairs`` + ``repair_whole_repairs``),
+        ``repair_ingress_bytes`` (what crossed the wire INTO this miner
+        for them, the aggregates that failed their hash included),
+        ``repair_recovered_bytes``, ``repair_fallbacks`` (chains that
+        ended in a whole-fragment repair) and, by stage name,
+        ``stage_count`` and ``stage_seconds`` (raw, unrounded) of
+        ``miner.repair`` and its children and of the hops this miner
+        served as a helper (``miner.symbol.hop``)."""
+        with self._stages_mu:
+            stages = {k: tuple(v) for k, v in self._stages.items()}
+        out = {name: getattr(self, name) for name in (
+            "repair_ingress_bytes", "repair_recovered_bytes",
+            "repair_symbol_repairs", "repair_whole_repairs",
+            "repair_fallbacks")}
+        out["repairs"] = out["repair_symbol_repairs"] \
+            + out["repair_whole_repairs"]
+        out["stage_count"] = {k: v[0] for k, v in stages.items()}
+        out["stage_seconds"] = {k: v[1] for k, v in stages.items()}
+        return out
+
+    def metrics(self) -> dict[str, float]:
+        """``counters()`` as ``cess_miner_*_total`` series and, a
+        stage, ``cess_miner_stage_<name>_seconds`` / ``_count``
+        (``<name>``: the stage's without its first part, dots as
+        underscores: ``repair``, ``repair_chain``, ``symbol_hop``):
+        merged into GET /metrics when the node carries ``node.miner =
+        agent`` (node/metrics.py collect())."""
+        return _series("miner", self.counters())
 
 
 @codec.register

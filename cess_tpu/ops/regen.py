@@ -246,7 +246,15 @@ class RegenCodec(TPUCodec):
     matmul. The warm path (``warm_reconstruct``, a program per shape
     and placement; ``warm_hits`` under ``xor`` / ``auto``) is inherited
     unchanged, so ``engine.warm_repair`` serves regen patterns the same
-    way it serves plain reconstructs."""
+    way it serves plain reconstructs.
+
+    A fold's matrix is ``[1, coeff]``, one a coefficient, and a chain
+    of k helpers asks for k of them a repair: RS(10,4) repaired from
+    the ten lowest holders meets 140 over its 14 patterns. The LRU
+    holds every one GF(2^8) has beside the decode matrices (an operand
+    is 128 bytes), so a chain rebuilds none."""
+
+    MATRICES = TPUCodec.MATRICES + gf.FIELD - 1
 
     def _build_matrix(self, kind: str, present: tuple[int, ...],
                       missing: tuple[int, ...]) -> np.ndarray:

@@ -604,16 +604,20 @@ class SubmissionEngine:
         """pairs [B, 2, n] (or [2, n]) uint8 (accumulator, fragment)
         rows -> future of the folded [B, 1, n] partial sums
         (acc ^ coeff*fragment) — the helper hop of the regenerating
-        repair chain (ops/regen.py). Needs a codec with the symbol
-        surface (``make_engine(..., rs_backend="regen")``); a
-        breaker-degraded batch serves from the host twin."""
+        repair chain (ops/regen.py). One request may also be the list
+        of its two 1-D ``u8[n]`` NumPy rows, as for
+        ``submit_reconstruct``: a helper's accumulator and its held
+        fragment go to the device from where they lie. Needs a codec
+        with the symbol surface (``make_engine(...,
+        rs_backend="regen")``); a breaker-degraded batch serves from
+        the host twin."""
         self._need_codec()
         if not hasattr(self.codec, "fold_symbol"):
             raise ValueError(
                 "repair symbols need a regenerating codec; build the "
                 "engine with rs_backend='regen'")
         coeff = int(coeff)
-        pairs, squeeze = self._norm_shards(pairs, 2)
+        pairs, squeeze = self._norm_survivors(pairs, 2)
         key = ("repair", "symbol", (coeff,), (), pairs.shape[2])
         return self._submit("repair", key, pairs.shape[0],
                             {"survivors": pairs}, {"coeff": coeff},
@@ -1991,8 +1995,10 @@ class SubmissionEngine:
                                                  bucket, degraded, lane)
             if as_rows:
                 surv = self._put_rows(surv, q, bucket, n)
-                with self._lock:
-                    self.stats.classes["repair"].linear_puts += 1
+            with self._lock:
+                st = self.stats.classes["repair"]
+                st.linear_puts += as_rows
+                st.symbol_folds += kind == "symbol"
             out = prog(surv, *pattern)[:total]
         return self._split_rows(batch, out, lane), bucket
 

@@ -97,7 +97,8 @@ class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
                  "saturated", "shed", "batches", "batched_requests",
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
-                 "linear_puts", "patterns_new", "matrix_build_s",
+                 "linear_puts", "symbol_folds", "patterns_new",
+                 "matrix_build_s",
                  "missions", "device_calls", "prf_evals",
                  "chunks", "gathered_bytes", "gather_seconds",
                  "latencies", "hist", "stage_n", "stage_s",
@@ -130,6 +131,10 @@ class ClassStats:
         # codec; a batch with a device-resident contributor, and one
         # served by a host codec (the breaker's fallback), leave it 0
         self.linear_puts = 0
+        # repair class: batches of the ``symbol`` kind, a helper's hop
+        # of a regenerating repair (engine.py submit_repair_symbol);
+        # the other kinds (reconstruct, decode) leave it 0
+        self.symbol_folds = 0
         # repair class, device codec: batches whose erasure pattern the
         # codec held no matrix for (engine.py _counting_matrices), and
         # the host seconds their matrices took to build (GF
@@ -340,6 +345,7 @@ class EngineStats:
                 "operand_bytes": st.operand_bytes,
                 "linear_fetches": st.linear_fetches,
                 "linear_puts": st.linear_puts,
+                "symbol_folds": st.symbol_folds,
                 "patterns_new": st.patterns_new,
                 "matrix_build_s": st.matrix_build_s,
                 "missions": st.missions,

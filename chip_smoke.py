@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke: the storage lifecycle on one TPU, at protocol widths.
 
-    python chip_smoke.py [--seed N]       one chip: phases A, B, C
+    python chip_smoke.py [--seed N]       one chip: phases A, B, C, D
     python chip_smoke.py --chips 4        four chips: pool lanes + (2, 2) mesh only
     python chip_smoke.py --rehearse       tiny sizes, any backend; proves control
                                           flow only and never prints the chip's
@@ -12,6 +12,9 @@ Phase A  RS(4,8) codec + PoDR2 audit through the submission engine, checked
 Phase B  the lifecycle at CESS's own geometry, RS(2,1) with 16 MiB segments:
          validators, gateway, miners, TEE; upload -> audit -> repair.
 Phase C  streamed ingest through the fused encode+tag program.
+Phase D  the regenerating repair plane at RS(2,1), 8 MiB fragments: one fold
+         and one three-hop chain through a regen engine, every hop against
+         the host twin and the chain's end against the oracle.
 
 One process, no platform set here, resilience off: a device failure fails the
 run instead of degrading to the CPU reference. Without --rehearse the script
@@ -491,6 +494,67 @@ def phase_c(run: Run) -> None:
 
 
 # ---------------------------------------------------------------------------
+# Phase D — the regenerating repair plane (ops/regen.py), RS(2,1)
+# ---------------------------------------------------------------------------
+def phase_d(run: Run) -> None:
+    """The first evidence that the plane runs on the chip, without the
+    benchmark: a regen engine's fold against the host twin, and a chain
+    of three hops, each aggregate a host array between them."""
+    import numpy as np
+
+    from cess_tpu.ops import regen
+    from cess_tpu.ops.rs_ref import ReferenceCodec
+    from cess_tpu.serve import make_engine
+
+    k, m = 2, 1
+    n = run.segment // k
+    rng = np.random.default_rng(run.seed + 3)
+    coded = ReferenceCodec(k, m).encode(
+        rng.integers(0, 256, (k, n), dtype=np.uint8))
+    with run.phase("D") as line:
+        eng = make_engine(k, m, rs_backend="regen")
+        try:
+            eng.warm_repair([((1, 2), (0,)), ((0, 2), (1,)),
+                             ((0, 1), (2,))], n, buckets=(1,))
+            programs = run.clock.programs
+            acc = rng.integers(0, 256, n, dtype=np.uint8)
+            out = eng.repair_symbol([acc, coded[1]], 0x8E)[0]
+            check("D: the device fold differs from the host twin",
+                  np.array_equal(out, regen.fold_symbol_host(
+                      acc, coded[1], 0x8E)))
+            # row 0 from rows 1 and 2, then a third hop that folds row
+            # 0's own coefficient-1 copy back in: the aggregate is zero
+            chain = list(zip((1, 2), regen.repair_coeffs(k, m, (1, 2),
+                                                         (0,)))) + [(0, 1)]
+            hops, acc = [], None
+            for j, coeff in chain:
+                first = np.zeros(n, np.uint8) if acc is None else acc
+                acc = eng.repair_symbol([first, coded[j]], coeff)[0]
+                check("D: an aggregate is not a host array",
+                      type(acc) is np.ndarray)
+                hops.append(acc)
+                check(f"D: hop {len(hops)} differs from the host twin",
+                      np.array_equal(acc, regen.fold_symbol_host(
+                          first, coded[j], coeff)))
+            check("D: two hops do not rebuild the lost row",
+                  np.array_equal(hops[1], coded[0]))
+            check("D: the third hop does not cancel it", not hops[2].any())
+            check("D: a warmed fold compiled",
+                  run.clock.programs == programs)
+            eng.flush()
+            repair = eng.stats_snapshot()["classes"]["repair"]
+            check("D: folds not counted", repair["symbol_folds"] == 4)
+            line.update(bytes_a_hop=int(2 * n), hops=len(hops),
+                        strategy=eng.codec.strategy,
+                        engine=engine_totals(eng),
+                        repair={kk: repair[kk] for kk in
+                                ("batches", "symbol_folds", "linear_puts",
+                                 "linear_fetches", "patterns_new")})
+        finally:
+            eng.close()
+
+
+# ---------------------------------------------------------------------------
 # --chips 4: the two paths that exist only across chips
 # ---------------------------------------------------------------------------
 def phase_pool(run: Run) -> None:
@@ -631,7 +695,7 @@ def main(argv=None) -> int:
         return 3
 
     run = Run(args)
-    phases = (phase_a, phase_b, phase_c) if args.chips == 1 \
+    phases = (phase_a, phase_b, phase_c, phase_d) if args.chips == 1 \
         else (phase_pool, phase_mesh)
     for phase in phases:
         phase(run)

@@ -29,7 +29,7 @@ def _smoke(tmp_path, *args):
 
 
 @pytest.mark.parametrize("chips,phases", [
-    (1, ["A", "B", "C", "total"]),
+    (1, ["A", "B", "C", "D", "total"]),
     (4, ["pool", "mesh", "total"]),
 ])
 def test_rehearsal_runs_every_phase_and_never_the_chip_line(

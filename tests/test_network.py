@@ -498,6 +498,87 @@ def test_repair_symbol_corruption_falls_back_to_fragments(storage_net):
     assert rt.file_bank.restoral_order(frag) is None
 
 
+@pytest.mark.parametrize("mode,row", [("fragments", 0), ("symbols", 2)])
+def test_try_repair_and_the_entry_point_are_one_path(storage_net, mode,
+                                                     row):
+    """``try_repair`` is ``restore_fragment`` after its chain lookups: a
+    second miner handed what the chain holds (the segment's hashes, the
+    lost row) stores the same bytes, counts the same and submits the
+    same two extrinsics as the miner that went through the order."""
+    spec, net, node, gw, miners, tee, cfg = storage_net
+    rt = node.runtime
+    frag, f = _break_fragment(node, miners, row=row)
+    net.run_slots(1)
+    first, second = [m for m in miners if frag not in m.store][:2]
+    seg = next(s for s in f.segments if frag in s.fragment_hashes)
+
+    class Recorder:
+        def __init__(self, real=None):
+            self.real, self.sent = real, []
+            self.runtime = getattr(real, "runtime", None)
+
+        def submit_extrinsic(self, who, call, *args):
+            self.sent.append((who, call, args))
+            if self.real is not None:
+                self.real.submit_extrinsic(who, call, *args)
+
+    handed = []
+    entry = first.restore_fragment
+    first.restore_fragment = lambda *a: handed.append(a) or entry(*a)
+    nodes = first.node, second.node
+    first.node, second.node = Recorder(node), Recorder()
+    before = [m.counters() for m in (first, second)]
+    try:
+        for m in (first, second):
+            m.repair_mode = mode
+        assert first.try_repair(frag, miners, [gw])
+        assert second.restore_fragment(seg.fragment_hashes, row, miners,
+                                       [gw])
+        sent = first.node.sent, second.node.sent
+    finally:
+        del first.restore_fragment
+        (first.node, second.node) = nodes
+        for m in (first, second):
+            m.repair_mode = "fragments"
+    assert handed == [(seg.fragment_hashes, row, miners, [gw])]
+    assert first.store[frag] == second.store[frag]
+    assert fragment_hash(first.store[frag]) == frag
+    assert np.array_equal(first.tags[frag], second.tags[frag])
+    calls = ["file_bank.claim_restoral_order",
+             "file_bank.restoral_order_complete"]
+    for m, got in zip((first, second), sent):
+        assert got == [(m.account, call, (frag,)) for call in calls]
+    deltas = []
+    for m, was in zip((first, second), before):
+        now = m.counters()
+        deltas.append({k: now[k] - was[k] for k in was
+                       if not k.startswith("stage_")})
+        ran = {k: n - was["stage_count"].get(k, 0)
+               for k, n in now["stage_count"].items()
+               if k != "miner.symbol.hop"}
+        assert {k: n for k, n in ran.items() if n} == {
+            "miner.repair": 1, "miner.repair.holders": 1,
+            "miner.repair.chain" if mode == "symbols"
+            else "miner.repair.fragments": 1,
+            "miner.repair.hash": 1, "miner.repair.store": 1,
+            "miner.repair.report": 1}
+    assert deltas[0] == deltas[1]
+    n = cfg.fragment_size
+    assert deltas[0] == {
+        "repair_ingress_bytes": n if mode == "symbols" else cfg.k * n,
+        "repair_recovered_bytes": n, "repair_fallbacks": 0, "repairs": 1,
+        "repair_symbol_repairs": int(mode == "symbols"),
+        "repair_whole_repairs": int(mode == "fragments")}
+    # the order the first miner went through completes on the chain
+    net.run_slots(1)
+    assert rt.file_bank.restoral_order(frag) is None
+    ev = rt.state.events_of("file_bank", "RestoralComplete")
+    assert dict(ev[-1].data)["miner"] == first.account
+    # the second copy is set aside: one custodian a fragment
+    del second.store[frag]
+    second.tags.pop(frag, None)
+
+
 def test_repair_rejects_corrupt_reconstruction(storage_net):
     """Integrity regression: a decode fed bad survivor bytes must NOT
     be stored or claimed — the reconstructed fragment re-hashes

@@ -396,6 +396,45 @@ def test_rows_program_compiles_for_v5e(one_chip, for_tpu, k, m, r, bucket):
     _fits_hbm(compiled)
 
 
+@pytest.mark.parametrize("k,m", [
+    pytest.param(10, 4, id="a-hop-on-the-archival-tier"),
+    pytest.param(2, 1, id="a-hop-at-the-protocols-geometry")])
+def test_symbol_fold_compiles_for_v5e(one_chip, for_tpu, k, m):
+    """A helper's hop of a chained repair (PR 40): the regenerating
+    codec's fold ``[1, 2, 8 MiB] -> [1, 1, 8 MiB]`` as the engine calls
+    it, (accumulator, fragment) as two linear rows and the matrix
+    ``[1, coeff]`` the program's operand. The codec builds the operand
+    it would put; the program is the rows program at q = 2, r = 1
+    whatever the geometry, holds the Pallas kernel under its pinned
+    name (what ``rs_kernel_roofline.restore`` matches), and takes its
+    rows dense; the only ``reshape`` in it is each row's own into
+    ``u8[1, 1, n]`` (its relayout, a loop a row): none of the pair as a
+    whole, whose compile time would grow with the array."""
+    from cess_tpu.ops import regen
+
+    n = 8 * MiB
+    codec = regen.RegenCodec(k, m, strategy="pallas")
+    coeff = regen.repair_coeffs(k, m, tuple(range(1, k + 1)), (0,))[0]
+    apply_ = codec._matrix_for("symbol", (coeff,), ())
+    assert apply_.mat.tolist() == [[1, coeff]] and not apply_.baked
+    bmat = rs_pallas.operand_np(apply_._host[0], rs_pallas.group_for(1))
+    rows = tuple(jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
+                 for _ in range(2))
+    t0 = time.perf_counter()
+    compiled = rs._apply_rows.lower(
+        (jax.ShapeDtypeStruct(bmat.shape, bmat.dtype, sharding=one_chip),),
+        rows, strategy=codec.strategy, q=2).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    text = compiled.as_text()
+    assert re.search(rf"%{RS}\.\d+ = [^\n]* custom-call\(", text)
+    reshaped = re.findall(r"= (\S+?)\{\S* reshape\(", text)
+    assert reshaped and set(reshaped) == {f"u8[1,1,{n}]"}
+    assert compiled.out_info.shape == (1, 1, n)
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.argument_size_in_bytes - 2 * n < 65536, mem
+    _fits_hbm(compiled)
+
+
 @pytest.mark.parametrize("shape,k", [
     pytest.param((4, 3, 8 * MiB), 2, id="upload-rs2p1-4-segments"),
     pytest.param((1, 12, 4 * MiB), 4, id="upload-rs4p8-1-segment")])
