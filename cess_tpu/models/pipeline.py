@@ -23,11 +23,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import constants
 from ..obs import trace
 from ..ops import gf, podr2
-from ..ops.rs import default_strategy, _MatrixApply
+from ..ops.rs import default_strategy, _MatrixApply, _stack_rows
 
 
 # The two row regroupings of the data plane, written WITHOUT
@@ -46,6 +47,34 @@ def split_rows(segments: jax.Array, k: int) -> jax.Array:
     n = segments.shape[1] // k
     return jnp.stack([segments[:, j * n:(j + 1) * n] for j in range(k)],
                      axis=1)
+
+
+# A batch of host segments crosses the link as LINEAR rows and becomes
+# ``u8[B, k, n]`` on the device (PR 43): the TPU packs the second-minor
+# dimension of a uint8 array four rows to a 32-bit word, so a host ->
+# device put of ``u8[B, k*n]`` (or of any ``u8[.., r, n]``) is packed on
+# the host before it crosses, at 5.1-5.7 GiB/s for the stream cells'
+# 128 MiB, which capped every one-chip stream cell; a 1-D ``u8[n]``
+# crosses as it lies (ops/rs.py LinearRows, PERF.md section 5). The two
+# halves of the form, one on each side of the link:
+
+
+def linear_rows(chunk: np.ndarray, k: int) -> tuple:
+    """The host half: a C-contiguous ``[B, k*n]`` chunk as its ``B*k``
+    rows ``u8[n]``, row ``j`` of segment ``i`` at ``i*k + j`` — views,
+    no byte is copied. ``jax.device_put`` takes the tuple in one call."""
+    b, size = chunk.shape
+    return tuple(chunk.reshape(b * k, size // k))
+
+
+def stack_rows(segments, k: int) -> jax.Array:
+    """The device half (traced): what :func:`linear_rows` put, stacked
+    to ``[B, k, n]`` in ``split_rows``' own style (ops/rs.py
+    ``_stack_rows``: ``stack`` forms, no ``reshape``); a ``[B, k*n]``
+    array (``forward``'s callers) goes through ``split_rows``."""
+    if isinstance(segments, (tuple, list)):
+        return _stack_rows(segments, k)
+    return split_rows(segments, k)
 
 
 @jax.jit
@@ -207,9 +236,12 @@ class StoragePipeline:
         per batch/id shape, so the streaming driver reuses one
         compiled program per bucket.
 
-        Signature: (segments [B, segment_size] u8,
+        Signature: (segments: the batch's B*k linear rows u8[frag]
+                    (``linear_rows``, what the streaming driver puts)
+                    | [B, segment_size] u8,
                     fragment_ids [B*(k+m)] | [B, k+m] | [B, k+m, 2])
                  -> {"fragments": [B, k+m, frag], "tags": [B, k+m, blocks, limbs]}
+        One body: jit traces it once per input form.
         """
         if self._fused is None:
             cfg = self.config
@@ -219,7 +251,7 @@ class StoragePipeline:
                 # op_name metadata, so a device trace can tell the
                 # fused step's relayouts from anything else's
                 with jax.named_scope(FUSED_SCOPE):
-                    return self.fused_step(split_rows(segments, cfg.k),
+                    return self.fused_step(stack_rows(segments, cfg.k),
                                            fragment_ids)
 
             self._fused = jax.jit(run)

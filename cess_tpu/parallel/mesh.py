@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.pipeline import FUSED_SCOPE, StoragePipeline, merge_rows
+from ..models.pipeline import FUSED_SCOPE, StoragePipeline, merge_rows, \
+    stack_rows
 from ..ops import pfield as pf
 from ..ops import podr2
 
@@ -133,8 +134,13 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
     and sliced locally (the topology-invariance contract of
     sharded_pipeline_step), and the tags go through the plain jnp MAC.
 
-    In: data [B, k, n] uint8 (fragment-major), ids [B, k+m] int32
-    (or [B, k+m, 2] uint32 hash word pairs when ``pair_ids``).
+    In: the batch as linear rows (:func:`stream_entry`'s ``put``): a
+    tuple of ``(B / seg) * k`` arrays ``u8[seg * n]`` sharded over
+    (seg, byte) together, so that a device holds, as 1-D rows, its own
+    byte slice of every fragment of its own segments, and stacks them
+    to ``[B / seg, k, n / byte]`` itself (models/pipeline.py
+    ``stack_rows``); ids [B, k+m] int32 (or [B, k+m, 2] uint32 hash
+    word pairs when ``pair_ids``).
     Out: {"fragments" [B, k+m, n], "tags" [B, k+m, blocks, limbs]} —
     the StoragePipeline.forward shape contract.
     """
@@ -147,12 +153,13 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
         f"{blocks_total} blocks not divisible by byte axis {byte_shards}")
     blocks_local = blocks_total // byte_shards
 
-    def whole_fragments(data, ids):
+    def whole_fragments(rows, ids):
         with jax.named_scope(FUSED_SCOPE):
-            out = pipeline.fused_step(data, ids)
+            out = pipeline.fused_step(stack_rows(rows, cfg.k), ids)
         return out["fragments"], out["tags"]
 
-    def sliced_fragments(data, ids):
+    def sliced_fragments(rows, ids):
+        data = stack_rows(rows, cfg.k)
         b, k, n_local = data.shape
         parity = pipeline._parity(data)
         shards = jnp.concatenate([data, parity], axis=-2)
@@ -174,7 +181,7 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
     mapped = jax.shard_map(
         step,
         mesh=mesh,
-        in_specs=(P("seg", None, "byte"), ids_spec),
+        in_specs=(P(("seg", "byte")), ids_spec),   # every row alike
         out_specs=(P("seg", None, "byte"), P("seg", None, "byte", None)),
         # the whole-fragment step has no collective and nothing
         # replicated, so the varying-axes check has nothing to prove
@@ -185,8 +192,8 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
     )
     jitted = jax.jit(mapped)
 
-    def run(data, ids):
-        shards, tags = jitted(data, ids)
+    def run(rows, ids):
+        shards, tags = jitted(rows, ids)
         return {"fragments": shards, "tags": tags}
 
     return run
@@ -200,23 +207,36 @@ def stream_entry(pipeline: StoragePipeline, mesh: Mesh, batch: int,
         ing = StreamingIngest(pipe, batch,
                               **stream_entry(pipe, mesh, batch))
 
-    ``put`` reshapes each staged [batch, segment_size] host chunk to
-    fragment-major [batch, k, fragment_size] and places it sharded
-    over (seg, byte) in ONE device_put; ``put_ids`` places the id
-    batch sharded over 'seg'. The driver itself stays
-    topology-agnostic.
+    ``put`` takes the batch as the driver stages it, its ``batch * k``
+    linear rows (1-D uint8 views of the staged chunk), and gives every
+    device the byte slice of the rows of its own ``batch / seg``
+    segments, still linear (a view of a view: no host copy, and no
+    packing of a ``u8[.., k, n]`` array on the host before it crosses,
+    PERF.md PR 43), in ONE device_put; row slot ``t`` of every device
+    together is one global ``u8[seg * n]`` array, the program's
+    argument ``t``. ``put_ids`` places the id batch sharded over
+    'seg'. The driver itself stays topology-agnostic.
     """
     cfg = pipeline.config
     rows = cfg.k + cfg.m
     program = sharded_stream_step(pipeline, mesh, pair_ids)
-    data_sh = NamedSharding(mesh, P("seg", None, "byte"))
+    seg, byte = mesh.shape["seg"], mesh.shape["byte"]
+    slots = batch // seg * cfg.k           # rows a device holds
+    n_local = cfg.fragment_size // byte
+    rows_sh = NamedSharding(mesh, P(("seg", "byte")))
+    devices = list(mesh.devices.reshape(-1))   # (seg, byte), seg-major
+    d = len(devices)
     ids_sh = NamedSharding(
         mesh, P("seg", None, None) if pair_ids else P("seg", None))
 
-    def put(chunk):
-        chunk = np.asarray(chunk).reshape(batch, cfg.k,
-                                          cfg.fragment_size)
-        return jax.device_put(chunk, data_sh)
+    def put(rows_up):
+        pieces = [rows_up[s * slots + t][b * n_local:(b + 1) * n_local]
+                  for t in range(slots)
+                  for s in range(seg) for b in range(byte)]
+        placed = jax.device_put(pieces, devices * slots)
+        return tuple(jax.make_array_from_single_device_arrays(
+            (d * n_local,), rows_sh, placed[t * d:(t + 1) * d])
+            for t in range(slots))
 
     def put_ids(ids):
         ids = np.asarray(ids)

@@ -249,6 +249,7 @@ class StreamStats:
     """
 
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
+                 "linear_puts", "put_arrays",
                  "h2d_s", "dispatch_s", "stall_s", "wall_s")
     __slots__ = _COUNTERS + ("lanes", "hist")
 
@@ -257,6 +258,11 @@ class StreamStats:
         self.segments = 0          # real segments ingested
         self.padded_segments = 0   # zero rows added to the ragged tail
         self.bytes_in = 0          # host bytes staged (real, not pad)
+        # batches whose bytes went up as linear 1-D rows (views of the
+        # staged chunk, stacked on the device: PERF.md, PR 43), and the
+        # host arrays handed to the put for them
+        self.linear_puts = 0
+        self.put_arrays = 0
         self.h2d_s = 0.0           # host time ENQUEUEING device_put
         self.dispatch_s = 0.0      # host time ENQUEUEING the program
         self.stall_s = 0.0         # host time blocked on device results

@@ -18,7 +18,13 @@ a host byte stream:
 
 - each batch is staged ONCE with ``jax.device_put`` (one H2D copy from
   host bytes to device tags — the fused program never materializes an
-  intermediate on the host);
+  intermediate on the host), and crosses the link in a LINEAR layout:
+  the put seam is handed the staged chunk as its 1-D ``uint8`` row views
+  (models/pipeline.py ``linear_rows``: no host copy) and the program
+  stacks them to ``u8[B, k, n]`` on the device — a 2-D ``uint8`` array
+  would be packed four rows to a word on the host first, which capped
+  every one-chip stream at 5.1-5.7 GiB/s whatever the program cost
+  (PERF.md, PR 43);
 - dispatch is asynchronous, so staging batch i+1 overlaps the device
   computing batch i (double buffering falls out of async dispatch +
   a bounded in-flight window: at most ``depth`` batches are enqueued
@@ -51,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.pipeline import linear_rows
 from ..obs import flight as _flight
 from ..obs import trace
 from ..resilience import faults
@@ -108,11 +115,14 @@ class StreamingIngest:
     depth:    in-flight window — batches enqueued on the device before
               the driver blocks on the oldest (2 = classic double
               buffering: one computing, one staged).
-    program:  override the device program (fn(segments, ids) -> dict
-              with "fragments"/"tags") — the mesh entry passes its
-              shard_map'd step here.
+    program:  override the device program (fn(staged, ids) -> dict
+              with "fragments"/"tags"; ``staged`` is what ``put``
+              returned) — the mesh entry passes its shard_map'd step
+              here.
     put / put_ids: override staging (default jax.device_put) — the
-              mesh entry passes sharded placements.
+              mesh entry passes sharded placements. ``put`` receives
+              the batch as the tuple of its ``batch * k`` linear rows
+              (1-D uint8 views of the staged chunk).
     pool:     optional DevicePool (serve/pool.py) — device-aware
               placement: the driver derives its (program, put,
               put_ids) from ``pool.stream_entry``'s mesh over the
@@ -274,12 +284,16 @@ class StreamingIngest:
                     bt0 = time.perf_counter()
                     with trace.stage("stream.put", parent=bspan) as put:
                         faults.inject("stream.h2d")   # chaos: staging
-                        dev = self._put(chunk)
+                        rows_up = linear_rows(chunk, cfg.k)
+                        dev = self._put(rows_up)
                         ids_dev = self._put_ids(ids)
                     h2d = put.seconds
                     st.h2d_s += h2d
+                    st.linear_puts += 1
+                    st.put_arrays += len(rows_up)
                     # gauge: the devices this batch was placed over
-                    st.lanes = len(dev.sharding.device_set)
+                    st.lanes = len(jax.tree.leaves(dev)[0]
+                                   .sharding.device_set)
                     with trace.stage("stream.dispatch",
                                      parent=bspan) as launch:
                         faults.inject("stream.dispatch")  # chaos: launch

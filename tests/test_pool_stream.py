@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
+from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline, \
+    linear_rows
 from cess_tpu.ops import podr2, podr2_pallas, target
 from cess_tpu.ops.rs_ref import ReferenceCodec
-from cess_tpu.parallel.mesh import make_mesh, sharded_stream_step
+from cess_tpu.parallel.mesh import make_mesh, stream_entry
 from cess_tpu.serve import make_engine
 from cess_tpu.serve.pool import DevicePool
 from cess_tpu.serve.stream import StreamingIngest
@@ -47,6 +48,15 @@ def make_pipe(limbs=2):
     key = podr2.Podr2Key.generate(27, podr2.Podr2Params(limbs=limbs))
     return StoragePipeline(PipelineConfig(k=K, m=M, segment_size=SEG),
                            podr2_key=key)
+
+
+def sharded_step(pipe, mesh, segs, ids, pair=False):
+    """One batch through a mesh's stream entry as the driver stages it:
+    the chunk's linear row views through the entry's ``put``, then its
+    program (parallel/mesh.py sharded_stream_step)."""
+    entry = stream_entry(pipe, mesh, len(segs), pair_ids=pair)
+    return entry["program"](entry["put"](linear_rows(segs, K)),
+                            entry["put_ids"](ids))
 
 
 def plain_reference(pipe, segs, ids):
@@ -99,8 +109,7 @@ def test_sharded_step_bit_identical_to_fused_program(seg, byte, pair):
     ids = rnd((BATCH, ROWS, 2), 6, np.uint32) if pair else \
         rnd((BATCH, ROWS), 6, np.uint32).astype(np.int32)
     want = pipe.fused_program()(jnp.asarray(segs), jnp.asarray(ids))
-    got = sharded_stream_step(pipe, mesh, pair_ids=pair)(
-        jnp.asarray(segs.reshape(BATCH, K, FRAG)), jnp.asarray(ids))
+    got = sharded_step(pipe, mesh, segs, ids, pair)
     for name in ("fragments", "tags"):
         assert np.array_equal(np.asarray(got[name]),
                               np.asarray(want[name])), name
@@ -119,13 +128,13 @@ def test_pooled_step_traces_the_fused_steps_body(monkeypatch):
         return real(self, data, ids)
 
     monkeypatch.setattr(StoragePipeline, "fused_step", counting)
-    data = jnp.asarray(rnd((BATCH, K, FRAG), 8))
-    ids = jnp.arange(BATCH * ROWS, dtype=jnp.int32).reshape(BATCH, ROWS)
-    sharded_stream_step(pipe, make_mesh(jax.devices()[:4], 4, 1))(data, ids)
+    segs = rnd((BATCH, SEG), 8)
+    ids = np.arange(BATCH * ROWS, dtype=np.int32).reshape(BATCH, ROWS)
+    sharded_step(pipe, make_mesh(jax.devices()[:4], 4, 1), segs, ids)
     assert calls == [(BATCH // 4, K, FRAG)]           # per-device rows
-    pipe.fused_program()(data.reshape(BATCH, SEG), ids)
+    pipe.fused_program()(jnp.asarray(segs), jnp.asarray(ids))
     assert calls[1:] == [(BATCH, K, FRAG)]
-    sharded_stream_step(pipe, make_mesh(jax.devices()[:4], 2, 2))(data, ids)
+    sharded_step(pipe, make_mesh(jax.devices()[:4], 2, 2), segs, ids)
     assert len(calls) == 2
 
 

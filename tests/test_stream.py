@@ -9,6 +9,10 @@
   (topology invariance extends to the streaming program);
 - stream stage counters are exact and export through the engine's
   cess_engine_stream_* metrics surface;
+- a staged batch crosses the link linear (PR 43): the put seam is handed
+  1-D uint8 views of the staged chunk (no host copy), the fused program
+  stacks them on the device, and the result equals ``forward()`` on
+  ``[B, segment_size]`` byte for byte at RS(2,1) and RS(4,8);
 - the repair warm path (rs.py warm_reconstruct / engine.warm_repair)
   returns byte-exact reconstructions through pre-compiled programs.
 """
@@ -18,7 +22,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
+from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline, \
+    linear_rows
 from cess_tpu.ops import podr2, rs
 from cess_tpu.serve import AdmissionPolicy, make_engine
 from cess_tpu.serve.stream import StreamingIngest, _rebatch
@@ -173,6 +178,143 @@ def test_stream_detach_stops_metric_contribution():
         assert "cess_engine_stream_batches" not in eng.stats_metrics()
     finally:
         eng.close()
+
+
+# -- the linear way up (PR 43) ----------------------------------------------
+
+GEOMETRIES = {"rs2p1": (2, 1), "rs4p8": (4, 8)}
+
+
+def geometry_pipe(name, frag=FRAG):
+    k, m = GEOMETRIES[name]
+    return StoragePipeline(PipelineConfig(k=k, m=m, segment_size=k * frag),
+                           podr2_key=podr2.Podr2Key.generate(43))
+
+
+@pytest.mark.parametrize("id_kind", ["default", "pairs"])
+@pytest.mark.parametrize("n_segments", [6, 7], ids=["even", "ragged"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_linear_path_equals_forward(geometry, n_segments, id_kind):
+    """The driver's way (linear row views up, stacked on the device)
+    against ``forward()`` on the ``[N, segment_size]`` array: the same
+    fragments and tags, byte for byte."""
+    pipe = geometry_pipe(geometry)
+    cfg = pipe.config
+    rows = cfg.k + cfg.m
+    segs = rnd((n_segments, cfg.segment_size), 430 + n_segments)
+    ids = rnd((n_segments, rows, 2), 431, np.uint32) \
+        if id_kind == "pairs" else None
+    ing = StreamingIngest(pipe, 3)
+    got = ing.ingest(segs, fragment_ids=ids)
+    want = pipe.forward(segs, fragment_ids=ids)
+    assert got["fragments"].shape == (n_segments, rows, FRAG)
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(want[name])), name
+    st = ing.stats
+    assert st.linear_puts == st.batches == -(-n_segments // 3)
+    assert st.put_arrays == st.batches * 3 * cfg.k
+    assert st.raw()["linear_puts"] == st.batches
+    assert st.metrics()["cess_engine_stream_put_arrays"] == st.put_arrays
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_fused_program_takes_both_input_forms(geometry):
+    """One body: the linear rows the driver puts and the ``[B, S]``
+    array ``forward`` passes give the same bits."""
+    pipe = geometry_pipe(geometry)
+    cfg = pipe.config
+    segs = rnd((4, cfg.segment_size), 432)
+    ids = jnp.arange(4 * (cfg.k + cfg.m), dtype=jnp.int32)
+    rows_up = linear_rows(segs, cfg.k)
+    assert len(rows_up) == 4 * cfg.k
+    linear = pipe.fused_program()(jax.device_put(rows_up), ids)
+    array = pipe.fused_program()(jnp.asarray(segs), ids)
+    assert np.array_equal(np.asarray(linear["fragments"][:, :cfg.k])
+                          .reshape(4, -1), segs)       # systematic rows
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(linear[name]),
+                              np.asarray(array[name])), name
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_put_seam_receives_views_of_the_staged_chunk(geometry):
+    """No host copy on the way up: what the put seam is handed are 1-D
+    uint8 views into the caller's own array (a full batch of an array
+    source is staged where it lies); the ragged tail's pad is the one
+    copy, made before the views as before."""
+    pipe = geometry_pipe(geometry)
+    cfg = pipe.config
+    segs = rnd((5, cfg.segment_size), 433)
+    seen = []
+
+    def put(x):
+        seen.append(x)
+        return jax.device_put(x)
+
+    ing = StreamingIngest(pipe, 2, put=put)
+    got = ing.ingest(segs)
+    want = pipe.forward(segs)
+    assert np.array_equal(np.asarray(got["tags"]), np.asarray(want["tags"]))
+    batches = [x for x in seen if isinstance(x, tuple)]    # not the ids
+    assert len(batches) == 3
+    for i, rows_up in enumerate(batches):
+        assert len(rows_up) == 2 * cfg.k
+        for j, row in enumerate(rows_up):
+            assert isinstance(row, np.ndarray) and row.ndim == 1
+            assert row.dtype == np.uint8 and row.shape == (FRAG,)
+            assert row.flags.c_contiguous
+            assert np.shares_memory(row, segs) == (i < 2)   # tail: padded
+        # the views tile the chunk in order: row j of segment i at i*k+j
+        flat = np.concatenate(rows_up)
+        real = segs[2 * i:2 * i + 2].reshape(-1)
+        assert np.array_equal(flat[:real.size], real)
+        assert not flat[real.size:].any()
+    assert ing.stats.linear_puts == ing.stats.batches == 3
+
+
+def _spanned(log, name, fn):
+    """benchmark/spans.py ``Spans.wrap``'s shape: a plain closure."""
+    def call(*args, **kw):
+        log.append(name)
+        return fn(*args, **kw)
+    return call
+
+
+def _flip_parity(program):
+    """benchmark/traffic/stream.py's control: a program around the
+    program, handed whatever the put returned."""
+    def broken(dev, ids):
+        out = dict(program(dev, ids))
+        f = out["fragments"]
+        out["fragments"] = f.at[:, -1, 0].set(f[:, -1, 0] ^ 1)
+        return out
+    return broken
+
+
+@pytest.mark.parametrize("fault", [False, True], ids=["sound", "fault"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_the_benchmarks_seams_drive_the_linear_path(geometry, fault):
+    """A traced benchmark run hands the driver ``program=wrap(
+    fused_program())`` and ``put=wrap(jax.device_put)``, its controls a
+    program around ``program(dev, ids)``: the same code runs, linear."""
+    pipe = geometry_pipe(geometry)
+    segs = rnd((4, pipe.config.segment_size), 434)
+    log = []
+    program = pipe.fused_program()
+    if fault:
+        program = _flip_parity(program)
+    ing = StreamingIngest(
+        pipe, 2, program=_spanned(log, "stream.dispatch", program),
+        put=_spanned(log, "stream.device_put", jax.device_put))
+    got = ing.ingest(segs)
+    want = pipe.forward(segs)
+    assert log == ["stream.device_put", "stream.device_put",
+                   "stream.dispatch"] * 2               # rows, ids, call
+    assert np.array_equal(np.asarray(got["tags"]), np.asarray(want["tags"]))
+    differ = np.asarray(got["fragments"]) != np.asarray(want["fragments"])
+    assert differ.sum() == (4 if fault else 0)   # a parity byte a segment
+    assert ing.stats.linear_puts == ing.stats.batches == 2
 
 
 # -- sharded mesh stream entry ---------------------------------------------

@@ -1,6 +1,7 @@
 """The main path's Pallas kernels, the engine's two audit programs and
 its tag program, its flatten of a byte result into linear rows, the
-gateway's parity-rows program and the pooled stream step
+gateway's parity-rows program, the fused ingest program over the linear
+rows the stream driver puts and the pooled stream step
 over four chips, compiled at protocol widths for a
 DESCRIBED TPU v5e (no chip attached): the installed TPU compiler
 refuses here what it would refuse on the chip — a kernel Mosaic cannot
@@ -458,19 +459,69 @@ def test_parity_rows_compile_for_v5e(one_chip, for_tpu, shape, k):
     assert "reshape" not in compiled.as_text()
 
 
+def _whole_array_reshapes(text, row_bytes):
+    """``reshape`` instructions of a compiled program whose result is
+    larger than one row: the relayouting reshape whose compile time
+    grows with the array (models/pipeline.py). A row's own ``u8[n]`` ->
+    ``u8[1, n]`` is the stack's, and compiles in milliseconds."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = u8\[([\d,]*)\]\S* "
+                     r"reshape\(", line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")
+                          if d]) > row_bytes:
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("k,m", [pytest.param(4, 8, id="rs4p8"),
+                                 pytest.param(2, 1, id="rs2p1")])
+def test_linear_fused_program_compiles_for_v5e(one_chip, for_tpu, k, m):
+    """The one-chip stream cells' program as the driver calls it since
+    PR 43 (models/pipeline.py fused_program over ``linear_rows``): a
+    batch of 8 segments as its 8k linear ``u8[16 MiB / k]`` rows, stacked
+    on the device in front of the fused step. 1-D dense arguments (their
+    logical 128 MiB, where a ``u8[8, 2, 8 MiB]`` operand is twice that),
+    no reshape of more than a row, both kernels under their pinned
+    names, compiled in seconds."""
+    n = constants.SEGMENT_SIZE // k
+    cfg = PipelineConfig(k=k, m=m, segment_size=constants.SEGMENT_SIZE,
+                         strategy="pallas")
+    rows = tuple(jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
+                 for _ in range(8 * k))
+    ids = jax.ShapeDtypeStruct((8 * (k + m),), jnp.int32,
+                               sharding=one_chip)
+    t0 = time.perf_counter()
+    compiled = StoragePipeline(cfg).fused_program().lower(
+        rows, ids).compile()
+    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    text = compiled.as_text()
+    for name in (RS, TAGS):
+        assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", text), name
+    assert not _whole_array_reshapes(text, n)
+    out = compiled.out_info
+    assert out["fragments"].shape == (8, k + m, n)
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.argument_size_in_bytes - 8 * k * n < 65536, mem
+    _fits_hbm(compiled)
+
+
 def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):
     """The four-lane host's program (benchmark cell stream-4p8.pool4):
-    sharded_stream_step on a (4, 1) mesh at RS(4,8), 32 segments a batch.
-    Each chip must hold the one-chip step's two kernels under their
-    pinned names, and the step needs no collective."""
+    sharded_stream_step on a (4, 1) mesh at RS(4,8), 32 segments a batch,
+    handed over as stream_entry's ``put`` stages them since PR 43: 32
+    row slots ``u8[4 x 4 MiB]``, a lane's linear row in each. Each chip
+    must hold the one-chip step's two kernels under their pinned names,
+    stack its rows without a reshape of more than a row, and the step
+    needs no collective."""
     cfg = PipelineConfig(k=4, m=8, segment_size=constants.SEGMENT_SIZE,
                          strategy="pallas")
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("seg", "byte"))
     step = pmesh.sharded_stream_step(StoragePipeline(cfg), mesh)
+    rows_sh = NamedSharding(mesh, P(("seg", "byte")))
     args = [
-        jax.ShapeDtypeStruct((32, 4, 4 * MiB), jnp.uint8,
-                             sharding=NamedSharding(
-                                 mesh, P("seg", None, "byte"))),
+        tuple(jax.ShapeDtypeStruct((4 * 4 * MiB,), jnp.uint8,
+                                   sharding=rows_sh) for _ in range(32)),
         jax.ShapeDtypeStruct((32, 12), jnp.int32,
                              sharding=NamedSharding(mesh, P("seg", None)))]
     t0 = time.perf_counter()
@@ -481,4 +532,5 @@ def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):
         assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", text), name
     assert not re.search(r"all-reduce|all-gather|all-to-all|"
                          r"collective-permute|reduce-scatter", text)
+    assert not _whole_array_reshapes(text, 4 * MiB)
     _fits_hbm(compiled)

@@ -14,8 +14,21 @@ survivors as the engine sends them up since PR 32, q linear ``u8[N]`` rows
 put one by one and stacked into the kernel's ``u8[1, q, N]`` operand by a
 jitted program on the device (``ops/rs.py _stack_rows``' form), against the
 same bytes put as one ``u8[1, q, N]`` host array. A CPU run says nothing.
+
+    chiprun -- python tools/link_probe.py stream
+
+The stream table (PR 43): one streamed batch of the one-chip stream cells
+(8 segments of 16 MiB, 128 MiB) in every form the stream driver could put
+it in, as views of ONE C-contiguous host chunk (no host copy): the packed
+``u8[8, 16 MiB]`` the driver put until PR 43, the fragment-major
+``u8[8, k, n]`` the pool's entry puts, and the linear forms ``u8[128 MiB]``,
+``8 x u8[16 MiB]`` and ``8k x u8[n]``; each alone, "+ stack" (the put, then
+one jitted program that makes the ``u8[8, k, n]`` the fused step reads, in
+``split_rows``' slice-and-stack style) and "stack alone" (the same program
+over rows already on the device), at RS(4,8) (k = 4) and RS(2,1) (k = 2).
 """
 import statistics
+import sys
 import time
 
 import jax
@@ -86,10 +99,87 @@ def put_stacked(q):
         stack(*jax.device_put(hs))))
 
 
+SEGMENT = 16 << 20   # one segment
+BATCH = 8            # the one-chip stream cells' batch
+
+
+def _stackers(k):
+    """form -> (host views of a chunk, jitted stack to ``u8[BATCH, k, n]``
+    or None where the put already has that shape)."""
+    n = SEGMENT // k
+
+    def split(x):                        # models/pipeline.py split_rows
+        return jnp.stack([x[:, j * n:(j + 1) * n] for j in range(k)],
+                         axis=1)
+
+    def from_flat(x):
+        return jnp.stack([jnp.stack(
+            [x[i * SEGMENT + j * n:i * SEGMENT + (j + 1) * n]
+             for j in range(k)]) for i in range(BATCH)])
+
+    def from_segments(*segs):
+        return jnp.stack([jnp.stack([s[j * n:(j + 1) * n]
+                                     for j in range(k)]) for s in segs])
+
+    def from_rows(*rows):                # ops/rs.py _stack_rows
+        return jnp.stack([jnp.stack(rows[i:i + k])
+                          for i in range(0, len(rows), k)])
+
+    return {
+        f"u8[{BATCH},S]": (lambda c: [c], split),
+        f"u8[{BATCH},{k},S/{k}]": (
+            lambda c: [c.reshape(BATCH, k, n)], None),
+        f"u8[{BATCH}*S]": (lambda c: [c.reshape(-1)], from_flat),
+        f"{BATCH} x u8[S]": (list, from_segments),
+        f"{BATCH * k} x u8[S/{k}]": (
+            lambda c: list(c.reshape(BATCH * k, n)), from_rows),
+    }
+
+
+def stream_table():
+    rng = np.random.default_rng(43)
+    chunks = [rng.integers(0, 256, (BATCH, SEGMENT), dtype=np.uint8)
+              for _ in range(2)]         # alternated: no put of a warm page
+    print(f"S = {SEGMENT}; one batch = {BATCH} x S = "
+          f"{BATCH * SEGMENT >> 20} MiB; median (min) of {REPS}, ms")
+    print(f"{'k':>2} {'what':<16}{'put':>9}{'(min)':>9}{'GiB/s':>7}"
+          f"{'+ stack':>9}{'(min)':>9}{'GiB/s':>7}{'stack alone':>13}"
+          f"{'compile s':>11}")
+    gib = BATCH * SEGMENT / 2**30
+    for k in (4, 2):
+        for what, (views, stack) in _stackers(k).items():
+            hosts = [views(c) for c in chunks]
+            assert all(np.shares_memory(v, c)      # views, not copies
+                       for h, c in zip(hosts, chunks) for v in h)
+            put_ms, put_min = _median_ms(
+                lambda i: hosts[i % 2],
+                lambda hs: jax.block_until_ready(jax.device_put(hs)))
+            line = (f"{k:>2} {what:<16}{put_ms:>9.3f}{put_min:>9.3f}"
+                    f"{gib / (put_ms / 1e3):>7.2f}")
+            if stack is not None:
+                jitted = jax.jit(stack)
+                t0 = time.perf_counter()
+                jax.block_until_ready(jitted(*jax.device_put(hosts[0])))
+                compile_s = time.perf_counter() - t0
+                both, both_min = _median_ms(
+                    lambda i: hosts[i % 2],
+                    lambda hs: jax.block_until_ready(
+                        jitted(*jax.device_put(hs))))
+                alone, _ = _median_ms(
+                    lambda i: jax.device_put(hosts[i % 2]),
+                    lambda ds: jax.block_until_ready(jitted(*ds)))
+                line += (f"{both:>9.3f}{both_min:>9.3f}"
+                         f"{gib / (both / 1e3):>7.2f}{alone:>13.3f}"
+                         f"{compile_s:>11.2f}")
+            print(line, flush=True)
+
+
 def main():
     dev = jax.devices()[0]
-    print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}; "
-          f"N = {N}; median (min) of {REPS}, ms")
+    print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
+    if "stream" in sys.argv[1:]:
+        return stream_table()
+    print(f"N = {N}; median (min) of {REPS}, ms")
     table = [("d2h u8[1,1,N]", 1, whole(1, 1, N)),
              ("d2h u8[1,N]", 1, whole(1, N)),
              ("d2h u8[N]", 1, whole(N)),
