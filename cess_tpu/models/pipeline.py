@@ -39,6 +39,14 @@ from ..ops.rs import default_strategy, _MatrixApply, _stack_rows
 # [8, 4, 4 MiB] took over eight minutes and [4, 3, 8 MiB] ->
 # [12, 8 MiB] 168 s for a described v5e (PR 22 rehearsals), against
 # under two seconds for the slice/concatenate forms below. Same bytes.
+#
+# Who still regroups (PR 44): ``split_rows`` the ``[B, segment_size]``
+# input form (``forward``, ``encode_step``); ``merge_rows`` the engine
+# path's ``tag_step`` and the byte-sharded mesh steps (parallel/mesh.py).
+# The fused step does neither: the RS kernel writes the codeword
+# ``[B, k+m, n]`` itself and the tag kernel takes that batch as it is
+# (``fused_step``), so the 6.6 / 7.4 ms a batch of ``merge_rows`` and the
+# ``concatenate`` of data and parity are not in the stream cells' program.
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -213,26 +221,29 @@ class StoragePipeline:
         """The body of the fused encode+tag step over fragment-major
         rows: data [B, k, n] u8 + ids ([B*(k+m)] | [B, k+m] |
         [B, k+m, 2]) -> {"fragments": [B, k+m, n], "tags":
-        [B, k+m, blocks, limbs]}. Traced by exactly two callers, each
+        [B, k+m, blocks, limbs]}. The fragments keep that one shape
+        from the RS kernel, which writes the codeword (the data rows
+        pass through it, ops/rs.py ``codeword``), to the tag kernel,
+        which takes the batch as it is (ops/podr2_pallas.py
+        ``tag_fragments_fused``), and to the result: nothing in
+        between regroups rows. Traced by exactly two callers, each
         under ``jax.named_scope(FUSED_SCOPE)``: :meth:`fused_program`
-        (one chip, after ``split_rows``) and parallel/mesh.py's
-        sharded stream step on a (lanes, 1) mesh (per device, on rows
-        the host already staged fragment-major) — one body, so the
-        one-chip and the pooled program cannot drift apart."""
-        b = data.shape[0]
-        parity = self._parity(data)
-        shards = jnp.concatenate([data, parity], axis=-2)
-        rows = shards.shape[-2]
-        flat = merge_rows(shards)
+        (one chip, after ``stack_rows``) and parallel/mesh.py's
+        sharded stream step on a (lanes, 1) mesh (per device, on the
+        rows the host staged for it) — one body, so the one-chip and
+        the pooled program cannot drift apart."""
+        shards = self._parity.codeword(data)
+        b, rows, _ = shards.shape
         ids = fragment_ids.reshape(
             (b * rows, 2) if fragment_ids.ndim == 3 else (b * rows,))
-        tags = podr2.tag_fragments(self.podr2_key, ids, flat)
+        tags = podr2.tag_fragments(self.podr2_key, ids, shards)
         return {"fragments": shards,
                 "tags": tags.reshape(b, rows, *tags.shape[1:])}
 
     def fused_program(self):
-        """The fused encode+tag device program: ONE jitted call,
-        results bit-identical to encode_step -> tag_step. jit caches
+        """The fused encode+tag device program: ONE jitted call, one
+        call of each kernel, results bit-identical to encode_step ->
+        tag_step. jit caches
         per batch/id shape, so the streaming driver reuses one
         compiled program per bucket.
 

@@ -254,7 +254,10 @@ def _tag_batch(key: Podr2Key, fragment_ids, fragments, weights):
     or None — they are host arithmetic on a concrete alpha, so a caller
     whose key is traced brings them. The lowering follows the shape:
     the Pallas kernel inside its envelope, the plain-jnp MAC outside it
-    (and without weights): identical results either way."""
+    (and without weights): identical results either way. ``fragments``:
+    [F, bytes], or the fused ingest step's [B, rows, bytes] (F = B *
+    rows, row-major), which only the kernel's entry point takes apart
+    (podr2_pallas.tag_fragments_fused)."""
     from . import podr2_pallas
 
     sectors = key.alpha.shape[0]
@@ -264,15 +267,19 @@ def _tag_batch(key: Podr2Key, fragment_ids, fragments, weights):
             lambda i: prf_elems(key.prf_key, i, blocks,
                                 key.limbs))(fragment_ids)
         return podr2_pallas.tag_fragments_fused(weights, prf, fragments)
+    if fragments.ndim == 3:
+        fragments = fragments.reshape(-1, fragments.shape[-1])
     return jax.vmap(lambda i, d: tag_fragment(key, i, d))(fragment_ids,
                                                           fragments)
 
 
 def tag_fragments(key: Podr2Key, fragment_ids, fragments) -> jax.Array:
-    """Batched tag-gen: ids [F], fragments [F, fragment_bytes] ->
-    [F, blocks, limbs]. Routes through the fused Pallas kernel
-    (ops/podr2_pallas.py) when the shape envelope allows — identical
-    results, one VMEM pass instead of materialised pack/MAC stages.
+    """Batched tag-gen: ids [F], fragments [F, fragment_bytes] (or
+    still in their batch's shape, [B, rows, fragment_bytes] with F =
+    B * rows, row-major) -> [F, blocks, limbs]. Routes through the
+    fused Pallas kernel (ops/podr2_pallas.py) when the shape envelope
+    allows — identical results, one VMEM pass instead of materialised
+    pack/MAC stages.
     Eager, or inside the caller's own trace with the key its constants
     (models/pipeline.py fused_step); a tag batch a call, with the key
     as operands, is ``tag_dispatch``."""

@@ -25,7 +25,9 @@ bitwidth-changing bitcasts, and strided u8 gathers ICE the compiler —
 the interleaved weights avoid all three.
 
 Layout contract:
-- data [F, blocks, 2*sectors] uint8 (a reshape of the fragment bytes);
+- data [F, blocks, 2*sectors] uint8 (a reshape of the fragment bytes,
+  [F, bytes] or still in their batch's shape [B, rows, bytes]:
+  ``tag_fragments_fused``);
 - w0/w1 [limbs, 2*sectors] int32: the 16-bit limbs of W per MAC limb;
 - prf   [F, limbs, blocks] uint32 (limb-major: block axis on lanes);
 - out   [F, limbs, blocks] uint32, transposed by the caller to the
@@ -175,14 +177,34 @@ def tag_fragments_fused(weights, prf: jax.Array,
                         fragments: jax.Array) -> jax.Array:
     """fragments [F, bytes] uint8, prf [F, blocks, limbs] ->
     tags [F, blocks, limbs] (the tag_from_elems contract, fused).
-    ``weights``: ``weight_limbs(alpha)``, host arrays or traced."""
-    fcount, nbytes = fragments.shape
+    ``weights``: ``weight_limbs(alpha)``, host arrays or traced.
+
+    The fragments may keep their batch's shape, [B, rows, bytes] (F =
+    B * rows, row-major: models/pipeline.py fused_step): the kernel's
+    view is then taken by splitting the MINOR dimension first,
+    [B, rows, blocks, lanes], the one relayout of the bytes (a uint8
+    array is tiled over its last two dimensions), and the two leading
+    dimensions, no longer tiled, are swapped and merged at no cost:
+    the TPU keeps a ``u8[B, rows, bytes]`` batch with B second-minor
+    (rows = k + m is no multiple of the 8-row tile, a batch of 8 is),
+    so the kernel walks the fragments row-major in THAT order and only
+    the PRF values and the tags, 1/64 of the bytes, are reordered.
+    No ``[B * rows, bytes]`` copy is made in front."""
+    *lead, nbytes = fragments.shape
     w0, w1 = weights
     limbs, lanes = w0.shape
     blocks = nbytes // lanes
     tile = min(blocks, DEFAULT_BLOCK_TILE)
-    out = _tags_3d(jnp.asarray(w0), jnp.asarray(w1),
-                   jnp.moveaxis(prf, -1, 1),
-                   fragments.reshape(fcount, blocks, lanes),
+    prf = jnp.moveaxis(prf, -1, 1)                  # [F, limbs, blocks]
+    view = fragments.reshape(*lead, blocks, lanes)
+    if len(lead) == 2:
+        b, rows = lead
+        view = jnp.swapaxes(view, 0, 1).reshape(rows * b, blocks, lanes)
+        prf = jnp.swapaxes(prf.reshape(b, rows, limbs, blocks), 0, 1)
+        prf = prf.reshape(rows * b, limbs, blocks)
+    out = _tags_3d(jnp.asarray(w0), jnp.asarray(w1), prf, view,
                    limbs, lanes, tile)
+    if len(lead) == 2:
+        out = jnp.swapaxes(out.reshape(rows, b, limbs, blocks), 0, 1)
+        out = out.reshape(b * rows, limbs, blocks)
     return jnp.moveaxis(out, 1, -1)                 # [F, blocks, limbs]

@@ -149,6 +149,16 @@ def _apply_pallas(bmat: jax.Array, data: jax.Array) -> jax.Array:
     return rs_pallas.apply_operand(bmat, data)
 
 
+@jax.jit
+def _codeword_pallas(bmat: jax.Array, data: jax.Array) -> jax.Array:
+    """``_apply_pallas`` with the data rows passed through the kernel:
+    [..., q, n] -> [..., q + r, n], the rows as read and then the
+    product's, written by the one call."""
+    from . import rs_pallas
+
+    return rs_pallas.apply_operand(bmat, data, passthrough=True)
+
+
 # The dense lowerings: (matrix operands..., data) -> result, each one
 # module-level jit. The matrix's VALUES are arguments, so the program
 # jit compiles for one (matrix shape, data shape, placement) serves
@@ -307,13 +317,16 @@ class _MatrixApply:
 
         return rs_xor.apply_schedule(self._sched, data)
 
-    def __call__(self, data) -> jax.Array:
-        """Apply to ``data``: ``u8[..., q, n]`` or, under the dense
-        strategies, ``LinearRows`` (stacked inside the program)."""
+    def _check_rows(self, data) -> None:
         if data.shape[-2] != self.mat.shape[1]:
             raise ValueError(
                 f"expected {self.mat.shape[1]} shard rows, got {data.shape[-2]}"
             )
+
+    def __call__(self, data) -> jax.Array:
+        """Apply to ``data``: ``u8[..., q, n]`` or, under the dense
+        strategies, ``LinearRows`` (stacked inside the program)."""
+        self._check_rows(data)
         if self.strategy == "xor":
             return self._apply_xor(data)
         if self.strategy == "auto":
@@ -324,6 +337,17 @@ class _MatrixApply:
             return _apply_rows(self.operands(data.shape), data.rows,
                                strategy=self.strategy, q=data.q)
         return _DENSE[self.strategy](*self.operands(data.shape), data)
+
+    def codeword(self, data: jax.Array) -> jax.Array:
+        """``u8[..., q, n]`` -> ``u8[..., q + r, n]``: the data rows
+        followed by the product's, a systematic encode's fragments in
+        one array. Under ``pallas`` the kernel writes both (the rows
+        pass through it as read, rs_pallas ``_apply_3d``), so nothing
+        joins two arrays afterwards; the other strategies concatenate."""
+        if self.strategy != "pallas":
+            return jnp.concatenate([data, self(data)], axis=-2)
+        self._check_rows(data)
+        return _codeword_pallas(*self.operands(data.shape), data)
 
     def aot(self, shape, dtype=jnp.uint8, device=None):
         """AOT-compile a baked apply (``xor`` / ``auto``: the schedule

@@ -24,7 +24,19 @@ earlier stack and not re-measured on this code — see PERF.md):
 Layout contract: data [..., q, n] uint8 is viewed as [B, q, n] (segment
 rows are contiguous); the grid walks (segment-group, column-tile) and
 each step applies the (8rg x 8qg) GF(2) block-diagonal bit-matrix
-``kron(I_group, expand_bitmatrix(mat))`` to one (g x q x TILE_N) tile.
+``kron(I_group, expand_bitmatrix(mat))`` to one (g x q x TILE_N) tile
+and writes the (g x r x TILE_N) product: [B, q, n] -> [B, r, n].
+
+With the static ``passthrough`` (PR 44; only the fused ingest step
+passes it, models/pipeline.py fused_step through ops/rs.py
+``codeword``) a step's output tile is (g x (q + r) x TILE_N): its first
+q rows are the step's input tile, stored as read (a VMEM copy, no MXU
+work), the product's r rows follow — a systematic encode's codeword
+[B, q + r, n] out of the one call, so no ``concatenate`` joins data and
+parity behind it. Without it the kernel body, the grid and the block
+specs are exactly the ones above: every other caller's program (the
+codec's encode and decode, the repair and restoral classes) is what it
+was.
 """
 from __future__ import annotations
 
@@ -51,11 +63,17 @@ KERNEL_NAME = "_apply_3d"
 
 
 def _make_kernel(q: int, r: int, g: int, tile_n: int, subtiles: int,
-                 acc_dtype, mxu_pack: bool):
+                 acc_dtype, mxu_pack: bool, passthrough: bool = False):
     op_dtype = jnp.bfloat16 if acc_dtype == jnp.float32 else jnp.int8
     ts = tile_n // subtiles
 
+    # the product's rows of a step's output tile
+    out_rows = slice(q, q + r) if passthrough else slice(None)
+
     def kernel(bmat_ref, pack_ref, data_ref, out_ref):
+        if passthrough:
+            # the systematic rows: the step's input tile, stored as read
+            out_ref[:, :q, :] = data_ref[...]
         for s in range(subtiles):
             sl = slice(s * ts, (s + 1) * ts)
             data = data_ref[:, :, sl].astype(jnp.int32)      # [g, q, ts]
@@ -69,7 +87,7 @@ def _make_kernel(q: int, r: int, g: int, tile_n: int, subtiles: int,
                 y = (prod.astype(jnp.int32) & 1).astype(jnp.int8)
                 packed = jnp.dot(pack_ref[:], y,
                                  preferred_element_type=jnp.int32)
-                out_ref[:, :, sl] = packed.reshape(
+                out_ref[:, out_rows, sl] = packed.reshape(
                     g, r, ts).astype(jnp.uint8)
             else:
                 obits = prod.astype(jnp.int32) & 1           # parity == XOR
@@ -77,20 +95,25 @@ def _make_kernel(q: int, r: int, g: int, tile_n: int, subtiles: int,
                 weights = jax.lax.broadcasted_iota(
                     jnp.int32, (1, 1, 8, 1), 2)
                 packed = jnp.sum(obits << weights, axis=2)   # [g, r, ts]
-                out_ref[:, :, sl] = packed.astype(jnp.uint8)
+                out_ref[:, out_rows, sl] = packed.astype(jnp.uint8)
 
     return kernel
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 9))
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 9, 10))
 def _apply_3d(bmat: jax.Array, packmat: jax.Array, q: int, r: int, g: int,
               tile_n: int, subtiles: int, use_int8: bool,
-              data3d: jax.Array, mxu_pack: bool) -> jax.Array:
-    """bmat [8rg, 8qg] block-diag; data3d [B, q, n] -> [B, r, n]."""
+              data3d: jax.Array, mxu_pack: bool,
+              passthrough: bool = False) -> jax.Array:
+    """bmat [8rg, 8qg] block-diag; data3d [B, q, n] -> [B, r, n], or
+    with ``passthrough`` [B, q + r, n]: the input rows, then the
+    product's."""
     b, _, n = data3d.shape
     acc_dtype = jnp.int32 if use_int8 else jnp.float32
-    kernel = _make_kernel(q, r, g, tile_n, subtiles, acc_dtype, mxu_pack)
+    kernel = _make_kernel(q, r, g, tile_n, subtiles, acc_dtype, mxu_pack,
+                          passthrough)
     grid = (b // g, n // tile_n)
+    rows = q + r if passthrough else r
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -102,11 +125,11 @@ def _apply_3d(bmat: jax.Array, packmat: jax.Array, q: int, r: int, g: int,
             pl.BlockSpec((g, q, tile_n), lambda i, t: (i, 0, t),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((g, r, tile_n), lambda i, t: (i, 0, t),
+        out_specs=pl.BlockSpec((g, rows, tile_n), lambda i, t: (i, 0, t),
                                memory_space=pltpu.VMEM),
         # vma: inside shard_map (parallel/mesh.py) the output varies
         # over the same mesh axes as the data shard it was made from
-        out_shape=jax.ShapeDtypeStruct((b, r, n), jnp.uint8,
+        out_shape=jax.ShapeDtypeStruct((b, rows, n), jnp.uint8,
                                        vma=jax.typeof(data3d).vma),
         interpret=target.interpret(),
         name=KERNEL_NAME,
@@ -147,12 +170,15 @@ def apply_operand(bmat: jax.Array, data: jax.Array,
                   tile_n: int = DEFAULT_TILE_N, use_int8: bool = True,
                   group: int = DEFAULT_GROUP,
                   subtiles: int = DEFAULT_SUBTILES,
-                  mxu_pack: bool = True) -> jax.Array:
+                  mxu_pack: bool = True,
+                  passthrough: bool = False) -> jax.Array:
     """Apply the matrix operand ``bmat`` (``operand_np`` for this
     batch's group, on the device or traced) to [..., q, n] uint8 data.
 
-    Returns [..., r, n] uint8. n is padded to a multiple of tile_n if
-    needed (zero columns encode to zero parity — harmless, stripped).
+    Returns [..., r, n] uint8, or with ``passthrough`` [..., q + r, n]:
+    the data rows as read, then the product's (the module note). n is
+    padded to a multiple of tile_n if needed (zero columns encode to
+    zero parity — harmless, stripped).
     """
     data = jnp.asarray(data, dtype=jnp.uint8)
     *lead, q, n = data.shape
@@ -168,8 +194,8 @@ def apply_operand(bmat: jax.Array, data: jax.Array,
     while tile_n % sub:
         sub //= 2
     out = _apply_3d(bmat, jnp.asarray(_pack_np(r, g)), q, r, g, tile_n,
-                    sub, use_int8, flat, mxu_pack)
-    out = out.reshape(*lead, r, data.shape[-1])
+                    sub, use_int8, flat, mxu_pack, passthrough)
+    out = out.reshape(*lead, out.shape[-2], data.shape[-1])
     if pad:
         out = out[..., :n]
     return out

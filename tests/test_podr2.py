@@ -367,6 +367,49 @@ def test_tag_fragments_with_traced_key_falls_back():
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("limbs", [2, 3])
+@pytest.mark.parametrize("b,rows", [(1, 3), (3, 3), (8, 12), (2, 12), (5, 1)])
+@pytest.mark.parametrize("path", ["kernel", "jnp"])
+def test_tag_fragments_takes_the_batchs_shape(path, b, rows, limbs):
+    """Fragments still in their batch's shape, ``[B, rows, bytes]`` (the
+    fused ingest step since PR 44: no ``[B * rows, bytes]`` copy in
+    front), tag to the same words as the same bytes flat, ids and tags
+    row-major either way — through the kernel, which walks them rows
+    first, and outside its envelope through the plain MAC."""
+    from cess_tpu.ops import podr2_pallas
+
+    blocks = 4 if path == "kernel" else 192        # 192: ragged grid
+    assert podr2_pallas.supported(256, blocks) == (path == "kernel")
+    key = podr2.Podr2Key.generate(46, podr2.Podr2Params(limbs=limbs))
+    rng = np.random.default_rng(b * 31 + rows)
+    frags = rng.integers(0, 256, (b, rows, blocks * podr2.BLOCK_BYTES),
+                         dtype=np.uint8)
+    ids = rng.integers(0, 2 ** 32, (b * rows, 2), dtype=np.uint32)
+    flat = podr2.tag_fragments(key, ids, frags.reshape(b * rows, -1))
+    shaped = podr2.tag_fragments(key, ids, frags)
+    assert shaped.shape == (b * rows, blocks, limbs)
+    np.testing.assert_array_equal(np.asarray(shaped), np.asarray(flat))
+    one = podr2.tag_fragment(key, ids[-1], frags[-1, -1])
+    np.testing.assert_array_equal(np.asarray(shaped[-1]), np.asarray(one))
+
+
+def test_two_dimensional_tag_batch_traces_what_it_traced():
+    """A 2-D ``[F, bytes]`` batch (``TAG_PROGRAM``, the engine's tag
+    class) is untouched by the batch-shaped form: one reshape of the
+    fragments into the kernel's view, the PRF's limb-major transpose
+    and the tags' transpose back, nothing else moved."""
+    import jax
+
+    key = podr2.Podr2Key.generate(47)
+    frags = jax.ShapeDtypeStruct((4, 4 * podr2.BLOCK_BYTES), jnp.uint8)
+    ids = jax.ShapeDtypeStruct((4, 2), jnp.uint32)
+    text = str(jax.make_jaxpr(
+        lambda i, f: podr2.tag_fragments(key, i, f))(ids, frags))
+    assert text.count("u8[4,4,512] = reshape") == 1
+    assert text.count("transpose[permutation=(0, 2, 1)]") == 2
+    assert "permutation=(1, 0" not in text
+
+
 def test_fused_envelope_is_protocol_geometry_only():
     """Only sectors == 256 (the single Mosaic-validated shape) may
     route into the kernel; everything else takes the jnp path."""

@@ -44,9 +44,10 @@ def rnd(shape, seed=0, dtype=np.uint8):
     return rng.integers(0, np.iinfo(dtype).max, shape, dtype=dtype)
 
 
-def make_pipe(limbs=2):
+def make_pipe(limbs=2, strategy=None):
     key = podr2.Podr2Key.generate(27, podr2.Podr2Params(limbs=limbs))
-    return StoragePipeline(PipelineConfig(k=K, m=M, segment_size=SEG),
+    return StoragePipeline(PipelineConfig(k=K, m=M, segment_size=SEG,
+                                          strategy=strategy),
                            podr2_key=key)
 
 
@@ -61,14 +62,17 @@ def sharded_step(pipe, mesh, segs, ids, pair=False):
 
 def plain_reference(pipe, segs, ids):
     """Fragments by the NumPy codec, tags one fragment at a time through
-    the plain jnp MAC (no kernel, no batching, no mesh)."""
-    codec = ReferenceCodec(K, M)
-    frags = np.stack([codec.encode(s.reshape(K, FRAG)) for s in segs])
-    flat_ids = ids.reshape(len(segs) * ROWS, *ids.shape[2:])
+    the plain jnp MAC (no kernel, no batching, no mesh), at the
+    pipeline's own geometry (tests/test_stream.py holds the one-chip
+    fused program to it too)."""
+    k, rows = pipe.config.k, pipe.config.k + pipe.config.m
+    codec = ReferenceCodec(k, pipe.config.m)
+    frags = np.stack([codec.encode(s.reshape(k, -1)) for s in segs])
+    flat_ids = ids.reshape(len(segs) * rows, *ids.shape[2:])
     tags = np.stack([
         np.asarray(podr2.tag_fragment(pipe.podr2_key, fid, frag))
-        for fid, frag in zip(flat_ids, frags.reshape(-1, FRAG))])
-    return frags, tags.reshape(len(segs), ROWS, *tags.shape[1:])
+        for fid, frag in zip(flat_ids, frags.reshape(len(flat_ids), -1))])
+    return frags, tags.reshape(len(segs), rows, *tags.shape[1:])
 
 
 @pytest.mark.parametrize("n_segments", [16, 11], ids=["even", "ragged"])
@@ -98,12 +102,20 @@ def test_pooled_stream_matches_plain_reference(limbs, id_kind, n_segments):
     assert ing.stats.padded_segments == -n_segments % BATCH
 
 
-@pytest.mark.parametrize("seg,byte", [(4, 1), (2, 2), (1, 4)])
+@pytest.mark.parametrize("seg,byte,strategy", [
+    (4, 1, None), (2, 2, None), (1, 4, None),
+    # the chip's lowering (interpret mode) where the pool's lanes run it:
+    # per device the RS kernel writes the codeword and the tag kernel
+    # takes the lane's fragments in their batch's shape (PR 44), batch
+    # 2 a lane and batch 1 (the group degrades to 1)
+    (4, 1, "pallas"), (8, 1, "pallas")])
 @pytest.mark.parametrize("pair", [False, True], ids=["scalar", "pair"])
-def test_sharded_step_bit_identical_to_fused_program(seg, byte, pair):
+def test_sharded_step_bit_identical_to_fused_program(seg, byte, strategy,
+                                                     pair):
     """byte == 1 traces the fused step's own body per device; byte > 1
-    keeps the sliced-PRF jnp body. Same bits either way."""
-    pipe = make_pipe()
+    keeps the sliced-PRF jnp body. Same bits either way, and the same
+    as the plain reference."""
+    pipe = make_pipe(strategy=strategy)
     mesh = make_mesh(jax.devices()[:seg * byte], seg=seg, byte=byte)
     segs = rnd((BATCH, SEG), 5)
     ids = rnd((BATCH, ROWS, 2), 6, np.uint32) if pair else \
@@ -113,6 +125,9 @@ def test_sharded_step_bit_identical_to_fused_program(seg, byte, pair):
     for name in ("fragments", "tags"):
         assert np.array_equal(np.asarray(got[name]),
                               np.asarray(want[name])), name
+    want_frags, want_tags = plain_reference(pipe, segs, ids)
+    assert np.array_equal(np.asarray(got["fragments"]), want_frags)
+    assert np.array_equal(np.asarray(got["tags"]), want_tags)
 
 
 def test_pooled_step_traces_the_fused_steps_body(monkeypatch):

@@ -125,6 +125,56 @@ def test_pallas_kernel_matches_oracle(use_int8):
         np.testing.assert_array_equal(got, ref.encode_parity(data))
 
 
+@pytest.mark.parametrize("mxu_pack", [True, False], ids=["mxupack", "vpupack"])
+@pytest.mark.parametrize("use_int8", [True, False], ids=["int8", "bf16"])
+@pytest.mark.parametrize("batch", [1, 3, 4], ids=["b1", "b3-odd", "b4"])
+@pytest.mark.parametrize("k,m", [(2, 1), (4, 8)], ids=["rs2p1", "rs4p8"])
+def test_pallas_passthrough_writes_the_codeword(k, m, batch, use_int8,
+                                                mxu_pack):
+    """The kernel with its static pass-through (PR 44, interpret mode):
+    the input rows come out first, as read, and the product's rows
+    after them, in one array — the same bytes as parity + concatenate,
+    whatever the group (an odd batch degrades it to 1), the pad to the
+    tile and the lowering. Without it the kernel's output is what it
+    was: the oracle's parity."""
+    from cess_tpu.ops.rs_pallas import apply_operand, group_for, operand_np
+
+    ref = ReferenceCodec(k, m)
+    bmat = jnp.asarray(
+        operand_np(gf.expand_bitmatrix(ref.parity), group_for(batch),
+                   use_int8),
+        dtype=jnp.int8 if use_int8 else jnp.bfloat16)
+    for n in (512, 700):
+        data = rand((batch, k, n), seed=n + batch)
+        kw = dict(tile_n=512, use_int8=use_int8, mxu_pack=mxu_pack)
+        parity = np.asarray(apply_operand(bmat, data, **kw))
+        np.testing.assert_array_equal(parity, ref.encode_parity(data))
+        got = np.asarray(apply_operand(bmat, data, passthrough=True, **kw))
+        assert got.shape == (batch, k + m, n)
+        np.testing.assert_array_equal(got[:, :k], data)    # the user's bytes
+        np.testing.assert_array_equal(
+            got, np.concatenate([data, parity], axis=-2))
+
+
+@pytest.mark.parametrize("k,m", GEOMETRIES)
+@pytest.mark.parametrize("strategy", STRATEGIES + ["xor", "auto"])
+def test_matrix_apply_codeword_is_the_systematic_encode(k, m, strategy):
+    """``_MatrixApply.codeword`` (what the fused ingest step calls): the
+    data rows followed by the parity rows under every strategy, one
+    kernel call under ``pallas``, bit-identical to ``TPUCodec.encode``
+    and the oracle."""
+    from cess_tpu.ops.rs import _MatrixApply
+
+    data = rand((3, k, 640), seed=k * 7 + m)
+    apply_ = _MatrixApply(gf.cauchy_parity_matrix(k, m), strategy)
+    got = np.asarray(apply_.codeword(jnp.asarray(data)))
+    np.testing.assert_array_equal(got, ReferenceCodec(k, m).encode(data))
+    np.testing.assert_array_equal(
+        got, np.asarray(TPUCodec(k, m, strategy=strategy).encode(data)))
+    with pytest.raises(ValueError, match="shard rows"):
+        apply_.codeword(jnp.asarray(data[:, :-1]))
+
+
 def test_bitmatrix_expansion_roundtrip():
     """expand_bitmatrix really is the GF multiply, for all 256 constants."""
     xs = np.arange(256, dtype=np.uint8).reshape(1, 256)

@@ -13,6 +13,12 @@
   1-D uint8 views of the staged chunk (no host copy), the fused program
   stacks them on the device, and the result equals ``forward()`` on
   ``[B, segment_size]`` byte for byte at RS(2,1) and RS(4,8);
+- the fused program keeps the fragments' shape between its two kernels
+  (PR 44: the RS kernel writes the codeword, the tag kernel takes the
+  batch as it is) and is still bit-identical to ``encode_step`` ->
+  ``tag_step`` and to the NumPy codec + the plain per-fragment MAC, at
+  both geometries, batch 1, an odd batch, pair ids, both input forms,
+  with the chip's ``pallas`` lowering (interpret mode) and the CPU's;
 - the repair warm path (rs.py warm_reconstruct / engine.warm_repair)
   returns byte-exact reconstructions through pre-compiled programs.
 """
@@ -27,6 +33,7 @@ from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline, \
 from cess_tpu.ops import podr2, rs
 from cess_tpu.serve import AdmissionPolicy, make_engine
 from cess_tpu.serve.stream import StreamingIngest, _rebatch
+from test_pool_stream import plain_reference
 
 K, M = 2, 1
 FRAG = 1024                 # 2 PoDR2 blocks per fragment
@@ -183,22 +190,61 @@ def test_stream_detach_stops_metric_contribution():
 # -- the linear way up (PR 43) ----------------------------------------------
 
 GEOMETRIES = {"rs2p1": (2, 1), "rs4p8": (4, 8)}
+# the lowering of the RS apply: the backend's default (``gather`` on the
+# CPU: parity + concatenate) and the chip's (``pallas``, here in
+# interpret mode: the kernel writes the codeword, PR 44)
+STRATEGIES = [pytest.param(None, id="default"),
+              pytest.param("pallas", id="pallas")]
 
 
-def geometry_pipe(name, frag=FRAG):
+def geometry_pipe(name, frag=FRAG, strategy=None):
     k, m = GEOMETRIES[name]
-    return StoragePipeline(PipelineConfig(k=k, m=m, segment_size=k * frag),
+    return StoragePipeline(PipelineConfig(k=k, m=m, segment_size=k * frag,
+                                          strategy=strategy),
                            podr2_key=podr2.Podr2Key.generate(43))
 
 
+@pytest.mark.parametrize("strategy", STRATEGIES)
+@pytest.mark.parametrize("id_kind", ["scalars", "pairs"])
+@pytest.mark.parametrize("batch", [1, 3, 4], ids=["b1", "b3-odd", "b4"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_fused_program_equals_the_steps_and_the_reference(
+        geometry, batch, id_kind, strategy):
+    """One program, the fragments in one shape from the RS kernel to
+    the tag kernel (PR 44), against the two steps it fuses and against
+    the plain reference: every byte and every tag word, and the
+    systematic rows are the user's bytes."""
+    pipe = geometry_pipe(geometry, strategy=strategy)
+    cfg = pipe.config
+    rows = cfg.k + cfg.m
+    segs = rnd((batch, cfg.segment_size), 440 + batch)
+    ids = rnd((batch, rows, 2), 441, np.uint32) if id_kind == "pairs" \
+        else rnd((batch, rows), 442, np.uint32).astype(np.int32)
+    got = pipe.fused_program()(jax.device_put(linear_rows(segs, cfg.k)),
+                               jnp.asarray(ids))
+    assert got["fragments"].shape == (batch, rows, FRAG)
+    assert got["tags"].shape == (batch, rows, FRAG // 512, 2)
+    frags = np.asarray(got["fragments"])
+    assert np.array_equal(frags[:, :cfg.k].reshape(batch, -1), segs)
+    shards = pipe.encode_step(segs)
+    assert np.array_equal(frags, np.asarray(shards))
+    assert np.array_equal(np.asarray(got["tags"]),
+                          np.asarray(pipe.tag_step(shards, ids)))
+    want_frags, want_tags = plain_reference(pipe, segs, ids)
+    assert np.array_equal(frags, want_frags)
+    assert np.array_equal(np.asarray(got["tags"]), want_tags)
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
 @pytest.mark.parametrize("id_kind", ["default", "pairs"])
 @pytest.mark.parametrize("n_segments", [6, 7], ids=["even", "ragged"])
 @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
-def test_linear_path_equals_forward(geometry, n_segments, id_kind):
+def test_linear_path_equals_forward(geometry, n_segments, id_kind,
+                                    strategy):
     """The driver's way (linear row views up, stacked on the device)
     against ``forward()`` on the ``[N, segment_size]`` array: the same
     fragments and tags, byte for byte."""
-    pipe = geometry_pipe(geometry)
+    pipe = geometry_pipe(geometry, strategy=strategy)
     cfg = pipe.config
     rows = cfg.k + cfg.m
     segs = rnd((n_segments, cfg.segment_size), 430 + n_segments)
@@ -218,11 +264,12 @@ def test_linear_path_equals_forward(geometry, n_segments, id_kind):
     assert st.metrics()["cess_engine_stream_put_arrays"] == st.put_arrays
 
 
+@pytest.mark.parametrize("strategy", STRATEGIES)
 @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
-def test_fused_program_takes_both_input_forms(geometry):
+def test_fused_program_takes_both_input_forms(geometry, strategy):
     """One body: the linear rows the driver puts and the ``[B, S]``
     array ``forward`` passes give the same bits."""
-    pipe = geometry_pipe(geometry)
+    pipe = geometry_pipe(geometry, strategy=strategy)
     cfg = pipe.config
     segs = rnd((4, cfg.segment_size), 432)
     ids = jnp.arange(4 * (cfg.k + cfg.m), dtype=jnp.int32)
