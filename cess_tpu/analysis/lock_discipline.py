@@ -287,11 +287,7 @@ class _NodeRule(Rule):
         # ops/regen.py joined in ISSUE 15: RegenCodec's warm/apply
         # caches are shared by the engine batcher and pool-lane worker
         # threads, so any locking it grows is this family's territory.
-        # ops/xor_sched.py + ops/rs_xor.py joined in ISSUE 18: the
-        # schedule memo and executor jit caches are hit from the same
-        # batcher/pool-lane threads via _MatrixApply.
-        if "ops" in parts and parts[-1] in ("regen.py", "xor_sched.py",
-                                            "rs_xor.py"):
+        if "ops" in parts and parts[-1] == "regen.py":
             return True
         return "serve" in parts or "node" in parts \
             or "resilience" in parts or "obs" in parts \

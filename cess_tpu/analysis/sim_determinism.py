@@ -51,12 +51,7 @@ class _SimRule(Rule):
         # and matrix constructions feed the repair storm's replay
         # contract, so a clock read or entropy draw there would break
         # bit-identical replays just like one inside sim/
-        # ops/xor_sched.py + ops/rs_xor.py (ISSUE 18): the schedule
-        # witness is canonical bytes — same matrix, byte-identical
-        # program on every host — so wallclock/entropy/dict-order
-        # anywhere in compile or execute breaks that contract
-        if "ops" in parts and parts[-1] in ("regen.py", "xor_sched.py",
-                                            "rs_xor.py"):
+        if "ops" in parts and parts[-1] == "regen.py":
             return True
         # the remediation plane's action journal is part of the replay
         # witness (same seed => byte-identical action log), so it is

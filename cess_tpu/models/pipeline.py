@@ -110,7 +110,9 @@ class PipelineConfig:
     k: int = constants.REF_K
     m: int = constants.REF_M
     segment_size: int = constants.SEGMENT_SIZE
-    strategy: str | None = None  # None -> rs.default_strategy()
+    # None -> rs.default_strategy(), the platform's; a test names the
+    # chip's lowering on a CPU box (ops/rs.py TPUCodec)
+    strategy: str | None = None
     sectors: int = podr2.SECTORS  # PoDR2 block geometry
 
     @property

@@ -62,7 +62,6 @@ import numpy as np
 from .. import constants
 from ..obs import trace
 from . import pfield as pf
-from . import xor_sched
 
 SECTORS = 256                       # field elements per block
 BLOCK_BYTES = SECTORS * pf.BYTES_PER_ELEM   # 512
@@ -529,12 +528,18 @@ _PROGRAMS = {"podr2.challenge": CHALLENGE_PROGRAM,
              "podr2.coeffs": COEFFS_PROGRAM}
 
 
+def _rows_bucket(rows: int) -> int:
+    """The power of two that ``rows`` pads to (1 for none): the row
+    count a program of this module is compiled for."""
+    return 1 << max(rows - 1, 0).bit_length()
+
+
 def _coeffs_dispatch(words, fragment_ids) -> jax.Array:
     """COEFFS_PROGRAM over host ids [F, 2], zero rows up to the next
     power of two; the first F of its r."""
     ids = np.asarray(fragment_ids).reshape(-1, 2)
     f = len(ids)
-    padded = np.zeros((xor_sched.rows_bucket(f), 2), np.uint32)
+    padded = np.zeros((_rows_bucket(f), 2), np.uint32)
     padded[:f] = ids
     r = COEFFS_PROGRAM(words, padded)
     return r if f == len(padded) else r[:f]
@@ -623,7 +628,7 @@ def chunk_plan(rows: int) -> tuple[int, int]:
     power-of-two bucket in one step up to PROVE_CHUNK, whole chunks
     past it."""
     if rows <= PROVE_CHUNK:
-        return xor_sched.rows_bucket(rows), 1
+        return _rows_bucket(rows), 1
     return PROVE_CHUNK, -(-rows // PROVE_CHUNK)
 
 
@@ -746,7 +751,7 @@ def round_coeffs(seed_bytes: bytes, fragment_ids) -> np.ndarray:
 
     def dispatch(words, ids):
         f = len(ids)
-        piece = min(xor_sched.rows_bucket(f), COEFF_ROWS)
+        piece = min(_rows_bucket(f), COEFF_ROWS)
         padded = np.zeros((-(-f // piece) * piece, 2), np.uint32)
         padded[:f] = ids
         parts = [COEFFS_PROGRAM(words, padded[at:at + piece])

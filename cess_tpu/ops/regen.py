@@ -29,7 +29,7 @@ Device surfaces live behind the existing ``ErasureCodec`` gate
 subclasses TPUCodec, swaps every decode/repair matrix construction for
 the closed form, and adds the batched symbol fold
 (``fold_symbol`` — a [1, 2] GF matmul over (accumulator, fragment) row
-pairs via the same gather/bitmatrix/pallas lowerings) through the
+pairs via the same gather/pallas lowerings) through the
 per-shape programs of ops/rs.py (the coefficient an operand), warmed by
 ``engine.warm_repair`` base and per lane.
 ``RegenReference`` is the NumPy twin serving as the byte-exact oracle
@@ -244,9 +244,8 @@ class RegenCodec(TPUCodec):
     repair matrix comes from the closed-form Cauchy construction, and
     ``fold_symbol`` runs the helper partial-sum hop as a batched device
     matmul. The warm path (``warm_reconstruct``, a program per shape
-    and placement; ``warm_hits`` under ``xor`` / ``auto``) is inherited
-    unchanged, so ``engine.warm_repair`` serves regen patterns the same
-    way it serves plain reconstructs.
+    and placement) is inherited unchanged, so ``engine.warm_repair``
+    serves regen patterns the same way it serves plain reconstructs.
 
     A fold's matrix is ``[1, coeff]``, one a coefficient, and a chain
     of k helpers asks for k of them a repair: RS(10,4) repaired from
@@ -269,8 +268,8 @@ class RegenCodec(TPUCodec):
         """Pre-compile + pre-stage the symbol fold for one exact pair
         shape, per device, and stage this coefficient's operands — the
         regen leg of ``engine.warm_repair``. Same contract as
-        ``warm_reconstruct``: under the dense strategies the
-        coefficient is an operand and one program folds them all."""
+        ``warm_reconstruct``: the coefficient is an operand and one
+        program folds them all."""
         self._warm_program(("symbol", (int(coeff),), ()), shape, device)
 
     def fold_symbol(self, pairs, coeff: int, *, sink: dict | None = None):

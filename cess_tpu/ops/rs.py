@@ -3,25 +3,19 @@
 The reference framework's segment->fragment erasure coding runs as a
 sequential CPU loop in off-chain components (SURVEY.md §2.3, §6); here
 it becomes a batched GF(2^8) matrix apply on TPU. Two lowerings, both
-byte-exact against the NumPy oracle (cess_tpu/ops/rs_ref.py):
+byte-exact against the NumPy oracle (cess_tpu/ops/rs_ref.py), and the
+platform chooses between them (``default_strategy``):
 
-- ``gather``: the classic SIMD "split table" scheme (two 16-entry
-  nibble tables per generator coefficient) vectorised over the byte
-  axis — VPU-bound, no bit expansion, minimal HBM traffic.
-- ``bitmatrix``: every GF(2^8) constant multiply is an 8x8 GF(2)
-  matrix, so the whole (r x q) GF apply becomes one (8r x 8q) 0/1
-  matrix applied to bit-planes with XOR accumulation = bf16 matmul on
-  the MXU followed by ``& 1``. 8x bit expansion, but all FLOPs land on
-  the systolic array. (A Pallas-fused variant that keeps the expansion
-  in VMEM lives in cess_tpu/ops/rs_pallas.py.)
-- ``xor``: the bitmatrix compiled ONCE into a CSE'd XOR schedule
-  (cess_tpu/ops/xor_sched.py) executed bit-sliced on the VPU
-  (cess_tpu/ops/rs_xor.py) — sparse work instead of the dense 8x
+- ``pallas``, the chip's: every GF(2^8) constant multiply is an 8x8
+  GF(2) matrix, so the whole (r x q) GF apply becomes one (8r x 8q)
+  0/1 matrix applied to bit-planes with XOR accumulation = a matmul on
+  the MXU followed by ``& 1``, fused into one Pallas kernel that keeps
+  the 8x bit expansion in VMEM (cess_tpu/ops/rs_pallas.py). Every
+  line of PERF_LEDGER.jsonl ran it.
+- ``gather``, the CPU's (the test mesh, the degraded fallback): the
+  classic SIMD "split table" scheme (two 16-entry nibble tables per
+  generator coefficient) vectorised over the byte axis, no bit
   expansion.
-- ``auto``: a compile-time cost model picks dense vs scheduled-XOR per
-  (matrix, dispatch shape); the choice is recorded in cache_meta so
-  program-cache keys attribute it. Explicit ``strategy=`` always
-  forces.
 
 Geometry (k, m) is first-class (reference pins FRAGMENT_COUNT=3 i.e.
 RS(2,1), /root/reference/runtime/src/lib.rs:1026-1027; BASELINE.json
@@ -29,18 +23,15 @@ targets RS(4,8)). Decode/repair matrices for a given erasure pattern
 are built host-side (tiny Gauss-Jordan) and applied with the same
 batched device kernels.
 
-One program per SHAPE, the pattern's matrix an operand. The dense
-lowerings (gather, bitmatrix, pallas) take the matrix's tables as
-arguments of the device program, so a repair program compiled for one
-``(q, r, n, batch)`` serves every ``(present, missing)`` of that shape:
-RS(10,4) has 4,004 single-loss patterns once the repairer takes
-whichever ten helpers answer, and none of them compiles anything after
-the shape is warm (``TPUCodec.warm_reconstruct``). What a pattern costs
-is its matrix (0.27 ms on the host at (10,4)) and the put of its
-operands (under 1 KiB); the codec keeps the newest
-``TPUCodec.MATRICES`` of them, operands placed, and rebuilds the rest.
-``xor`` / ``auto`` compile the matrix INTO the program by nature and
-stay one program per pattern.
+One program model: a lowering is a function of (matrix operands,
+data), so a program compiled for one ``(q, r, n, batch)`` serves every
+``(present, missing)`` of that shape. RS(10,4) has 4,004 single-loss
+patterns once the repairer takes whichever ten helpers answer, and
+none of them compiles anything after the shape is warm
+(``TPUCodec.warm_reconstruct``). What a pattern costs is its matrix
+(0.27 ms on the host at (10,4)) and the put of its operands (under
+1 KiB); the codec keeps the newest ``TPUCodec.MATRICES`` of them,
+operands placed, and rebuilds the rest.
 """
 from __future__ import annotations
 
@@ -49,7 +40,7 @@ import contextlib
 import functools
 import math
 import threading
-from typing import Callable, NamedTuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +50,7 @@ from ..obs import trace
 from ..resilience import faults
 from . import gf
 
-Strategy = str  # "gather" | "bitmatrix" | "pallas" | "xor" | "auto"
+Strategy = str  # "gather" | "pallas"
 
 # ---------------------------------------------------------------------------
 # Table construction (host side, tiny)
@@ -112,35 +103,6 @@ def _apply_gather(lo: jax.Array, hi: jax.Array, data: jax.Array) -> jax.Array:
 
 
 @jax.jit
-def _apply_bitmatrix(bmat: jax.Array, data: jax.Array) -> jax.Array:
-    """GF apply via the GF(2) bit-matrix lowering on the MXU.
-
-    bmat: [8r, 8q] bf16 0/1 matrix (gf.expand_bitmatrix of the GF matrix);
-    data: [..., q, n] uint8. Returns [..., r, n] uint8.
-    """
-    q = data.shape[-2]
-    n = data.shape[-1]
-    r8 = bmat.shape[0]
-    # unpack bytes to bit-planes: [..., q, n] -> [..., 8q, n]
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (data[..., :, None, :] >> shifts[None, :, None]) & 1  # [..., q, 8, n]
-    bits = bits.reshape(*data.shape[:-2], 8 * q, n)
-    # bit-matrix apply with f32 accumulation; entries <= 8q so exact
-    prod = jnp.einsum(
-        "ab,...bn->...an",
-        bmat,
-        bits.astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32,
-    )
-    obits = prod.astype(jnp.int32) & 1  # XOR accumulate == parity of the sum
-    # pack bit-planes back to bytes: [..., 8r, n] -> [..., r, n]
-    obits = obits.reshape(*data.shape[:-2], r8 // 8, 8, n)
-    weights = (1 << jnp.arange(8, dtype=jnp.int32))[None, :, None]
-    out = jnp.sum(obits * weights, axis=-2, dtype=jnp.int32)
-    return out.astype(jnp.uint8)
-
-
-@jax.jit
 def _apply_pallas(bmat: jax.Array, data: jax.Array) -> jax.Array:
     """GF apply via the fused Pallas kernel (ops/rs_pallas.py): bmat is
     ``rs_pallas.operand_np`` for this batch's group."""
@@ -159,12 +121,11 @@ def _codeword_pallas(bmat: jax.Array, data: jax.Array) -> jax.Array:
     return rs_pallas.apply_operand(bmat, data, passthrough=True)
 
 
-# The dense lowerings: (matrix operands..., data) -> result, each one
+# The lowerings: (matrix operands..., data) -> result, each one
 # module-level jit. The matrix's VALUES are arguments, so the program
 # jit compiles for one (matrix shape, data shape, placement) serves
 # every matrix of that shape.
-_DENSE = {"gather": _apply_gather, "bitmatrix": _apply_bitmatrix,
-          "pallas": _apply_pallas}
+_DENSE = {"gather": _apply_gather, "pallas": _apply_pallas}
 
 
 class LinearRows(NamedTuple):
@@ -201,16 +162,11 @@ def _stack_rows(rows, q: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("strategy", "q"))
 def _apply_rows(operands, rows, *, strategy: Strategy, q: int):
-    """The dense lowerings over linear rows: stack, then apply, one
+    """The lowerings over linear rows: stack, then apply, one
     program per (strategy, matrix shape, row count, n, placement). The
     inner program is traced into this one, so the Pallas kernel keeps
     its name (``_apply_3d``) in the compiled text and in a trace."""
     return _DENSE[strategy](*operands, _stack_rows(rows, q))
-
-
-# the stack alone, for the strategies whose program is the matrix
-# (``xor`` / ``auto``): their executables take the stacked array
-_STACK_ROWS = jax.jit(_stack_rows, static_argnames=("q",))
 
 
 # ---------------------------------------------------------------------------
@@ -219,43 +175,21 @@ _STACK_ROWS = jax.jit(_stack_rows, static_argnames=("q",))
 
 
 class _MatrixApply:
-    """A GF matrix as the operands of a chosen strategy's program (the
-    dense strategies), or compiled into it (``xor`` / ``auto``)."""
+    """A GF matrix as the operands of a chosen strategy's program."""
 
     def __init__(self, mat: np.ndarray, strategy: Strategy):
         self.mat = np.asarray(mat, dtype=np.uint8)
         self.strategy = strategy
-        # the dense strategies' operands on the device, put once per
-        # (placement, kernel group) and kept with the matrix
+        # the operands on the device, put once per (placement, kernel
+        # group) and kept with the matrix
         self._placed: dict[tuple, tuple] = {}
         if strategy == "gather":
             self._host = nibble_tables(self.mat)
-        elif strategy in ("bitmatrix", "pallas"):
+        elif strategy == "pallas":
             self._host = (gf.expand_bitmatrix(self.mat),)
-        elif strategy == "xor":
-            from . import xor_sched  # local: default strategies never pay it
-
-            self._sched = xor_sched.compile_schedule(
-                gf.expand_bitmatrix(self.mat))
-        elif strategy == "auto":
-            # compile-time cost model: bake BOTH lowerings, pick per
-            # dispatch shape (the decision is pure arithmetic over
-            # static shapes — results never change, only which program
-            # serves them; cache_meta records the choice)
-            from . import xor_sched
-
-            self._sched = xor_sched.compile_schedule(
-                gf.expand_bitmatrix(self.mat))
-            self._auto_base = _MatrixApply(self.mat, default_strategy())
         else:
-            raise ValueError(f"unknown strategy {strategy!r}")
-
-    @property
-    def baked(self) -> bool:
-        """True where the matrix is compiled into the program (one
-        program per matrix), False where it is the program's operand
-        (one program per shape)."""
-        return self.strategy not in _DENSE
+            raise ValueError(f"unknown strategy {strategy!r}: "
+                             "'gather' or 'pallas'")
 
     def operands(self, shape) -> tuple:
         """The matrix as the device operands of this strategy's program
@@ -275,47 +209,12 @@ class _MatrixApply:
             host = self._host
             if self.strategy == "pallas":
                 host = (rs_pallas.operand_np(host[0], group),)
-            dtype = jnp.bfloat16 if self.strategy == "bitmatrix" else None
-            placed = tuple(jnp.asarray(t, dtype=dtype) for t in host)
+            placed = tuple(jnp.asarray(t) for t in host)
             # under a jit trace the operands may be the trace's own:
             # never keep those
             if not any(isinstance(t, jax.core.Tracer) for t in placed):
                 self._placed[key] = placed
         return placed
-
-    def _decide(self, shape) -> dict:
-        """Cost-model verdict for one data shape (strategy="auto")."""
-        from . import xor_sched
-
-        rows = 1
-        for d in shape[:-2]:
-            rows *= int(d)
-        return xor_sched.estimate(self._sched.r8, self._sched.q8,
-                                  self._sched.n_xors,
-                                  xor_sched.rows_bucket(rows))
-
-    def cache_meta(self, shape) -> tuple:
-        """Program-cache key components attributing this apply: the
-        strategy that serves ``shape`` plus the cost-model estimate
-        (nested str/int tuples, so they ride ProgramCache keys into
-        OpProfiler/CompileLedger verbatim). Empty — zero cache-key
-        growth — for the dense default strategies."""
-        if self.strategy == "auto":
-            est = self._decide(tuple(shape))
-            return (("strategy", "auto:" + est["chosen"]),
-                    ("dense_cost", est["dense_cost"]),
-                    ("xor_cost", est["xor_cost"]),
-                    ("n_xors", est["n_xors"]))
-        if self.strategy == "xor":
-            return (("strategy", "xor"),
-                    ("n_xors", self._sched.n_xors),
-                    ("dense_xors", self._sched.dense_xors))
-        return ()
-
-    def _apply_xor(self, data: jax.Array) -> jax.Array:
-        from . import rs_xor
-
-        return rs_xor.apply_schedule(self._sched, data)
 
     def _check_rows(self, data) -> None:
         if data.shape[-2] != self.mat.shape[1]:
@@ -324,15 +223,9 @@ class _MatrixApply:
             )
 
     def __call__(self, data) -> jax.Array:
-        """Apply to ``data``: ``u8[..., q, n]`` or, under the dense
-        strategies, ``LinearRows`` (stacked inside the program)."""
+        """Apply to ``data``: ``u8[..., q, n]`` or ``LinearRows``
+        (stacked inside the program)."""
         self._check_rows(data)
-        if self.strategy == "xor":
-            return self._apply_xor(data)
-        if self.strategy == "auto":
-            if self._decide(data.shape)["chosen"] == "xor":
-                return self._apply_xor(data)
-            return self._auto_base(data)
         if isinstance(data, LinearRows):
             return _apply_rows(self.operands(data.shape), data.rows,
                                strategy=self.strategy, q=data.q)
@@ -343,23 +236,11 @@ class _MatrixApply:
         followed by the product's, a systematic encode's fragments in
         one array. Under ``pallas`` the kernel writes both (the rows
         pass through it as read, rs_pallas ``_apply_3d``), so nothing
-        joins two arrays afterwards; the other strategies concatenate."""
+        joins two arrays afterwards; ``gather`` concatenates."""
         if self.strategy != "pallas":
             return jnp.concatenate([data, self(data)], axis=-2)
         self._check_rows(data)
         return _codeword_pallas(*self.operands(data.shape), data)
-
-    def aot(self, shape, dtype=jnp.uint8, device=None):
-        """AOT-compile a baked apply (``xor`` / ``auto``: the schedule
-        IS the program, one executable a matrix) for one exact input
-        shape; calls of the executable skip the jit dispatch/tracing
-        machinery entirely (TPUCodec.warm_reconstruct). ``device`` pins
-        which device the executable is compiled and staged for (None =
-        the current default device); the compiled program is bound to
-        that one device."""
-        with _placed_on(device):
-            return jax.jit(self.__call__).lower(
-                jax.ShapeDtypeStruct(tuple(shape), dtype)).compile()
 
 
 def _placed_on(device):
@@ -373,21 +254,17 @@ def _placement_device():
     active ``jax.default_device`` scope's device (the pool's per-lane
     placement, serve/engine.py ``_lane_placement``), or None when no
     scope is active — JAX's backend default. This is the device
-    component of the keys of what is kept per device (a matrix's
-    placed operands, a baked pattern's AOT executable): an executable
-    is bound to the device it was compiled for, so a warm hit compiled
-    under device 0's scope must never be dispatched inside device 3's
-    (the one-device-assumption bug this key component fixes)."""
+    component of the key of what is kept per device, a matrix's
+    placed operands: operands put under device 0's scope must never be
+    handed to a program dispatched inside device 3's."""
     return jax.config.jax_default_device
 
 
 def default_strategy() -> Strategy:
-    """Pick the lowering for the current default backend.
-
-    Every cell of the benchmark runs ``pallas`` on the chip (every
-    line of PERF_LEDGER.jsonl; PERF.md section 5); ``gather`` is the
-    CPU path (the test mesh, the degraded fallback).
-    """
+    """Platform -> lowering, the only selector there is: ``gather``
+    on the CPU (the test mesh, the degraded fallback), ``pallas`` on
+    every other backend (every cell of the benchmark, every line of
+    PERF_LEDGER.jsonl; PERF.md section 5)."""
     return "gather" if jax.default_backend() == "cpu" else "pallas"
 
 
@@ -401,9 +278,13 @@ class TPUCodec:
     What it keeps, and how much: the newest ``MATRICES`` decode /
     repair matrices (an LRU of ``_MatrixApply``, each with its device
     operands: a matrix takes a fraction of a millisecond to rebuild).
-    The dense strategies' programs are jit's to keep, one per (matrix
-    shape, data shape, placement); under ``xor`` / ``auto`` the codec
-    keeps one AOT executable per warmed (pattern, data shape, device).
+    The programs are jit's to keep, one per (matrix shape, data shape,
+    placement).
+
+    ``strategy`` stays an argument (here, ``RegenCodec``, ``make_codec``,
+    ``PipelineConfig``) because two platforms need two values: it is
+    how tier-1 runs the chip's kernel in interpret mode and how
+    tests/test_tpu_compile.py compiles it for the TPU from a CPU box.
     """
 
     MATRICES = 64
@@ -420,19 +301,6 @@ class TPUCodec:
         self._cache: "collections.OrderedDict[tuple, _MatrixApply]" = \
             collections.OrderedDict()
         self._mu = threading.Lock()
-        # xor / auto only: (pattern, data shape, device) -> the AOT
-        # executable with the pattern's schedule baked in, and its
-        # observable dispatches: lets callers (the tests) PROVE the
-        # warm program ran rather than a silent fallback to the cold
-        # jit path
-        self._warm: dict[tuple, Callable] = {}
-        self.warm_hits = 0
-
-    @property
-    def baked(self) -> bool:
-        """True under ``xor`` / ``auto``: a pattern's matrix is
-        compiled into its program, so warming is per pattern."""
-        return self._parity_apply.baked
 
     # -- encode -------------------------------------------------------------
     def encode_parity(self, data: jax.Array) -> jax.Array:
@@ -479,7 +347,7 @@ class TPUCodec:
                 apply_ = _MatrixApply(
                     self._build_matrix(kind, present, missing),
                     self.strategy)
-            if shape is not None and not apply_.baked:
+            if shape is not None:
                 apply_.operands(shape)
         with self._mu:
             apply_ = self._cache.setdefault(key, apply_)
@@ -495,44 +363,24 @@ class TPUCodec:
         return present, tuple(missing)
 
     def _warm_program(self, pattern: tuple, shape, device) -> None:
-        """Compile, once, the program that serves ``pattern`` at
-        ``shape`` on ``device`` (None: the current placement), and
-        stage this pattern's operands there. Dense strategies: one run
-        of the strategy's jitted program over zeros — jit keeps one
-        executable per (matrix shape, data shape, placement), and every
-        later matrix of that shape is only its argument. Baked ones: an
-        AOT executable of the pattern's own, kept in ``_warm``."""
+        """Compile, once, the program that serves ``pattern``'s shape
+        at ``shape`` on ``device`` (None: the current placement), and
+        stage this pattern's operands there: one run of the strategy's
+        jitted program over zeros. jit keeps one executable per (matrix
+        shape, data shape, placement), and every later matrix of that
+        shape is only its argument."""
         apply_ = self._matrix_for(*pattern)
         with _placed_on(device):
-            if not apply_.baked:
-                jax.block_until_ready(apply_(jnp.zeros(shape, jnp.uint8)))
-                return
-            key = (pattern, tuple(shape), _placement_device())
-        if key not in self._warm:
-            self._warm[key] = apply_.aot(shape, device=device)
+            jax.block_until_ready(apply_(jnp.zeros(shape, jnp.uint8)))
 
     def _apply(self, pattern: tuple, data,
                sink: dict | None = None) -> jax.Array:
         """Apply the pattern's matrix to ``data`` (an array, or
         ``LinearRows`` already on the device): the strategy's jitted
-        program with the matrix as its operands or, under ``xor`` /
-        ``auto``, the pattern's warmed executable for this shape and
-        placement when there is one (the key carries the CURRENT
-        placement: under a pool lane's default_device scope only that
-        lane's executable can hit). Those executables take the array:
-        linear rows are stacked for them by a program of its own."""
+        program with the matrix as its operands."""
         if not isinstance(data, LinearRows):
             data = jnp.asarray(data, dtype=jnp.uint8)
-        apply_ = self._matrix_for(*pattern, shape=data.shape, sink=sink)
-        if apply_.baked:
-            if isinstance(data, LinearRows):
-                data = _STACK_ROWS(data.rows, q=data.q)
-            warm = self._warm.get(
-                (pattern, tuple(data.shape), _placement_device()))
-            if warm is not None:
-                self.warm_hits += 1
-                return warm(data)
-        return apply_(data)
+        return self._matrix_for(*pattern, shape=data.shape, sink=sink)(data)
 
     def warm_reconstruct(self, present, missing=None, shape=None,
                          device=None):
@@ -545,9 +393,7 @@ class TPUCodec:
         in the latency budget (the benchmark's repair cells warm their
         shapes in set-up and count 0 compilations in their windows).
         The named pattern's matrix is built and its operands staged
-        too. Under ``xor`` / ``auto`` the matrix is the program: there
-        this warms the named pattern alone, as an AOT executable that
-        ``warm_hits`` counts the dispatches of.
+        too.
 
         ``device`` pins the device the program is compiled for (the
         device-pool path warms once per lane); None warms for the
@@ -585,22 +431,6 @@ class TPUCodec:
         faults.inject("rs.decode")
         return self._apply(("decode", tuple(present), ()), survivors, sink)
 
-    def program_meta(self, kind: str, present=(), missing=(),
-                     shape=()) -> tuple:
-        """Program-cache key metadata for one engine op: which strategy
-        serves (kind, pattern, shape) and the cost-model estimate that
-        picked it (serve/engine.py appends this to ProgramCache keys so
-        OpProfiler/CompileLedger attribute the choice). Returns () — no
-        key growth at all — unless this codec runs strategy "xor" or
-        "auto"; the default strategies stay invisible here."""
-        if self.strategy not in ("xor", "auto"):
-            return ()
-        if kind == "encode":
-            apply_ = self._parity_apply
-        else:
-            apply_ = self._matrix_for(kind, tuple(present), tuple(missing))
-        return apply_.cache_meta(tuple(shape))
-
 
 # ---------------------------------------------------------------------------
 # ErasureCodec factory — the trait boundary of the north star
@@ -616,6 +446,8 @@ def make_codec(k: int, m: int, backend: str = "cpu", strategy: Strategy | None =
     default and the JAX/TPU path selectable. backend: "cpu" | "native"
     (C++ via ctypes) | "tpu"/"jax" | "regen" (regenerating-code repair
     plane, ops/regen.py) | "auto" (tpu if a TPU is present).
+    strategy: the device codecs' lowering, for a test that names the
+    chip's on a CPU box (see TPUCodec); None lets the platform choose.
     """
     if backend == "auto":
         backend = "tpu" if jax.default_backend() != "cpu" else "cpu"

@@ -1,10 +1,10 @@
 """Where a Pallas kernel traced right now will run, and hence whether
 it is lowered for the TPU or interpreted.
 
-One function decides for all three kernels (rs_pallas, rs_xor,
-podr2_pallas): they are compiled by Mosaic when the dispatch lands on
-a TPU and run in Pallas interpret mode everywhere else (the CPU test
-mesh, an AuditBackend("cpu") pinned to the host device on a TPU box).
+One function decides for both kernels (rs_pallas, podr2_pallas):
+they are compiled by Mosaic when the dispatch lands on a TPU and run
+in Pallas interpret mode everywhere else (the CPU test mesh, an
+AuditBackend("cpu") pinned to the host device on a TPU box).
 """
 from __future__ import annotations
 

@@ -59,16 +59,14 @@ class ProgramCache:
     bucket policy has one place to be enforced.
 
     Bounded: the keys that do name data — the per-fragment
-    verify_batch closure, whose key still carries its round's digest,
-    and a repair under the ``xor`` / ``auto`` strategies, whose key
-    carries the schedule's cost-model meta — are hot for a while and
-    dead afterwards, and an unbounded dict would be a slow leak of
-    closures and what they captured. LRU with a generous capacity
-    keeps everything live resident and lets the dead fall out. The
-    repair class (one entry per ``(q, r, n, bucket)``, the pattern an
-    argument) and the stacked audit programs (prove, verify_agg: the
-    round and the PoDR2 key are operands) hold one entry per shape,
-    the same call after call.
+    verify_batch closure, whose key still carries its round's digest —
+    are hot for a while and dead afterwards, and an unbounded dict
+    would be a slow leak of closures and what they captured. LRU with
+    a generous capacity keeps everything live resident and lets the
+    dead fall out. The repair class (one entry per ``(q, r, n,
+    bucket)``, the pattern an argument) and the stacked audit programs
+    (prove, verify_agg: the round and the PoDR2 key are operands) hold
+    one entry per shape, the same call after call.
     """
 
     CAPACITY = 256

@@ -863,32 +863,23 @@ class SubmissionEngine:
         result leaves the device through (_fetch_linear). A claim
         whose survivors are already on the device runs the codec's
         program for the stacked array, which is NOT warmed here: the
-        first such batch of a shape compiles it. A codec whose
-        strategy compiles the matrix into the program (``xor`` /
-        ``auto``) is warmed for the named patterns alone, through
-        ``TPUCodec.warm_reconstruct``; a host codec has nothing to
-        warm."""
+        first such batch of a shape compiles it. A host codec has
+        nothing to warm."""
         self._need_codec()
         codec = self.codec
-        # (lane, its device): the base programs, then every lane's
+        # the base programs, then every lane's
         lanes = self.pool.lanes if self.pool is not None else ()
-        placements = [(None, None)] + [(lane, lane.device)
-                                       for lane in lanes]
+        placements = [None, *lanes]
         patterns = [(tuple(p), tuple(mi)) for p, mi in patterns]
 
-        def run(kind, aux, q, bucket, lane, device):
+        def run(kind, aux, q, bucket, lane):
             """One (kind, shape, bucket, placement): the cache entry,
             and on a device codec one run of a host claim's way over
-            zeros (after the codec's own warm call of the kind, for
-            the strategies that need one)."""
+            zeros."""
             prog, pattern = self._repair_program(codec, kind, aux, n,
                                                  bucket, False, lane)
             if not self._on_device(codec):
                 return
-            if codec.baked:
-                warm = codec.warm_fold if kind == "symbol" \
-                    else codec.warm_reconstruct
-                warm(*pattern, (bucket, q, n), device=device)
             with self._lane_placement(lane, False):
                 # the codec's own call: patterns_new counts batches
                 out = prog.__wrapped__(self._put_rows([], q, bucket, n),
@@ -908,9 +899,9 @@ class SubmissionEngine:
                 # across lanes without any lane paying compile/staging
                 # time (and a program warmed for device 0 is never
                 # handed a lane-3 batch)
-                for lane, device in placements:
+                for lane in placements:
                     run("reconstruct", aux, len(present), bucket_rows(b),
-                        lane, device)
+                        lane)
         # regen leg: when the codec carries the symbol surface
         # (RegenCodec.warm_fold), warm the helper-fold program and
         # stage every coefficient the single-missing patterns can ask
@@ -930,9 +921,8 @@ class SubmissionEngine:
         coeffs.discard(0)
         for c in sorted(coeffs):
             for b in buckets:
-                for lane, device in placements:
-                    run("symbol", {"coeff": c}, 2, bucket_rows(b), lane,
-                        device)
+                for lane in placements:
+                    run("symbol", {"coeff": c}, 2, bucket_rows(b), lane)
 
     def warm_verify(self, challenged: int, missions: int = 512) -> None:
         """Load the verify class's round programs for every shape a
@@ -1920,20 +1910,6 @@ class SubmissionEngine:
         return jax.default_device(lane.device)
 
     @staticmethod
-    def _codec_meta(codec, kind, present=(), missing=(), shape=()) -> tuple:
-        """Cost-model attribution components for a program-cache key:
-        codecs that auto-select a lowering (TPUCodec.program_meta,
-        strategy="xor"/"auto") report which strategy serves this
-        (kind, pattern, shape) plus the estimate that picked it, so
-        OpProfiler/CompileLedger keep the programs apart. Zero-cost
-        seam: one load + None check, and default-strategy codecs
-        return () — cache keys grow only when the selector is armed."""
-        meta = getattr(codec, "program_meta", None)
-        if meta is None:
-            return ()
-        return meta(kind, present=present, missing=missing, shape=shape)
-
-    @staticmethod
     def _key(key: tuple, degraded: bool, lane=None) -> tuple:
         """Degraded programs cache under their own keys — a breaker
         flip must never hand a device program a CPU batch or vice
@@ -1957,10 +1933,8 @@ class SubmissionEngine:
             _, k, n = data.shape
             data = _pad_axis0(data, bucket)
         with self._stage("encode", "dispatch"):
-            meta = self._codec_meta(codec, "encode",
-                                    shape=(bucket, k, n))
             prog = self.programs.get(self._key(("encode", k, n, bucket),
-                                               degraded, lane) + meta,
+                                               degraded, lane),
                                      lambda: codec.encode)
             out = prog(data)[:total]
         return self._split_rows(batch, out, lane), bucket
@@ -2027,13 +2001,9 @@ class SubmissionEngine:
         survivors, *pattern)``. The shape is the pattern's row counts
         ``(q, r)``; the pattern itself (which rows, which coefficient)
         is an argument, so a pattern never seen before builds no
-        program. Only the cost-model meta of a codec whose strategy
-        compiles the matrix into its program (``xor`` / ``auto``,
-        _codec_meta) still reads the pattern."""
+        program."""
         if kind == "reconstruct":
             present, missing = aux["present"], aux["missing"]
-            meta = self._codec_meta(codec, "repair", present, missing,
-                                    (bucket, len(present), n))
             key = ("repair", len(present), len(missing), n, bucket)
             call, pattern = codec.reconstruct, (present, missing)
         elif kind == "symbol":
@@ -2046,18 +2016,12 @@ class SubmissionEngine:
                 from ..ops import regen
 
                 call = regen.fold_symbol_pairs
-                meta = ()
-            else:
-                meta = self._codec_meta(codec, "symbol", pattern, (),
-                                        (bucket, 2, n))
             key = ("symbol", n, bucket)
         else:
             pattern = (aux["present"],)
-            meta = self._codec_meta(codec, "decode", pattern[0], (),
-                                    (bucket, len(pattern[0]), n))
             key = ("decode", len(pattern[0]), n, bucket)
             call = codec.decode_data
-        prog = self.programs.get(self._key(key, degraded, lane) + meta,
+        prog = self.programs.get(self._key(key, degraded, lane),
                                  lambda: self._counting_matrices(codec,
                                                                  call))
         return prog, pattern
@@ -2366,8 +2330,8 @@ class SubmissionEngine:
 
 
 def make_engine(k: int | None = None, m: int | None = None, *,
-                rs_backend: str = "cpu", strategy: str | None = None,
-                podr2_key=None, audit_backend: str = "cpu",
+                rs_backend: str = "cpu", podr2_key=None,
+                audit_backend: str = "cpu",
                 policy: AdmissionPolicy | None = None,
                 resilience=None, tracer=None, slo=None, adaptive=None,
                 admission=None, pool=None,
@@ -2406,7 +2370,7 @@ def make_engine(k: int | None = None, m: int | None = None, *,
     if k is not None:
         from ..ops import rs
 
-        codec = rs.make_codec(k, m, backend=rs_backend, strategy=strategy)
+        codec = rs.make_codec(k, m, backend=rs_backend)
     audit = None
     if podr2_key is not None:
         from ..ops import audit_backend as ab

@@ -809,50 +809,6 @@ class TestSimDeterminism:
         assert r.suppressed == []
         assert analysis.load_baseline(BASELINE) == {}
 
-    def test_xor_codec_joins_the_family(self):
-        """ISSUE 18: the XOR-schedule compiler's witness is canonical
-        bytes (same matrix => byte-identical program), and its memo /
-        executor jit caches are shared across batcher and pool-lane
-        threads — so ops/xor_sched.py and ops/rs_xor.py join the
-        determinism AND lock-discipline families while their ops/
-        siblings stay exempt."""
-        for path in ("cess_tpu/ops/xor_sched.py",
-                     "cess_tpu/ops/rs_xor.py"):
-            assert rules_at(lint(DIRTY_SIM, path)) == \
-                {"sim-wallclock", "sim-entropy"}, path
-            assert lint(CLEAN_SIM, path).findings == []
-            assert "lock-unguarded-write" in rules_at(
-                lint(DIRTY_LOCK, path)), path
-            assert not any(
-                r.startswith("lock-")
-                for r in rules_at(lint(CLEAN_LOCK, path))), path
-        # the borrow stays scoped: other ops modules inherit neither
-        assert lint(DIRTY_SIM, "cess_tpu/ops/fixture.py").findings == []
-        assert lint(DIRTY_LOCK,
-                    "cess_tpu/ops/fixture.py").findings == []
-
-    def test_xor_modules_scan_clean_under_every_family(self):
-        """ISSUE 18 satellite: the shipped ops/xor_sched.py and
-        ops/rs_xor.py pass trace-safety, lock-discipline, span-balance
-        AND the sim determinism family with zero suppressions; the
-        dirty twins prove each family really fires at both paths, and
-        the baseline stays empty."""
-        for path in ("cess_tpu/ops/xor_sched.py",
-                     "cess_tpu/ops/rs_xor.py"):
-            for dirty, rule in ((DIRTY_TRACE, "trace-print"),
-                                (DIRTY_LOCK, "lock-unguarded-write"),
-                                (DIRTY_SPAN, "span-balance"),
-                                (DIRTY_SIM, "sim-wallclock")):
-                assert rule in rules_at(lint(dirty, path)), (path, rule)
-        r = analysis.lint_paths(
-            [os.path.join(REPO, "cess_tpu", "ops", "xor_sched.py"),
-             os.path.join(REPO, "cess_tpu", "ops", "rs_xor.py")],
-            root=REPO)
-        assert r.errors == []
-        assert [f.format() for f in r.findings] == []
-        assert r.suppressed == []
-        assert analysis.load_baseline(BASELINE) == {}
-
     def test_remediate_module_scans_clean_under_every_family(self):
         """ISSUE 16 satellite: the shipped serve/remediate.py passes
         trace-safety, lock-discipline, span-balance AND the sim

@@ -178,12 +178,11 @@ def test_chaos_drill_journals_the_drain():
 
 # -- warm programs are device-keyed (the one-device key bugfix) -------------
 
-@pytest.mark.parametrize("strategy", ["gather", "xor"])
+@pytest.mark.parametrize("strategy", ["gather", "pallas"])
 def test_warm_reconstruct_hits_only_its_own_device(strategy, compiles):
     devs = jax.devices()
     assert len(devs) >= 3       # conftest: 8 virtual CPU devices
     codec = rs.TPUCodec(K, M, strategy=strategy)
-    baked = strategy == "xor"   # an AOT executable a pattern, counted
     data = rnd((K, 264), 11)    # a width no other test compiles
     coded = np.asarray(codec.encode(data))
     surv, present, missing = coded[[1, 2]], (1, 2), (0,)
@@ -195,19 +194,23 @@ def test_warm_reconstruct_hits_only_its_own_device(strategy, compiles):
     compiled = compiles()
     with jax.default_device(devs[2]):
         out = np.asarray(codec.reconstruct(surv, present, missing))
-    assert codec.warm_hits == 0 and compiles() > compiled
+    assert compiles() > compiled
     assert np.array_equal(out[0], data[0])
-    # warmed FOR its placement the same call compiles nothing
+    # warmed FOR its placement the same call compiles nothing, and
+    # neither does another pattern of the same shape: the program is
+    # the shape's, the pattern's matrix its argument
     compiled = compiles()
     with jax.default_device(devs[1]):
         out2 = np.asarray(codec.reconstruct(surv, present, missing))
-    assert codec.warm_hits == baked and compiles() == compiled
-    assert np.array_equal(out2, out)
+        other = np.asarray(codec.reconstruct(coded[[0, 2]], (0, 2), (1,)))
+    assert compiles() == compiled
+    assert np.array_equal(out2, out) and np.array_equal(other[0], data[1])
     # no scope + no device keeps the PR-2 single-device contract
     codec.warm_reconstruct(present, missing, surv.shape)
     compiled = compiles()
     np.asarray(codec.reconstruct(surv, present, missing))
-    assert codec.warm_hits == 2 * baked and compiles() == compiled
+    np.asarray(codec.reconstruct(coded[[0, 1]], (0, 1), (2,)))
+    assert compiles() == compiled
 
 
 def test_engine_warm_repair_warms_every_lane(compiles):
@@ -233,6 +236,38 @@ def test_engine_warm_repair_warms_every_lane(compiles):
                                      2)
                 jax.block_until_ready(
                     eng.codec.reconstruct(rows, (0, 2), (1,)))
+        assert compiles() == compiled
+    finally:
+        eng.close()
+
+
+def test_pool_warms_per_device_under_the_chips_lowering(compiles):
+    """The Pallas kernel (interpret mode here) behind a pool of two
+    lanes: warm_repair compiles the shape's program for each lane's
+    device, and after it neither a claim through the engine nor a
+    pattern never named, on either lane, compiles anything."""
+    from cess_tpu.serve.engine import SubmissionEngine
+
+    n = 392                         # a width no other test compiles
+    eng = SubmissionEngine(rs.TPUCodec(K, M, strategy="pallas"),
+                           policy=AdmissionPolicy(max_delay=0.002),
+                           pool=DevicePool(n=2))
+    try:
+        eng.warm_repair([((1, 2), (0,))], n, buckets=(1,))
+        assert {("repair", 2, 1, n, 1, ("device", 0)),
+                ("repair", 2, 1, n, 1, ("device", 1))} \
+            <= set(eng.programs._programs)
+        coded = rs.make_codec(K, M, backend="cpu").encode(rnd((1, K, n), 14))
+        compiled = compiles()
+        out = eng.reconstruct(coded[:, [0, 2]], (0, 2), (1,), timeout=60)
+        assert np.array_equal(np.asarray(out), coded[:, [1]])
+        for lane in eng.pool.lanes:
+            with jax.default_device(lane.device):
+                rows = rs.LinearRows(
+                    tuple(jax.device_put(list(coded[0, [0, 1]]))), 2)
+                got = eng.codec.reconstruct(rows, (0, 1), (2,))
+                assert got.devices() == {lane.device}
+                assert np.array_equal(np.asarray(got), coded[:, [2]])
         assert compiles() == compiled
     finally:
         eng.close()

@@ -259,6 +259,17 @@ def test_a_second_round_at_another_size_compiles_nothing(
             eng.close()
 
 
+def test_chunk_plan_pads_below_a_chunk_and_steps_past_it():
+    """(fragments a device step, steps): a held set below a chunk pads
+    to its power of two and runs in one step (one program a bucket);
+    past a chunk every step is a whole chunk (one program more)."""
+    assert [podr2.chunk_plan(rows) for rows in (0, 1, 2, 3, CHUNK)] == \
+        [(1, 1), (1, 1), (2, 1), (4, 1), (CHUNK, 1)]
+    assert [podr2.chunk_plan(rows)
+            for rows in (CHUNK + 1, 2 * CHUNK, 3 * CHUNK + 2)] == \
+        [(CHUNK, 2), (CHUNK, 2), (CHUNK, 4)]
+
+
 def test_round_coeffs_are_aggregate_coeffs_in_fixed_pieces(monkeypatch):
     """r as host words, from calls of one shape past COEFF_ROWS."""
     ids = np.random.default_rng(5).integers(

@@ -116,10 +116,9 @@ def test_unseen_patterns_build_no_program_and_compile_nothing(form,
         assert snap["classes"]["repair"]["linear_puts"] \
             == snap["classes"]["repair"]["batches"] == 300
         # the bounds: 8 programs and the codec's newest MATRICES
-        # matrices; the gather strategy keeps no executable of its own
+        # matrices; the codec keeps no executable of its own
         assert len(eng.programs) == 8
         assert len(codec._cache) <= TPUCodec.MATRICES == 64
-        assert not codec._warm
         repair = snap["classes"]["repair"]
         # make_codec hands every (10,4) engine of the process one codec:
         # at most MATRICES of the 300 can have been held already
@@ -143,7 +142,7 @@ def test_unservable_pattern_is_refused(present, missing, match):
     surv = np.zeros((len(present), N), np.uint8)
     with pytest.raises(ValueError, match=match):
         codec.reconstruct(surv, present, missing)
-    assert not codec._cache and not codec._warm
+    assert not codec._cache
     eng = _engine(10, 4)
     try:
         with pytest.raises(ValueError, match=match):

@@ -7,16 +7,18 @@ is held to the references by chip_smoke.py and by every cell of the
 benchmark (benchmark/run.py decides ``correct`` against
 benchmark/reference/).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cess_tpu.ops import gf
-from cess_tpu.ops.rs import TPUCodec, make_codec
+from cess_tpu.ops import gf, rs
+from cess_tpu.ops.rs import LinearRows, TPUCodec, make_codec
 from cess_tpu.ops.rs_ref import ReferenceCodec
 
-GEOMETRIES = [(2, 1), (4, 8), (4, 2), (10, 4)]
-STRATEGIES = ["gather", "bitmatrix", "pallas"]
+GEOMETRIES = [(2, 1), (2, 2), (3, 3), (4, 8), (4, 2), (10, 4)]
+# the two lowerings: the CPU's, and the chip's in interpret mode
+STRATEGIES = ["gather", "pallas"]
 
 
 def rand(shape, seed=0):
@@ -43,7 +45,7 @@ def test_encode_batched(strategy):
     np.testing.assert_array_equal(np.asarray(tpu.encode(data)), ref.encode(data))
 
 
-@pytest.mark.parametrize("k,m", [(2, 1), (4, 8)])
+@pytest.mark.parametrize("k,m", [(2, 1), (2, 2), (3, 3), (4, 8)])
 @pytest.mark.parametrize("strategy", STRATEGIES)
 def test_reconstruct_all_erasure_patterns(k, m, strategy):
     """Any k survivors recover every missing shard exactly."""
@@ -66,6 +68,14 @@ def test_reconstruct_all_erasure_patterns(k, m, strategy):
         np.testing.assert_array_equal(got_data, data)
 
 
+HELPERS_10P4 = [
+    ((0, 1, 2, 4, 5, 7, 8, 10, 12, 13), (3,)),
+    ((1, 2, 3, 4, 5, 6, 8, 9, 11, 13), (0,)),
+    ((0, 2, 3, 5, 6, 7, 9, 10, 11, 13), (1, 12)),
+    ((0, 1, 3, 4, 6, 7, 9, 10, 11, 12), (2, 5, 13)),
+    ((1, 2, 3, 5, 6, 8, 10, 11, 12, 13), (0, 4, 7, 9))]
+
+
 @pytest.mark.parametrize("strategy", STRATEGIES)
 def test_reconstruct_10p4_from_helpers_that_are_not_the_lowest(strategy):
     """The archival tier's repair: ten helpers that are NOT the k lowest
@@ -76,18 +86,60 @@ def test_reconstruct_10p4_from_helpers_that_are_not_the_lowest(strategy):
     ref = ReferenceCodec(k, m)
     tpu = TPUCodec(k, m, strategy=strategy)
     shards = ref.encode(rand((2, k, 128), seed=104))
-    for present, missing in [
-            ((0, 1, 2, 4, 5, 7, 8, 10, 12, 13), (3,)),
-            ((1, 2, 3, 4, 5, 6, 8, 9, 11, 13), (0,)),
-            ((0, 2, 3, 5, 6, 7, 9, 10, 11, 13), (1, 12)),
-            ((0, 1, 3, 4, 6, 7, 9, 10, 11, 12), (2, 5, 13)),
-            ((1, 2, 3, 5, 6, 8, 10, 11, 12, 13), (0, 4, 7, 9))]:
+    for present, missing in HELPERS_10P4:
         survivors = shards[:, list(present), :]
         got = np.asarray(tpu.reconstruct(survivors, present, missing))
         np.testing.assert_array_equal(got, shards[:, list(missing), :])
         np.testing.assert_array_equal(
             got, ref.reconstruct(survivors, present, missing))
-    assert len(tpu._cache) == 5 and not tpu._warm
+    assert len(tpu._cache) == 5
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_decode_data_10p4_from_helpers_that_are_not_the_lowest(
+        strategy, compiles):
+    """The same helpers asked for the DATA rows (a download that has to
+    decode): every set of ten gives back the user's bytes, and since
+    every decode matrix is 10 x 10 the first pattern's program serves
+    the other four, its matrix an argument."""
+    k, m = 10, 4
+    ref = ReferenceCodec(k, m)
+    tpu = TPUCodec(k, m, strategy=strategy)
+    data = rand((2, k, 136), seed=105)    # a width no other test compiles
+    shards = ref.encode(data)
+    compiled = []
+    for present, _ in HELPERS_10P4:
+        survivors = shards[:, list(present), :]
+        got = np.asarray(tpu.decode_data(survivors, present))
+        np.testing.assert_array_equal(got, data)
+        np.testing.assert_array_equal(
+            got, ref.decode_data(survivors, present))
+        compiled.append(compiles())
+    assert len(set(compiled)) == 1 and len(tpu._cache) == 5
+
+
+@pytest.mark.parametrize("kind", ["reconstruct", "decode_data"])
+@pytest.mark.parametrize("k,m", GEOMETRIES)
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_linear_rows_equal_the_stacked_array(k, m, strategy, kind):
+    """The chip's repair input since PR 32: the survivors as ``B * k``
+    linear ``u8[n]`` rows, stacked inside the program that applies the
+    matrix (``_apply_rows``). Byte for byte the same call on the stacked
+    array, and the oracle's answer."""
+    ref = ReferenceCodec(k, m)
+    tpu = TPUCodec(k, m, strategy=strategy)
+    data = rand((2, k, 128), seed=k * 13 + m)
+    shards = ref.encode(data)
+    present = tuple(range(m, m + k)) if m < k else tuple(range(k, 2 * k))
+    survivors = shards[:, list(present), :]
+    rows = LinearRows(tuple(jnp.asarray(r) for seg in survivors
+                            for r in seg), k)
+    assert rows.shape == survivors.shape
+    call = getattr(tpu, kind)
+    got = np.asarray(call(rows, present))
+    np.testing.assert_array_equal(got, np.asarray(call(survivors, present)))
+    np.testing.assert_array_equal(
+        got, getattr(ref, kind)(survivors, present))
 
 
 @pytest.mark.parametrize("strategy", STRATEGIES)
@@ -157,10 +209,10 @@ def test_pallas_passthrough_writes_the_codeword(k, m, batch, use_int8,
 
 
 @pytest.mark.parametrize("k,m", GEOMETRIES)
-@pytest.mark.parametrize("strategy", STRATEGIES + ["xor", "auto"])
+@pytest.mark.parametrize("strategy", STRATEGIES)
 def test_matrix_apply_codeword_is_the_systematic_encode(k, m, strategy):
     """``_MatrixApply.codeword`` (what the fused ingest step calls): the
-    data rows followed by the parity rows under every strategy, one
+    data rows followed by the parity rows under both strategies, one
     kernel call under ``pallas``, bit-identical to ``TPUCodec.encode``
     and the oracle."""
     from cess_tpu.ops.rs import _MatrixApply
@@ -173,6 +225,47 @@ def test_matrix_apply_codeword_is_the_systematic_encode(k, m, strategy):
         got, np.asarray(TPUCodec(k, m, strategy=strategy).encode(data)))
     with pytest.raises(ValueError, match="shard rows"):
         apply_.codeword(jnp.asarray(data[:, :-1]))
+
+
+@pytest.mark.parametrize("name", "xor auto bitmatrix".split())
+def test_a_strategy_that_is_not_a_lowering_is_refused(name):
+    """The codec has two lowerings; any other name is a caller's
+    mistake, refused where the codec is built and with the two named."""
+    with pytest.raises(ValueError, match="'gather' or 'pallas'"):
+        TPUCodec(2, 1, strategy=name)
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_leading_dims_and_row_mismatch(strategy):
+    """Any leading batch dimensions ride through an apply; a wrong row
+    count is refused before anything is dispatched, for an array and
+    for linear rows."""
+    ref = ReferenceCodec(2, 1)
+    tpu = TPUCodec(2, 1, strategy=strategy)
+    data = rand((2, 3, 2, 36), seed=10)
+    parity = np.asarray(tpu.encode_parity(data))
+    assert parity.shape == (2, 3, 1, 36)
+    np.testing.assert_array_equal(parity, ref.encode_parity(data))
+    shards = ref.encode(data)
+    got = np.asarray(tpu.reconstruct(shards[..., [1, 2], :], (1, 2)))
+    np.testing.assert_array_equal(got, shards[..., [0], :])
+    with pytest.raises(ValueError, match="shard rows"):
+        tpu.encode_parity(rand((3, 36), seed=1))
+    with pytest.raises(ValueError, match="shard rows"):
+        tpu.reconstruct(LinearRows(
+            tuple(jnp.asarray(r) for r in rand((3, 36), seed=2)), 3), (1, 2))
+
+
+def test_default_strategy_follows_the_platform(monkeypatch):
+    """Platform -> lowering, the one selector: ``gather`` on the CPU,
+    the Pallas kernel on anything else; a codec built without a
+    strategy takes it."""
+    assert rs.default_strategy() == "gather"      # conftest: the CPU
+    assert TPUCodec(2, 1).strategy == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert rs.default_strategy() == "pallas"
+    assert TPUCodec(2, 1).strategy == "pallas"
+    assert set(rs._DENSE) == {"gather", "pallas"}
 
 
 def test_bitmatrix_expansion_roundtrip():

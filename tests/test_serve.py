@@ -897,6 +897,37 @@ def test_program_cache_lru_bounded():
 
 # -- buckets + program cache -------------------------------------------------
 
+def test_codec_program_keys_name_shapes_only():
+    """One program model: the codec's classes key their programs by
+    (op, row counts, width, bucket) and nothing else. After an encode,
+    a warm_repair and claims of all three repair kinds the cache holds
+    flat tuples of str / int, one a shape: no pattern, no coefficient,
+    nothing a lowering added."""
+    k, m, n = 2, 1, 448                 # a width no other test compiles
+    eng = make_engine(k, m, rs_backend="regen",
+                      policy=AdmissionPolicy(max_delay=0.002))
+    try:
+        eng.encode(rnd((1, k, n), 90), timeout=60)
+        eng.warm_repair([((1, 2), (0,)), ((0, 2), (1,))], n, buckets=(1,))
+        warmed = set(eng.programs._programs)
+        for kind in ("reconstruct", "decode_data", "repair_symbol"):
+            payload, args, want = _repair_kind(kind, k, m, n, 91)
+            out = getattr(eng, kind)(payload, *args, timeout=60)
+            assert out.tobytes() == np.asarray(want).tobytes()
+        keys = set(eng.programs._programs)
+        # the claims built only what a decode needs: warm_repair does
+        # not name that kind
+        assert keys - warmed == {("decode", k, n, 1),
+                                 ("linear_rows", 1, k, n)}
+        assert keys == {("encode", k, n, 1), ("repair", k, 1, n, 1),
+                        ("symbol", n, 1), ("decode", k, n, 1),
+                        ("linear_rows", 1, 1, n), ("linear_rows", 1, k, n),
+                        ("linear_rows", 1, k + m, n)}
+        assert all(type(part) in (str, int) for key in keys for part in key)
+    finally:
+        eng.close()
+
+
 def test_bucket_padding_and_program_reuse(pkey):
     from cess_tpu.serve.buckets import bucket_rows
 

@@ -9,7 +9,7 @@ refuses here what it would refuse on the chip — a kernel Mosaic cannot
 lower, a program that does not fit 16 GiB of HBM — at no chip time.
 
 Nothing runs, so nothing here says anything about results or speed.
-Interpret-mode tests (test_rs_tpu / test_xor_sched / test_podr2) pin
+Interpret-mode tests (test_rs_tpu / test_podr2) pin
 the results; chip_smoke.py is the run on the chip.
 
 The topology is described inside a module-scoped fixture (never at
@@ -32,8 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 from cess_tpu import constants
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline
 from cess_tpu.node import offchain
-from cess_tpu.ops import gf, podr2, podr2_pallas, rs, rs_pallas, rs_xor, \
-    target, xor_sched
+from cess_tpu.ops import gf, podr2, podr2_pallas, rs, rs_pallas, target
 from cess_tpu.parallel import mesh as pmesh
 from cess_tpu.serve import engine
 
@@ -111,12 +110,6 @@ def _rs_operand():
     return rs._DENSE["pallas"]
 
 
-def _xor_encode(k, m):
-    sched = xor_sched.compile_schedule(
-        gf.expand_bitmatrix(gf.cauchy_parity_matrix(k, m)))
-    return lambda d: rs_xor.apply_schedule(sched, d, force="pallas")
-
-
 def _podr2_tags():
     key = podr2.Podr2Key.generate(0)
     w0, w1 = podr2_pallas.weight_limbs(key.alpha)
@@ -129,9 +122,9 @@ def _podr2_tags():
     return run
 
 
-def _fused_forward():
+def _fused_forward(k, m):
     # strategy named: default_strategy() asks the (CPU) backend
-    cfg = PipelineConfig(k=4, m=8, segment_size=constants.SEGMENT_SIZE,
+    cfg = PipelineConfig(k=k, m=m, segment_size=constants.SEGMENT_SIZE,
                          strategy="pallas")
     return StoragePipeline(cfg).fused_program()
 
@@ -158,10 +151,11 @@ CASES = [
     ("podr2_pallas-tags", _podr2_tags,
      [((8, 2, 16384), jnp.uint32), ((8, 16384, 512), jnp.uint8)],
      (TAGS,)),
-    ("rs_xor-rs4p8-encode", lambda: _xor_encode(4, 8),
-     [((8, 4, 4 * MiB), jnp.uint8)], ()),
-    ("fused-forward-rs4p8", _fused_forward,
+    # the fused ingest program's array form (a batch as u8[B, 16 MiB])
+    ("fused-forward-rs4p8", lambda: _fused_forward(4, 8),
      [((8, 16 * MiB), jnp.uint8), ((8 * 12,), jnp.int32)], (RS, TAGS)),
+    ("fused-forward-rs2p1", lambda: _fused_forward(2, 1),
+     [((8, 16 * MiB), jnp.uint8), ((8 * 3,), jnp.int32)], (RS, TAGS)),
 ]
 
 
@@ -422,7 +416,7 @@ def test_symbol_fold_compiles_for_v5e(one_chip, for_tpu, k, m):
     codec = regen.RegenCodec(k, m, strategy="pallas")
     coeff = regen.repair_coeffs(k, m, tuple(range(1, k + 1)), (0,))[0]
     apply_ = codec._matrix_for("symbol", (coeff,), ())
-    assert apply_.mat.tolist() == [[1, coeff]] and not apply_.baked
+    assert apply_.mat.tolist() == [[1, coeff]]
     bmat = rs_pallas.operand_np(apply_._host[0], rs_pallas.group_for(1))
     rows = tuple(jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
                  for _ in range(2))

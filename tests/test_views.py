@@ -123,39 +123,6 @@ class TestViewerSmoke:
         assert "segments (worst 1 of" in out
         assert "fragment timelines (first 2 of" in out
 
-    def test_xor_view_renders_the_schedule_dump_fixture(self, capsys):
-        # fixture collected from real engines (strategy="auto" and a
-        # forced strategy="xor") after encode + warm_repair +
-        # reconstruct traffic, so it carries both cost-model-chosen
-        # and forced program attributions
-        mod = _viewer("xor_view")
-        assert mod.main([_fixture("xor_schedule_dump.json")]) == 0
-        out = capsys.readouterr().out
-        assert "xor-schedule dump:" in out
-        assert "compiled schedules (" in out
-        assert "cached programs (" in out
-        assert "scratch high-water" in out
-        assert "saving" in out
-        # chosen-vs-forced strategy per cached program is visible
-        assert "[cost-model]" in out and "[forced]" in out
-        assert "strategy=auto:" in out and "strategy=xor" in out
-
-    def test_xor_view_collect_roundtrips_a_live_engine(self, capsys):
-        import numpy as np
-
-        from cess_tpu.serve import make_engine
-
-        mod = _viewer("xor_view")
-        eng = make_engine(2, 1, rs_backend="jax", strategy="xor")
-        try:
-            eng.encode(np.zeros((2, 64), np.uint8))
-            dump = mod.collect(eng)
-        finally:
-            eng.close()
-        assert dump["kind"] == "xor_schedule_dump"
-        assert dump["schedules"] and dump["programs"]
-        assert all(p["forced"] for p in dump["programs"])
-
     def test_viewers_reject_foreign_payloads(self):
         # each _load names its RPC in the rejection so an operator
         # who mixes up dump files learns which file they actually got
@@ -166,8 +133,7 @@ class TestViewerSmoke:
                               ("remediation_view",
                                "chain_status.json"),
                               ("custody_view",
-                               "remediation_status.json"),
-                              ("xor_view", "profile_dump.json")):
+                               "remediation_status.json")):
             mod = _viewer(viewer)
             with pytest.raises(SystemExit):
                 mod.main([_fixture(wrong)])
