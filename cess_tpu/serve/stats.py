@@ -249,7 +249,7 @@ class StreamStats:
     """
 
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
-                 "linear_puts", "put_arrays",
+                 "bytes_out", "linear_puts", "put_arrays",
                  "h2d_s", "dispatch_s", "stall_s", "wall_s")
     __slots__ = _COUNTERS + ("lanes", "hist")
 
@@ -258,6 +258,10 @@ class StreamStats:
         self.segments = 0          # real segments ingested
         self.padded_segments = 0   # zero rows added to the ragged tail
         self.bytes_in = 0          # host bytes staged (real, not pad)
+        # fragment bytes + tag bytes of every finished batch (real rows,
+        # not pad): over ``bytes_in`` and less the tags, the code's
+        # stored bytes per user byte as a count ((k + m) / k exactly)
+        self.bytes_out = 0
         # batches whose bytes went up as linear 1-D rows (views of the
         # staged chunk, stacked on the device: PERF.md, PR 43), and the
         # host arrays handed to the put for them

@@ -235,6 +235,7 @@ class StreamingIngest:
                 run_span.event("stall", s=round(stall, 6))
             if real < self.batch:
                 out = {k: v[:real] for k, v in out.items()}
+            st.bytes_out += out["fragments"].nbytes + out["tags"].nbytes
             out["rows"] = real
             return out
 

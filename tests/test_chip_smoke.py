@@ -46,6 +46,13 @@ def test_rehearsal_runs_every_phase_and_never_the_chip_line(
         eng = ln.get("engine")
         if eng is not None:
             assert eng["failed"] == eng["fallback"] == eng["degraded"] == 0
+    if chips == 1:
+        # phase C's second geometry (PR 47): the fused program at the
+        # archival tier's RS(10,4), nine segments through batches of 8
+        wide = lines[2]["wide"]
+        assert (wide["k"], wide["m"]) == (10, 4)
+        assert wide["stream"]["segments"] == 9
+        assert wide["stream"]["padded_segments"] == 7
     # the cache was placed from outside, and only there
     assert lines[-2]["cache_dir"] == str(tmp_path / "jax_cache")
 

@@ -98,6 +98,8 @@ def test_stream_bit_identical_both_limb_widths(limbs):
     assert st.segments == 7
     assert st.padded_segments == 2          # tail padded 1 -> 3
     assert st.bytes_in == 7 * SEG
+    # what came out, pad rows not counted: fragments and tags
+    assert st.bytes_out == 7 * ROWS * (FRAG + FRAG // 512 * limbs * 4)
 
 
 def test_stream_explicit_ids_and_device_results():
@@ -139,6 +141,8 @@ def test_stream_stats_export_through_engine_metrics():
         assert m["cess_engine_stream_batches"] == 2
         assert m["cess_engine_stream_segments"] == 4
         assert m["cess_engine_stream_bytes_in"] == 4 * SEG
+        assert m["cess_engine_stream_bytes_out"] \
+            == 4 * ROWS * (FRAG + FRAG // 512 * 2 * 4)
         assert "cess_engine_stream_stall_frac" in m
         snap = eng.stats_snapshot()
         assert snap["streams"][0]["batches"] == 2
@@ -189,7 +193,7 @@ def test_stream_detach_stops_metric_contribution():
 
 # -- the linear way up (PR 43) ----------------------------------------------
 
-GEOMETRIES = {"rs2p1": (2, 1), "rs4p8": (4, 8)}
+GEOMETRIES = {"rs2p1": (2, 1), "rs4p8": (4, 8), "rs10p4": (10, 4)}
 # the lowering of the RS apply: the backend's default (``gather`` on the
 # CPU: parity + concatenate) and the chip's (``pallas``, here in
 # interpret mode: the kernel writes the codeword, PR 44)
@@ -258,6 +262,10 @@ def test_linear_path_equals_forward(geometry, n_segments, id_kind,
         assert np.array_equal(np.asarray(got[name]),
                               np.asarray(want[name])), name
     st = ing.stats
+    # the code's stored bytes per user byte as a count (PR 47): the
+    # fragments' share of ``bytes_out`` over ``bytes_in`` is (k + m) / k
+    tag_bytes = n_segments * rows * (FRAG // 512) * 2 * 4
+    assert (st.bytes_out - tag_bytes) * cfg.k == st.bytes_in * rows
     assert st.linear_puts == st.batches == -(-n_segments // 3)
     assert st.put_arrays == st.batches * 3 * cfg.k
     assert st.raw()["linear_puts"] == st.batches
@@ -282,6 +290,57 @@ def test_fused_program_takes_both_input_forms(geometry, strategy):
     for name in ("fragments", "tags"):
         assert np.array_equal(np.asarray(linear[name]),
                               np.asarray(array[name])), name
+
+
+@pytest.fixture(scope="module")
+def bench_ref():
+    """The benchmark's frozen plain references (benchmark/reference),
+    as benchmark/run.py sees them: what decides a cell's ``correct``."""
+    import importlib
+    import os
+    import sys
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, root)
+    try:
+        return (importlib.import_module("reference.rs_ref"),
+                importlib.import_module("reference.podr2_ref"))
+    finally:
+        sys.path.remove(root)
+
+
+@pytest.mark.parametrize("form", ["linear", "array"])
+@pytest.mark.parametrize("strategy", STRATEGIES)
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_fused_program_against_the_benchmarks_reference(
+        bench_ref, geometry, strategy, form):
+    """The comparison that decides ``correct`` in the stream cells
+    (benchmark/traffic/stream.py ``check``), at a small size: a batch
+    of the fused program, from the driver's linear rows and from
+    ``[B, segment_size]``, byte for byte against ``ReferenceCodec.encode``
+    and word for word against ``podr2_ref.tag_fragment`` under the
+    stream's default ids, the systematic rows the user's bytes."""
+    rs_ref, podr2_ref = bench_ref
+    pipe = geometry_pipe(geometry, strategy=strategy)
+    cfg = pipe.config
+    rows = cfg.k + cfg.m
+    segs = rnd((2, cfg.segment_size), 470)
+    ids = jnp.arange(2 * rows, dtype=jnp.int32)
+    staged = jax.device_put(linear_rows(segs, cfg.k)) if form == "linear" \
+        else jnp.asarray(segs)
+    got = pipe.fused_program()(staged, ids)
+    frags, tags = np.asarray(got["fragments"]), np.asarray(got["tags"])
+    assert np.array_equal(frags[:, :cfg.k].reshape(2, -1), segs)
+    codec = rs_ref.ReferenceCodec(cfg.k, cfg.m)
+    key = podr2_ref.generate_key(43)
+    for s in range(2):
+        assert np.array_equal(frags[s],
+                              codec.encode(segs[s].reshape(cfg.k, -1)))
+        for row in (0, cfg.k - 1, cfg.k, rows - 1):
+            want = podr2_ref.tag_fragment(key, np.int32(s * rows + row),
+                                          frags[s, row])
+            assert np.array_equal(tags[s, row], np.asarray(want)), (s, row)
 
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))

@@ -87,16 +87,17 @@ def for_tpu(monkeypatch):
     jax.clear_caches()
 
 
-def _rs_constant(mat):
+def _rs_constant(mat, passthrough=False):
     # the matrix a constant of the program, as the fused ingest has it
     bmat = gf.expand_bitmatrix(mat)
     return lambda d: rs_pallas.apply_operand(
         jnp.asarray(rs_pallas.operand_np(
-            bmat, rs_pallas.group_for(d.shape[0]))), d)
+            bmat, rs_pallas.group_for(d.shape[0]))), d,
+        passthrough=passthrough)
 
 
-def _rs_encode(k, m):
-    return _rs_constant(gf.cauchy_parity_matrix(k, m))
+def _rs_encode(k, m, passthrough=False):
+    return _rs_constant(gf.cauchy_parity_matrix(k, m), passthrough)
 
 
 def _rs_repair_one_row():
@@ -122,9 +123,9 @@ def _podr2_tags():
     return run
 
 
-def _fused_forward(k, m):
+def _fused_forward(k, m, segment_size=constants.SEGMENT_SIZE):
     # strategy named: default_strategy() asks the (CPU) backend
-    cfg = PipelineConfig(k=k, m=m, segment_size=constants.SEGMENT_SIZE,
+    cfg = PipelineConfig(k=k, m=m, segment_size=segment_size,
                          strategy="pallas")
     return StoragePipeline(cfg).fused_program()
 
@@ -139,6 +140,11 @@ CASES = [
      [((8, 4, 4 * MiB), jnp.uint8)], (RS,)),
     ("rs_pallas-rs2p1-encode", lambda: _rs_encode(2, 1),
      [((8, 2, 8 * MiB), jnp.uint8)], (RS,)),
+    # the archival tier's ingest (PR 47): the kernel as the fused step
+    # calls it, the ten data rows passed through: [8, 10, 8 MiB] ->
+    # [8, 14, 8 MiB], a grid step's bit-planes [2, 10, 8, 32768]
+    ("rs_pallas-rs10p4-encode", lambda: _rs_encode(10, 4, passthrough=True),
+     [((8, 10, 8 * MiB), jnp.uint8)], (RS,)),
     ("rs_pallas-repair-one-row", _rs_repair_one_row,
      [((1, 4, 8 * MiB), jnp.uint8)], (RS,)),
     # the archival tier (RS(10,4)): ten 8 MiB helpers -> r lost rows,
@@ -156,6 +162,8 @@ CASES = [
      [((8, 16 * MiB), jnp.uint8), ((8 * 12,), jnp.int32)], (RS, TAGS)),
     ("fused-forward-rs2p1", lambda: _fused_forward(2, 1),
      [((8, 16 * MiB), jnp.uint8), ((8 * 3,), jnp.int32)], (RS, TAGS)),
+    ("fused-forward-rs10p4", lambda: _fused_forward(10, 4, 80 * MiB),
+     [((8, 80 * MiB), jnp.uint8), ((8 * 14,), jnp.int32)], (RS, TAGS)),
 ]
 
 
@@ -500,19 +508,24 @@ def _fused_step_keeps_the_fragments_shape(text, b, rows, n):
     assert "reshape" not in {op for op, _ in _u8_ops(text, n + 1)}
 
 
-@pytest.mark.parametrize("k,m", [pytest.param(4, 8, id="rs4p8"),
-                                 pytest.param(2, 1, id="rs2p1")])
-def test_linear_fused_program_compiles_for_v5e(one_chip, for_tpu, k, m):
+@pytest.mark.parametrize("k,m,segment_size", [
+    pytest.param(4, 8, constants.SEGMENT_SIZE, id="rs4p8"),
+    pytest.param(2, 1, constants.SEGMENT_SIZE, id="rs2p1"),
+    pytest.param(10, 4, 80 * MiB, id="rs10p4")])
+def test_linear_fused_program_compiles_for_v5e(one_chip, for_tpu, k, m,
+                                               segment_size):
     """The one-chip stream cells' program as the driver calls it since
     PR 43 (models/pipeline.py fused_program over ``linear_rows``): a
-    batch of 8 segments as its 8k linear ``u8[16 MiB / k]`` rows, stacked
-    on the device in front of the fused step. 1-D dense arguments (their
-    logical 128 MiB, where a ``u8[8, 2, 8 MiB]`` operand is twice that),
-    no reshape of more than a row, both kernels under their pinned
+    batch of 8 segments as its 8k linear ``u8[segment_size / k]`` rows,
+    stacked on the device in front of the fused step. 1-D dense arguments
+    (their logical 128 MiB, where a ``u8[8, 2, 8 MiB]`` operand is twice
+    that), no reshape of more than a row, both kernels under their pinned
     names, compiled in seconds; since PR 44 the fragments keep their
-    shape from one kernel to the other."""
-    n = constants.SEGMENT_SIZE // k
-    cfg = PipelineConfig(k=k, m=m, segment_size=constants.SEGMENT_SIZE,
+    shape from one kernel to the other. ``rs10p4`` is the archival
+    tier's cell (stream-10p4.corpus, PR 47): 80 rows of 8 MiB, 640 MiB
+    of arguments."""
+    n = segment_size // k
+    cfg = PipelineConfig(k=k, m=m, segment_size=segment_size,
                          strategy="pallas")
     rows = tuple(jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
                  for _ in range(8 * k))
