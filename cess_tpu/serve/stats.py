@@ -231,6 +231,12 @@ class StreamStats:
       the host runs ahead and the device's queue is full: the stream
       is bound by what the device works through per batch — program
       and transfers alike, which overlap there.
+    - ``gate_s`` is host time spent BLOCKED on a transfer's arrival:
+      a batch's put waits for the put two before it to have arrived
+      (serve/stream.py ``_run``, PR 50), and that wait is no part of
+      ``stall_s``. Beside it, it tells a stream the link paces (the
+      gate takes most of the waiting) from one the program paces (the
+      stall does).
     - ``h2d_s`` and ``dispatch_s`` time an ENQUEUE, never the work:
       ``device_put`` and the program call return once the transfer or
       the program is queued (128 MiB "staged" in 0.65 ms on a v5e,
@@ -250,7 +256,7 @@ class StreamStats:
 
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
                  "bytes_out", "linear_puts", "put_arrays",
-                 "h2d_s", "dispatch_s", "stall_s", "wall_s")
+                 "h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s")
     __slots__ = _COUNTERS + ("lanes", "hist")
 
     def __init__(self):
@@ -270,6 +276,7 @@ class StreamStats:
         self.h2d_s = 0.0           # host time ENQUEUEING device_put
         self.dispatch_s = 0.0      # host time ENQUEUEING the program
         self.stall_s = 0.0         # host time blocked on device results
+        self.gate_s = 0.0          # host time blocked on a put's arrival
         self.wall_s = 0.0          # wall time of completed run() calls
         # a GAUGE, not a counter: devices the last staged batch was
         # placed over (1 on one device; a DevicePool's lane count, or a
@@ -299,7 +306,7 @@ def stream_gauges(raw: dict) -> dict:
     wall = raw["wall_s"]
     out["stall_frac"] = round(raw["stall_s"] / wall, 4) if wall else 0.0
     out["h2d_frac"] = round(raw["h2d_s"] / wall, 4) if wall else 0.0
-    for k in ("h2d_s", "dispatch_s", "stall_s", "wall_s"):
+    for k in ("h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s"):
         out[k] = round(out[k], 6)
     return out
 

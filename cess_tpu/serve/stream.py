@@ -27,8 +27,15 @@ a host byte stream:
   (PERF.md, PR 43);
 - dispatch is asynchronous, so staging batch i+1 overlaps the device
   computing batch i (double buffering falls out of async dispatch +
-  a bounded in-flight window: at most ``depth`` batches are enqueued
-  before the driver blocks on the oldest);
+  a bounded in-flight window: at most ``depth`` batches' programs are
+  enqueued before the driver blocks on the oldest). The next batch's
+  put is asked for BEFORE that wait, as soon as the put two before it
+  has arrived: two puts are on the link at any time, which is how it
+  carries most (tools/link_probe.py ``stream``, "2 in flight"), and it
+  never stands still while the host waits for a result. That keeps one
+  more batch's rows on the device than the window's ``depth``; no
+  order of the three calls has the one without the other (PERF.md,
+  PR 50);
 - the ragged final batch is padded with zero segments to the SAME
   program shape (no tail recompile; every pipeline op is
   row-independent, so the pad rows are sliced off bit-exactly);
@@ -36,8 +43,9 @@ a host byte stream:
   (enqueue time of the staging and of the program, stall time, pad
   waste) and exported through the engine's ``cess_engine_stream_*``
   metrics when attached (SubmissionEngine.attach_stream); the same
-  four extents are ``cess:stream.stage`` / ``.put`` / ``.dispatch`` /
-  ``.stall`` in any profiler trace taken meanwhile (obs.trace.stage).
+  extents are ``cess:stream.stage`` / ``.gate`` / ``.put`` /
+  ``.dispatch`` / ``.stall`` in any profiler trace taken meanwhile
+  (obs.trace.stage).
 
 Results are bit-identical to the direct per-step path
 (``encode_step`` -> ``tag_step``) — tests/test_stream.py pins this on
@@ -112,9 +120,13 @@ class StreamingIngest:
 
     pipeline: the StoragePipeline whose fused program to drive.
     batch:    segments per device batch (the compiled shape).
-    depth:    in-flight window — batches enqueued on the device before
-              the driver blocks on the oldest (2 = classic double
-              buffering: one computing, one staged).
+    depth:    in-flight window — batches whose program is enqueued
+              before the driver blocks on the oldest (2 = classic double
+              buffering: one computing, one staged). It bounds device
+              memory: the results of ``depth`` batches beside what the
+              caller holds, and the rows of ``depth + 1`` (one more
+              batch's put is asked for ahead of the wait; a batch's
+              rows go with its result).
     program:  override the device program (fn(staged, ids) -> dict
               with "fragments"/"tags"; ``staged`` is what ``put``
               returned) — the mesh entry passes its shard_map'd step
@@ -213,19 +225,39 @@ class StreamingIngest:
             return self._engine.tracer
         return trace.armed_tracer()
 
+    def _escaped(self, e, bspan, bt0: float, rows: int) -> None:
+        """A staging/dispatch failure (fault injection, OOM) must still
+        land the batch span in the ring, error attached — a traced
+        chaos run shows WHICH batch died, not a silent hole in the
+        export — and burn the stream SLO's error budget like any engine
+        failure (_observe_failure): a stream that died must not scrape
+        as a clean SLO."""
+        if bspan is not trace.NOOP_SPAN:
+            bspan.set(error=repr(e)).finish()
+        eng = self._engine
+        if eng is not None and eng.slo is not None:
+            eng.slo.observe("stream", time.perf_counter() - bt0,
+                            ok=False, tenant=self.tenant, rows=rows)
+        # black-box journal: the exception is about to escape the
+        # stream driver — an incident trigger
+        _flight.note("stream", "escape", error=repr(e))
+
     def _run(self, segments, fragment_ids) -> Iterator[dict]:
         cfg = self.pipeline.config
         rows = cfg.k + cfg.m
         program = self._program or self.pipeline.fused_program()
         st = self.stats
         t_run = time.perf_counter()
+        # (result, real rows, the batch's rows on the device): a batch's
+        # rows are let go of with its result, never while its program
+        # may still be pending
         inflight: collections.deque = collections.deque()
-        run_span = trace.NOOP_SPAN
+        run_span = bspan = trace.NOOP_SPAN
         batches = stalls = 0
 
         def drain_one():
             nonlocal stalls
-            out, real = inflight.popleft()
+            out, real, _ = inflight.popleft()
             with trace.stage("stream.stall", parent=run_span) as stalled:
                 jax.block_until_ready(out["tags"])
             stall = stalled.seconds
@@ -271,55 +303,63 @@ class StreamingIngest:
                                 self.batch)
                 if chunk is None:
                     break
-                # enforce the in-flight window BEFORE putting the next
-                # batch on the device: at most ``depth`` batches are
-                # ever enqueued (depth=2 = one computing + one staged),
-                # which is what bounds in-flight device memory
-                while len(inflight) >= self.depth:
-                    yield drain_one()
+                # the gate: the put is asked for BEFORE the wait for the
+                # oldest result, once the put two before it has arrived:
+                # two puts are on the link at any time, as many as cross
+                # it fastest (tools/link_probe.py), and it never stands
+                # still while the host waits for a result. Asked for
+                # after that wait, one put crossed at a time and the
+                # RS(2,1) ingest's device idled a third of every batch
+                # (PERF.md, PR 50). One runtime call for all the put's
+                # rows: its last row alone is not the last to arrive.
+                # (At depth 1 the put two before is out with its result:
+                # nothing to wait for.)
+                if len(inflight) >= 2:
+                    with trace.stage("stream.gate",
+                                     parent=run_span) as gate:
+                        jax.block_until_ready(inflight[-2][2])
+                    st.gate_s += gate.seconds
                 bspan = trace.NOOP_SPAN if tracer is None \
                     else tracer.start("stream.batch", sys="stream",
                                       parent=run_span, rows=real,
                                       pad=pad)
+                bt0 = time.perf_counter()
                 try:
-                    bt0 = time.perf_counter()
                     with trace.stage("stream.put", parent=bspan) as put:
                         faults.inject("stream.h2d")   # chaos: staging
                         rows_up = linear_rows(chunk, cfg.k)
                         dev = self._put(rows_up)
                         ids_dev = self._put_ids(ids)
-                    h2d = put.seconds
-                    st.h2d_s += h2d
-                    st.linear_puts += 1
-                    st.put_arrays += len(rows_up)
-                    # gauge: the devices this batch was placed over
-                    st.lanes = len(jax.tree.leaves(dev)[0]
-                                   .sharding.device_set)
+                except BaseException as e:
+                    self._escaped(e, bspan, bt0, real)
+                    raise
+                # the window, enforced before the program is enqueued
+                # and its result allocated: ``depth`` programs' batches
+                # at most (depth=2 = one computing + one staged), and
+                # this batch's rows ahead of them. A program is
+                # enqueued before any later put is asked for: behind the
+                # next batch's put (a result fewer on the device for the
+                # rows more) every cell ran as under the old order
+                # (PERF.md, PR 50)
+                while len(inflight) >= self.depth:
+                    yield drain_one()
+                try:
                     with trace.stage("stream.dispatch",
                                      parent=bspan) as launch:
                         faults.inject("stream.dispatch")  # chaos: launch
                         out = program(dev, ids_dev)
                 except BaseException as e:
-                    # a staging/dispatch failure (fault injection, OOM)
-                    # must still land the batch span in the ring, error
-                    # attached — a traced chaos run shows WHICH batch
-                    # died, not a silent hole in the export — and burn
-                    # the stream SLO's error budget like any engine
-                    # failure (_observe_failure): a stream that died
-                    # must not scrape as a clean SLO
-                    if bspan is not trace.NOOP_SPAN:
-                        bspan.set(error=repr(e)).finish()
-                    eng = self._engine
-                    if eng is not None and eng.slo is not None:
-                        eng.slo.observe("stream",
-                                        time.perf_counter() - bt0,
-                                        ok=False, tenant=self.tenant,
-                                        rows=real)
-                    # black-box journal: the exception is about to
-                    # escape the stream driver — an incident trigger
-                    _flight.note("stream", "escape", error=repr(e))
+                    self._escaped(e, bspan, bt0, real)
                     raise
-                dispatch = launch.seconds
+                h2d, dispatch = put.seconds, launch.seconds
+                # everything a batch counts, it counts here, with
+                # ``batches``: no snapshot sees a put without its batch
+                st.h2d_s += h2d
+                st.linear_puts += 1
+                st.put_arrays += len(rows_up)
+                # gauge: the devices this batch was placed over
+                st.lanes = len(jax.tree.leaves(dev)[0]
+                               .sharding.device_set)
                 st.dispatch_s += dispatch
                 st.hist.observe(h2d + dispatch)
                 # SLO/tenant feed (obs/slo.py): streamed batches ride
@@ -338,18 +378,20 @@ class StreamingIngest:
                         batch=self.batch, rows=real,
                         nbytes=real * cfg.segment_size,
                         h2d_s=h2d, dispatch_s=dispatch)
-                if bspan is not trace.NOOP_SPAN:
-                    bspan.finish(h2d_s=round(h2d, 6),
-                                 dispatch_s=round(dispatch, 6))
+                bspan.finish(h2d_s=round(h2d, 6),
+                             dispatch_s=round(dispatch, 6))
                 st.batches += 1
                 batches += 1
                 st.segments += real
                 st.bytes_in += real * cfg.segment_size
                 seg_off += self.batch
-                inflight.append((out, real))
+                inflight.append((out, real, dev))
             while inflight:
                 yield drain_one()
         finally:
+            # a consumer that stopped between a batch's put and its
+            # program: the batch's span still lands (no-op otherwise)
+            bspan.finish()
             st.wall_s += time.perf_counter() - t_run
             if run_span is not trace.NOOP_SPAN:
                 run_span.finish(batches=batches, stalls=stalls)

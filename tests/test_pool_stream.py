@@ -12,6 +12,9 @@ CPU devices:
   the sliced-PRF jnp body and is still bit-identical;
 - ``StreamStats.lanes``: 4 with the pool, 1 without, a gauge that
   attached streams do not sum;
+- a pooled stream long enough for the driver's gate (PR 50: a put waits
+  for the sharded rows of the put two before it): the one-chip stream's
+  bits, and the counters a batch counts, as before;
 - the Pallas tag kernel types its output over the mesh axes its data
   varies over, so it traces under a checked ``shard_map``.
 """
@@ -100,6 +103,32 @@ def test_pooled_stream_matches_plain_reference(limbs, id_kind, n_segments):
     assert np.array_equal(np.asarray(out["tags"]), want_tags)
     assert ing.stats.lanes == LANES
     assert ing.stats.padded_segments == -n_segments % BATCH
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_pooled_stream_past_the_gate_counts_as_one_chip_does(depth):
+    """Five batches, the last ragged: from the third put on (depth 2)
+    a put waits for the sharded rows of the put two before it. Results
+    and every counter of
+    a batch are the one-chip stream's; only ``lanes`` differs."""
+    pipe = make_pipe()
+    segs = rnd((4 * BATCH + 3, SEG), 14)
+    one = StreamingIngest(pipe, BATCH, depth=depth)
+    pooled = StreamingIngest(pipe, BATCH, depth=depth,
+                             pool=DevicePool(n=LANES))
+    want, got = one.ingest(segs), pooled.ingest(segs)
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(want[name])), name
+    a, b = one.stats.raw(), pooled.stats.raw()
+    counted = ("batches", "segments", "padded_segments", "bytes_in",
+               "bytes_out", "linear_puts", "put_arrays")
+    assert {k: b[k] for k in counted} == {k: a[k] for k in counted}
+    assert b["linear_puts"] == b["batches"] == 5
+    assert b["put_arrays"] == 5 * BATCH * K
+    assert (a["lanes"], b["lanes"]) == (1, LANES)
+    # at depth 1 the put two before is out with its result: no gate
+    assert (b["gate_s"] > 0) == (depth > 1) and b["stall_s"] > 0
 
 
 @pytest.mark.parametrize("seg,byte,strategy", [

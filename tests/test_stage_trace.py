@@ -526,13 +526,19 @@ def test_stream_stages_ride_the_batch_and_run_spans():
     assert {s["parent_id"] for s in by("stream.put")} == batches
     assert {s["parent_id"] for s in by("stream.dispatch")} == batches
     assert len(by("stream.stall")) == 3
-    assert {s["parent_id"] for s in by("stream.stall")
+    # a put waits for the put two before it to have arrived, under a
+    # stage and a counter of its own: one wait for every batch past the
+    # second (serve/stream.py _run, PR 50)
+    assert len(by("stream.gate")) == 1
+    assert {s["parent_id"] for s in by("stream.stall") + by("stream.gate")
             + by("stream.stage")} == {run["span_id"]}
     # the counters kept their names, and are what the stages timed
     raw = ingest.stats.raw()
     assert raw["batches"] == 3 and raw["stall_s"] > 0
     assert 0.0 < raw["h2d_s"] <= sum(s["dur_s"]
                                      for s in by("stream.put")) + 1e-4
+    assert 0.0 < raw["gate_s"] <= sum(s["dur_s"]
+                                      for s in by("stream.gate")) + 1e-4
 
 
 # -- the PoDR2 challenge -----------------------------------------------------
@@ -608,7 +614,7 @@ def profiled(pkey, tmp_path_factory):
         eng.reconstruct(coded[:, 1:], (1, 2), (0,))
         _round(eng, pkey)
         gateway.upload("alice", "b", "f", rnd((2 * seg,), 2).tobytes())
-        for _ in stream.run(rnd((3, seg), 3)):
+        for _ in stream.run(rnd((7, seg), 3)):
             pass
         eng.flush()
     finally:
@@ -665,11 +671,13 @@ def test_profile_lays_engine_batches_inside_gateway_stages(profiled,
         != {e[0] for e in mine if e[1] == outer}       # two threads
 
 
-@pytest.mark.parametrize("name", ["stream.stage", "stream.put",
-                                  "stream.dispatch", "stream.stall"])
+@pytest.mark.parametrize("name", ["stream.stage", "stream.gate",
+                                  "stream.put", "stream.dispatch",
+                                  "stream.stall"])
 def test_profile_holds_the_stream_stages(profiled, name):
     mine = [e for e in profiled if e[1] == name]
-    assert len(mine) >= 2          # 3 streamed rows, 2 a batch
+    # 7 streamed rows, 2 a batch: four batches, the gate from the third
+    assert len(mine) >= 2
     assert all(e[3] >= e[2] for e in mine)
 
 

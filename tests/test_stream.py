@@ -19,18 +19,31 @@
   ``tag_step`` and to the NumPy codec + the plain per-fragment MAC, at
   both geometries, batch 1, an odd batch, pair ids, both input forms,
   with the chip's ``pallas`` lowering (interpret mode) and the CPU's;
+- the driver's order (PR 50): a batch's put is asked for before the
+  wait for the oldest result, once the put two before it has arrived
+  (``gate_s``, no part of ``stall_s``), its program directly behind its
+  own put; ``depth`` results and ``depth + 1`` batches' rows at most; a
+  fault at either seam, or a consumer that stops, leaves every span
+  closed and the counters consistent;
 - the repair warm path (rs.py warm_reconstruct / engine.warm_repair)
   returns byte-exact reconstructions through pre-compiled programs.
 """
+import time
+import weakref
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from cess_tpu import obs
 from cess_tpu.models.pipeline import PipelineConfig, StoragePipeline, \
     linear_rows
+from cess_tpu.obs import flight
+from cess_tpu.obs.slo import SloBoard, SloTarget
 from cess_tpu.ops import podr2, rs
+from cess_tpu.resilience import FaultInjected, FaultPlan, FaultSpec, faults
 from cess_tpu.serve import AdmissionPolicy, make_engine
 from cess_tpu.serve.stream import StreamingIngest, _rebatch
 from test_pool_stream import plain_reference
@@ -421,6 +434,189 @@ def test_the_benchmarks_seams_drive_the_linear_path(geometry, fault):
     differ = np.asarray(got["fragments"]) != np.asarray(want["fragments"])
     assert differ.sum() == (4 if fault else 0)   # a parity byte a segment
     assert ing.stats.linear_puts == ing.stats.batches == 2
+
+
+# -- the driver's order (PR 50) ---------------------------------------------
+
+# five batches, a letter an event: g the gate (a put waits for the put
+# two before it to have arrived), p a put, w the wait for the oldest
+# result, P a program enqueued. At depth 2: put 0, program 0, put 1,
+# program 1, put 2, THEN the first wait for a result
+ORDERS = {1: "pPpwPpwPpwPpwPw",      # the put two before is out: no gate
+          2: "pPpPgpwPgpwPgpwPww",
+          3: "pPpPgpPgpwPgpwPwww"}
+
+
+class _Recorded:
+    """A recording ``put=`` / ``program=`` pair around the real ones,
+    and ``jax.block_until_ready`` told apart by what is waited for: a
+    put's rows (the gate) or a result (the stall)."""
+
+    def __init__(self, pipe, monkeypatch, gate_sleep=0.0):
+        self.events = []
+        self.puts = []              # weak references to a put's first row
+        # batches' rows on the device at most: those the driver holds,
+        # and those of batches put whose result nobody has waited for
+        self.rows_held = self.rows_owed = self.results = 0
+        self.fused = pipe.fused_program()
+        self.on_put = lambda: None
+        block = jax.block_until_ready
+
+        def waited(x):
+            if isinstance(x, tuple) and any(x[0] is p() for p in self.puts):
+                # all the rows of the put two before the next, one call
+                assert x[0] is self.puts[-2]() and len(x) > 1
+                self.events.append("g")
+                time.sleep(gate_sleep)
+            else:
+                self.events.append("w")
+            return block(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", waited)
+
+    def put(self, rows):
+        self.on_put()
+        self.events.append("p")
+        dev = jax.device_put(rows)
+        self.puts.append(weakref.ref(dev[0]))
+        self.rows_held = max(self.rows_held,
+                             sum(p() is not None for p in self.puts))
+        self.rows_owed = max(self.rows_owed, self.events.count("p")
+                             - self.events.count("w"))
+        return dev
+
+    def program(self, dev, ids):
+        self.events.append("P")
+        self.results = max(self.results, self.events.count("P")
+                           - self.events.count("w"))
+        return self.fused(dev, ids)
+
+
+@pytest.mark.parametrize("depth", sorted(ORDERS))
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_the_put_is_asked_for_before_the_wait_for_the_oldest_result(
+        geometry, depth, monkeypatch):
+    """Put i + 1 is asked for before result i - 1 is waited for, once
+    put i - 1 has arrived (two puts on the link at a time), and program
+    i is enqueued behind its own put, before any later one. Never more
+    than ``depth`` results in flight, nor more than ``depth + 1``
+    batches' rows alive: a batch's rows go with its result, never while
+    its program may be pending (so at depth 1 the put two before is
+    out, and nothing gates). ``gate_s`` grows only once two puts are
+    held and is no part of ``stall_s``; the results are ``forward``'s,
+    ragged tail included."""
+    pipe = geometry_pipe(geometry)
+    cfg = pipe.config
+    segs = rnd((9, cfg.segment_size), 500)          # 5 batches, 1 ragged
+    rec = _Recorded(pipe, monkeypatch, gate_sleep=0.02)
+    ing = StreamingIngest(pipe, 2, depth=depth, put=rec.put,
+                          put_ids=jax.device_put, program=rec.program)
+    gate_at_put = []
+    rec.on_put = lambda: gate_at_put.append(ing.stats.gate_s)
+    tracer = obs.Tracer()
+    with obs.armed(tracer):
+        outs = list(ing.run(segs))
+    assert "".join(rec.events) == ORDERS[depth]
+    assert rec.results == depth
+    assert (rec.rows_held, rec.rows_owed) == (depth + 1, depth + 1)
+    want = pipe.forward(segs)
+    for name in ("fragments", "tags"):
+        got = np.concatenate([np.asarray(o[name]) for o in outs])
+        assert np.array_equal(got, np.asarray(want[name])), name
+    st = ing.stats
+    gates = ORDERS[depth].count("g")                # 3, or 0 at depth 1
+    assert gate_at_put[:2] == [0.0, 0.0]
+    assert (gate_at_put[2] >= 0.02) == (gates > 0)
+    assert st.gate_s >= gates * 0.02 and st.raw()["gate_s"] == st.gate_s
+    assert (st.gate_s > 0) == (gates > 0)
+    assert "cess_engine_stream_gate_s" in st.metrics()
+    # each clock runs inside its own stage's span, and no gate lies
+    # inside a stall: the gates' sleeps are in no part of ``stall_s``
+    spans = tracer.finished()
+    by = lambda name: [s for s in spans if s["name"] == name]  # noqa: E731
+    assert len(by("stream.gate")) == gates and len(by("stream.stall")) == 5
+    for counter, stage in (("gate_s", "stream.gate"),
+                           ("stall_s", "stream.stall")):
+        assert getattr(st, counter) <= sum(s["dur_s"]
+                                           for s in by(stage)) + 1e-4
+    for g in by("stream.gate"):
+        for w in by("stream.stall"):
+            assert g["ts_s"] + g["dur_s"] <= w["ts_s"] + 1e-6 \
+                or w["ts_s"] + w["dur_s"] <= g["ts_s"] + 1e-6
+
+
+def test_a_stream_of_two_batches_never_gates(monkeypatch):
+    pipe = geometry_pipe("rs2p1")
+    rec = _Recorded(pipe, monkeypatch)
+    ing = StreamingIngest(pipe, 2, put=rec.put, put_ids=jax.device_put,
+                          program=rec.program)
+    ing.ingest(rnd((4, pipe.config.segment_size), 501))
+    assert "".join(rec.events) == "pPpPww"
+    assert ing.stats.gate_s == 0.0 and ing.stats.stall_s > 0.0
+
+
+@pytest.mark.parametrize("site", ["stream.h2d", "stream.dispatch"])
+def test_a_fault_at_either_seam_lands_the_batch_and_burns_the_slo(site):
+    """The third batch dies in its put, or in its program (behind the
+    window now: the first result is already out): its span lands with
+    the error, the stream SLO is burnt, the journal has the escape, and
+    what was counted was counted with ``batches``."""
+    pipe = geometry_pipe("rs2p1")
+    cfg = pipe.config
+    segs = rnd((10, cfg.segment_size), 502)
+    tracer = obs.Tracer()
+    board = SloBoard((SloTarget("stream", 1.0),))
+    eng = make_engine(2, 1, policy=AdmissionPolicy(max_delay=0.005),
+                      tracer=tracer, slo=board)
+    recorder = flight.FlightRecorder(b"pr50")
+    try:
+        ing = StreamingIngest(pipe, 2, engine=eng, tenant="t")
+        outs = []
+        with faults.armed(FaultPlan({site: {2: FaultSpec("raise")}})), \
+                flight.armed(recorder), pytest.raises(FaultInjected):
+            for out in ing.run(segs):
+                outs.append(out)
+    finally:
+        eng.close()
+    # the window stands between a batch's put and its program
+    assert len(outs) == (0 if site == "stream.h2d" else 1)
+    spans = tracer.finished()
+    assert tracer.started == len(spans)             # none left open
+    batches = [s for s in spans if s["name"] == "stream.batch"]
+    assert len(batches) == 3
+    assert ["error" in s["attrs"] for s in batches] == [False, False, True]
+    assert "FaultInjected" in batches[2]["attrs"]["error"]
+    st = ing.stats
+    assert st.linear_puts == st.batches == 2
+    assert st.put_arrays == st.batches * 2 * cfg.k
+    assert st.segments == 4 and st.bytes_in == 4 * cfg.segment_size
+    mine = board.snapshot()["tenants"]["t"]["stream"]
+    assert (mine["requests"], mine["failed"], mine["rows"]) == (3, 1, 4)
+    assert [e["kind"] for e in recorder.journal_tail("stream")] == ["escape"]
+
+
+def test_a_consumer_that_stops_after_one_result_closes_every_span():
+    """The first result comes out between the third batch's put and its
+    program: that batch's span still lands, and a put is counted only
+    with its batch, so no snapshot sees one without the other."""
+    pipe = geometry_pipe("rs2p1")
+    cfg = pipe.config
+    tracer = obs.Tracer()
+    ing = StreamingIngest(pipe, 2)
+    with obs.armed(tracer):
+        run = ing.run(rnd((10, cfg.segment_size), 503))
+        next(run)
+        st = ing.stats
+        assert st.linear_puts == st.batches == 2
+        assert st.put_arrays == st.batches * 2 * cfg.k
+        run.close()
+    spans = tracer.finished()
+    assert tracer.started == len(spans)
+    names = [s["name"] for s in spans]
+    assert names.count("stream.put") == names.count("stream.batch") == 3
+    assert names.count("stream.dispatch") == 2
+    assert names.count("stream.run") == 1
+    assert st.wall_s > 0
 
 
 # -- sharded mesh stream entry ---------------------------------------------

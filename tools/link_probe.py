@@ -25,7 +25,12 @@ it in, as views of ONE C-contiguous host chunk (no host copy): the packed
 ``8 x u8[16 MiB]`` and ``8k x u8[n]``; each alone, "+ stack" (the put, then
 one jitted program that makes the ``u8[8, k, n]`` the fused step reads, in
 ``split_rows``' slice-and-stack style) and "stack alone" (the same program
-over rows already on the device), at RS(4,8) (k = 4) and RS(2,1) (k = 2).
+over rows already on the device), at RS(4,8) (k = 4) and RS(2,1) (k = 2),
+and the archival tier's RS(10,4) batch (8 segments of 80 MiB, 640 MiB) as
+its 80 rows. "2 in flight" (PR 50): the interval of a put when the next one
+is asked for before the last is waited for, as the stream driver asks since
+then (serve/stream.py ``_run``'s gate): what the link carries with two puts
+on it, against "put", one put waited for.
 """
 import statistics
 import sys
@@ -99,14 +104,15 @@ def put_stacked(q):
         stack(*jax.device_put(hs))))
 
 
-SEGMENT = 16 << 20   # one segment
 BATCH = 8            # the one-chip stream cells' batch
+# (k, segment bytes) of the stream cells: RS(4,8), RS(2,1), archival RS(10,4)
+GEOMETRIES = ((4, 16 << 20), (2, 16 << 20), (10, 80 << 20))
 
 
-def _stackers(k):
+def _stackers(k, segment):
     """form -> (host views of a chunk, jitted stack to ``u8[BATCH, k, n]``
     or None where the put already has that shape)."""
-    n = SEGMENT // k
+    n = segment // k
 
     def split(x):                        # models/pipeline.py split_rows
         return jnp.stack([x[:, j * n:(j + 1) * n] for j in range(k)],
@@ -114,7 +120,7 @@ def _stackers(k):
 
     def from_flat(x):
         return jnp.stack([jnp.stack(
-            [x[i * SEGMENT + j * n:i * SEGMENT + (j + 1) * n]
+            [x[i * segment + j * n:i * segment + (j + 1) * n]
              for j in range(k)]) for i in range(BATCH)])
 
     def from_segments(*segs):
@@ -136,26 +142,43 @@ def _stackers(k):
     }
 
 
+def _in_flight_ms(hosts):
+    """Median interval of a put when the next is asked for before the last
+    one is waited for (two in flight)."""
+    out, flying = [], jax.device_put(hosts[0])
+    for i in range(1, REPS + 3):
+        t0 = time.perf_counter()
+        nxt = jax.device_put(hosts[i % 2])
+        jax.block_until_ready(flying)
+        flying = nxt
+        out.append((time.perf_counter() - t0) * 1e3)
+    jax.block_until_ready(flying)
+    return statistics.median(out[2:])
+
+
 def stream_table():
     rng = np.random.default_rng(43)
-    chunks = [rng.integers(0, 256, (BATCH, SEGMENT), dtype=np.uint8)
-              for _ in range(2)]         # alternated: no put of a warm page
-    print(f"S = {SEGMENT}; one batch = {BATCH} x S = "
-          f"{BATCH * SEGMENT >> 20} MiB; median (min) of {REPS}, ms")
-    print(f"{'k':>2} {'what':<16}{'put':>9}{'(min)':>9}{'GiB/s':>7}"
-          f"{'+ stack':>9}{'(min)':>9}{'GiB/s':>7}{'stack alone':>13}"
-          f"{'compile s':>11}")
-    gib = BATCH * SEGMENT / 2**30
-    for k in (4, 2):
-        for what, (views, stack) in _stackers(k).items():
+    print(f"one batch = {BATCH} x S; median (min) of {REPS}, ms")
+    print(f"{'k':>2} {'S MiB':>5} {'what':<16}{'put':>9}{'(min)':>9}"
+          f"{'GiB/s':>7}{'2 in flight':>12}{'+ stack':>9}{'(min)':>9}"
+          f"{'GiB/s':>7}{'stack alone':>13}{'compile s':>11}")
+    for k, segment in GEOMETRIES:
+        chunks = [rng.integers(0, 256, (BATCH, segment), dtype=np.uint8)
+                  for _ in range(2)]     # alternated: no put of a warm page
+        gib = BATCH * segment / 2**30
+        for what, (views, stack) in _stackers(k, segment).items():
+            if k == 10 and " x " not in what:
+                continue     # the archival batch as rows alone: the
+                             # one-array forms left the driver in PR 43
             hosts = [views(c) for c in chunks]
             assert all(np.shares_memory(v, c)      # views, not copies
                        for h, c in zip(hosts, chunks) for v in h)
             put_ms, put_min = _median_ms(
                 lambda i: hosts[i % 2],
                 lambda hs: jax.block_until_ready(jax.device_put(hs)))
-            line = (f"{k:>2} {what:<16}{put_ms:>9.3f}{put_min:>9.3f}"
-                    f"{gib / (put_ms / 1e3):>7.2f}")
+            line = (f"{k:>2} {segment >> 20:>5} {what:<16}{put_ms:>9.3f}"
+                    f"{put_min:>9.3f}{gib / (put_ms / 1e3):>7.2f}"
+                    f"{_in_flight_ms(hosts):>12.3f}")
             if stack is not None:
                 jitted = jax.jit(stack)
                 t0 = time.perf_counter()
