@@ -27,7 +27,7 @@ import numpy as np
 
 from .. import constants
 from ..obs import trace
-from ..ops import gf, podr2
+from ..ops import gf, podr2, rs
 from ..ops.rs import default_strategy, _MatrixApply, _stack_rows
 
 
@@ -40,13 +40,13 @@ from ..ops.rs import default_strategy, _MatrixApply, _stack_rows
 # [12, 8 MiB] 168 s for a described v5e (PR 22 rehearsals), against
 # under two seconds for the slice/concatenate forms below. Same bytes.
 #
-# Who still regroups (PR 44): ``split_rows`` the ``[B, segment_size]``
+# Who still regroups (PR 51): ``split_rows`` the ``[B, segment_size]``
 # input form (``forward``, ``encode_step``); ``merge_rows`` the engine
-# path's ``tag_step`` and the byte-sharded mesh steps (parallel/mesh.py).
-# The fused step does neither: the RS kernel writes the codeword
-# ``[B, k+m, n]`` itself and the tag kernel takes that batch as it is
-# (``fused_step``), so the 6.6 / 7.4 ms a batch of ``merge_rows`` and the
-# ``concatenate`` of data and parity are not in the stream cells' program.
+# path's ``tag_step`` and the byte-sharded mesh steps (parallel/mesh.py);
+# ``stack_rows`` those mesh steps and a streamed batch of other than 8
+# or 16 segments. The fused step over the stream's rows does none: the
+# RS kernel reads the rows as put and writes the codeword fragment-major,
+# and the tag kernel takes that batch as it is (``fused_step``).
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -57,14 +57,14 @@ def split_rows(segments: jax.Array, k: int) -> jax.Array:
                      axis=1)
 
 
-# A batch of host segments crosses the link as LINEAR rows and becomes
-# ``u8[B, k, n]`` on the device (PR 43): the TPU packs the second-minor
-# dimension of a uint8 array four rows to a 32-bit word, so a host ->
-# device put of ``u8[B, k*n]`` (or of any ``u8[.., r, n]``) is packed on
-# the host before it crosses, at 5.1-5.7 GiB/s for the stream cells'
-# 128 MiB, which capped every one-chip stream cell; a 1-D ``u8[n]``
-# crosses as it lies (ops/rs.py LinearRows, PERF.md section 5). The two
-# halves of the form, one on each side of the link:
+# A batch of host segments crosses the link as LINEAR rows (PR 43; a
+# step that wants ``u8[B, k, n]`` stacks them on the device, the fused
+# step does not: PR 51): the TPU packs the second-minor dimension of a
+# uint8 array four rows to a 32-bit word, so a host -> device put of
+# ``u8[B, k*n]`` (or of any ``u8[.., r, n]``) is packed on the host
+# before it crosses, at 5.1-5.7 GiB/s for the stream cells' 128 MiB,
+# which capped every one-chip stream cell; a 1-D ``u8[n]`` crosses as it
+# lies (ops/rs.py LinearRows, PERF.md section 5). The form's two halves:
 
 
 def linear_rows(chunk: np.ndarray, k: int) -> tuple:
@@ -76,10 +76,10 @@ def linear_rows(chunk: np.ndarray, k: int) -> tuple:
 
 
 def stack_rows(segments, k: int) -> jax.Array:
-    """The device half (traced): what :func:`linear_rows` put, stacked
-    to ``[B, k, n]`` in ``split_rows``' own style (ops/rs.py
-    ``_stack_rows``: ``stack`` forms, no ``reshape``); a ``[B, k*n]``
-    array (``forward``'s callers) goes through ``split_rows``."""
+    """The device half where a step wants ``[B, k, n]`` (traced; the
+    byte-sharded mesh steps): what :func:`linear_rows` put, stacked in
+    ``split_rows``' own style (ops/rs.py ``_stack_rows``); an array goes
+    through ``split_rows``. The fused step takes the rows unstacked."""
     if isinstance(segments, (tuple, list)):
         return _stack_rows(segments, k)
     return split_rows(segments, k)
@@ -220,27 +220,45 @@ class StoragePipeline:
         return tags.reshape(b, rows, *tags.shape[1:])
 
     def fused_step(self, data, fragment_ids):
-        """The body of the fused encode+tag step over fragment-major
-        rows: data [B, k, n] u8 + ids ([B*(k+m)] | [B, k+m] |
-        [B, k+m, 2]) -> {"fragments": [B, k+m, n], "tags":
-        [B, k+m, blocks, limbs]}. The fragments keep that one shape
-        from the RS kernel, which writes the codeword (the data rows
-        pass through it, ops/rs.py ``codeword``), to the tag kernel,
-        which takes the batch as it is (ops/podr2_pallas.py
-        ``tag_fragments_fused``), and to the result: nothing in
+        """The body of the fused encode+tag step: data, the batch as its
+        B*k linear rows u8[n] (a tuple: ``linear_rows``, what the
+        streaming driver puts) or fragment-major [B, k, n] u8, + ids
+        ([B*(k+m)] | [B, k+m] | [B, k+m, 2]) -> {"fragments":
+        [B, k+m, n], "tags": [B, k+m, blocks, limbs]}. What the input
+        is decides the RS kernel's entry: rows go to it as they lie and
+        it writes the codeword fragment-major, in the layout the chip
+        keeps the ``"fragments"`` result in (ops/rs.py
+        ``codeword_rows``: since PR 51 no stack in front of the kernel
+        and no copy behind it; a batch that entry does not take, other
+        than 8 or 16 segments, stacks by its shape); an array goes through
+        ``codeword`` as before. Either way the kernel writes the data
+        rows too, and the tag kernel takes the batch as it is
+        (ops/podr2_pallas.py ``tag_fragments_fused``): nothing in
         between regroups rows. Traced by exactly two callers, each
         under ``jax.named_scope(FUSED_SCOPE)``: :meth:`fused_program`
-        (one chip, after ``stack_rows``) and parallel/mesh.py's
-        sharded stream step on a (lanes, 1) mesh (per device, on the
-        rows the host staged for it) — one body, so the one-chip and
-        the pooled program cannot drift apart."""
-        shards = self._parity.codeword(data)
+        (one chip) and parallel/mesh.py's sharded stream step on a
+        (lanes, 1) mesh (per device, on the rows the host staged for
+        it) — one body, so the one-chip and the pooled program cannot
+        drift apart."""
+        if isinstance(data, (tuple, list)):
+            shards = rs.codeword_rows(self._parity, data, self.config.k)
+        else:
+            shards = self._parity.codeword(data)
         b, rows, _ = shards.shape
         ids = fragment_ids.reshape(
             (b * rows, 2) if fragment_ids.ndim == 3 else (b * rows,))
         tags = podr2.tag_fragments(self.podr2_key, ids, shards)
         return {"fragments": shards,
                 "tags": tags.reshape(b, rows, *tags.shape[1:])}
+
+    def rows_direct(self, batch: int, n: int) -> bool:
+        """Whether the fused step hands a batch of ``batch`` segments
+        held as linear rows ``u8[n]`` to the RS kernel unstacked
+        (ops/rs.py ``rows_direct``). The programs built over the step
+        (:meth:`fused_program`, parallel/mesh.py's) carry it as their
+        ``direct_rows(staged)``, and ``StreamStats.direct_rows`` counts
+        by that."""
+        return rs.rows_direct(self._parity, batch, n)
 
     def fused_program(self):
         """The fused encode+tag device program: ONE jitted call, one
@@ -254,7 +272,8 @@ class StoragePipeline:
                     | [B, segment_size] u8,
                     fragment_ids [B*(k+m)] | [B, k+m] | [B, k+m, 2])
                  -> {"fragments": [B, k+m, frag], "tags": [B, k+m, blocks, limbs]}
-        One body: jit traces it once per input form.
+        One body: jit traces it once per input form. The rows go to the
+        step as they are; the array is split into ``[B, k, n]`` first.
         """
         if self._fused is None:
             cfg = self.config
@@ -264,10 +283,14 @@ class StoragePipeline:
                 # op_name metadata, so a device trace can tell the
                 # fused step's relayouts from anything else's
                 with jax.named_scope(FUSED_SCOPE):
-                    return self.fused_step(stack_rows(segments, cfg.k),
-                                           fragment_ids)
+                    if not isinstance(segments, (tuple, list)):
+                        segments = split_rows(segments, cfg.k)
+                    return self.fused_step(segments, fragment_ids)
 
             self._fused = jax.jit(run)
+            self._fused.direct_rows = lambda staged: \
+                isinstance(staged, (tuple, list)) and self.rows_direct(
+                    len(staged) // cfg.k, staged[0].shape[0])
         return self._fused
 
     def forward(self, segments: jnp.ndarray,

@@ -137,8 +137,8 @@ class LinearRows(NamedTuple):
     where the same 16 MiB as two ``u8[n]`` take 2.5 (PERF.md section 5,
     the link probe). The codec's decode-side calls (``reconstruct`` /
     ``decode_data`` / ``fold_symbol``) take one in place of the array
-    and stack it on the device, inside the program that applies the
-    matrix (``_apply_rows``)."""
+    and stack it on the device, in the program that applies the matrix
+    (``_apply_rows``); the fused ingest does not (``codeword_rows``)."""
     rows: tuple
     q: int
 
@@ -154,8 +154,8 @@ def _stack_rows(rows, q: int) -> jax.Array:
     libtpu (models/pipeline.py split_rows); this compiles in under a
     second at q = 10, n = 8 MiB, and runs there in 3.2 ms (0.97 ms at
     q = 2), twice as fast as ``dynamic_update_slice`` into zeros
-    (PERF.md, PR 32: each row is relayouted on its own, then the rows
-    are concatenated)."""
+    (PERF.md, PR 32). Who still stacks (PR 51): the repair programs
+    (``_apply_rows``), ``gather``, and a batch ``codeword_rows`` leaves."""
     return jnp.stack([jnp.stack(rows[i:i + q])
                       for i in range(0, len(rows), q)])
 
@@ -480,3 +480,55 @@ def make_codec(k: int, m: int, backend: str = "cpu", strategy: Strategy | None =
 
         return RegenCodec(k, m, strategy=strategy)
     raise ValueError(f"unknown ErasureCodec backend {backend!r}")
+
+
+# ---------------------------------------------------------------------------
+# The rows entry (PR 51): a systematic encode over linear rows
+# ---------------------------------------------------------------------------
+# Appended below everything else on purpose: the persistent compile
+# cache's key holds source locations, and the lines above keep theirs.
+
+
+@functools.partial(jax.jit, static_argnames=("q",))
+def _codeword_rows_pallas(bmat: jax.Array, rows, *, q: int) -> jax.Array:
+    """The B * q linear rows of a batch -> its codeword, fragment-major
+    ``u8[q + r, B, n]``, through the kernel's rows entry
+    (rs_pallas.apply_rows_operand): no ``_stack_rows`` in front, no
+    ``u8[B, q, n]`` array at all."""
+    from . import rs_pallas
+
+    return rs_pallas.apply_rows_operand(bmat, rows, q)
+
+
+def rows_direct(parity: _MatrixApply, batch: int, n: int) -> bool:
+    """Whether :func:`codeword_rows` hands ``batch * q`` linear rows of
+    ``n`` bytes to the kernel as they lie: the chip's lowering, and a
+    batch the rows entry takes (rs_pallas.rows_tile: by shape, 8 or 16
+    segments, rows of whole column tiles)."""
+    if parity.strategy != "pallas":
+        return False
+    from . import rs_pallas
+
+    return bool(rs_pallas.rows_tile(batch, n))
+
+
+def codeword_rows(parity: _MatrixApply, rows, q: int) -> jax.Array:
+    """``_MatrixApply.codeword`` for a caller that holds the batch as
+    its ``B * q`` linear rows ``u8[n]`` (``LinearRows.rows``, traced:
+    the fused ingest step, models/pipeline.py): ``u8[B, q + r, n]``,
+    the data rows followed by the product's.
+
+    Under ``pallas`` the kernel reads the rows as they were put and
+    writes the codeword fragment-major, ``u8[q + r, B, n]``, the layout
+    the chip keeps a ``u8[B, q + r, n]`` batch in anyway (B, a multiple
+    of 8, is the dimension it tiles): the ``swapaxes`` here moves no
+    byte. A batch the rows entry does not take (``rows_direct``: other
+    than 8 or 16 segments, rows of no whole column tile) and the
+    ``gather`` lowering stack the rows and go the array's way."""
+    rows = tuple(rows)
+    b, n = len(rows) // q, rows[0].shape[0]
+    if not rows_direct(parity, b, n):
+        return parity.codeword(_stack_rows(rows, q))
+    return jnp.swapaxes(
+        _codeword_rows_pallas(*parity.operands((b, q, n)), rows, q=q),
+        0, 1)

@@ -27,16 +27,16 @@ each step applies the (8rg x 8qg) GF(2) block-diagonal bit-matrix
 ``kron(I_group, expand_bitmatrix(mat))`` to one (g x q x TILE_N) tile
 and writes the (g x r x TILE_N) product: [B, q, n] -> [B, r, n].
 
-With the static ``passthrough`` (PR 44; only the fused ingest step
-passes it, models/pipeline.py fused_step through ops/rs.py
-``codeword``) a step's output tile is (g x (q + r) x TILE_N): its first
-q rows are the step's input tile, stored as read (a VMEM copy, no MXU
-work), the product's r rows follow — a systematic encode's codeword
-[B, q + r, n] out of the one call, so no ``concatenate`` joins data and
-parity behind it. Without it the kernel body, the grid and the block
-specs are exactly the ones above: every other caller's program (the
-codec's encode and decode, the repair and restoral classes) is what it
-was.
+With the static ``passthrough`` (PR 44; the fused ingest step over an
+ARRAY, ops/rs.py ``codeword``) a step's output tile is (g x (q + r) x
+TILE_N): the input tile as read, then the product's r rows: the
+codeword [B, q + r, n] out of the one call. Without it the kernel body,
+grid and block specs are the ones above: every other caller's program
+(the codec's encode / decode, the repair and restoral classes) is what
+it was. The ROWS entry (PR 51, at the end of this file; the fused step
+over the stream's linear rows, ops/rs.py ``codeword_rows``) has the
+other contract: in, the batch's B * q rows u8[n] as B * q operands, each
+read as [n / 128, 128]; out, the codeword FRAGMENT-MAJOR [q + r, B, n].
 """
 from __future__ import annotations
 
@@ -200,3 +200,140 @@ def apply_operand(bmat: jax.Array, data: jax.Array,
         out = out[..., :n]
     return out
 
+
+
+# ---------------------------------------------------------------------------
+# The rows entry (PR 51): linear rows in, the codeword fragment-major out
+# ---------------------------------------------------------------------------
+
+# Columns of a batch of 8 the rows entry takes a step. A step holds
+# every segment of its column tile (the batch is the result's
+# second-minor dimension), so the tile is a quarter of DEFAULT_TILE_N:
+# at 16384 and 8 segments a step the kernel does not fit VMEM at
+# RS(4,8) or RS(10,4) (described-v5e compiles, PR 51), and on the chip
+# 4096 / 8192 / 16384 ran within 4% of each other where they fit.
+ROWS_TILE_N = 8192
+LANES = 128     # a linear row on the chip is a [n / 128, 128] array
+
+
+def rows_tile(batch: int, n: int) -> int:
+    """The rows entry's column tile for ``batch`` segments of ``n``-byte
+    rows, or 0 where the entry does not take the batch and its caller
+    stacks instead (ops/rs.py ``codeword_rows``). By shape alone: the
+    batch is the second-minor dimension of the ``u8[q + r, B, n]``
+    result, which the chip tiles in eights, and a step holds all of it,
+    so 8 or 16 segments (16 at half the tile); a row is read as
+    ``[n / 128, 128]`` in blocks of whole column tiles."""
+    if batch not in (8, 16):
+        return 0
+    tile = min(n, ROWS_TILE_N * 8 // batch)
+    return tile if n % tile == 0 and tile % LANES == 0 else 0
+
+
+@functools.lru_cache(maxsize=16)
+def _rows_out_np(q: int, r: int, g: int, b: int):
+    """The rows entry's two packing matrices, NUMPY only (as _pack_np):
+    int8 ``pack [b / g, r * b, 8rg]`` and ``keep [b / g, q * b, 8qg]``.
+    Group G's holds ``PACK_W`` where a bit-row of its segments' product
+    (``pack``) or of their data (``keep``) goes into byte-row
+    ``row * b + segment`` of the step's output: the MXU packs the bits
+    to bytes, as in ``_pack_np``, and in the same pass puts every
+    segment's row where the fragment-major block wants it, so no
+    sublane of the result is moved afterwards."""
+    w = np.asarray(PACK_W, dtype=np.int8)
+    pack = np.zeros((b // g, r * b, 8 * r * g), dtype=np.int8)
+    keep = np.zeros((b // g, q * b, 8 * q * g), dtype=np.int8)
+    for grp in range(b // g):
+        for gi in range(g):
+            seg = grp * g + gi
+            for i in range(r):
+                at = 8 * (gi * r + i)
+                pack[grp, i * b + seg, at:at + 8] = w
+            for j in range(q):
+                at = 8 * (gi * q + j)
+                keep[grp, j * b + seg, at:at + 8] = w
+    return pack, keep
+
+
+def _make_rows_kernel(q: int, r: int, g: int, b: int, ts: int):
+    def kernel(bmat_ref, pack_ref, keep_ref, *refs):
+        *row_refs, out_ref = refs
+        shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1)
+        parity = data_rows = None
+        for grp in range(b // g):
+            # the group's g * q row blocks [ts / 128, 128], unpacked,
+            # then flattened to lanes in VMEM: an int32 word holds one
+            # byte, so the flatten moves whole words (the byte shuffle
+            # _stack_rows pays in HBM is the unpack the kernel does
+            # anyway)
+            blocks = jnp.stack([ref[...] for ref in
+                                row_refs[grp * g * q:(grp + 1) * g * q]])
+            data = blocks.astype(jnp.int32).reshape(g * q, ts)
+            # from here _make_kernel's arithmetic: bit-planes ->
+            # int8 MXU product -> & 1 -> the packing matmul
+            bits = (data[:, None, :] >> shifts) & 1          # [gq, 8, ts]
+            bits = bits.reshape(8 * q * g, ts).astype(jnp.int8)
+            prod = jnp.dot(bmat_ref[:], bits,
+                           preferred_element_type=jnp.int32)
+            y = (prod & 1).astype(jnp.int8)
+            part = jnp.dot(pack_ref[grp], y,
+                           preferred_element_type=jnp.int32)
+            parity = part if parity is None else parity + part
+            # the systematic rows: the same bits packed back to bytes
+            part = jnp.dot(keep_ref[grp], bits,
+                           preferred_element_type=jnp.int32)
+            data_rows = part if data_rows is None else data_rows + part
+        out_ref[:q] = data_rows.reshape(q, b, ts).astype(jnp.uint8)
+        out_ref[q:] = parity.reshape(r, b, ts).astype(jnp.uint8)
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _apply_rows_3d(bmat: jax.Array, q: int, r: int, g: int, ts: int,
+                   rows: tuple) -> jax.Array:
+    """bmat [8rg, 8qg] block-diag; ``rows`` the B * q linear rows
+    u8[n] of a batch (row j of segment i at i * q + j) -> the codeword
+    u8[q + r, B, n], fragment-major: the input rows, then the
+    product's."""
+    n = rows[0].shape[0]
+    b = len(rows) // q
+    pack, keep = (jnp.asarray(t) for t in _rows_out_np(q, r, g, b))
+
+    def whole(t):
+        return pl.BlockSpec(t.shape, lambda c: (0,) * t.ndim,
+                            memory_space=pltpu.VMEM)
+
+    row_spec = pl.BlockSpec((ts // LANES, LANES), lambda c: (c, 0),
+                            memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        _make_rows_kernel(q, r, g, b, ts),
+        grid=(n // ts,),
+        in_specs=[whole(bmat), whole(pack), whole(keep)]
+        + [row_spec] * len(rows),
+        out_specs=pl.BlockSpec((q + r, b, ts), lambda c: (0, 0, c),
+                               memory_space=pltpu.VMEM),
+        # vma: as _apply_3d's, the codeword varies as the rows do
+        out_shape=jax.ShapeDtypeStruct((q + r, b, n), jnp.uint8,
+                                       vma=jax.typeof(rows[0]).vma),
+        interpret=target.interpret(),
+        name=KERNEL_NAME,
+    )(bmat, pack, keep,
+      *[row.reshape(n // LANES, LANES) for row in rows])
+
+
+def apply_rows_operand(bmat: jax.Array, rows, q: int) -> jax.Array:
+    """The rows entry: the matrix operand ``bmat`` (``operand_np`` for
+    this batch's group) applied to a batch still as its ``B * q`` linear
+    rows ``u8[n]``, each taken as ``u8[n / 128, 128]`` (a bitcast on the
+    chip). Returns the codeword ``u8[q + r, B, n]``, fragment-major.
+    Only for a batch ``rows_tile`` takes."""
+    n = rows[0].shape[0]
+    b = len(rows) // q
+    ts = rows_tile(b, n)
+    assert ts, f"the rows entry does not take {b} x u8[{n}]"
+    g = group_for(b)
+    r = bmat.shape[0] // (8 * g)
+    assert bmat.shape == (8 * r * g, 8 * q * g), \
+        f"matrix operand {bmat.shape} for {g} x {q} data rows"
+    return _apply_rows_3d(bmat, q, r, g, ts, tuple(rows))

@@ -137,9 +137,12 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
     In: the batch as linear rows (:func:`stream_entry`'s ``put``): a
     tuple of ``(B / seg) * k`` arrays ``u8[seg * n]`` sharded over
     (seg, byte) together, so that a device holds, as 1-D rows, its own
-    byte slice of every fragment of its own segments, and stacks them
-    to ``[B / seg, k, n / byte]`` itself (models/pipeline.py
-    ``stack_rows``); ids [B, k+m] int32 (or [B, k+m, 2] uint32 hash
+    byte slice of every fragment of its own segments. On a (lanes, 1)
+    mesh a lane hands them to the fused step as they are (since PR 51
+    its RS kernel reads them unstacked, 8 or 16 segments a lane; other
+    counts stack by their shape); with the byte axis sharded a device
+    stacks them to ``[B / seg, k, n / byte]`` itself (models/pipeline.py
+    ``stack_rows``). ids [B, k+m] int32 (or [B, k+m, 2] uint32 hash
     word pairs when ``pair_ids``).
     Out: {"fragments" [B, k+m, n], "tags" [B, k+m, blocks, limbs]} —
     the StoragePipeline.forward shape contract.
@@ -154,8 +157,12 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
     blocks_local = blocks_total // byte_shards
 
     def whole_fragments(rows, ids):
+        # a lane's rows as they were put: the step's RS kernel reads
+        # them unstacked and writes the lane's codeword fragment-major
+        # (models/pipeline.py fused_step, PR 51); the result's varying
+        # axes follow the rows'
         with jax.named_scope(FUSED_SCOPE):
-            out = pipeline.fused_step(stack_rows(rows, cfg.k), ids)
+            out = pipeline.fused_step(rows, ids)
         return out["fragments"], out["tags"]
 
     def sliced_fragments(rows, ids):
@@ -196,6 +203,12 @@ def sharded_stream_step(pipeline: StoragePipeline, mesh: Mesh,
         shards, tags = jitted(rows, ids)
         return {"fragments": shards, "tags": tags}
 
+    # what StreamStats.direct_rows counts by, as fused_program's: a
+    # lane holds the n / byte bytes of each of its row slots
+    seg = mesh.shape["seg"]
+    run.direct_rows = lambda staged: byte_shards == 1 \
+        and pipeline.rows_direct(len(staged) // cfg.k,
+                                 staged[0].shape[0] // seg)
     return run
 
 

@@ -255,7 +255,7 @@ class StreamStats:
     """
 
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
-                 "bytes_out", "linear_puts", "put_arrays",
+                 "bytes_out", "linear_puts", "put_arrays", "direct_rows",
                  "h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s")
     __slots__ = _COUNTERS + ("lanes", "hist")
 
@@ -273,6 +273,11 @@ class StreamStats:
         # host arrays handed to the put for them
         self.linear_puts = 0
         self.put_arrays = 0
+        # batches whose program handed the put's rows to the RS kernel
+        # unstacked (the program's own ``direct_rows``, models/
+        # pipeline.py: PERF.md, PR 51); a program that does not say
+        # counts none
+        self.direct_rows = 0
         self.h2d_s = 0.0           # host time ENQUEUEING device_put
         self.dispatch_s = 0.0      # host time ENQUEUEING the program
         self.stall_s = 0.0         # host time blocked on device results

@@ -357,6 +357,8 @@ class StreamingIngest:
                 st.h2d_s += h2d
                 st.linear_puts += 1
                 st.put_arrays += len(rows_up)
+                st.direct_rows += bool(getattr(
+                    program, "direct_rows", lambda staged: False)(dev))
                 # gauge: the devices this batch was placed over
                 st.lanes = len(jax.tree.leaves(dev)[0]
                                .sharding.device_set)

@@ -485,11 +485,17 @@ def phase_c(run: Run) -> None:
         check("C: systematic rows are not the segment bytes",
               np.array_equal(np.asarray(out["fragments"][:, :k]),
                              segs.reshape(total, k, cfg.fragment_size)))
+        # on the chip every batch's rows go to the RS kernel unstacked
+        # (PR 51); the CPU's lowering stacks them
+        check("C: direct_rows is not what the program says of its batches",
+              ing.stats.direct_rows == ing.stats.batches
+              * pipe.rows_direct(batch, cfg.fragment_size))
         line.update(
             bytes_in=int(segs.nbytes),
             devices=devices_of(out["fragments"], out["tags"]),
             stream={kk: ing.stats.snapshot()[kk] for kk in
-                    ("batches", "segments", "padded_segments")},
+                    ("batches", "segments", "padded_segments",
+                     "direct_rows")},
             tpu_custom_call=run.require_kernels(
                 pipe._parity, key, (batch, k, cfg.fragment_size),
                 (batch * (k + m), cfg.fragment_size)),
@@ -521,7 +527,8 @@ def _stream_wide(run: Run) -> dict:
     key = podr2.Podr2Key.generate(run.seed + 3)
     segs = np.random.default_rng(run.seed + 3).integers(
         0, 256, (total, k * n), dtype=np.uint8)
-    ing = StreamingIngest(StoragePipeline(cfg, podr2_key=key), batch=batch)
+    pipe = StoragePipeline(cfg, podr2_key=key)
+    ing = StreamingIngest(pipe, batch=batch)
     done = 0
     for out in ing.run(segs):            # a batch at a time: 896 MiB each
         frags = np.asarray(out["fragments"])
@@ -546,11 +553,13 @@ def _stream_wide(run: Run) -> dict:
     check("C wide: stored bytes per user byte is not (k + m) / k",
           (snap["bytes_out"] - total * tags.nbytes) * k
           == snap["bytes_in"] * rows)
+    check("C wide: direct_rows is not what the program says of its batches",
+          snap["direct_rows"] == snap["batches"] * pipe.rows_direct(batch, n))
     return {"k": k, "m": m, "bytes_in": int(segs.nbytes),
             "devices": devices_of(out["fragments"], out["tags"]),
             "stream": {kk: snap[kk] for kk in
                        ("batches", "segments", "padded_segments",
-                        "bytes_out")}}
+                        "bytes_out", "direct_rows")}}
 
 
 # ---------------------------------------------------------------------------

@@ -84,3 +84,36 @@ def test_fused_step_carries_both_names_and_its_scope():
     text = pipe.fused_program().lower(segs, ids).as_text(debug_info=True)
     assert pipeline.FUSED_SCOPE == "cess_fused_step"
     assert pipeline.FUSED_SCOPE in text
+
+
+@pytest.mark.parametrize("batch,direct", [(8, True), (16, True), (4, False)],
+                         ids=["b8", "b16", "b4-stacks"])
+def test_rows_form_program_carries_both_names_once_each(batch, direct):
+    """The fused program over the driver's linear rows (PR 51): the RS
+    kernel's rows entry is a second ``pallas_call`` under the SAME pinned
+    name, exactly one a batch beside the tag kernel's one, under the
+    step's scope — whether the batch goes to the kernel unstacked or,
+    no multiple of 8, stacks by its shape."""
+    cfg = pipeline.PipelineConfig(k=2, m=1, segment_size=2 * 8192,
+                                  strategy="pallas")
+    pipe = pipeline.StoragePipeline(cfg,
+                                    podr2_key=podr2.Podr2Key.generate(1))
+    assert pipe.rows_direct(batch, cfg.fragment_size) == direct
+    rows = tuple(jnp.zeros((cfg.fragment_size,), jnp.uint8)
+                 for _ in range(batch * cfg.k))
+    ids = jnp.arange(batch * 3, dtype=jnp.int32)
+    names = _pallas_names(pipe.fused_program(), rows, ids)
+    assert sorted(names) == sorted([rs_pallas.KERNEL_NAME,
+                                    podr2_pallas.KERNEL_NAME])
+    text = pipe.fused_program().lower(rows, ids).as_text(debug_info=True)
+    assert pipeline.FUSED_SCOPE in text
+
+
+def test_rs_rows_entry_call_carries_the_name():
+    bmat = gf.expand_bitmatrix(gf.cauchy_parity_matrix(2, 1))
+    rows = tuple(jnp.zeros((rs_pallas.ROWS_TILE_N,), jnp.uint8)
+                 for _ in range(16))
+    operand = jnp.asarray(rs_pallas.operand_np(bmat, rs_pallas.group_for(8)))
+    assert _pallas_names(
+        lambda *r: rs_pallas.apply_rows_operand(operand, r, 2),
+        *rows) == [rs_pallas.KERNEL_NAME]

@@ -162,24 +162,77 @@ def test_sharded_step_bit_identical_to_fused_program(seg, byte, strategy,
 def test_pooled_step_traces_the_fused_steps_body(monkeypatch):
     """One body for one chip and for the pool: the (lanes, 1) step calls
     StoragePipeline.fused_step (as fused_program does), the byte-sharded
-    step does not."""
+    step does not. Since PR 51 a lane hands the step its rows as they
+    were put, unstacked: the RS kernel's entry follows from what the
+    step is handed, and no ``[B, k, n]`` array is made in front of it."""
     pipe = make_pipe()
     calls = []
     real = StoragePipeline.fused_step
 
     def counting(self, data, ids):
-        calls.append(data.shape)
+        calls.append([r.shape for r in data]
+                     if isinstance(data, (tuple, list)) else data.shape)
         return real(self, data, ids)
 
     monkeypatch.setattr(StoragePipeline, "fused_step", counting)
     segs = rnd((BATCH, SEG), 8)
     ids = np.arange(BATCH * ROWS, dtype=np.int32).reshape(BATCH, ROWS)
     sharded_step(pipe, make_mesh(jax.devices()[:4], 4, 1), segs, ids)
-    assert calls == [(BATCH // 4, K, FRAG)]           # per-device rows
+    assert calls == [[(FRAG,)] * (BATCH // 4 * K)]    # per-device rows
     pipe.fused_program()(jnp.asarray(segs), jnp.asarray(ids))
     assert calls[1:] == [(BATCH, K, FRAG)]
+    pipe.fused_program()(jax.device_put(linear_rows(segs, K)),
+                         jnp.asarray(ids))
+    assert calls[2:] == [[(FRAG,)] * (BATCH * K)]     # the rows, unstacked
     sharded_step(pipe, make_mesh(jax.devices()[:4], 2, 2), segs, ids)
-    assert len(calls) == 2
+    assert len(calls) == 3
+
+
+GEOMETRIES = {"rs4p8": (4, 8), "rs2p1": (2, 1), "rs10p4": (10, 4)}
+
+
+@pytest.mark.parametrize("id_kind", ["default", "pair"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_pooled_lanes_hand_their_rows_to_the_rs_kernel_unstacked(
+        geometry, id_kind):
+    """The four-lane mesh under the chip's lowering (interpret mode), 8
+    segments a lane as in ``stream-4p8.pool4``: every lane's program
+    takes the rows entry (PR 51: ``direct_rows`` counts every batch),
+    and fragments and tags equal the plain reference's and the one-chip
+    array form's byte for byte, ragged tail included. Two segments a
+    lane fall back to the stack by their shape, same bits."""
+    k, m = GEOMETRIES[geometry]
+    rows = k + m
+    pipe = StoragePipeline(
+        PipelineConfig(k=k, m=m, segment_size=k * 1024, strategy="pallas"),
+        podr2_key=podr2.Podr2Key.generate(51))
+    n_segments = 32 + 5
+    segs = rnd((n_segments, k * 1024), 510)
+    pair = id_kind == "pair"
+    ids = rnd((n_segments, rows, 2), 511, np.uint32) if pair else \
+        np.arange(n_segments * rows, dtype=np.int32).reshape(-1, rows)
+    pool = DevicePool(n=LANES)
+    ing = StreamingIngest(pipe, 32, **pool.stream_entry(pipe, 32,
+                                                        pair_ids=pair))
+    got = ing.ingest(segs, ids if pair else None)
+    want_frags, want_tags = plain_reference(pipe, segs, ids)
+    assert np.array_equal(np.asarray(got["fragments"]), want_frags)
+    assert np.array_equal(np.asarray(got["tags"]), want_tags)
+    array = pipe.fused_program()(jnp.asarray(segs[:8]),
+                                 jnp.asarray(ids[:8]))
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(got[name][:8]),
+                              np.asarray(array[name])), name
+    st = ing.stats
+    assert st.direct_rows == st.linear_puts == st.batches == 2
+    assert st.lanes == LANES
+    # two segments a lane: no multiple of 8, so the lanes stack
+    small = StreamingIngest(pipe, BATCH, **pool.stream_entry(
+        pipe, BATCH, pair_ids=pair))
+    out = small.ingest(segs[:BATCH], ids[:BATCH] if pair else None)
+    assert np.array_equal(np.asarray(out["fragments"]), want_frags[:BATCH])
+    assert np.array_equal(np.asarray(out["tags"]), want_tags[:BATCH])
+    assert small.stats.direct_rows == 0 and small.stats.batches == 1
 
 
 def test_stream_stats_lanes_is_a_gauge():

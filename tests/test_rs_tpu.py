@@ -227,6 +227,66 @@ def test_matrix_apply_codeword_is_the_systematic_encode(k, m, strategy):
         apply_.codeword(jnp.asarray(data[:, :-1]))
 
 
+@pytest.mark.parametrize("batch,n", [(8, 1024), (16, 512), (8, 16384)],
+                         ids=["b8", "b16", "b8-two-tiles"])
+@pytest.mark.parametrize("k,m", GEOMETRIES)
+def test_pallas_rows_entry_writes_the_codeword_fragment_major(k, m, batch,
+                                                              n):
+    """The kernel's rows entry (PR 51, rs_pallas.apply_rows_operand): the
+    batch's ``B * k`` linear rows in, each read as ``[n / 128, 128]``,
+    the codeword ``u8[k + m, B, n]`` out, equal to the oracle's encode
+    with the two leading dimensions swapped; the same matrix operand as
+    the array entry (``operand_np``, ``group_for``)."""
+    from cess_tpu.ops import rs_pallas
+
+    data = rand((batch, k, n), seed=batch + k)
+    operand = rs_pallas.operand_np(
+        gf.expand_bitmatrix(gf.cauchy_parity_matrix(k, m)),
+        rs_pallas.group_for(batch))
+    rows = tuple(jnp.asarray(r) for r in data.reshape(batch * k, n))
+    got = np.asarray(rs_pallas.apply_rows_operand(jnp.asarray(operand),
+                                                  rows, k))
+    assert got.shape == (k + m, batch, n)
+    np.testing.assert_array_equal(got[:k].swapaxes(0, 1), data)
+    np.testing.assert_array_equal(got.swapaxes(0, 1),
+                                  ReferenceCodec(k, m).encode(data))
+
+
+@pytest.mark.parametrize("batch,n,tile", [
+    (8, 4 << 20, 8192), (8, 8 << 20, 8192), (16, 4 << 20, 4096),
+    (8, 1024, 1024), (16, 512, 512),
+    # what the entry leaves to the stack, by shape: a batch of other
+    # than 8 or 16, rows of no whole column tile or no whole lane row
+    (1, 4 << 20, 0), (4, 4 << 20, 0), (12, 1024, 0), (24, 1024, 0),
+    (8, 8192 + 512, 0), (8, 576, 0)])
+def test_rows_tile_decides_by_shape(batch, n, tile):
+    from cess_tpu.ops import rs_pallas
+
+    assert rs_pallas.rows_tile(batch, n) == tile
+
+
+@pytest.mark.parametrize("batch,n", [(8, 1024), (4, 1024), (8, 576)],
+                         ids=["direct", "odd-batch-stacks", "odd-row-stacks"])
+@pytest.mark.parametrize("k,m", [(2, 1), (4, 8), (10, 4)])
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_codeword_rows_is_the_systematic_encode(k, m, strategy, batch, n):
+    """``rs.codeword_rows`` (what the fused ingest step calls on linear
+    rows): ``u8[B, k + m, n]`` equal to ``codeword`` of the stacked array
+    and to the oracle, whichever way the shape and the lowering send
+    it; ``rows_direct`` says which."""
+    from cess_tpu.ops.rs import _MatrixApply
+
+    data = rand((batch, k, n), seed=3 * k + batch)
+    apply_ = _MatrixApply(gf.cauchy_parity_matrix(k, m), strategy)
+    assert rs.rows_direct(apply_, batch, n) == (
+        strategy == "pallas" and (batch, n) == (8, 1024))
+    rows = [jnp.asarray(r) for r in data.reshape(batch * k, n)]
+    got = np.asarray(rs.codeword_rows(apply_, rows, k))
+    np.testing.assert_array_equal(got, ReferenceCodec(k, m).encode(data))
+    np.testing.assert_array_equal(
+        got, np.asarray(apply_.codeword(jnp.asarray(data))))
+
+
 @pytest.mark.parametrize("name", "xor auto bitmatrix".split())
 def test_a_strategy_that_is_not_a_lowering_is_refused(name):
     """The codec has two lowerings; any other name is a caller's

@@ -25,6 +25,11 @@
   own put; ``depth`` results and ``depth + 1`` batches' rows at most; a
   fault at either seam, or a consumer that stops, leaves every span
   closed and the counters consistent;
+- the rows entry (PR 51): under the chip's lowering a batch of 8 or 16
+  goes to the RS kernel as the put's linear rows and comes back
+  fragment-major, bit-identical to the array form and the reference at
+  the three geometries; other batches and the CPU's lowering stack;
+  ``StreamStats.direct_rows`` counts the batches that went unstacked;
 - the repair warm path (rs.py warm_reconstruct / engine.warm_repair)
   returns byte-exact reconstructions through pre-compiled programs.
 """
@@ -434,6 +439,112 @@ def test_the_benchmarks_seams_drive_the_linear_path(geometry, fault):
     differ = np.asarray(got["fragments"]) != np.asarray(want["fragments"])
     assert differ.sum() == (4 if fault else 0)   # a parity byte a segment
     assert ing.stats.linear_puts == ing.stats.batches == 2
+
+
+# -- the rows entry (PR 51) -------------------------------------------------
+
+@pytest.mark.parametrize("id_kind", ["scalars", "pairs"])
+@pytest.mark.parametrize("batch,direct", [(8, True), (16, True), (4, False)],
+                         ids=["b8", "b16", "b4-stacks"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_rows_entry_equals_the_array_form_and_the_reference(
+        geometry, batch, direct, id_kind):
+    """Under the chip's lowering (interpret mode) the fused program hands
+    the driver's linear rows to the RS kernel as they lie and takes the
+    codeword fragment-major (PR 51): no ``u8[B, k, n]`` array in the
+    program, a ``u8[k + m, B, n]`` one instead, and ``"fragments"`` /
+    ``"tags"`` equal to the array form's and to the plain reference's
+    byte for byte. A batch that is no multiple of 8 stacks by its shape:
+    the old program, the same bits."""
+    pipe = geometry_pipe(geometry, strategy="pallas")
+    cfg = pipe.config
+    rows = cfg.k + cfg.m
+    assert pipe.rows_direct(batch, FRAG) == direct
+    segs = rnd((batch, cfg.segment_size), 510 + batch)
+    ids = rnd((batch, rows, 2), 511, np.uint32) if id_kind == "pairs" \
+        else rnd((batch, rows), 512, np.uint32).astype(np.int32)
+    staged = jax.device_put(linear_rows(segs, cfg.k))
+    got = pipe.fused_program()(staged, jnp.asarray(ids))
+    array = pipe.fused_program()(jnp.asarray(segs), jnp.asarray(ids))
+    want_frags, want_tags = plain_reference(pipe, segs, ids)
+    assert got["fragments"].shape == (batch, rows, FRAG)
+    assert np.array_equal(np.asarray(got["fragments"]), want_frags)
+    assert np.array_equal(np.asarray(got["tags"]), want_tags)
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(array[name])), name
+    text = str(jax.make_jaxpr(pipe.fused_program())(staged,
+                                                    jnp.asarray(ids)))
+    stacked = f"u8[{batch},{cfg.k},{FRAG}]"
+    fragment_major = f"u8[{rows},{batch},{FRAG}]"
+    assert (stacked in text) == (not direct)
+    assert (fragment_major in text) == direct
+    assert pipe.fused_program().direct_rows(staged) == direct
+    assert not pipe.fused_program().direct_rows(jnp.asarray(segs))
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_the_cpus_lowering_keeps_the_stack(geometry):
+    """``gather`` (the CPU's) stacks the rows whatever the batch: the
+    rows entry is the chip's kernel's."""
+    pipe = geometry_pipe(geometry)
+    cfg = pipe.config
+    assert not pipe.rows_direct(8, FRAG)
+    segs = rnd((8, cfg.segment_size), 513)
+    ids = jnp.arange(8 * (cfg.k + cfg.m), dtype=jnp.int32)
+    staged = jax.device_put(linear_rows(segs, cfg.k))
+    got = pipe.fused_program()(staged, ids)
+    want = geometry_pipe(geometry, strategy="pallas").fused_program()(
+        staged, ids)
+    for name in ("fragments", "tags"):
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(want[name])), name
+    assert f"u8[8,{cfg.k},{FRAG}]" in str(
+        jax.make_jaxpr(pipe.fused_program())(staged, ids))
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_direct_rows_counts_the_batches_the_kernel_took_unstacked(geometry):
+    """``StreamStats.direct_rows``: equal to ``batches`` on the rows
+    path (the ragged tail is padded to the batch first), in ``raw()``,
+    the snapshot, the export and the engine's merged sum; 0 for a program
+    handed an array, for the CPU's lowering, for a batch that stacks by
+    its shape, and for a program that does not say (the benchmark's span
+    wrapper hides the attribute: its witness is the trace)."""
+    pipe = geometry_pipe(geometry, strategy="pallas")
+    cfg = pipe.config
+    segs = rnd((19, cfg.segment_size), 514)
+    want = pipe.forward(segs)
+    eng = make_engine(cfg.k, cfg.m, rs_backend="jax")
+    try:
+        ing = StreamingIngest(pipe, 8, engine=eng)
+        got = ing.ingest(segs)
+        for name in ("fragments", "tags"):
+            assert np.array_equal(np.asarray(got[name]),
+                                  np.asarray(want[name])), name
+        st = ing.stats
+        assert st.direct_rows == st.linear_puts == st.batches == 3
+        assert st.raw()["direct_rows"] == st.snapshot()["direct_rows"] == 3
+        assert st.metrics()["cess_engine_stream_direct_rows"] == 3
+        assert eng.stats_metrics()["cess_engine_stream_direct_rows"] == 3
+    finally:
+        eng.close()
+
+    def packed(rows_up):        # an array program: [B, segment_size] up
+        return jnp.asarray(np.concatenate(rows_up).reshape(8, -1)) \
+            if isinstance(rows_up, tuple) else jax.device_put(rows_up)
+
+    for name, kw in {
+            "array": dict(pipe=pipe, batch=8, put=packed),
+            "gather": dict(pipe=geometry_pipe(geometry), batch=8),
+            "stacks": dict(pipe=pipe, batch=4),
+            "wrapped": dict(pipe=pipe, batch=8, program=_spanned(
+                [], "stream.dispatch", pipe.fused_program()))}.items():
+        ing = StreamingIngest(kw.pop("pipe"), kw.pop("batch"), **kw)
+        got = ing.ingest(segs)
+        assert np.array_equal(np.asarray(got["tags"]),
+                              np.asarray(want["tags"])), name
+        assert ing.stats.direct_rows == 0 < ing.stats.batches, name
 
 
 # -- the driver's order (PR 50) ---------------------------------------------

@@ -482,30 +482,46 @@ def _u8_ops(text, at_least):
     return found
 
 
-def _fused_step_keeps_the_fragments_shape(text, b, rows, n):
-    """The witness of PR 44 (models/pipeline.py fused_step): between
-    the RS kernel, which writes the codeword ``u8[B, k + m, n]`` itself,
-    and the tag kernel nothing regroups rows. No ``merge_rows`` (a
-    ``while`` of ``dynamic-update-slice`` into ``u8[B * rows, n]``, with
-    its ``pad``), no ``concatenate`` of data and parity (a ``pad`` and
-    an add on this compiler); what touches the whole codeword is the
-    kernel, at most two copies (into the layout of the ``"fragments"``
-    result, and from there into the tag kernel's tiling: the one
-    minor-dimension split) and views of those. One call of each kernel.
-    And, as since PR 43, no ``reshape`` of more than a row: the
-    relayouting reshape's compile time grows with the array
-    (models/pipeline.py); a row's own ``u8[n]`` -> ``u8[1, n]`` is the
-    stack's, and compiles in milliseconds."""
+def _rows_reach_the_kernel_as_they_lie(text, b, k, rows, n):
+    """The witness of PR 51 over PR 44's (models/pipeline.py fused_step
+    over linear rows): the RS kernel takes the batch's rows as they were
+    put, each a ``bitcast`` to ``u8[n / 128, 128]``, and writes the
+    codeword fragment-major, ``u8[rows, B, n]``, which is the layout of
+    the ``"fragments"`` result (a ``bitcast`` of the kernel's output).
+    So: no stacked ``u8[B, k, n]`` array anywhere; in front of the kernel
+    no ``concatenate``, ``pad``, ``fusion``, ``reshape`` or loop over a
+    row or more (what XLA adds itself, its asynchronous prefetch of a few
+    whole rows into its other memory space — ``slice-start`` /
+    ``copy-start`` and a ``ConcatBitcast`` custom call — moves tiles,
+    never bytes within a word); behind it AT MOST ONE copy-like
+    operation of codeword size, the tag kernel's view (the one
+    minor-dimension split), where PR 44's program had two. One call of
+    each kernel under its pinned name. No ``merge_rows`` flat form, as
+    since PR 44."""
     for name in (RS, TAGS):
         assert len(re.findall(rf"%{name}\.\d+ = [^\n]* custom-call\(",
                               text)) == 1, name
-    ops = _u8_ops(text, b * rows * n)
-    assert {op for op, _ in ops} <= {"custom-call", "copy", "bitcast",
-                                     "fusion", "parameter", "tuple"}, ops
-    assert sum(op == "copy" for op, _ in ops) <= 2, ops
-    assert sum(op == "custom-call" for op, _ in ops) == 1, ops
+    assert f"u8[{b},{k},{n}]" not in text          # the stack's result
     assert f"u8[{b * rows},{n}]" not in text       # merge_rows' flat form
-    assert "reshape" not in {op for op, _ in _u8_ops(text, n + 1)}
+    row_or_more = _u8_ops(text, n)
+    assert {op for op, _ in row_or_more} <= {
+        "parameter", "bitcast", "tuple", "custom-call", "copy",
+        "copy-start", "copy-done", "slice-start", "slice-done"}, \
+        row_or_more
+    # the custom calls that touch a row or more: the RS kernel, and
+    # XLA's own joins of its prefetched slices
+    for line in text.splitlines():
+        if " custom-call(" in line and any(
+                shape in line.split(" custom-call(")[0]
+                for _, shape in row_or_more):
+            assert line.lstrip().startswith(("%" + RS, "ROOT %" + RS)) \
+                or 'custom_call_target="ConcatBitcast"' in line, line
+    whole = _u8_ops(text, b * rows * n)
+    assert f"u8[{rows},{b},{n}]" in {shape for _, shape in whole}
+    assert sum(op == "custom-call" for op, _ in whole) == 1, whole
+    assert sum(op == "copy" for op, _ in whole) <= 1, whole
+    # a synchronous copy of a row or more is that one or none
+    assert sum(op == "copy" for op, _ in row_or_more) <= 1, row_or_more
 
 
 @pytest.mark.parametrize("k,m,segment_size", [
@@ -516,14 +532,14 @@ def test_linear_fused_program_compiles_for_v5e(one_chip, for_tpu, k, m,
                                                segment_size):
     """The one-chip stream cells' program as the driver calls it since
     PR 43 (models/pipeline.py fused_program over ``linear_rows``): a
-    batch of 8 segments as its 8k linear ``u8[segment_size / k]`` rows,
-    stacked on the device in front of the fused step. 1-D dense arguments
-    (their logical 128 MiB, where a ``u8[8, 2, 8 MiB]`` operand is twice
-    that), no reshape of more than a row, both kernels under their pinned
-    names, compiled in seconds; since PR 44 the fragments keep their
-    shape from one kernel to the other. ``rs10p4`` is the archival
-    tier's cell (stream-10p4.corpus, PR 47): 80 rows of 8 MiB, 640 MiB
-    of arguments."""
+    batch of 8 segments as its 8k linear ``u8[segment_size / k]`` rows.
+    1-D dense arguments (their logical 128 MiB, where a
+    ``u8[8, 2, 8 MiB]`` operand is twice that), both kernels under their
+    pinned names, compiled in seconds; since PR 51 the rows go to the RS
+    kernel unstacked and the codeword comes back fragment-major, so the
+    program's only temporary is the tag kernel's view. ``rs10p4`` is the
+    archival tier's cell (stream-10p4.corpus, PR 47): 80 rows of 8 MiB,
+    640 MiB of arguments."""
     n = segment_size // k
     cfg = PipelineConfig(k=k, m=m, segment_size=segment_size,
                          strategy="pallas")
@@ -538,17 +554,17 @@ def test_linear_fused_program_compiles_for_v5e(one_chip, for_tpu, k, m,
     print(f"compiled in {took:.1f} s")                     # pytest -s
     assert took < FUSED_COMPILE_SECONDS
     text = compiled.as_text()
-    _fused_step_keeps_the_fragments_shape(text, 8, k + m, n)
+    _rows_reach_the_kernel_as_they_lie(text, 8, k, k + m, n)
     out = compiled.out_info
     assert out["fragments"].shape == (8, k + m, n)
     assert out["tags"].shape == (8, k + m, n // 512, 2)
     mem = compiled.memory_analysis()
     assert 0 <= mem.argument_size_in_bytes - 8 * k * n < 65536, mem
-    # the stacked data, the codeword as the kernel writes it (its rows
-    # padded to the 8-row tile) and the tag kernel's view: 520 MiB at
-    # RS(4,8) and 773 at RS(2,1), where merge_rows' padded flat copy
-    # beside them made it 904 and 1,029
-    assert mem.temp_size_in_bytes < 7 * 8 * k * n, mem
+    # the tag kernel's view of the codeword and nothing else of its
+    # size: 385.5 MiB at RS(4,8), 192.3 at RS(2,1), 897.8 at RS(10,4),
+    # where the stacked data and the codeword as the array entry writes
+    # it (rows padded to the 8-row tile) made it 520, 773 and 2,809
+    assert mem.temp_size_in_bytes < 1.2 * 8 * (k + m) * n, mem
     _fits_hbm(compiled)
 
 
@@ -558,9 +574,9 @@ def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):
     handed over as stream_entry's ``put`` stages them since PR 43: 32
     row slots ``u8[4 x 4 MiB]``, a lane's linear row in each. Each chip
     must hold the one-chip step's two kernels under their pinned names,
-    once each, stack its rows without a reshape of more than a row, keep
-    the fragments' shape between the kernels as the one-chip program
-    does (one body), and the step needs no collective."""
+    once each, hand its rows to the RS kernel unstacked and take the
+    codeword fragment-major as the one-chip program does (one body,
+    PR 51), and the step needs no collective."""
     cfg = PipelineConfig(k=4, m=8, segment_size=constants.SEGMENT_SIZE,
                          strategy="pallas")
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("seg", "byte"))
@@ -577,5 +593,7 @@ def test_pooled_stream_step_compiles_for_v5e_2x2(topo, for_tpu):
     text = compiled.as_text()
     assert not re.search(r"all-reduce|all-gather|all-to-all|"
                          r"collective-permute|reduce-scatter", text)
-    _fused_step_keeps_the_fragments_shape(text, 8, 12, 4 * MiB)
+    _rows_reach_the_kernel_as_they_lie(text, 8, 4, 12, 4 * MiB)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.2 * 8 * 12 * 4 * MiB
     _fits_hbm(compiled)
