@@ -1856,22 +1856,39 @@ class SubmissionEngine:
         rows (_linear_rows, on the device the result is on) and hand
         each request of the batch its own ``np.uint8 [rows_i, r, n]``:
         a view of the one row where a request is one row, else one
-        ``memcpy`` a row of that request. The batch is never put back
-        together on the host: sixteen coalesced claims would be one
-        fresh allocation of 128 MiB a batch, faulted in page by page
-        (PERF.md, PR 38: 45 of a batch's 57 ms of fetch at five
-        claims)."""
+        ``memcpy`` a row of that request (the ``regroup``: a stage of
+        its own inside ``fetch``, ``regroup_s`` / ``regrouped_bytes``,
+        only where a request has more rows than one; ``result_bytes``
+        counts what is handed back either way). The batch is
+        never put back together on the host: sixteen coalesced claims
+        would be one fresh allocation of 128 MiB a batch, faulted in
+        page by page (PERF.md, PR 38: 45 of a batch's 57 ms of fetch at
+        five claims)."""
         flat = [np.asarray(row)
                 for row in self._linear_rows_program(out.shape, lane)(out)]
-        with self._lock:
-            self.stats.classes[cls].linear_fetches += 1
-        per, pieces, at = out.shape[1], [], 0
-        for r in batch:
+        per, at = out.shape[1], 0
+        pieces, many = [], []
+        for i, r in enumerate(batch):
             rows = flat[at:at + r.rows * per]
-            whole = rows[0] if len(rows) == 1 else np.stack(rows)
-            pieces.append(whole.reshape((r.rows,) + out.shape[1:]))
             at += len(rows)
-        return pieces
+            pieces.append(rows[0])
+            if len(rows) > 1:
+                many.append((i, rows))
+        regroup_s = regrouped = 0
+        if many:
+            with trace.stage(f"engine.{cls}.fetch.regroup") as stage:
+                for i, rows in many:
+                    pieces[i] = np.stack(rows)
+            regroup_s = stage.seconds
+            regrouped = sum(pieces[i].nbytes for i, _ in many)
+        with self._lock:
+            st = self.stats.classes[cls]
+            st.linear_fetches += 1
+            st.result_bytes += at * out.shape[2]
+            st.regroup_s += regroup_s
+            st.regrouped_bytes += regrouped
+        return [piece.reshape((r.rows,) + out.shape[1:])
+                for r, piece in zip(batch, pieces)]
 
     def _rs_backend(self, degraded: bool):
         """The ErasureCodec serving this batch: the configured device

@@ -98,7 +98,8 @@ class ClassStats:
                  "saturated", "shed", "batches", "batched_requests",
                  "rows", "padded_rows", "operand_bytes", "linear_fetches",
                  "linear_puts", "symbol_folds", "patterns_new",
-                 "matrix_build_s",
+                 "matrix_build_s", "result_bytes", "regroup_s",
+                 "regrouped_bytes",
                  "missions", "device_calls", "prf_evals",
                  "chunks", "gathered_bytes", "gather_seconds",
                  "latencies", "hist", "stage_n", "stage_s",
@@ -144,6 +145,18 @@ class ClassStats:
         # counts programs, one per shape
         self.patterns_new = 0
         self.matrix_build_s = 0.0
+        # what a byte result costs on its way back to host callers
+        # (engine.py _split_rows -> _fetch_linear: an all-host batch of
+        # encode or repair, fetched as linear rows): the bytes handed
+        # to the requests as host arrays; and the host seconds and the
+        # bytes of the ``np.stack`` that regroups a request's rows into
+        # its own ``[rows, r, n]`` — only where it runs: a request
+        # whose result is one row gets a view of it and counts nothing
+        # there (the ``cess:engine.<cls>.fetch.regroup`` span, inside
+        # ``fetch``)
+        self.result_bytes = 0
+        self.regroup_s = 0.0
+        self.regrouped_bytes = 0
         # verify class, aggregated proofs (engine.py _op_verify_agg,
         # _op_verify_round): missions judged, device programs called
         # for them, and PRF evaluations those programs issue (rows on
@@ -360,6 +373,7 @@ class EngineStats:
                 "saturated": st.saturated,
                 "shed": st.shed,
                 "batches": st.batches,
+                "batched_requests": st.batched_requests,
                 "batch_occupancy": round(st.occupancy, 4),
                 "pad_waste": round(st.pad_waste, 4),
                 "rows": st.rows,
@@ -370,6 +384,9 @@ class EngineStats:
                 "symbol_folds": st.symbol_folds,
                 "patterns_new": st.patterns_new,
                 "matrix_build_s": st.matrix_build_s,
+                "result_bytes": st.result_bytes,
+                "regroup_s": st.regroup_s,
+                "regrouped_bytes": st.regrouped_bytes,
                 "missions": st.missions,
                 "device_calls": st.device_calls,
                 "prf_evals": st.prf_evals,

@@ -8,7 +8,8 @@
                                           last line
 
 Phase A  RS(4,8) codec + PoDR2 audit through the submission engine, checked
-         against rs_ref.ReferenceCodec and the jnp tag path on the CPU device.
+         against rs_ref.ReferenceCodec and the jnp tag path on the CPU device;
+         a deal's burst of eight host repairs with four rows lost, by hash.
 Phase B  the lifecycle at CESS's own geometry, RS(2,1) with 16 MiB segments:
          validators, gateway, miners, TEE; upload -> audit -> repair.
 Phase C  streamed ingest through the fused encode+tag program, at RS(4,8)
@@ -184,6 +185,7 @@ def phase_a(run: Run) -> None:
                 present, missing)
             check("A: reconstruct differs from ReferenceCodec",
                   np.array_equal(np.asarray(rec), want[:, missing]))
+            line["burst"] = _burst_repair(eng, want)
 
             flat = got.reshape(segs * rows, n)
             ids = np.stack([podr2.fragment_id_from_hash(
@@ -224,6 +226,44 @@ def phase_a(run: Run) -> None:
                 sync=sync_probe(eng.codec.encode, jax.device_put(data)))
         finally:
             eng.close()
+
+
+def _burst_repair(eng, coded) -> dict:
+    """BASELINE's 4-erasure repair as a rebuilder's burst (PR 52): the
+    eight segments of ``coded`` [8, 12, n] have lost the same four rows,
+    data rows among them; each is one host request of its four lowest
+    survivors' rows as they lie, all submitted in a row through the
+    engine's repair class after ``warm_repair`` of the shape at buckets
+    1 to 8; every rebuilt row is hashed against the original's."""
+    from cess_tpu.crypto.hashing import fragment_hash
+
+    lost = (0, 3, 6, 10)
+    helpers = tuple(j for j in range(coded.shape[1]) if j not in lost)[:4]
+    n = coded.shape[2]
+    eng.warm_repair([(helpers, lost)], n, buckets=(1, 2, 4, 8))
+    before = eng.stats_snapshot()
+    futs = [eng.submit_reconstruct([seg[j] for j in helpers], helpers, lost)
+            for seg in coded]
+    for seg, fut in zip(coded, futs):
+        rec = fut.result()
+        for i, row in enumerate(lost):
+            check(f"A: burst: row {row} hashes to another value",
+                  fragment_hash(rec[i].tobytes())
+                  == fragment_hash(seg[row].tobytes()))
+    eng.flush()
+    after = eng.stats_snapshot()
+    a, b = (s["classes"]["repair"] for s in (before, after))
+    check("A: burst: a warmed shape built a program",
+          after["programs_built"] == before["programs_built"])
+    out = {key: b[key] - a[key] for key in (
+        "batches", "batched_requests", "padded_rows", "linear_puts",
+        "result_bytes", "regrouped_bytes")}
+    check(f"A: burst: counters {out}",
+          out["batched_requests"] == len(futs)
+          and out["linear_puts"] == out["batches"]
+          and out["result_bytes"] == out["regrouped_bytes"]
+          == len(futs) * len(lost) * n)
+    return out
 
 
 def sync_probe(fn, x, reps: int = 7) -> dict:

@@ -369,22 +369,27 @@ def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, packed):
     assert mem.argument_size_in_bytes == packed * rows * r * n, mem
 
 
-@pytest.mark.parametrize("k,m,r,bucket", [
-    pytest.param(2, 1, 1, 1, id="one-claim-rs2p1"),
-    pytest.param(2, 1, 1, 2, id="two-claims-rs2p1"),
-    pytest.param(2, 1, 1, 8, id="a-storm-of-eight-rs2p1"),
-    pytest.param(2, 1, 1, 16, id="a-storm-of-sixteen-rs2p1"),
-    pytest.param(10, 4, 1, 1, id="one-claim-rs10p4"),
-    pytest.param(10, 4, 3, 1, id="three-rows-lost-rs10p4")])
-def test_rows_program_compiles_for_v5e(one_chip, for_tpu, k, m, r, bucket):
+@pytest.mark.parametrize("k,m,r,bucket,n", [
+    pytest.param(2, 1, 1, 1, 8 * MiB, id="one-claim-rs2p1"),
+    pytest.param(2, 1, 1, 2, 8 * MiB, id="two-claims-rs2p1"),
+    pytest.param(2, 1, 1, 8, 8 * MiB, id="a-storm-of-eight-rs2p1"),
+    pytest.param(2, 1, 1, 16, 8 * MiB, id="a-storm-of-sixteen-rs2p1"),
+    pytest.param(10, 4, 1, 1, 8 * MiB, id="one-claim-rs10p4"),
+    pytest.param(10, 4, 3, 1, 8 * MiB, id="three-rows-lost-rs10p4"),
+    pytest.param(4, 8, 4, 1, 4 * MiB, id="four-rows-lost-rs4p8"),
+    pytest.param(4, 8, 4, 8, 4 * MiB, id="a-burst-of-eight-rs4p8")])
+def test_rows_program_compiles_for_v5e(one_chip, for_tpu, k, m, r, bucket,
+                                       n):
     """A host claim's way up since PR 32 (ops/rs.py _apply_rows): the
-    survivors as ``bucket * k`` linear ``u8[8 MiB]`` rows, stacked and
+    survivors as ``bucket * k`` linear ``u8[n]`` rows, stacked and
     repaired by ONE program, the pattern's matrix its operand. It
     compiles in seconds (``stack`` forms only, no relayouting reshape
     of the whole), holds the Pallas kernel under its pinned name, and
     its arguments are the rows' logical bytes: dense 1-D rows, where
-    the stacked ``u8[1, 2, n]`` operand was twice its bytes."""
-    n = 8 * MiB
+    the stacked ``u8[1, 2, n]`` operand was twice its bytes. The RS(4,8)
+    cases (PR 52) are the decode shape of BASELINE's 4-erasure repair,
+    ``[bucket, 4, 4 MiB] -> [bucket, 4, 4 MiB]`` with every data row
+    lost (a true inverse), alone and as a deal's burst of eight."""
     bmat = rs_pallas.operand_np(
         gf.expand_bitmatrix(gf.repair_matrix(
             k, m, tuple(range(r, r + k)), tuple(range(r)))),
