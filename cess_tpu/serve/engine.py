@@ -35,9 +35,9 @@ submitters keep getting numpy back, even when a batch mixes both. A
 repair batch of host submitters alone crosses the link in the shape
 the link is fast in, both ways: the survivors go up as linear ``u8[n]``
 rows put from the callers' own memory and are stacked on the device
-inside the program (``_put_rows``, PR 32), the result comes down as
-linear rows (``_fetch_linear``, PR 28); nothing is copied on the host
-on the way up. So
+inside the program (``_put_rows``, PR 32), the result comes down as one
+linear piece a batch row (``_fetch_linear``, PR 28, 53); a request of
+one segment is copied on the host neither way. So
 ``StoragePipeline -> engine -> device`` is one H2D copy total for the
 concat-coalesced classes (encode / repair / tag / verify), provided
 the payloads live on the backend's device. The stacked classes
@@ -361,18 +361,18 @@ _VERIFY_PROGRAM = jax.jit(_verify_missions,
 
 @jax.jit
 def _linear_rows(out):
-    """A byte result ``[rows, r, n]`` as its ``rows * r`` rows, each a
-    1-D array: the shape in which bytes leave the device. The TPU packs
-    four rows of the second-minor dimension into each 32-bit word, so
-    ``u8[1, 1, n]`` is a buffer of 4 n bytes with the fragment strided
-    through it, and the runtime's device -> host copy of it takes 41 ms
-    for 8 MiB where a 1-D ``u8[n]`` (dense, no packing across rows)
-    takes 1.7 ms (PERF.md, PR 28). Index forms only: dropping unit
-    dimensions compiles in well under a second, where a ``reshape``
-    that moves bytes between dimensions compiles in time proportional
-    to the array (models/pipeline.py split_rows)."""
-    return tuple(out[i, j] for i in range(out.shape[0])
-                 for j in range(out.shape[1]))
+    """A byte result ``[rows, r, n]`` as ``rows`` dense 1-D pieces of
+    ``r * n`` bytes, the shape in which bytes leave the device: the TPU
+    packs four rows of the second-minor dimension into a 32-bit word, so
+    ``u8[1, 1, n]`` is 4 n bytes with the fragment strided through it and
+    takes 41 ms to fetch at 8 MiB where a dense ``u8[n]`` takes 1.7
+    (PERF.md, PR 28). Index forms, and a 1-D concatenate of the rows they
+    give: a ``reshape`` that moves bytes between dimensions compiles in
+    time proportional to the array (models/pipeline.py split_rows)."""
+    if out.shape[1] == 1:
+        return tuple(out[i, 0] for i in range(out.shape[0]))
+    return tuple(jnp.concatenate([out[i, j] for j in range(out.shape[1])])
+                 for i in range(out.shape[0]))
 
 
 def _caller_submit(cls: str):
@@ -1809,7 +1809,7 @@ class SubmissionEngine:
         """Slice a batch result back per request. Device submitters get
         ``jax.Array`` slices (no host materialization anywhere on their
         path); an all-host batch is fetched ONCE and sliced as numpy —
-        a byte result ``[rows, r, n]`` as linear rows (_fetch_linear),
+        a byte result ``[rows, r, n]`` as linear pieces (_fetch_linear),
         every other result whole.
 
         The result is synced BEFORE futures resolve: zero-copy means
@@ -1852,27 +1852,27 @@ class SubmissionEngine:
 
     def _fetch_linear(self, cls: str, out: jax.Array, lane,
                       batch: list[_Request]) -> list[np.ndarray]:
-        """Fetch a byte result ``[rows, r, n]`` as ``rows * r`` linear
-        rows (_linear_rows, on the device the result is on) and hand
-        each request of the batch its own ``np.uint8 [rows_i, r, n]``:
-        a view of the one row where a request is one row, else one
-        ``memcpy`` a row of that request (the ``regroup``: a stage of
-        its own inside ``fetch``, ``regroup_s`` / ``regrouped_bytes``,
-        only where a request has more rows than one; ``result_bytes``
-        counts what is handed back either way). The batch is
-        never put back together on the host: sixteen coalesced claims
-        would be one fresh allocation of 128 MiB a batch, faulted in
-        page by page (PERF.md, PR 38: 45 of a batch's 57 ms of fetch at
-        five claims)."""
-        flat = [np.asarray(row)
-                for row in self._linear_rows_program(out.shape, lane)(out)]
-        per, at = out.shape[1], 0
+        """Fetch a byte result ``[rows, r, n]`` as ``rows`` linear pieces
+        of ``r * n`` bytes (_linear_rows), every piece on its way before
+        the first is waited for, and hand each request its own
+        ``np.uint8 [rows_i, r, n]``: a request of one batch row its
+        piece itself, reshaped (a view: no host copy; a caller that
+        keeps one row of it keeps all ``r * n`` bytes alive); a request
+        of several (``reconstruct`` of ``[B, k, n]``) one ``memcpy`` a
+        piece — the ``regroup``, a stage inside ``fetch``, ``regroup_s``
+        / ``regrouped_bytes``; ``result_bytes`` counts either. The batch
+        is never rebuilt on the host: a fresh 128 MiB a batch of sixteen
+        claims (PERF.md, PR 38: 45 of 57 ms of fetch at five)."""
+        sent = self._linear_rows_program(out.shape, lane)(out)
+        for piece in sent:
+            piece.copy_to_host_async()
+        flat, at = [np.asarray(piece) for piece in sent], 0
         pieces, many = [], []
         for i, r in enumerate(batch):
-            rows = flat[at:at + r.rows * per]
-            at += len(rows)
+            rows = flat[at:at + r.rows]
+            at += r.rows
             pieces.append(rows[0])
-            if len(rows) > 1:
+            if r.rows > 1:
                 many.append((i, rows))
         regroup_s = regrouped = 0
         if many:
@@ -1884,7 +1884,7 @@ class SubmissionEngine:
         with self._lock:
             st = self.stats.classes[cls]
             st.linear_fetches += 1
-            st.result_bytes += at * out.shape[2]
+            st.result_bytes += at * out.shape[1] * out.shape[2]
             st.regroup_s += regroup_s
             st.regrouped_bytes += regrouped
         return [piece.reshape((r.rows,) + out.shape[1:])

@@ -121,10 +121,10 @@ class ClassStats:
         # batches are built on the host from what a round reads; the
         # concatenated classes leave it 0
         self.operand_bytes = 0
-        # batches whose result left the device as linear rows: an
-        # all-host batch's byte result [rows, r, n] (engine.py
-        # _fetch_linear); device submitters' and non-byte results
-        # (tags, verdicts) leave it 0
+        # batches whose result left the device as linear pieces, one a
+        # batch row: an all-host batch's byte result [rows, r, n]
+        # (engine.py _fetch_linear); device submitters' and non-byte
+        # results (tags, verdicts) leave it 0
         self.linear_fetches = 0
         # repair class: batches whose survivors went up as linear rows
         # put from the callers' own memory and stacked on the device
@@ -147,13 +147,16 @@ class ClassStats:
         self.matrix_build_s = 0.0
         # what a byte result costs on its way back to host callers
         # (engine.py _split_rows -> _fetch_linear: an all-host batch of
-        # encode or repair, fetched as linear rows): the bytes handed
-        # to the requests as host arrays; and the host seconds and the
-        # bytes of the ``np.stack`` that regroups a request's rows into
-        # its own ``[rows, r, n]`` — only where it runs: a request
-        # whose result is one row gets a view of it and counts nothing
-        # there (the ``cess:engine.<cls>.fetch.regroup`` span, inside
-        # ``fetch``)
+        # encode or repair, fetched as one linear piece a batch row):
+        # the bytes handed to the requests as host arrays; and the host
+        # seconds and the bytes of the ``np.stack`` that regroups a
+        # request's pieces into its own ``[rows, r, n]`` — only where
+        # it runs, for a request of several batch rows: a request of
+        # one row, however many rows ``r`` its result has, gets a view
+        # of its piece and counts nothing there (the
+        # ``cess:engine.<cls>.fetch.regroup`` span, inside ``fetch``).
+        # ``regrouped_bytes`` / ``result_bytes`` is the share of the
+        # results that paid a second host copy
         self.result_bytes = 0
         self.regroup_s = 0.0
         self.regrouped_bytes = 0

@@ -48,11 +48,12 @@ def test_rehearsal_runs_every_phase_and_never_the_chip_line(
             assert eng["failed"] == eng["fallback"] == eng["degraded"] == 0
     if chips == 1:
         # phase A's burst (PR 52): eight host repairs of one four-row
-        # loss pattern through the repair class, every result regrouped
+        # loss pattern through the repair class, every result a view of
+        # its own fetched piece (PR 53)
         burst = lines[0]["burst"]
         assert burst["batched_requests"] == 8 >= burst["batches"] >= 1
-        assert burst["result_bytes"] == burst["regrouped_bytes"] \
-            == 8 * 4 * (64 * 1024 // 4)
+        assert burst["result_bytes"] == 8 * 4 * (64 * 1024 // 4)
+        assert burst["regrouped_bytes"] == 0
         # phase C's second geometry (PR 47): the fused program at the
         # archival tier's RS(10,4), nine segments through batches of 8
         wide = lines[2]["wide"]

@@ -14,9 +14,12 @@ CPU mesh at small n:
   holds (every count that pads to a bucket);
 - the batcher coalesces: requests that gather behind a busy executor
   leave as one batch (``batched_requests`` > ``batches``);
-- what a many-row result costs is counted and staged: ``result_bytes``,
-  ``regroup_s``, ``regrouped_bytes``, ``cess:engine.repair.fetch.regroup``
-  inside ``fetch`` — and none of it for a result of one row.
+- a one-segment request's many-row result is a VIEW of the one piece its
+  batch row left the device as (PR 53): ``result_bytes`` counts it,
+  ``regroup_s`` / ``regrouped_bytes`` stay 0 and no
+  ``cess:engine.repair.fetch.regroup`` span is made, at every count of
+  requests a batch can hold; a request of several segments still
+  regroups, once a batch, inside ``fetch``.
 """
 import glob
 import itertools
@@ -64,6 +67,21 @@ def _check(futs, lost) -> None:
         assert np.array_equal(got, REF.reconstruct(
             CODED[s, list(helpers)], helpers, lost)), (s, lost)
         assert np.array_equal(got, CODED[s, list(lost)]), (s, lost)
+
+
+def _several(eng, lost, count=3):
+    """One request of ``count`` segments: ``reconstruct`` of
+    ``[count, k, n]``, the request that still regroups."""
+    helpers = _helpers(lost)
+    got = eng.reconstruct(CODED[:count][:, list(helpers)], helpers, lost)
+    assert np.array_equal(got, CODED[:count][:, list(lost)])
+
+
+def _root(a: np.ndarray) -> np.ndarray:
+    """The array whose buffer ``a`` is a view of."""
+    while isinstance(a.base, np.ndarray):
+        a = a.base
+    return a
 
 
 def _repair(eng) -> dict:
@@ -169,7 +187,7 @@ def test_a_burst_behind_a_busy_batcher_is_one_plus_seven(gate):
 
 
 # -- what a many-row result costs -------------------------------------------
-def test_a_many_row_result_is_counted_where_it_is_regrouped():
+def test_a_burst_of_many_row_results_is_handed_views():
     eng = make_engine(K, M, rs_backend="jax",
                       policy=AdmissionPolicy(max_delay=30.0))
     lost = (0, 2, 5, 9)
@@ -182,16 +200,71 @@ def test_a_many_row_result_is_counted_where_it_is_regrouped():
     finally:
         eng.close()
     assert st["batches"] == st["linear_fetches"] == 1
-    # eight requests of four rebuilt rows each, every one stacked anew
-    assert st["result_bytes"] == st["regrouped_bytes"] == BURST * LOST * N
-    assert 0.0 < st["regroup_s"] <= st["stages"]["fetch"]["s"]
+    # eight requests of four rebuilt rows each, none copied once more
+    assert st["result_bytes"] == BURST * LOST * N
+    assert st["regrouped_bytes"] == 0 and st["regroup_s"] == 0.0
     for name in ("result_bytes", "regroup_s", "regrouped_bytes",
                  "batched_requests"):
         assert flat[f"cess_engine_repair_{name}"] == st[name]
+    for s, fut in enumerate(futs):
+        got = fut.result(0)
+        assert got.flags.c_contiguous
+        # its four rows lie end to end in the one fetched piece, which
+        # is its own and nobody else's
+        piece = _root(got)
+        assert piece is not got and piece.shape == (LOST * N,)
+        assert all(_root(row) is piece for row in got)
+        assert np.array_equal(got, CODED[s, list(lost)])
 
 
-def test_a_request_of_several_segments_is_regrouped_too():
-    """``[B, k, n]`` in one request: its ``B * r`` rows are its own."""
+@pytest.mark.parametrize("size", range(1, BURST + 1))
+def test_every_count_of_requests_a_batch_is_views_of_the_reference(size):
+    """Every padded count of bucket 8: ``size`` requests, one batch, each
+    result its own piece and byte-equal to the reference."""
+    eng = make_engine(K, M, rs_backend="jax",
+                      policy=AdmissionPolicy(max_delay=30.0))
+    lost = (3, 4, 8, 11)
+    try:
+        futs = _burst(eng, lost, size)
+        eng.flush()
+        _check(futs, lost)
+        st = _repair(eng)
+    finally:
+        eng.close()
+    assert st["batches"] == st["linear_fetches"] == 1
+    assert st["batched_requests"] == size
+    assert st["result_bytes"] == size * LOST * N
+    assert st["regrouped_bytes"] == 0 and st["regroup_s"] == 0.0
+    got = [fut.result(0) for fut in futs]
+    assert all(g.flags.c_contiguous for g in got)
+    assert not any(np.shares_memory(a, b)
+                   for a, b in itertools.combinations(got, 2))
+
+
+def test_a_warmed_shape_builds_no_program_during_a_burst(compiles):
+    """The flatten of ``r > 1`` is warmed with its shape like any other:
+    bursts of every size after ``warm_repair`` leave ``programs_built``
+    and the compile count flat."""
+    eng = make_engine(K, M, rs_backend="jax")
+    try:
+        eng.warm_repair([((4, 5, 6, 7), (0, 1, 2, 3))], N,
+                        buckets=(1, 2, 4, 8))
+        warmed = eng.stats_snapshot()["programs_built"]
+        compiled = compiles()
+        for size, lost in zip(range(1, BURST + 1), LOST_SETS[3::61]):
+            _check(_burst(eng, lost, size), lost)
+        st = _repair(eng)
+        assert eng.stats_snapshot()["programs_built"] == warmed
+        assert compiles() == compiled
+    finally:
+        eng.close()
+    assert st["completed"] == sum(range(1, BURST + 1))
+    assert st["regrouped_bytes"] == 0 < st["result_bytes"]
+
+
+def test_a_request_of_several_segments_is_regrouped():
+    """``[B, k, n]`` in one request: its ``B`` pieces are its own, and
+    are stacked once into its ``[B, r, n]``."""
     eng = make_engine(K, M, rs_backend="jax")
     lost = (1, 4, 6, 7)
     helpers = _helpers(lost)
@@ -202,6 +275,7 @@ def test_a_request_of_several_segments_is_regrouped_too():
         eng.close()
     assert np.array_equal(got, CODED[:3][:, list(lost)])
     assert st["result_bytes"] == st["regrouped_bytes"] == 3 * LOST * N
+    assert 0.0 < st["regroup_s"] <= st["stages"]["fetch"]["s"]
 
 
 def test_a_one_row_result_is_a_view_and_regroups_nothing():
@@ -249,9 +323,10 @@ def test_the_regroup_is_a_child_span_of_the_fetch_stage():
     lost = (2, 3, 8, 10)
     try:
         for _ in range(2):
-            futs = _burst(eng, lost)
-            eng.flush()
-            _check(futs, lost)
+            _several(eng, lost)
+        futs = _burst(eng, lost)          # a batch that regroups nothing
+        eng.flush()
+        _check(futs, lost)
         st = _repair(eng)
     finally:
         eng.close()
@@ -259,14 +334,17 @@ def test_the_regroup_is_a_child_span_of_the_fetch_stage():
     fetches = {s["span_id"] for s in spans
                if s["name"] == "engine.repair.fetch"}
     regroups = [s for s in spans if s["name"] == REGROUP]
-    # one a batch, never one a request or a row
-    assert len(regroups) == len(fetches) == st["batches"] == 2
-    assert {s["parent_id"] for s in regroups} == fetches
+    # one a batch that holds a several-segment request, never one a
+    # request or a piece, and none for the burst's batch
+    assert len(fetches) == st["batches"] == 3
+    assert len(regroups) == 2
+    assert {s["parent_id"] for s in regroups} < fetches
+    assert st["regrouped_bytes"] == 2 * 3 * LOST * N
     # the six stages keep their names and counts: the regroup is no stage
     # of the batch's own
     assert set(st["stages"]) == {"queue", "assemble", "dispatch", "wait",
                                  "fetch", "resolve"}
-    assert all(acc["n"] == 2 for acc in st["stages"].values())
+    assert all(acc["n"] == 3 for acc in st["stages"].values())
 
 
 def test_a_profiler_trace_holds_the_regroup_inside_the_fetch(tmp_path):
@@ -276,14 +354,15 @@ def test_a_profiler_trace_holds_the_regroup_inside_the_fetch(tmp_path):
     eng = make_engine(K, M, rs_backend="jax")
     lost = (0, 6, 7, 9)
     try:
-        _check(_burst(eng, lost), lost)             # loaded before the trace
+        _several(eng, lost)                         # loaded before the trace
+        _check(_burst(eng, lost), lost)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
         try:
+            _several(eng, lost)
+            _several(eng, lost, 2)
             _check(_burst(eng, lost), lost)
-            helpers = _helpers((3,))
-            eng.reconstruct([CODED[0, j] for j in helpers], helpers, (3,))
             eng.flush()
         finally:
             jax.profiler.stop_trace()
@@ -300,8 +379,8 @@ def test_a_profiler_trace_holds_the_regroup_inside_the_fetch(tmp_path):
               if ev.name.startswith(trace.STAGE_PREFIX)]
     fetches = [e for e in events if e[1] == "engine.repair.fetch"]
     regroups = [e for e in events if e[1] == REGROUP]
-    # the burst's batches regroup, the one-row repair's batch does not
-    assert 1 <= len(regroups) == len(fetches) - 1
+    # the several-segment requests' batches regroup, the burst's do not
+    assert len(regroups) == 2 < len(fetches)
     for e in regroups:
         assert any(f[0] == e[0] and f[2] <= e[2] and e[3] <= f[3]
                    for f in fetches)

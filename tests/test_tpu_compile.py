@@ -47,6 +47,9 @@ COMPILE_SECONDS = 60
 # RS(2,1) here, alone on the machine; its tag view written as ONE reshape
 # of the batch, u8[8,12,4 MiB] -> u8[96,8192,512], took 156 s.
 FUSED_COMPILE_SECONDS = 10
+# The engine's flatten (PR 53): 2.6 s at u8[8, 4, 4 MiB] and under 0.1 s
+# at r = 1 here, alone on the machine.
+FLATTEN_COMPILE_SECONDS = 20
 
 
 @pytest.fixture(scope="module")
@@ -344,29 +347,41 @@ def test_round_derivations_compile_for_v5e(one_chip, for_tpu, program,
     assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == out
 
 
-@pytest.mark.parametrize("shape,packed", [
+@pytest.mark.parametrize("shape,held", [
     pytest.param((1, 1, 8 * MiB), 4, id="one-claim-rs2p1"),
-    pytest.param((2, 1, 8 * MiB), 2, id="two-claims-rs2p1"),
-    pytest.param((1, 4, 4 * MiB), 1, id="four-rows-rs4p8")])
-def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, packed):
+    pytest.param((2, 1, 8 * MiB), 4, id="two-claims-rs2p1"),
+    pytest.param((1, 3, 8 * MiB), 4, id="three-rows-lost-rs10p4"),
+    *(pytest.param((t, 4, 4 * MiB), 4 * t, id=f"{t}-of-a-burst-rs4p8")
+      for t in range(1, 9))])
+def test_linear_rows_compile_for_v5e(one_chip, for_tpu, shape, held):
     """The engine's flatten (serve/engine.py _linear_rows) at the shapes
-    of a repair's result: it compiles fast (index forms only, no
-    relayouting reshape), every row comes out 1-D and dense, and the
-    argument is ``packed`` times its logical bytes on the device (four
-    rows to a 32-bit word: a lone row fills a quarter of each) — the
-    layout fact the linear fetch rests on (PERF.md, PR 28): a compiler
-    that stops packing rows into words should be noticed."""
+    of a repair's result, every count of a burst of eight among them: it
+    compiles fast (index forms, and for ``r > 1`` a 1-D concatenate of
+    what they give: no relayouting reshape), every batch row comes out
+    ONE dense 1-D piece of its ``r * n`` logical bytes, and the argument
+    holds ``held`` rows of ``n`` bytes on the device (four rows to a
+    32-bit word: a lone row fills a quarter of each, three rows three
+    quarters, four all of it) — the layout fact the linear fetch rests
+    on (PERF.md, PR 28): a compiler that stops packing rows into words
+    should be noticed. At ``r == 1`` the program is PR 28's, operation
+    for operation: a slice and a squeeze a row."""
     rows, r, n = shape
+    arg = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    traced = engine._linear_rows.trace(arg)
+    ops = [e.primitive.name for e in traced.jaxpr.eqns]
+    assert ops.count("slice") == ops.count("squeeze") == rows * r
+    assert ops.count("concatenate") == (rows if r > 1 else 0)
+    assert len(ops) == 2 * rows * r + ops.count("concatenate")
     t0 = time.perf_counter()
-    compiled = engine._linear_rows.lower(
-        jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)).compile()
-    assert time.perf_counter() - t0 < COMPILE_SECONDS
+    compiled = traced.lower().compile()
+    assert time.perf_counter() - t0 < FLATTEN_COMPILE_SECONDS
     outs = jax.tree_util.tree_leaves(compiled.out_info)
-    assert [o.shape for o in outs] == [(n,)] * (rows * r)
+    assert [o.shape for o in outs] == [(r * n,)] * rows
     mem = compiled.memory_analysis()
-    # dense rows: their logical bytes, plus the table of a tuple result
+    # dense pieces: their logical bytes, plus the table of a tuple result
     assert 0 <= mem.output_size_in_bytes - rows * r * n < 4096, mem
-    assert mem.argument_size_in_bytes == packed * rows * r * n, mem
+    assert mem.argument_size_in_bytes == held * n, mem
+    assert mem.temp_size_in_bytes == 0, mem
 
 
 @pytest.mark.parametrize("k,m,r,bucket,n", [
