@@ -32,18 +32,29 @@ class Histogram:
     """Fixed-bound histogram: ``observe`` is O(log buckets), snapshots
     are consistent (taken under the lock), and same-bound histograms
     merge (the engine sums per-driver stream histograms into one
-    exposition family)."""
+    exposition family).
 
-    __slots__ = ("bounds", "_counts", "_sum", "_count", "_mu")
+    Each bucket keeps the SUM of what it observed beside the count
+    (:meth:`buckets`), so two readings of one histogram difference
+    exactly, bucket by bucket: a percentile of the interval between
+    them, and "seconds spent in observations above x" for any bound x
+    (the stage ladders of serve/stats.py are read that way; a sliding
+    sample window cannot be differenced)."""
+
+    __slots__ = ("bounds", "_counts", "_sums", "_sum", "_count", "_mu")
 
     def __init__(self, bounds=LATENCY_BUCKETS_S):
-        bs = tuple(float(b) for b in bounds)
+        # a ladder shared by many histograms stays ONE tuple
+        bs = bounds if type(bounds) is tuple \
+            and all(type(b) is float for b in bounds) \
+            else tuple(float(b) for b in bounds)
         if not bs or any(b2 <= b1 for b1, b2 in zip(bs, bs[1:])) \
                 or not all(math.isfinite(b) for b in bs):
             raise ValueError(f"bucket bounds must be finite and "
                              f"strictly increasing, got {bounds!r}")
         self.bounds = bs
         self._counts = [0] * (len(bs) + 1)   # last = above every bound
+        self._sums = [0.0] * (len(bs) + 1)
         self._sum = 0.0
         self._count = 0
         self._mu = threading.Lock()
@@ -54,6 +65,7 @@ class Histogram:
         i = bisect.bisect_left(self.bounds, value)
         with self._mu:
             self._counts[i] += 1
+            self._sums[i] += value
             self._sum += value
             self._count += 1
 
@@ -64,11 +76,12 @@ class Histogram:
             raise ValueError("cannot merge histograms with different "
                              f"bounds: {self.bounds} vs {other.bounds}")
         with other._mu:
-            counts = list(other._counts)
+            counts, sums = list(other._counts), list(other._sums)
             total_sum, total_n = other._sum, other._count
         with self._mu:
             for i, n in enumerate(counts):
                 self._counts[i] += n
+                self._sums[i] += sums[i]
             self._sum += total_sum
             self._count += total_n
         return self
@@ -78,6 +91,11 @@ class Histogram:
         with self._mu:
             return self._count
 
+    @property
+    def sum(self) -> float:
+        with self._mu:
+            return self._sum
+
     @classmethod
     def from_cumulative(cls, buckets, total_sum: float) -> "Histogram":
         """Rebuild a histogram from its wire form — the CUMULATIVE
@@ -85,7 +103,9 @@ class Histogram:
         (and a federator parses back out of ``_bucket{le=...}``
         samples). The last entry must be the ``+Inf`` bucket; counts
         must be nondecreasing. Inverse of :meth:`snapshot`, so
-        cross-node federation can reuse :meth:`merge`."""
+        cross-node federation can reuse :meth:`merge`. The wire form
+        carries no per-bucket sums: :meth:`buckets` of the rebuilt
+        histogram reads 0.0 seconds a bucket."""
         pairs = [(float(b), int(n)) for b, n in buckets]
         if len(pairs) < 2 or not math.isinf(pairs[-1][0]):
             raise ValueError("cumulative buckets must end with +Inf")
@@ -128,6 +148,17 @@ class Histogram:
                 lo = bound
             prev_acc = acc
         return lo
+
+    def buckets(self) -> list:
+        """The non-empty buckets, ``[[le, count, sum], ...]`` in ladder
+        order, NOT cumulative; ``le`` is the bucket's inclusive upper
+        bound, ``None`` for the one above every bound. JSON-shaped, and
+        the form two readings are differenced in: counts and sums of
+        equal ``le`` subtract."""
+        with self._mu:
+            counts, sums = list(self._counts), list(self._sums)
+        les = self.bounds + (None,)
+        return [[les[i], n, sums[i]] for i, n in enumerate(counts) if n]
 
     def snapshot(self) -> dict:
         """One consistent view: ``buckets`` is the CUMULATIVE

@@ -20,7 +20,7 @@ Design contracts, in priority order:
   span object, no dict, no clock read is allocated on the disabled
   path. tier-1 pins the singleton identity (tests/test_obs.py); what
   an armed tracer costs on the chip is not measured (ROADMAP.md,
-  Speed 8).
+  Speed 11).
 - **Deterministic span ids**: ids come from a per-tracer counter, and
   a trace id is fixed at construction — no wall clock, no randomness
   in identities — so two replays of the same workload under the same
@@ -53,6 +53,15 @@ while the program runs holds them on the same clock as the device's
 ``time.monotonic``; a stage's seconds are ``time.perf_counter`` — on
 Linux the same clock, but only the profiler's own clock can be laid
 against device idle gaps, which is why the stages go into its trace.
+
+A stage's hook keeps a SUM (``sink[name] = [count, seconds]``) and that
+is all it keeps: its exit is the hot path and does not grow. Whoever
+merges a unit's sink, once a batch, observes each entry's seconds into
+one ladder of buckets, :data:`STAGE_LADDER_S`, the same object for
+every engine class and the stream driver (serve/stats.py), and keeps
+the few waits that ran over :data:`LONG_WAIT_S` with what was in flight
+(``stats.LongWaits``): a distribution and the worst cases, where a sum
+cannot say whether 4 s of stall were a thousand waits or two.
 """
 from __future__ import annotations
 
@@ -408,6 +417,29 @@ def span(name: str, *, sys: str = "", **attrs):
 
 STAGE_PREFIX = "cess:"    # every stage's name in a profiler trace
 
+# A wait that ran longer than this is kept with its context, and the
+# ladder has a bound exactly here, so "seconds inside waits over it" is
+# a difference of buckets. A constant of the program, not an option.
+LONG_WAIT_S = 0.25
+LONG_WAITS_KEPT = 4       # the longest of a stage that are kept
+
+
+def _stage_ladder() -> tuple:
+    """50 us .. 10 s in two geometric runs that meet at LONG_WAIT_S (90
+    and 39 steps: ratio 1.0993 and 1.0992), 130 bounds, one bucket more
+    above them."""
+    lo, hi = 50e-6, 10.0
+    return tuple([lo * (LONG_WAIT_S / lo) ** (i / 90) for i in range(90)]
+                 + [LONG_WAIT_S * (hi / LONG_WAIT_S) ** (i / 39)
+                    for i in range(40)])
+
+
+# The one ladder every stage's distribution is kept on (seconds; upper
+# bounds, inclusive): obs/prom.Histogram over it, [count, seconds] a
+# bucket, so percentiles and sums of two snapshots' difference are exact
+# to a bucket (ratio <= 1.1).
+STAGE_LADDER_S = _stage_ladder()
+
 _ANNOTATION = None        # jax.profiler.TraceAnnotation, bound at first use
 
 
@@ -438,7 +470,9 @@ class _Stage:
             from jax.profiler import TraceAnnotation
 
             _ANNOTATION = TraceAnnotation
-        self._ann = _ANNOTATION(STAGE_PREFIX + self.name)
+        # the attrs (a streamed batch's ``seq``) are the annotation's
+        # metadata: encoded only while a profiler session is live
+        self._ann = _ANNOTATION(STAGE_PREFIX + self.name, **self.attrs)
         self._ann.__enter__()
         parent = self.parent
         if parent is None:
@@ -487,7 +521,9 @@ def stage(name: str, sink: dict | None = None, *, parent=None,
     - runs under ``jax.profiler.TraceAnnotation("cess:" + name)``,
       ALWAYS: whenever a profiler session is live the stage is in its
       ``.xplane.pb`` on the same clock as the device's ``XLA Ops``
-      line; outside a session the annotation is a flag check;
+      line, ``attrs`` its metadata (an event's ``stats``: the five
+      stages of a streamed batch share a ``seq``); outside a session
+      the annotation is a flag check;
     - is timed with ``time.perf_counter()`` at both ends: the seconds
       land on the returned object (``.seconds``) and, when a ``sink``
       dict is given, ``sink[name]`` accumulates ``[count, seconds]``

@@ -139,7 +139,7 @@ from ..resilience.retry import Budget
 from .buckets import ProgramCache, bucket_rows
 from .policy import (CLASSES, AdmissionPolicy, EngineClosed,
                      EngineSaturated, EngineShed, EngineTimeout)
-from .stats import EngineStats
+from .stats import STAGES, EngineStats
 
 
 class EngineFuture:
@@ -1500,13 +1500,11 @@ class SubmissionEngine:
     # -- stage clock (obs.trace.stage; stats.STAGES) ----------------------
     def _open_stages(self, batch: list[_Request],
                      span=trace.NOOP_SPAN) -> dict:
-        """Start the stage sink of a batch this thread is about to
-        run: the queue stage ends here (each member's enqueue -> now,
-        summed: a counter, no span), split at the instant the drain
-        trigger tripped into ``coalesce`` and ``wake`` (stats.py
-        QUEUE_PARTS; a member enqueued after the trip waited on no
-        policy), and the op runners' stages (_stage) land in the
-        returned sink, under ``span``."""
+        """Start the sink of a batch this thread is about to run. The
+        queue stage ends here (each member's enqueue -> now, summed: a
+        counter, no span), split where the drain trigger tripped into
+        ``coalesce`` / ``wake`` (stats.py QUEUE_PARTS; a member enqueued
+        after the trip waited on no policy); _stage fills the rest."""
         now = time.monotonic()
         coalesce = wake = 0.0
         for r in batch:
@@ -1527,9 +1525,11 @@ class SubmissionEngine:
                              (None, trace.NOOP_SPAN))
         return trace.stage(f"engine.{cls}.{stage}", sink, parent=span)
 
-    def _close_stages(self, cls: str, sink: dict) -> None:
+    def _close_stages(self, cls: str, sink: dict, *of) -> None:
         with self._lock:
-            self.stats.classes[cls].add_stages(sink)
+            long = self.stats.classes[cls].add_stages(sink)
+        if long:                  # a wait over LONG_WAIT_S: rare
+            self._long_waits(cls, sink, long, *of)
 
     def _run_batch(self, batch: list[_Request], lane=None,
                    tried=None) -> bool:
@@ -1645,7 +1645,7 @@ class SubmissionEngine:
         for r in batch:
             if r.span is not trace.NOOP_SPAN:
                 r.span.set(outcome="ok").finish()
-        self._close_stages(cls, stages)
+        self._close_stages(cls, stages, batch, device_rows, lane)
         return False
 
     def _observe_failure(self, r: _Request, now: float) -> None:
@@ -1801,7 +1801,7 @@ class SubmissionEngine:
                                         stages=stages)
                     r.future._resolve(out[0])
                     r.span.set(outcome="ok").finish()
-                self._close_stages(cls, stages)
+                self._close_stages(cls, stages, [r], rows, lane)
         return True
 
     # -- op runners (batcher thread only) -------------------------------
@@ -2344,6 +2344,23 @@ class SubmissionEngine:
         for acc in sink.values():
             acc[0] = 1
         return sink
+
+    def _long_waits(self, cls: str, sink: dict, long: list, batch=(),
+                    bucket: int = 0, lane=None) -> None:
+        """Keep a batch's waits that ran over ``LONG_WAIT_S`` (stats.py
+        LongWaits; ``stats_snapshot()["long_waits"]``, the flight
+        journal) with the batch they were of. A stage's start is read
+        back from the sink: a batch's stages run in STAGES' order and
+        the last ended a few microseconds ago."""
+        end = time.perf_counter()
+        for stage, seconds in long:
+            later = sum(sink.get(f"engine.{cls}.{name}", (0, 0.0))[1]
+                        for name in STAGES[STAGES.index(stage) + 1:])
+            self.stats.long_waits.observe(
+                f"engine.{cls}.{stage}", end - later - seconds, seconds,
+                lambda: {"cls": cls, "bucket": bucket,
+                         "rows": sum(r.rows for r in batch),
+                         "lane": None if lane is None else lane.index})
 
 
 def make_engine(k: int | None = None, m: int | None = None, *,

@@ -14,8 +14,11 @@ the counters need no locking of their own.
 from __future__ import annotations
 
 import collections
+import threading
 
+from ..obs import flight as _flight
 from ..obs import prom
+from ..obs.trace import LONG_WAIT_S, LONG_WAITS_KEPT, STAGE_LADDER_S
 from . import policy
 
 LATENCY_WINDOW = 512     # per-class sliding window for percentiles
@@ -92,6 +95,62 @@ QUEUE_PARTS = ("coalesce", "wake")
 # to the class that was drained.
 DRAIN_TRIGGERS = ("idle", "window", "size", "forced")
 
+# Every account above keeps its DISTRIBUTION beside its sum: one
+# obs/prom.Histogram over obs.trace.STAGE_LADDER_S (one tuple for the
+# whole program) a (class, account), fed where the sums are, once a
+# batch or a request — one batch's seconds in a stage (the queue's: its
+# members' summed), one request's ``submit`` / ``handoff``. A bucket
+# keeps [count, seconds], so the difference of two snapshots gives a
+# window's percentile and its seconds above any bound
+# (``stages[..]["buckets"]``, ``queue[..]``, ``caller[..]``; the
+# 512-sample latency ring cannot be differenced).
+LADDERS = STAGES + tuple("queue." + p for p in QUEUE_PARTS) + CALLER
+
+# The stages of a batch that are waits: an occurrence over
+# obs.trace.LONG_WAIT_S is kept with its context (LongWaits).
+LONG_WAIT_STAGES = ("wait", "fetch")
+
+
+class LongWaits:
+    """The worst cases beside the ladders: of each stage's occurrences
+    that ran over ``LONG_WAIT_S`` the ``LONG_WAITS_KEPT`` longest, each
+    ``{"stage", "start" (time.perf_counter()), "seconds", ...context}``,
+    and every one of them a ``long_wait`` note in the flight journal
+    (obs/flight.py; one global load when no recorder is armed). The
+    context is built by the call site's ``context(*args)`` and only
+    then: a wait under the threshold costs one comparison."""
+
+    __slots__ = ("channel", "_kept", "_mu")
+
+    def __init__(self, channel: str):
+        self.channel = channel        # the journal's subsystem
+        self._kept: dict[str, list] = {}
+        self._mu = threading.Lock()
+
+    def observe(self, stage: str, start: float, seconds: float,
+                context, *args) -> None:
+        if seconds > LONG_WAIT_S:
+            rec = {"stage": stage, "start": start, "seconds": seconds,
+                   **context(*args)}
+            with self._mu:
+                kept = self._kept.setdefault(stage, [])
+                kept.append(rec)
+                kept.sort(key=lambda r: -r["seconds"])
+                del kept[LONG_WAITS_KEPT:]
+            _flight.note(self.channel, "long_wait", **rec)
+
+    def snapshot(self) -> list:
+        """The kept records, oldest first."""
+        with self._mu:
+            kept = [dict(r) for recs in self._kept.values() for r in recs]
+        return sorted(kept, key=lambda r: r["start"])
+
+
+def _account(n: int, s: float, ladder: prom.Histogram) -> dict:
+    """One account of a snapshot: the sum as it always read, and the
+    ladder's non-empty buckets ``[[le, count, seconds], ...]``."""
+    return {"n": n, "s": s, "buckets": ladder.buckets()}
+
 
 class ClassStats:
     __slots__ = ("submitted", "completed", "failed", "timeouts",
@@ -103,7 +162,7 @@ class ClassStats:
                  "missions", "device_calls", "prf_evals",
                  "chunks", "gathered_bytes", "gather_seconds",
                  "latencies", "hist", "stage_n", "stage_s",
-                 "caller_n", "caller_s", "queue_s", "drains")
+                 "caller_n", "caller_s", "queue_s", "drains", "ladders")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -194,11 +253,19 @@ class ClassStats:
         self.queue_s = dict.fromkeys(QUEUE_PARTS, 0.0)
         # drains of this class by what tripped them (DRAIN_TRIGGERS)
         self.drains = dict.fromkeys(DRAIN_TRIGGERS, 0)
+        # each account's distribution (LADDERS)
+        self.ladders = {name: prom.Histogram(STAGE_LADDER_S)
+                        for name in LADDERS}
 
-    def add_stages(self, sink: dict) -> None:
+    def add_stages(self, sink: dict):
         """Merge one batch's stage sink (``{"engine.<cls>.<stage>":
         [count, seconds]}``, obs.trace.stage's shape; the queue's halves
-        are ``engine.<cls>.queue.coalesce`` / ``.wake``)."""
+        are ``engine.<cls>.queue.coalesce`` / ``.wake``): each entry
+        into its sum and, as one observation, into its ladder. Returns
+        the batch's waits (LONG_WAIT_STAGES) that ran over LONG_WAIT_S
+        as ``[(stage, seconds), ...]``, or None: the caller notes them
+        outside its lock."""
+        long = None
         for name, (n, seconds) in sink.items():
             stage = name.split(".", 2)[2]
             if stage in self.stage_n:
@@ -206,14 +273,19 @@ class ClassStats:
                 self.stage_s[stage] += seconds
             else:
                 self.queue_s[stage.rpartition(".")[2]] += seconds
+            self.ladders[stage].observe(seconds)
+            if seconds > LONG_WAIT_S and stage in LONG_WAIT_STAGES:
+                long = (long or []) + [(stage, seconds)]
         # float additions do not associate: the total is the halves'
         self.stage_s["queue"] = self.queue_s["coalesce"] \
             + self.queue_s["wake"]
+        return long
 
     def add_caller(self, account: str, seconds: float) -> None:
         """Count one request's ``submit`` or ``handoff`` (CALLER)."""
         self.caller_n[account] += 1
         self.caller_s[account] += seconds
+        self.ladders[account].observe(seconds)
 
     # -- derived -----------------------------------------------------------
     @property
@@ -256,24 +328,46 @@ class StreamStats:
     - ``h2d_s`` and ``dispatch_s`` time an ENQUEUE, never the work:
       ``device_put`` and the program call return once the transfer or
       the program is queued (128 MiB "staged" in 0.65 ms on a v5e,
-      PERF.md). A high ``h2d_frac`` therefore does NOT mean
-      transfer-bound; it means the host-side call itself is slow (a
-      synchronous backend such as the CPU's, a non-contiguous source
-      being copied, a full transfer queue). The last is what a
-      four-lane pool shows: the sharded put of 512 MiB over four v5e
-      chips takes 10.4–11.3 ms of the host thread a batch, seven times
-      the one-chip 1.58 ms for a quarter of the bytes, with the stream
-      paced by the host → device path (PERF.md, PR 27): no longer an
-      enqueue alone, still not the transfer. How long the bytes take is
-      only in a device trace, where the same extents are the
-      ``cess:stream.put`` / ``.dispatch`` / ``.stall`` stage spans
-      (obs.trace.stage) beside the device's own line.
+      PERF.md). Where ``h2d_s`` is a large part of ``wall_s`` the
+      stream is therefore NOT transfer-bound; the host-side call
+      itself is slow (a synchronous backend such as the CPU's, a
+      non-contiguous source being copied, a full transfer queue). The
+      last is what a four-lane pool shows: the sharded put of 512 MiB
+      over four v5e chips takes 29 ms of the host thread a batch
+      (PERF.md); its three calls are ``cess:stream.put.slice`` /
+      ``.place`` / ``.assemble`` in a trace (parallel/mesh.py).
+    - ``stage_s`` is the host's staging of a batch (the source's next
+      chunk, made contiguous, padded, its ids) and ``consumer_s`` the
+      time the driver stood suspended at a ``yield`` while its consumer
+      held a finished batch: with them a run's seconds add up,
+      ``wall_s = stage_s + gate_s + h2d_s + stall_s + dispatch_s +
+      consumer_s +`` a remainder (the loop's own bookkeeping), which is
+      a number and no longer a guess.
+    - ``stages`` keeps each of the five stages' DISTRIBUTION on the
+      program's one ladder (``{"n", "s", "buckets"}`` a stage, the
+      engine classes' shape), merged once a batch from the sink its
+      five ``obs.trace.stage`` blocks write: a window that hung reads
+      as seconds in the buckets above ``LONG_WAIT_S`` where a sound one
+      reads 0.0, and ``long_waits`` holds those waits themselves with
+      what was in flight (LongWaits). ``hist`` (the Prometheus family
+      ``cess_engine_stream_batch_seconds``) observes what a batch cost
+      the host thread: its gate, its put, the stall that let its
+      program into the window, and its dispatch.
+    How long the bytes take on the link is in no counter and no host
+    span; in a device trace the extents above are the
+    ``cess:stream.stage`` / ``.gate`` / ``.put`` / ``.dispatch`` /
+    ``.stall`` events, a batch's five under one ``seq``, beside the
+    device's own line: a program enqueued (its dispatch ended) and not
+    yet started is the device waiting for its operands.
     """
 
+    STAGES = ("stream.stage", "stream.gate", "stream.put",
+              "stream.dispatch", "stream.stall")
     _COUNTERS = ("batches", "segments", "padded_segments", "bytes_in",
                  "bytes_out", "linear_puts", "put_arrays", "direct_rows",
-                 "h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s")
-    __slots__ = _COUNTERS + ("lanes", "hist")
+                 "h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s",
+                 "stage_s", "consumer_s")
+    __slots__ = _COUNTERS + ("lanes", "hist", "ladders", "long_waits")
 
     def __init__(self):
         self.batches = 0           # device batches dispatched
@@ -299,17 +393,39 @@ class StreamStats:
         self.stall_s = 0.0         # host time blocked on device results
         self.gate_s = 0.0          # host time blocked on a put's arrival
         self.wall_s = 0.0          # wall time of completed run() calls
+        self.stage_s = 0.0         # host time staging the next batch
+        self.consumer_s = 0.0      # suspended at a yield: the consumer's
         # a GAUGE, not a counter: devices the last staged batch was
         # placed over (1 on one device; a DevicePool's lane count, or a
         # mesh's size, once a sharded put has staged a batch)
         self.lanes = 1
-        # per-batch host time (staging + dispatch) histogram — the
-        # mergeable form beside the aggregate stage clocks above
+        # what a batch cost the host thread (gate + put + the stall
+        # ahead of its program + dispatch), as a Prometheus family —
+        # the mergeable form beside the aggregate stage clocks above
         self.hist = prom.Histogram(prom.LATENCY_BUCKETS_S)
+        # the five stages' distributions, and the waits that hung
+        self.ladders = {name: prom.Histogram(STAGE_LADDER_S)
+                        for name in self.STAGES}
+        self.long_waits = LongWaits("stream")
 
-    def raw(self) -> dict:
+    def add_stages(self, sink: dict) -> None:
+        """Merge one turn's stage sink (obs.trace.stage's shape) and
+        empty it: each entry one observation of its stage's ladder."""
+        for name, (_, seconds) in sink.items():
+            self.ladders[name].observe(seconds)
+        sink.clear()
+
+    def scalars(self) -> dict:
+        """The counters and the gauge: what adds up across drivers."""
         out = {name: getattr(self, name) for name in self._COUNTERS}
         out["lanes"] = self.lanes
+        return out
+
+    def raw(self) -> dict:
+        out = self.scalars()
+        out["stages"] = {name: _account(h.count, h.sum, h)
+                         for name, h in self.ladders.items()}
+        out["long_waits"] = self.long_waits.snapshot()
         return out
 
     def snapshot(self) -> dict:
@@ -317,7 +433,7 @@ class StreamStats:
 
     def metrics(self) -> dict[str, float]:
         return {f"cess_engine_stream_{k}": float(v)
-                for k, v in self.snapshot().items()}
+                for k, v in stream_gauges(self.scalars()).items()}
 
 
 def stream_gauges(raw: dict) -> dict:
@@ -326,8 +442,8 @@ def stream_gauges(raw: dict) -> dict:
     out = dict(raw)
     wall = raw["wall_s"]
     out["stall_frac"] = round(raw["stall_s"] / wall, 4) if wall else 0.0
-    out["h2d_frac"] = round(raw["h2d_s"] / wall, 4) if wall else 0.0
-    for k in ("h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s"):
+    for k in ("h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s",
+              "stage_s", "consumer_s"):
         out[k] = round(out[k], 6)
     return out
 
@@ -341,6 +457,9 @@ class EngineStats:
         self.programs_built = 0     # program-cache misses (compiles)
         self.programs_reused = 0    # program-cache hits
         self.streams: list[StreamStats] = []   # attached stream drivers
+        # the batches' waits that ran over LONG_WAIT_S, with the batch
+        # they were of (engine.py _close_stages)
+        self.long_waits = LongWaits("engine")
         # ResilienceStats (cess_tpu/resilience/stats.py) when the
         # engine is resilience-configured — duck-typed (snapshot()/
         # metrics()) so this module never imports the package
@@ -398,17 +517,21 @@ class EngineStats:
                 "gather_seconds": st.gather_seconds,
                 "latency_p50": round(st.percentile(0.50), 6),
                 "latency_p99": round(st.percentile(0.99), 6),
-                "stages": {stage: {"n": st.stage_n[stage],
-                                   "s": st.stage_s[stage]}
+                "stages": {stage: _account(st.stage_n[stage],
+                                           st.stage_s[stage],
+                                           st.ladders[stage])
                            for stage in STAGES},
-                "caller": {acct: {"n": st.caller_n[acct],
-                                  "s": st.caller_s[acct]}
+                "caller": {acct: _account(st.caller_n[acct],
+                                          st.caller_s[acct],
+                                          st.ladders[acct])
                            for acct in CALLER},
-                "queue": {part: {"n": st.stage_n["queue"],
-                                 "s": st.queue_s[part]}
+                "queue": {part: _account(st.stage_n["queue"],
+                                         st.queue_s[part],
+                                         st.ladders["queue." + part])
                           for part in QUEUE_PARTS},
                 "drains": dict(st.drains),
             }
+        out["long_waits"] = self.long_waits.snapshot()
         if self.streams:
             out["streams"] = [s.snapshot() for s in self.streams]
         if self.resilience is not None:
@@ -445,7 +568,7 @@ class EngineStats:
         if self.streams:
             # sum RAW counters across attached drivers, then derive —
             # adding per-driver fractions would be meaningless
-            totals = self.streams[0].raw()
+            totals = self.streams[0].scalars()
             for s in self.streams[1:]:
                 for k in StreamStats._COUNTERS:
                     totals[k] += getattr(s, k)
@@ -472,10 +595,11 @@ class EngineStats:
     def histograms(self) -> dict[str, prom.Histogram]:
         """Histogram families for the text exposition: one
         submit->resolve latency family per op class, plus the summed
-        per-batch stream staging+dispatch family when drivers are
-        attached (per-driver histograms share bounds, so the merge is
-        exact — node/metrics.py renders these with
-        ``# TYPE ... histogram``)."""
+        family of what a streamed batch cost its host thread when
+        drivers are attached (per-driver histograms share bounds, so
+        the merge is exact — node/metrics.py renders these with
+        ``# TYPE ... histogram``). The stage ladders are not families:
+        130 series a (class, stage) is read from the snapshot."""
         out = {f"cess_engine_{cls}_latency_seconds": st.hist
                for cls, st in self.classes.items()}
         if self.streams:

@@ -41,11 +41,13 @@ a host byte stream:
   row-independent, so the pad rows are sliced off bit-exactly);
 - every stage is counted in :class:`~cess_tpu.serve.stats.StreamStats`
   (enqueue time of the staging and of the program, stall time, pad
-  waste) and exported through the engine's ``cess_engine_stream_*``
-  metrics when attached (SubmissionEngine.attach_stream); the same
-  extents are ``cess:stream.stage`` / ``.gate`` / ``.put`` /
-  ``.dispatch`` / ``.stall`` in any profiler trace taken meanwhile
-  (obs.trace.stage).
+  waste; each stage's distribution on the program's ladder, and the
+  waits over ``obs.trace.LONG_WAIT_S`` with what was in flight) and
+  exported through the engine's ``cess_engine_stream_*`` metrics when
+  attached (SubmissionEngine.attach_stream); the same extents are
+  ``cess:stream.stage`` / ``.gate`` / ``.put`` / ``.dispatch`` /
+  ``.stall`` in any profiler trace taken meanwhile (obs.trace.stage),
+  the five of one batch under one ``seq``.
 
 Results are bit-identical to the direct per-step path
 (``encode_step`` -> ``tag_step``) — tests/test_stream.py pins this on
@@ -58,6 +60,8 @@ batch over (seg, byte); the driver is topology-agnostic.
 from __future__ import annotations
 
 import collections
+import os
+import resource
 import time
 from typing import Iterator
 
@@ -71,6 +75,49 @@ from ..obs import trace
 from ..resilience import faults
 from .engine import _pad_axis0
 from .stats import StreamStats
+
+
+# /proc/pressure/<resource>, opened once and kept: a reading is one
+# pread a file. None until the first reading; absent files are left out.
+_PRESSURE: list | None = None
+
+# A reading serves as the "before" of every wait that begins within this
+# long of it. In a tight loop a reading is 7 us, but between two stages
+# of a streamed batch on a many-core host it read 40 (the kernel sums a
+# pressure file over every CPU's counters, cold; PERF.md, PR 54), twice
+# a batch; a wait worth a record lasts LONG_WAIT_S, and counters a
+# fifth of that older say the same of it. A constant, not an option.
+_COUNTERS_FRESH_S = 0.05
+
+
+def _host_counters() -> dict:
+    """What tells a starved host from a runtime that held a ready
+    result, read before a wait (at most ``_COUNTERS_FRESH_S`` before:
+    ``StreamingIngest._run``) and again after one that ran long: the
+    process's context switches (``nvcsw`` it gave the CPU up, ``nivcsw``
+    it was taken off it), minor faults and system seconds
+    (``getrusage``), and the machine's ``some`` stall totals in
+    microseconds (``psi_<resource>_us``) where the kernel keeps them."""
+    global _PRESSURE
+    if _PRESSURE is None:
+        found = []
+        for name in ("cpu", "memory", "io"):
+            try:
+                found.append((f"psi_{name}_us", os.open(
+                    f"/proc/pressure/{name}", os.O_RDONLY)))
+            except OSError:
+                pass
+        _PRESSURE = found
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    out = {"nvcsw": ru.ru_nvcsw, "nivcsw": ru.ru_nivcsw,
+           "minflt": ru.ru_minflt, "stime_s": ru.ru_stime}
+    for key, fd in _PRESSURE:
+        try:    # "some avg10=.. avg60=.. avg300=.. total=<us>" first
+            out[key] = int(os.pread(fd, 256, 0)
+                           .split(b"total=", 2)[1].split()[0])
+        except (OSError, IndexError, ValueError):
+            pass
+    return out
 
 
 def _as_host_array(source):
@@ -248,20 +295,52 @@ class StreamingIngest:
         program = self._program or self.pipeline.fused_program()
         st = self.stats
         t_run = time.perf_counter()
-        # (result, real rows, the batch's rows on the device): a batch's
-        # rows are let go of with its result, never while its program
-        # may still be pending
+        # (result, real rows, the batch's rows on the device, its seq):
+        # a batch's rows are let go of with its result, never while its
+        # program may still be pending
         inflight: collections.deque = collections.deque()
         run_span = bspan = trace.NOOP_SPAN
         batches = stalls = 0
+        # the stages of one turn of the loop, merged once a batch; and
+        # what a wait had in flight: puts asked for, programs enqueued,
+        # results handed out
+        sink: dict = {}
+        n_put = n_run = n_out = 0
+
+        read_at, reading = -1.0, None
+
+        def counters() -> dict:
+            """The host's counters as a wait's "before": the last
+            reading, a new one once that is ``_COUNTERS_FRESH_S`` old."""
+            nonlocal read_at, reading
+            now = time.perf_counter()
+            if now - read_at > _COUNTERS_FRESH_S:
+                read_at, reading = now, _host_counters()
+            return reading
+
+        def waited(seq, before) -> dict:
+            """A long wait's context (built after it, and only then):
+            the counters' change over the wait and the at most
+            ``_COUNTERS_FRESH_S`` between the reading and its start."""
+            nonlocal read_at, reading
+            read_at, reading = time.perf_counter(), _host_counters()
+            return {"seq": seq, "results_in_flight": n_run - n_out,
+                    "puts_in_flight": n_put - n_out,
+                    **{k: round(reading[k] - v, 6)
+                       for k, v in before.items() if k in reading}}
 
         def drain_one():
-            nonlocal stalls
-            out, real, _ = inflight.popleft()
-            with trace.stage("stream.stall", parent=run_span) as stalled:
+            nonlocal stalls, n_out
+            out, real, _, seq = inflight.popleft()
+            before = counters()
+            with trace.stage("stream.stall", sink, parent=run_span,
+                             seq=seq) as stalled:
                 jax.block_until_ready(out["tags"])
             stall = stalled.seconds
             st.stall_s += stall
+            st.long_waits.observe("stream.stall", stalled.t0, stall,
+                                  waited, seq, before)
+            n_out += 1
             stalls += 1
             if run_span is not trace.NOOP_SPAN:
                 run_span.event("stall", s=round(stall, 6))
@@ -270,6 +349,17 @@ class StreamingIngest:
             st.bytes_out += out["fragments"].nbytes + out["tags"].nbytes
             out["rows"] = real
             return out
+
+        def handed():
+            """The oldest batch, waited for and handed to the consumer:
+            while the driver stands at the yield the time is the
+            consumer's (``consumer_s``), also when it never comes back."""
+            out = drain_one()
+            t_yield = time.perf_counter()
+            try:
+                yield out
+            finally:
+                st.consumer_s += time.perf_counter() - t_yield
 
         try:
             tracer = self._tracer_now()
@@ -280,9 +370,13 @@ class StreamingIngest:
             seg_off = 0
             chunks = _rebatch(segments, self.batch)
             while True:
+                # a batch's number in this driver's life: the ``seq`` of
+                # its five stages, in a trace and under a tracer
+                seq = st.batches
                 # host-side staging of the next batch: the source's
                 # next rows, contiguous, padded, with their ids
-                with trace.stage("stream.stage", parent=run_span):
+                with trace.stage("stream.stage", sink, parent=run_span,
+                                 seq=seq) as staging:
                     chunk = next(chunks, None)
                     if chunk is not None:
                         chunk = np.ascontiguousarray(chunk,
@@ -301,6 +395,7 @@ class StreamingIngest:
                             ids = _pad_axis0(
                                 fragment_ids[seg_off:seg_off + real],
                                 self.batch)
+                st.stage_s += staging.seconds
                 if chunk is None:
                     break
                 # the gate: the put is asked for BEFORE the wait for the
@@ -315,17 +410,21 @@ class StreamingIngest:
                 # (At depth 1 the put two before is out with its result:
                 # nothing to wait for.)
                 if len(inflight) >= 2:
-                    with trace.stage("stream.gate",
-                                     parent=run_span) as gate:
+                    before = counters()
+                    with trace.stage("stream.gate", sink, parent=run_span,
+                                     seq=seq) as gate:
                         jax.block_until_ready(inflight[-2][2])
                     st.gate_s += gate.seconds
+                    st.long_waits.observe("stream.gate", gate.t0,
+                                          gate.seconds, waited, seq, before)
                 bspan = trace.NOOP_SPAN if tracer is None \
                     else tracer.start("stream.batch", sys="stream",
                                       parent=run_span, rows=real,
-                                      pad=pad)
+                                      pad=pad, seq=seq)
                 bt0 = time.perf_counter()
                 try:
-                    with trace.stage("stream.put", parent=bspan) as put:
+                    with trace.stage("stream.put", sink, parent=bspan,
+                                     seq=seq) as put:
                         faults.inject("stream.h2d")   # chaos: staging
                         rows_up = linear_rows(chunk, cfg.k)
                         dev = self._put(rows_up)
@@ -333,6 +432,7 @@ class StreamingIngest:
                 except BaseException as e:
                     self._escaped(e, bspan, bt0, real)
                     raise
+                n_put += 1
                 # the window, enforced before the program is enqueued
                 # and its result allocated: ``depth`` programs' batches
                 # at most (depth=2 = one computing + one staged), and
@@ -342,15 +442,16 @@ class StreamingIngest:
                 # rows more) every cell ran as under the old order
                 # (PERF.md, PR 50)
                 while len(inflight) >= self.depth:
-                    yield drain_one()
+                    yield from handed()
                 try:
-                    with trace.stage("stream.dispatch",
-                                     parent=bspan) as launch:
+                    with trace.stage("stream.dispatch", sink,
+                                     parent=bspan, seq=seq) as launch:
                         faults.inject("stream.dispatch")  # chaos: launch
                         out = program(dev, ids_dev)
                 except BaseException as e:
                     self._escaped(e, bspan, bt0, real)
                     raise
+                n_run += 1
                 h2d, dispatch = put.seconds, launch.seconds
                 # everything a batch counts, it counts here, with
                 # ``batches``: no snapshot sees a put without its batch
@@ -363,7 +464,13 @@ class StreamingIngest:
                 st.lanes = len(jax.tree.leaves(dev)[0]
                                .sharding.device_set)
                 st.dispatch_s += dispatch
-                st.hist.observe(h2d + dispatch)
+                # what the batch cost this thread: its gate, its put,
+                # the stall that let its program in, its dispatch (the
+                # staging is the source's; an enqueue alone says nothing)
+                st.hist.observe(sum(
+                    acc[1] for name, acc in sink.items()
+                    if name != "stream.stage"))
+                st.add_stages(sink)
                 # SLO/tenant feed (obs/slo.py): streamed batches ride
                 # the attached engine's board under the "stream" class
                 # (targetable like any op class); one attribute chain
@@ -387,13 +494,18 @@ class StreamingIngest:
                 st.segments += real
                 st.bytes_in += real * cfg.segment_size
                 seg_off += self.batch
-                inflight.append((out, real, dev))
+                inflight.append((out, real, dev, seq))
             while inflight:
-                yield drain_one()
+                yield from handed()
+                st.add_stages(sink)      # a stall of the final drain
         finally:
             # a consumer that stopped between a batch's put and its
             # program: the batch's span still lands (no-op otherwise)
             bspan.finish()
+            # what the last turns left in the sink: the staging that
+            # found the source dry, the final drain's stalls, a turn
+            # that was cut short
+            st.add_stages(sink)
             st.wall_s += time.perf_counter() - t_run
             if run_span is not trace.NOOP_SPAN:
                 run_span.finish(batches=batches, stalls=stalls)

@@ -235,6 +235,31 @@ def test_pooled_lanes_hand_their_rows_to_the_rs_kernel_unstacked(
     assert small.stats.direct_rows == 0 and small.stats.batches == 1
 
 
+def test_the_pooled_put_is_three_child_stages_a_batch():
+    """The pooled put's three calls (the row views, the one
+    ``device_put``, the global arrays) are three stages, children of the
+    driver's ``stream.put`` under an armed tracer: three a batch, never
+    one a row, each put's three in the call's order."""
+    from cess_tpu import obs
+
+    pipe = make_pipe()
+    ing = StreamingIngest(pipe, BATCH, pool=DevicePool(n=LANES))
+    tracer = obs.Tracer()
+    with obs.armed(tracer):
+        ing.ingest(rnd((3 * BATCH, SEG), 61))
+    spans = tracer.finished()
+    puts = [s for s in spans if s["name"] == "stream.put"]
+    assert len(puts) == 3
+    parts = ["stream.put.slice", "stream.put.place", "stream.put.assemble"]
+    for put in puts:
+        mine = sorted((s for s in spans
+                       if s["parent_id"] == put["span_id"]),
+                      key=lambda s: s["span_id"])
+        assert [s["name"] for s in mine] == parts
+        assert sum(s["dur_s"] for s in mine) <= put["dur_s"] + 1e-4
+    assert sum(s["name"].startswith("stream.put.") for s in spans) == 9
+
+
 def test_stream_stats_lanes_is_a_gauge():
     pipe = make_pipe()
     segs = rnd((BATCH, SEG), 9)

@@ -10,12 +10,22 @@ request under ``caller``; the queue's seconds split under ``queue``), the
 gateway's hash workers (``gateway.worker.copy`` / ``.hash``, a job each)
 and the PoDR2 challenge (``podr2.challenge`` / ``podr2.coeffs``).
 
+Since ISSUE 54 a stage keeps its distribution and its worst cases
+beside its sum: every account of every class and the stream driver's five
+stages are observed, where the sinks are merged, into one ladder of
+buckets that keep ``[count, seconds]`` (``obs.trace.STAGE_LADDER_S``), so
+two snapshots difference into a window's percentile; a batch's ``wait`` /
+``fetch`` over ``LONG_WAIT_S`` is kept with the batch it was of; a
+streamed batch's five stages carry its ``seq`` into the profiler trace.
+
 No timing thresholds here: counts, names, nesting and the accounting
 identities (a batch's stages are pieces of its members' submit -> resolve
 latency; ``coalesce + wake == queue``; submit + stages + hand-back is the
 blocking call).
 """
 import glob
+import importlib.util
+import math
 import os
 import time
 
@@ -31,7 +41,9 @@ from cess_tpu.obs import trace
 from cess_tpu.ops import podr2
 from cess_tpu.serve import AdmissionPolicy, make_engine
 from cess_tpu.serve.policy import CLASSES, EngineTimeout
-from cess_tpu.serve.stats import CALLER, QUEUE_PARTS, STAGES
+from cess_tpu.obs import flight
+from cess_tpu.serve.stats import (CALLER, LADDERS, QUEUE_PARTS, STAGES,
+                                  ClassStats, EngineStats, StreamStats)
 from cess_tpu.serve.stream import StreamingIngest
 
 K, M = 2, 1
@@ -297,7 +309,8 @@ def test_a_late_caller_counts_a_handback_of_zero_once():
         snap = eng.stats_snapshot()["classes"]["encode"]
     finally:
         eng.close()
-    assert snap["caller"]["handoff"] == {"n": 1, "s": 0.0}
+    handoff = snap["caller"]["handoff"]
+    assert (handoff["n"], handoff["s"]) == (1, 0.0)
     assert snap["caller"]["submit"]["n"] == 1
 
 
@@ -456,6 +469,149 @@ def test_profile_feed_takes_the_stage_clock(pkey):
 
 
 # -- tracer off / on ---------------------------------------------------------
+# -- a stage's distribution and its worst cases (ISSUE 54) -------------------
+
+def _ladder_reader():
+    """benchmark/stage_ladders.py, loaded from its file (benchmark/ is
+    no package): the readers' own arithmetic."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "stage_ladders.py")
+    spec = importlib.util.spec_from_file_location("stage_ladders", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_ladder_is_one_object_for_every_class_and_the_stream():
+    ladder = trace.STAGE_LADDER_S
+    assert ladder[0] == 50e-6 and ladder[-1] == 10.0
+    assert trace.LONG_WAIT_S in ladder              # a bound, exactly
+    assert all(b / a <= 1.1 for a, b in zip(ladder, ladder[1:]))
+    stats = EngineStats()
+    hists = [h for st in stats.classes.values()
+             for h in st.ladders.values()]
+    hists += list(StreamStats().ladders.values())
+    assert len(hists) == len(CLASSES) * len(LADDERS) + 5
+    assert all(h.bounds is ladder for h in hists)
+    assert set(LADDERS) == set(STAGES) | set(CALLER) \
+        | {"queue." + part for part in QUEUE_PARTS}
+
+
+@pytest.mark.parametrize("cls", sorted(CLASSES))
+def test_an_accounts_n_and_s_are_its_buckets_sums(pkey, cls):
+    """``n`` and ``s`` read as they did; the buckets beside them hold
+    the same occurrences and the same seconds, for the six stages, the
+    queue's halves and the caller's accounts; the flat gauges did not
+    grow."""
+    eng = _engine(pkey)
+    try:
+        _drive(eng, pkey, cls)
+        eng.flush()
+        st = eng.stats.classes[cls]
+        snap = eng.stats_snapshot()["classes"][cls]
+        metrics = eng.stats_metrics()
+    finally:
+        eng.close()
+    accounts = [(f"stages.{k}", v) for k, v in snap["stages"].items()]
+    accounts += [(f"caller.{k}", v) for k, v in snap["caller"].items()]
+    accounts += [(f"queue.{k}", v) for k, v in snap["queue"].items()]
+    assert len(accounts) == len(LADDERS)
+    for name, acc in accounts:
+        assert set(acc) == {"n", "s", "buckets"}, name
+        assert acc["n"] == sum(n for _, n, _ in acc["buckets"]) > 0, name
+        assert acc["s"] == pytest.approx(
+            sum(s for _, _, s in acc["buckets"]), rel=1e-9, abs=1e-12), name
+        les = [math.inf if le is None else le for le, _, _ in acc["buckets"]]
+        assert les == sorted(les) and len(set(les)) == len(les), name
+    assert snap["stages"]["wait"]["s"] == st.stage_s["wait"]
+    assert not [k for k in metrics if "bucket" in k or "long_wait" in k]
+
+
+def test_a_percentile_of_two_snapshots_is_within_a_bucket_of_the_exact():
+    """A scripted sequence of stage times between two snapshots: the
+    window's percentile, read from the difference of the buckets as the
+    benchmark's readers read it, lies in the bucket that holds the exact
+    one (so within the ladder's ratio of it), whatever stood in the
+    ladder before; the seconds above the long-wait bound are exact."""
+    reader = _ladder_reader()
+    rng = np.random.default_rng(54)
+    st = ClassStats()
+
+    def batch(seconds):
+        st.add_stages({"engine.repair.wait": [1, seconds],
+                       "engine.repair.queue": [1, 0.0],
+                       "engine.repair.queue.coalesce": [1, 0.0],
+                       "engine.repair.queue.wake": [1, seconds / 7]})
+
+    for seconds in rng.lognormal(math.log(0.05), 1.0, 300):   # the warm-up
+        batch(float(seconds))
+    before = st.ladders["wait"].buckets(), st.ladders["queue.wake"].buckets()
+    script = [float(x) for x in rng.lognormal(math.log(4e-3), 0.6, 997)]
+    script += [0.3, 1.7, 0.2500001]
+    for seconds in script:
+        batch(seconds)
+    after = st.ladders["wait"].buckets(), st.ladders["queue.wake"].buckets()
+    got = reader.window(before[0], after[0])
+    assert reader.count(got) == len(script)
+    exact = sorted(script)
+    for q in (0.5, 0.95, 0.99, 1.0):
+        want = exact[max(1, math.ceil(q * len(exact))) - 1]
+        read = reader.percentile_s(got, q)
+        assert want / 1.1 <= read <= want * 1.1, q
+    assert reader.seconds_over(got, trace.LONG_WAIT_S) == pytest.approx(
+        0.3 + 1.7 + 0.2500001)
+    wake = reader.window(before[1], after[1])
+    assert reader.percentile_s(wake, 0.95) == pytest.approx(
+        exact[math.ceil(0.95 * len(exact)) - 1] / 7, rel=0.1)
+    assert reader.window(after[0], after[0]) == []
+    assert reader.percentile_s([], 0.95) is None
+
+
+@pytest.mark.parametrize("seconds,long", [(0.3, True), (0.001, False)],
+                         ids=["0.3s", "1ms"])
+def test_a_batchs_long_wait_is_kept_with_the_batch_it_was_of(
+        seconds, long, monkeypatch):
+    """A repair whose result blocks for 0.3 s: the batch's ``wait`` is in
+    ``stats_snapshot()["long_waits"]`` with class, bucket, rows and lane,
+    and in the flight journal; at 1 ms nothing is kept."""
+    eng = make_engine(K, M, rs_backend="jax")
+    coded = np.asarray(eng.encode(rnd((1, K, FRAG), 9)))
+    eng.reconstruct(coded[:, 1:], (1, 2), (0,))         # compiled
+    block = jax.block_until_ready
+
+    def slow(x):
+        time.sleep(seconds)
+        return block(x)
+
+    recorder = flight.FlightRecorder(b"pr54")
+    try:
+        monkeypatch.setattr(jax, "block_until_ready", slow)
+        t0 = time.perf_counter()
+        with flight.armed(recorder):
+            eng.reconstruct(coded[:, 1:], (1, 2), (0,))
+            eng.flush()
+        t1 = time.perf_counter()
+        snap = eng.stats_snapshot()
+    finally:
+        monkeypatch.undo()
+        eng.close()
+    notes = [e for e in recorder.journal_tail("engine")
+             if e["kind"] == "long_wait"]
+    if not long:
+        assert snap["long_waits"] == [] and notes == []
+        return
+    (rec,) = snap["long_waits"]
+    assert rec["stage"] == "engine.repair.wait" and rec["seconds"] >= 0.3
+    assert (rec["cls"], rec["bucket"], rec["rows"], rec["lane"]) \
+        == ("repair", 1, 1, None)
+    assert t0 <= rec["start"] and rec["start"] + rec["seconds"] <= t1
+    assert len(notes) == 1
+    over = sum(s for le, _, s in
+               snap["classes"]["repair"]["stages"]["wait"]["buckets"]
+               if le is None or le > trace.LONG_WAIT_S)
+    assert over == pytest.approx(rec["seconds"])
+
+
 def test_stage_without_a_tracer_starts_no_span():
     assert trace.armed_tracer() is None
     sink = {}
@@ -629,7 +785,8 @@ def profiled(pkey, tmp_path_factory):
         for ev in line.events:
             if ev.name.startswith(trace.STAGE_PREFIX):
                 events.append((i, ev.name[len(trace.STAGE_PREFIX):],
-                               ev.start_ns, ev.start_ns + ev.duration_ns))
+                               ev.start_ns, ev.start_ns + ev.duration_ns,
+                               dict(ev.stats)))
     return events
 
 
@@ -720,6 +877,22 @@ def test_profile_holds_the_challenge(profiled):
     names = [e[1] for e in profiled]
     assert names.count("podr2.challenge") == 1      # one audit round
     assert names.count("podr2.coeffs") == 1
+
+
+def test_profile_holds_a_batchs_seq_in_its_five_stream_stages(profiled):
+    """The annotation's metadata: 7 streamed rows, 2 a batch, are four
+    batches, each of whose stages carry its ``seq`` (the gate from the
+    third on), and the staging that found the source dry the fifth's."""
+    by_seq: dict = {}
+    for e in profiled:
+        if e[1].startswith("stream."):
+            by_seq.setdefault(e[4]["seq"], []).append(e[1])
+    assert sorted(by_seq) == [0, 1, 2, 3, 4]
+    for seq in range(4):
+        want = ["stream.dispatch", "stream.put", "stream.stage",
+                "stream.stall"] + (["stream.gate"] if seq >= 2 else [])
+        assert sorted(by_seq[seq]) == sorted(want), seq
+    assert by_seq[4] == ["stream.stage"]
 
 
 def test_profile_has_one_event_per_stage_per_batch(profiled):

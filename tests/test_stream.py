@@ -30,6 +30,13 @@
   fragment-major, bit-identical to the array form and the reference at
   the three geometries; other batches and the CPU's lowering stack;
   ``StreamStats.direct_rows`` counts the batches that went unstacked;
+- a stage keeps its distribution and its worst cases (PR 54): the five
+  stages of a batch go through one sink into ``StreamStats.stages``, on
+  the program's one ladder, under one ``seq``; a wait over
+  ``LONG_WAIT_S`` lands in ``long_waits`` and the flight journal with
+  what was in flight, a short one builds nothing; ``wall_s`` is
+  ``stage_s + gate_s + h2d_s + stall_s + dispatch_s + consumer_s`` and
+  a remainder;
 - the repair warm path (rs.py warm_reconstruct / engine.warm_repair)
   returns byte-exact reconstructions through pre-compiled programs.
 """
@@ -50,6 +57,8 @@ from cess_tpu.obs.slo import SloBoard, SloTarget
 from cess_tpu.ops import podr2, rs
 from cess_tpu.resilience import FaultInjected, FaultPlan, FaultSpec, faults
 from cess_tpu.serve import AdmissionPolicy, make_engine
+from cess_tpu.serve import stream as stream_mod
+from cess_tpu.serve.stats import StreamStats
 from cess_tpu.serve.stream import StreamingIngest, _rebatch
 from test_pool_stream import plain_reference
 
@@ -728,6 +737,218 @@ def test_a_consumer_that_stops_after_one_result_closes_every_span():
     assert names.count("stream.dispatch") == 2
     assert names.count("stream.run") == 1
     assert st.wall_s > 0
+
+
+# -- a stage's distribution and its worst cases (PR 54) ---------------------
+
+PARTS = ("stage_s", "gate_s", "h2d_s", "stall_s", "dispatch_s",
+         "consumer_s")
+
+
+def test_a_streams_stages_keep_their_distribution_beside_their_sums():
+    """Every key ``raw()`` had reads as before; the five stages' ladders
+    hold what the float counters hold (the put's stage is ``h2d_s``), a
+    stage's ``n`` / ``s`` are its buckets' sums, and the Prometheus
+    family observes a batch's host cost once a batch."""
+    pipe = geometry_pipe("rs2p1")
+    ing = StreamingIngest(pipe, 2)
+    had = {"batches", "segments", "padded_segments", "bytes_in",
+           "bytes_out", "linear_puts", "put_arrays", "direct_rows",
+           "h2d_s", "dispatch_s", "stall_s", "gate_s", "wall_s", "lanes"}
+    assert had <= set(StreamStats().raw())
+    ing.ingest(rnd((9, pipe.config.segment_size), 540))     # 5 batches
+    st, raw = ing.stats, ing.stats.raw()
+    assert set(raw["stages"]) == set(StreamStats.STAGES)
+    for stage, counter in (("stream.stage", "stage_s"),
+                           ("stream.gate", "gate_s"),
+                           ("stream.put", "h2d_s"),
+                           ("stream.dispatch", "dispatch_s"),
+                           ("stream.stall", "stall_s")):
+        acc = raw["stages"][stage]
+        assert acc["n"] == sum(n for _, n, _ in acc["buckets"]), stage
+        assert acc["s"] == pytest.approx(
+            sum(s for _, _, s in acc["buckets"]), rel=1e-9), stage
+        assert acc["s"] == pytest.approx(raw[counter], rel=1e-9), stage
+    counts = {k: v["n"] for k, v in raw["stages"].items()}
+    assert counts == {"stream.stage": 6, "stream.gate": 3,
+                      "stream.put": 5, "stream.dispatch": 5,
+                      "stream.stall": 5}
+    assert raw["long_waits"] == []
+    assert st.hist.count == 5
+    # gate + put + the stall ahead of the program + dispatch, a batch:
+    # all of them but the final drain's two stalls
+    assert st.hist.sum <= st.gate_s + st.h2d_s + st.stall_s \
+        + st.dispatch_s + 1e-9
+    assert st.hist.sum >= st.h2d_s + st.dispatch_s
+    # scalars only on the flat surface; the gauge that misled is gone
+    metrics = st.metrics()
+    assert "cess_engine_stream_stage_s" in metrics
+    assert "cess_engine_stream_consumer_s" in metrics
+    assert not [k for k in metrics if "stages" in k or "long_waits" in k
+                or "h2d_frac" in k]
+
+
+def test_wall_s_is_its_seven_addends():
+    """``wall_s`` = the five stages' seconds + the time suspended at a
+    yield + a remainder, the loop's own bookkeeping: never negative (the
+    addends are disjoint pieces of the run) and, in the quietest of a
+    few short runs, under a millisecond. The consumer's sleeps are
+    ``consumer_s``, also the last one's, after which it never comes
+    back."""
+    pipe = geometry_pipe("rs2p1")
+    ing = StreamingIngest(pipe, 2)
+    segs = rnd((4, pipe.config.segment_size), 541)
+    ing.ingest(segs)                                # compile outside
+    remainders = []
+    for _ in range(5):
+        a = ing.stats.raw()
+        for _out in ing.run(segs):
+            time.sleep(0.01)
+        b = ing.stats.raw()
+        d = {k: b[k] - a[k] for k in PARTS + ("wall_s",)}
+        assert d["consumer_s"] >= 2 * 0.01
+        remainders.append(d["wall_s"] - sum(d[k] for k in PARTS))
+    assert min(remainders) >= -1e-9
+    assert min(remainders) < 1e-3
+    # a consumer that stops at a yield: its time there is still its own
+    a = ing.stats.raw()
+    run = ing.run(segs)
+    next(run)
+    time.sleep(0.02)
+    run.close()
+    b = ing.stats.raw()
+    assert b["consumer_s"] - a["consumer_s"] >= 0.02
+    assert b["wall_s"] - a["wall_s"] >= sum(b[k] - a[k] for k in PARTS) \
+        - 1e-9
+
+
+class _SlowResult:
+    """``jax.block_until_ready`` that sleeps before the n-th wait for a
+    RESULT (a dict's tags: not a put's rows), and counts the readings of
+    the host's counters."""
+
+    def __init__(self, monkeypatch, nth, seconds, fresh=0.0):
+        self.waits = self.readings = 0
+        # how old a reading may be and still serve as a wait's "before":
+        # at 0.0 every wait reads its own
+        monkeypatch.setattr(stream_mod, "_COUNTERS_FRESH_S", fresh)
+        block, read = jax.block_until_ready, stream_mod._host_counters
+
+        def waited(x):
+            if not isinstance(x, tuple):
+                if self.waits == nth:
+                    time.sleep(seconds)
+                self.waits += 1
+            return block(x)
+
+        def counters():
+            self.readings += 1
+            return read()
+
+        monkeypatch.setattr(jax, "block_until_ready", waited)
+        monkeypatch.setattr(stream_mod, "_host_counters", counters)
+
+
+@pytest.mark.parametrize("seconds,long", [(0.3, True), (0.001, False)],
+                         ids=["0.3s", "1ms"])
+def test_a_scripted_stall_lands_in_long_waits_with_what_was_in_flight(
+        seconds, long, monkeypatch):
+    """The wait for batch 1's result blocks: over ``LONG_WAIT_S`` it is
+    kept with its ``seq``, the results and puts in flight and the host's
+    counters over the wait, in ``raw()["long_waits"]`` and the flight
+    journal, and its seconds stand in the ladder above the threshold;
+    at 1 ms nothing is built (with a reading's freshness at 0, one
+    reading before each wait, none after)."""
+    pipe = geometry_pipe("rs2p1")
+    slow = _SlowResult(monkeypatch, nth=1, seconds=seconds)
+    ing = StreamingIngest(pipe, 2)
+    recorder = flight.FlightRecorder(b"pr54")
+    with flight.armed(recorder):
+        ing.ingest(rnd((10, pipe.config.segment_size), 542))  # 5 batches
+    raw = ing.stats.raw()
+    waits = 5 + 3                                   # stalls + gates
+    over = sum(s for le, _, s in raw["stages"]["stream.stall"]["buckets"]
+               if le is None or le > obs.trace.LONG_WAIT_S)
+    notes = recorder.journal_tail("stream")
+    if not long:
+        assert raw["long_waits"] == [] and notes == [] and over == 0.0
+        assert slow.readings == waits
+        return
+    assert slow.readings == waits + 1
+    (rec,) = raw["long_waits"]
+    assert rec["stage"] == "stream.stall" and rec["seq"] == 1
+    assert 0.3 <= rec["seconds"] == pytest.approx(over)
+    # batch 3's put is out ahead of the window while batch 1's result is
+    # waited for, batch 2's program behind it
+    assert (rec["results_in_flight"], rec["puts_in_flight"]) == (2, 3)
+    assert {"nvcsw", "nivcsw", "minflt", "stime_s"} <= set(rec)
+    assert rec["nvcsw"] >= 1                        # it slept
+    assert rec["start"] <= time.perf_counter() - rec["seconds"]
+    assert [e["kind"] for e in notes] == ["long_wait"]
+
+
+def test_a_reading_serves_the_waits_that_begin_within_its_freshness(
+        monkeypatch):
+    """The host's counters are not read before every wait: a reading
+    serves as "before" until it is ``_COUNTERS_FRESH_S`` old (50 ms), so
+    a run of short waits inside that reads them once; a long wait's own
+    reading after it is the next waits' "before"."""
+    assert stream_mod._COUNTERS_FRESH_S == obs.trace.LONG_WAIT_S / 5
+    pipe = geometry_pipe("rs2p1")
+    slow = _SlowResult(monkeypatch, nth=1, seconds=0.001, fresh=3600.0)
+    ing = StreamingIngest(pipe, 2)
+    ing.ingest(rnd((10, pipe.config.segment_size), 545))     # 8 waits
+    assert slow.readings == 1
+    ing.ingest(rnd((4, pipe.config.segment_size), 546))      # a new run
+    assert slow.readings == 2
+    slow = _SlowResult(monkeypatch, nth=1, seconds=0.3, fresh=3600.0)
+    ing.ingest(rnd((10, pipe.config.segment_size), 547))
+    assert slow.readings == 2                    # the first, the after
+    (rec,) = ing.stats.raw()["long_waits"]
+    assert rec["seq"] == 8 and rec["nvcsw"] >= 1
+
+
+def test_a_short_wait_costs_one_comparison():
+    from cess_tpu.serve.stats import LongWaits
+
+    def never():
+        raise AssertionError("a short wait built its context")
+
+    waits = LongWaits("stream")
+    waits.observe("stream.stall", 0.0, obs.trace.LONG_WAIT_S, never)
+    assert waits.snapshot() == []
+    for i in range(6):                              # the four longest
+        waits.observe("stream.stall", float(i), 1.0 + i,
+                      lambda seq: {"seq": seq}, i)
+    assert [w["seq"] for w in waits.snapshot()] == [2, 3, 4, 5]
+
+
+def test_the_five_stages_of_a_batch_carry_one_seq():
+    """Under a tracer every stream stage span has the ``seq`` of its
+    batch: staging, gate, put and dispatch of batch i in turn i, the
+    stall that waits for its result later; a second run goes on
+    counting."""
+    pipe = geometry_pipe("rs2p1")
+    ing = StreamingIngest(pipe, 2)
+    tracer = obs.Tracer()
+    with obs.armed(tracer):
+        ing.ingest(rnd((8, pipe.config.segment_size), 543))   # seq 0..3
+        ing.ingest(rnd((4, pipe.config.segment_size), 544))   # seq 4, 5
+    by_seq: dict = {}
+    for s in tracer.finished():
+        if s["name"] in StreamStats.STAGES or s["name"] == "stream.batch":
+            by_seq.setdefault(s["attrs"]["seq"], []).append(s["name"])
+    for seq in range(6):
+        names = sorted(by_seq[seq])
+        want = ["stream.batch", "stream.dispatch", "stream.put",
+                "stream.stage", "stream.stall"]
+        if seq in (2, 3):           # two puts held: the gate engages
+            want.append("stream.gate")
+        if seq == 4:                # the staging that found run 1 dry
+            want.append("stream.stage")
+        assert names == sorted(want), seq
+    assert sorted(by_seq) == [0, 1, 2, 3, 4, 5, 6]
+    assert by_seq[6] == ["stream.stage"]            # and run 2
 
 
 # -- sharded mesh stream entry ---------------------------------------------
