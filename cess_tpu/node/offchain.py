@@ -1083,18 +1083,18 @@ class TeeAgent:
     proofs on device.
 
     A round's verify missions are judged TOGETHER (``on_block`` ->
-    ``judge_round`` -> ``verify_round``, the one entry point): every
-    proof's wire bytes are decoded and held to the deployment's widths
-    on the host, the owed sets' fragment hashes become ids in one
-    vectorised pass, and what goes to the device — through the
-    engine's verify class where an engine is configured — is FLAT: one
-    row an owed fragment (its id and its mission's index), the proofs
-    [missions, sectors + limbs], the round's challenge and its two
-    aggregation key words. One compiled program a mission bucket (8,
-    64, 512 up to the protocol's VerifyMissionMax) folds the rows a
-    fixed number at a time and derives r itself; ``warm_verify`` loads
-    them, after which a round of any sizes compiles nothing. What the
-    verifier holds is held per mission (``verify_round``)."""
+    ``judge_round`` -> ``verify_round``, the one entry point): the owed
+    sets' fragment hashes become ids in one vectorised pass, and what
+    goes to the device — through the engine's verify class where an
+    engine is configured — is FLAT: one row an owed fragment (its id
+    and its mission's index), the round's challenge and its two
+    aggregation key words, and, once the folds are out and the wire
+    bytes decoded under them and held to the deployment's widths, the
+    proofs [missions, sectors + limbs]. One compiled program a mission
+    bucket (8, 64, 512 up to the protocol's VerifyMissionMax) folds
+    the rows a fixed number at a time and derives r itself;
+    ``warm_verify`` loads them, after which a round of any sizes
+    compiles nothing. What the verifier holds is held per mission."""
 
     def __init__(self, node: Node, controller: str, key: podr2.Podr2Key,
                  blocks_per_fragment: int, bls_seed: bytes | None = None,
@@ -1267,6 +1267,34 @@ class TeeAgent:
             return proof
         return None
 
+    def _decode_round(self, proofs) -> list:
+        """A round's wire proofs decoded (``_decode_proof``), in order.
+        Equal bytes decode once a call (every fillerless miner sends
+        the same all-zero idle proof); nothing is kept from one call to
+        the next."""
+        once: dict = {}
+        decoded = []
+        for blob in proofs:
+            if type(blob) is not bytes:
+                decoded.append(self._decode_proof(blob))
+                continue
+            if blob not in once:
+                once[blob] = self._decode_proof(blob)
+            decoded.append(once[blob])
+        return decoded
+
+    def _stacked(self, decoded, live) -> tuple:
+        """(mu [len(live), sectors], sigma [len(live), limbs]) of the
+        missions ``live`` as the close takes them; an undecodable proof
+        goes as the zero proof (its verdict is forced afterwards)."""
+        sectors, limbs = self.key.alpha.shape
+        mu = np.zeros((len(live), sectors), np.uint32)
+        sigma = np.zeros((len(live), limbs), np.uint32)
+        for row, i in enumerate(live):
+            if decoded[i] is not None:
+                mu[row], sigma[row] = decoded[i].mu, decoded[i].sigma
+        return mu, sigma
+
     def verify_round(self, proofs, owed_sets, seed: bytes,
                      challenge=None) -> list[bool]:
         """THE verifier's entry point: a round's missions judged
@@ -1287,65 +1315,83 @@ class TeeAgent:
         one program a mission bucket folds them, r derived there from
         the round's aggregation key words: through the engine's verify
         class where one is configured (``submit_verify_round``), else
-        by the same programs directly (``podr2.round_dispatch``). A
-        profiler trace holds ``cess:tee.round`` with ``.decode``,
-        ``.ids``, ``.challenge``, ``.submit`` and ``.gather`` inside
-        it."""
+        by the same programs directly (``podr2.round_folds`` /
+        ``round_verdicts``). The folds read nothing of the proofs, so
+        the order is ids -> challenge -> submit -> decode -> close ->
+        verdicts: the folds are on the device before a proof is
+        decoded, the wire bytes are decoded while it folds (after the
+        engine has said that the folds are out, so that the decode's
+        Python does not hold the interpreter against the batcher's
+        dispatch), and (mu, sigma) reach the program only at the close.
+        Which missions' rows are folded is decided by the owed sets
+        alone (non-empty); what the decode finds decides the verdict
+        afterwards, as above. A mission whose proof turns out
+        undecodable has therefore had its rows folded for nothing: it
+        closes against the zero proof and its verdict is forced False.
+        That is device work a malformed proof did not cost before; a
+        well-formed wrong proof has always cost it, so nothing new is
+        exposed. A profiler trace holds ``cess:tee.round`` with
+        ``.ids``, ``.challenge``, ``.submit`` (to the folds' enqueue),
+        ``.decode``, ``.close`` and ``.gather`` inside it."""
         with trace.stage("tee.round"):
-            with trace.stage("tee.round.decode"):
-                # equal bytes decode once a call (every fillerless
-                # miner sends the same all-zero idle proof); nothing is
-                # kept from one call to the next
-                once: dict = {}
-                decoded = []
-                for blob in proofs:
-                    if type(blob) is not bytes:
-                        decoded.append(self._decode_proof(blob))
-                        continue
-                    if blob not in once:
-                        once[blob] = self._decode_proof(blob)
-                    decoded.append(once[blob])
-            verdicts = [False] * len(decoded)
-            live = []
-            for i, (proof, owed) in enumerate(zip(decoded, owed_sets)):
-                if proof is None:
-                    continue
-                if len(owed):
-                    live.append(i)
-                else:
-                    verdicts[i] = not proof.sigma.any() \
-                        and not proof.mu.any()
-            if not live:
-                return verdicts
-            with trace.stage("tee.round.ids"):
-                sizes = [len(owed_sets[i]) for i in live]
-                ids = np.concatenate([podr2.fragment_ids_from_hashes(
-                    owed_sets[i]) for i in live])
-                mu = np.stack([decoded[i].mu for i in live])
-                sigma = np.stack([decoded[i].sigma for i in live])
-                words = podr2.aggregate_words(seed)
-            with trace.stage("tee.round.challenge"):
-                # challenge derivation is round-constant: one a round
-                idx, nu = challenge if challenge is not None \
-                    else podr2.gen_challenge(seed, self.blocks)
-                idx, nu = np.asarray(idx), np.asarray(nu)
-            with trace.stage("tee.round.submit"):
-                # getattr: tests construct partial TeeAgents via __new__
-                engine = getattr(self, "engine", None)
-                if engine is not None and engine.audit is not None:
-                    pending = engine.submit_verify_round(
-                        ids, sizes, self.blocks, idx, nu, words, mu, sigma,
-                        tenant=self.controller)
-                else:
-                    pending = podr2.round_dispatch(
-                        podr2.key_operands(self.key),
-                        podr2.round_rows(ids, sizes, mu, sigma), idx, nu,
-                        words)
+            verdicts = [False] * len(proofs)
+            live = [i for i, owed in zip(range(len(proofs)), owed_sets)
+                    if len(owed)]
+            late = None
+            if live:
+                with trace.stage("tee.round.ids"):
+                    sizes = [len(owed_sets[i]) for i in live]
+                    ids = np.concatenate([podr2.fragment_ids_from_hashes(
+                        owed_sets[i]) for i in live])
+                    words = podr2.aggregate_words(seed)
+                with trace.stage("tee.round.challenge"):
+                    # challenge derivation is round-constant: one a round
+                    idx, nu = challenge if challenge is not None \
+                        else podr2.gen_challenge(seed, self.blocks)
+                    idx, nu = np.asarray(idx), np.asarray(nu)
+                with trace.stage("tee.round.submit"):
+                    # getattr: tests construct partial TeeAgents via __new__
+                    engine = getattr(self, "engine", None)
+                    if engine is not None and engine.audit is not None:
+                        from ..serve.engine import LateProofs
+
+                        late = LateProofs()
+                        pending = engine.submit_verify_round(
+                            ids, sizes, self.blocks, idx, nu, words,
+                            tenant=self.controller, proofs=late)
+                        late.folds_out()
+                    else:
+                        key_ops = podr2.key_operands(self.key)
+                        acc = podr2.round_folds(
+                            key_ops, podr2.round_rows(ids, sizes), idx, nu,
+                            words)
+            try:
+                with trace.stage("tee.round.decode"):
+                    decoded = self._decode_round(proofs)
+                for i, (proof, owed) in enumerate(zip(decoded, owed_sets)):
+                    if proof is not None and not len(owed):
+                        verdicts[i] = not proof.sigma.any() \
+                            and not proof.mu.any()
+                if not live:
+                    return verdicts
+                with trace.stage("tee.round.close"):
+                    mu, sigma = self._stacked(decoded, live)
+                    if late is not None:
+                        late.put(mu, sigma)
+                    else:
+                        pending = podr2.round_verdicts(key_ops, acc, mu,
+                                                       sigma)
+            except BaseException as e:
+                # the batch waits for these proofs: it must not wait
+                # out the request's timeout for a caller that is gone
+                if late is not None:
+                    late.fail(e)
+                raise
             with trace.stage("tee.round.gather"):
-                ok = pending.result() if hasattr(pending, "result") \
+                ok = pending.result() if late is not None \
                     else np.asarray(pending)
             for i, good in zip(live, ok):
-                verdicts[i] = bool(good)
+                verdicts[i] = bool(good) and decoded[i] is not None
             return verdicts
 
     def _verify(self, blob, owed: list[bytes], seed: bytes,

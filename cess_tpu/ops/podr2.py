@@ -867,14 +867,20 @@ ROUND_CLOSE = jax.jit(round_close)
 
 @dataclasses.dataclass(frozen=True)
 class RoundRows:
-    """A round's missions laid out for the fold (host arrays)."""
+    """A round's missions laid out for the fold (host arrays): the owed
+    sets and nothing of the proofs, which only the close reads
+    (``round_proofs``), so the folds can be on the device before a
+    proof is decoded."""
     ids: np.ndarray         # [calls * ROUND_ROWS, 2] uint32, zero pad
     seg: np.ndarray         # [calls * ROUND_ROWS] int32, -1 on pad rows
     steps: tuple            # loop steps of each call
-    mu: np.ndarray          # [bucket, sectors] uint32, zero pad
-    sigma: np.ndarray       # [bucket, limbs] uint32, zero pad
     missions: int           # real missions (the first of the bucket)
     rows: int               # real rows
+
+    @property
+    def bucket(self) -> int:
+        """The mission bucket the round's programs are shaped for."""
+        return mission_bucket(self.missions)
 
     @property
     def rows_issued(self) -> int:
@@ -883,10 +889,9 @@ class RoundRows:
         return sum(self.steps) * ROUND_SUB
 
 
-def round_rows(ids, sizes, mu, sigma) -> RoundRows:
+def round_rows(ids, sizes) -> RoundRows:
     """Lay out missions for the fold: ids [T, 2] in mission order,
-    sizes [M] (every one >= 1, summing to T), proofs mu [M, sectors]
-    and sigma [M, limbs]."""
+    sizes [M] (every one >= 1, summing to T)."""
     sizes = np.asarray(sizes, dtype=np.int64)
     missions, total = len(sizes), int(sizes.sum())
     if missions < 1 or sizes.min() < 1 or total != len(ids):
@@ -899,13 +904,18 @@ def round_rows(ids, sizes, mu, sigma) -> RoundRows:
     seg[:total] = np.repeat(np.arange(missions, dtype=np.int32), sizes)
     steps = tuple(-(-min(ROUND_ROWS, total - c * ROUND_ROWS) // ROUND_SUB)
                   for c in range(calls))
-    bucket = mission_bucket(missions)
+    return RoundRows(ids_pad, seg, steps, missions, total)
+
+
+def round_proofs(mu, sigma, bucket: int) -> tuple:
+    """The missions' proofs as the close takes them: mu [M, sectors]
+    and sigma [M, limbs] padded with zero proofs to the mission bucket
+    (a pad mission's equation is 0 + 0 == 0)."""
     mu_pad = np.zeros((bucket,) + mu.shape[1:], dtype=np.uint32)
-    mu_pad[:missions] = mu
+    mu_pad[:len(mu)] = mu
     sigma_pad = np.zeros((bucket,) + sigma.shape[1:], dtype=np.uint32)
-    sigma_pad[:missions] = sigma
-    return RoundRows(ids_pad, seg, steps, mu_pad, sigma_pad, missions,
-                     total)
+    sigma_pad[:len(sigma)] = sigma
+    return mu_pad, sigma_pad
 
 
 def key_operands(key: Podr2Key) -> tuple:
@@ -917,33 +927,51 @@ def key_operands(key: Podr2Key) -> tuple:
             str(jax.random.key_impl(key.prf_key)))
 
 
-def round_dispatch(key_ops: tuple, rows: RoundRows, idx, nu, agg_words):
-    """Enqueue the round: one fold a ROUND_ROWS rows, then the close.
-    Returns the device's bool [bucket]; nothing is waited for."""
+def round_folds(key_ops: tuple, rows: RoundRows, idx, nu, agg_words):
+    """Enqueue the round's folds, one a ROUND_ROWS rows: everything of
+    a round that needs no proof. Returns the device's accumulator
+    [bucket, limbs]; nothing is waited for."""
     alpha, prf_key_data, prf_impl = key_ops
-    acc = np.zeros(rows.sigma.shape, dtype=np.uint32)
+    acc = np.zeros((rows.bucket, alpha.shape[1]), dtype=np.uint32)
     for c, steps in enumerate(rows.steps):
         at = slice(c * ROUND_ROWS, (c + 1) * ROUND_ROWS)
         acc = ROUND_FOLD(rows.ids[at], rows.seg[at], np.int32(steps), acc,
                          idx, nu, agg_words, alpha, prf_key_data,
                          prf_impl=prf_impl)
-    return ROUND_CLOSE(alpha, acc, rows.mu, rows.sigma)
+    return acc
+
+
+def round_verdicts(key_ops: tuple, acc, mu, sigma):
+    """Enqueue the round's close over the folds' accumulator and the
+    missions' proofs mu [M, sectors], sigma [M, limbs], M up to the
+    accumulator's bucket. Returns the device's bool [bucket]; nothing
+    is waited for."""
+    return ROUND_CLOSE(key_ops[0], acc,
+                       *round_proofs(mu, sigma, acc.shape[0]))
+
+
+def round_dispatch(key_ops: tuple, rows: RoundRows, idx, nu, agg_words,
+                   mu, sigma):
+    """The whole round for a caller with its proofs in hand: the folds,
+    then the close."""
+    return round_verdicts(
+        key_ops, round_folds(key_ops, rows, idx, nu, agg_words), mu, sigma)
 
 
 def warm_round(key_ops: tuple, challenged: int, bucket: int):
     """Run the fold and the close of one mission bucket over zeros, for
     rounds of ``challenged`` blocks: after it such a round compiles
     nothing, whatever its sizes. Returns the device's result."""
-    alpha = key_ops[0]
+    sectors, limbs = key_ops[0].shape
     rows = RoundRows(
         ids=np.zeros((ROUND_ROWS, 2), np.uint32),
         seg=np.full(ROUND_ROWS, -1, np.int32), steps=(1,),
-        mu=np.zeros((bucket, alpha.shape[0]), np.uint32),
-        sigma=np.zeros((bucket, alpha.shape[1]), np.uint32),
         missions=bucket, rows=0)
     return round_dispatch(key_ops, rows, np.zeros((challenged,), np.int32),
                           np.zeros((challenged,), np.uint32),
-                          np.zeros((2,), np.uint32))
+                          np.zeros((2,), np.uint32),
+                          np.zeros((bucket, sectors), np.uint32),
+                          np.zeros((bucket, limbs), np.uint32))
 
 
 def verify_from_f(alpha, f, idx, nu, mu, sigma):

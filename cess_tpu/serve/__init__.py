@@ -34,7 +34,8 @@ on-chain offence filing, repair-mode flip) — opt-in via
 ``node.cli --remediate`` / ``Scenario.remediate=True``.
 """
 from .adaptive import AdaptiveBatchPolicy, AdmissionController
-from .engine import EngineFuture, SubmissionEngine, make_engine
+from .engine import (EngineFuture, LateProofs, SubmissionEngine,
+                     make_engine)
 from .policy import (AdmissionPolicy, EngineClosed, EngineError,
                      EngineSaturated, EngineShed, EngineTimeout)
 from .pool import DevicePool
@@ -54,6 +55,7 @@ __all__ = [
     "EngineShed",
     "EngineStats",
     "EngineTimeout",
+    "LateProofs",
     "Policy",
     "RemediationPlane",
     "StreamStats",

@@ -60,13 +60,13 @@ the chunk is the only shape, so the programs do not grow with F, and
 round (verify_round, PR 33: up to 500 missions, owed sets ragged from
 tens to ten thousands of fragments) is not stacked but FLAT: what
 ships is a row a owed fragment (its 8-byte id and its mission's
-index), the proofs [missions, sectors + limbs], the round (idx, nu)
-and its two aggregation key words — r is derived on the device — and
-one program a mission bucket (8, 64, 512) folds the rows
-ops/podr2.py ROUND_ROWS at a time into [missions, limbs], so the
-number of programs does not grow with the spread of the missions'
-sizes, pad is the last loop step's only, and requests of one round
-coalesce row-wise (``submit_verify_round``, ``warm_verify``).
+index), the round (idx, nu) and its two aggregation key words — r is
+derived on the device — and one program a mission bucket (8, 64, 512)
+folds the rows ops/podr2.py ROUND_ROWS at a time into [missions,
+limbs]; the proofs [missions, sectors + limbs] are taken LATE, for the
+close alone, so their decode runs under the folds (PR 56). Programs
+do not grow with the spread of the sizes, pad is the last loop step's,
+requests of one round coalesce row-wise (``submit_verify_round``).
 
 Protocol determinism is the hard constraint: engine-mediated results
 are bit-identical to the direct calls. That falls out of two facts —
@@ -782,43 +782,43 @@ class SubmissionEngine:
 
     @_caller_submit("verify")
     def submit_verify_round(self, fragment_ids, sizes, num_blocks, idx,
-                            nu, agg_words, mu, sigma,
+                            nu, agg_words, mu=None, sigma=None,
                             timeout: float | None = None,
-                            tenant: str | None = None) -> EngineFuture:
+                            tenant: str | None = None,
+                            proofs=None) -> EngineFuture:
         """A round's missions judged together (TeeAgent.verify_round):
         the missions' owed fragments FLAT, ids [T, 2] in mission order
-        with sizes [M] (each >= 1, summing to T), their proofs mu
-        [M, sectors] and sigma [M, limbs], the round (idx, nu) and its
-        aggregation key words (podr2.aggregate_words: r is derived on
-        the device) -> future of bool [M]. Requests of one round
-        coalesce row-wise: a mission is rows and an index, so ragged
-        owed sets cost no pad beyond the last loop step's and no
-        program of their own (ops/podr2.py ``round_fold``)."""
+        with sizes [M] (each >= 1, summing to T), the round (idx, nu)
+        and its aggregation key words (podr2.aggregate_words: r is
+        derived on the device) -> future of bool [M]. The proofs may
+        come LATE: the batch dispatches its folds from ids, sizes and
+        the round alone and takes mu [M, sectors], sigma [M, limbs]
+        only for the close, so a caller passes ``proofs=LateProofs()``
+        (end of this module), waits for its ``folds_out()``, decodes
+        while the device folds and ``put``s them; (mu, sigma) given
+        here are such proofs already put. Proofs that are mis-shaped,
+        ``fail``ed or not there by ``timeout`` fail this request alone.
+        A mission whose proof turns out undecodable has had its rows
+        folded for nothing, as a well-formed wrong proof always has.
+        Requests of one round coalesce row-wise: a mission is rows and
+        an index, so ragged owed sets cost no pad beyond the last loop
+        step's and no program of their own (podr2 ``round_fold``).
+        ``classes.verify.late_proofs`` counts the batches that waited
+        for proofs, the stage ``engine.verify.proofs`` their wait."""
         self._need_audit()
-        ids = np.ascontiguousarray(np.asarray(fragment_ids,
-                                              dtype=np.uint32)).reshape(-1, 2)
-        sizes = np.ascontiguousarray(np.asarray(sizes, dtype=np.int64))
-        mu = np.ascontiguousarray(np.asarray(mu, dtype=np.uint32))
-        sigma = np.ascontiguousarray(np.asarray(sigma, dtype=np.uint32))
-        words = np.ascontiguousarray(np.asarray(agg_words,
-                                                dtype=np.uint32))
-        if sizes.ndim != 1 or mu.ndim != 2 or sigma.ndim != 2 \
-                or not len(sizes) == len(mu) == len(sigma) \
-                or words.shape != (2,) \
-                or (len(sizes) and sizes.min() < 1) \
-                or int(sizes.sum()) != len(ids):
-            raise ValueError("expected ids [T, 2], sizes [M] >= 1 "
-                             "summing to T, mu [M, s], sigma [M, limbs] "
-                             "and two aggregation key words")
+        sectors, limbs = self.audit.key.alpha.shape
+        ids, sizes, words, proofs = _round_operands(
+            fragment_ids, sizes, agg_words, mu, sigma, proofs, sectors,
+            limbs)
         idx, nu = _check_round(idx, nu, num_blocks)
-        key = ("verify_round", num_blocks, mu.shape[1], sigma.shape[1],
+        key = ("verify_round", num_blocks, sectors, limbs,
                hashlib.sha256(_round_digest(num_blocks, idx, nu)
                               + words.tobytes()).digest()[:16])
-        return self._submit("verify", key, ids.shape[0],
-                            {"ids": ids, "sizes": sizes, "mu": mu,
-                             "sigma": sigma},
-                            {"idx": idx, "nu": nu, "agg_words": words},
-                            timeout, tenant=tenant)
+        proofs.future = self._submit(
+            "verify", key, ids.shape[0], {"ids": ids, "sizes": sizes},
+            {"idx": idx, "nu": nu, "agg_words": words, "proofs": proofs},
+            timeout, tenant=tenant)
+        return proofs.future
 
     def verify_round(self, fragment_ids, sizes, num_blocks, idx, nu,
                      agg_words, mu, sigma, timeout: float | None = None,
@@ -2181,20 +2181,23 @@ class SubmissionEngine:
             results = [bool(out[i]) for i in range(len(batch))]
         return results, rb * fb
 
-    def _count_verify(self, missions: int, calls: int, evals: int) -> None:
+    def _count_verify(self, missions: int, calls: int, evals: int,
+                      late: bool = False) -> None:
         with self._lock:
             st = self.stats.classes["verify"]
             st.missions += missions
             st.device_calls += calls
             st.prf_evals += evals
+            st.late_proofs += late
 
     def _round_program(self, challenged: int, sectors: int, limbs: int,
                        bucket: int, degraded: bool, lane,
                        warm: bool = False):
-        """The verify class's cached program for rounds of one mission
-        bucket: ops/podr2.py ``round_dispatch`` with the backend's key
-        read once as host words, placed on the backend's device.
-        ``warm``: a new entry runs once over zeros as it is built."""
+        """The verify class's cached programs for rounds of one mission
+        bucket, ``(fold, close)``: ops/podr2.py ``round_folds`` and
+        ``round_verdicts`` with the backend's key read once as host
+        words, placed on the backend's device. ``warm``: a new entry
+        runs both once over zeros as it is built."""
         from ..ops import podr2
 
         audit = self._audit_backend(degraded, lane)
@@ -2202,49 +2205,81 @@ class SubmissionEngine:
         def build():
             key_ops = podr2.key_operands(audit.key)
 
-            def placed(rows, idx, nu, agg_words):
+            def fold(rows, idx, nu, agg_words):
                 with jax.default_device(audit.device):
-                    return podr2.round_dispatch(key_ops, rows, idx, nu,
-                                                agg_words)
+                    return podr2.round_folds(key_ops, rows, idx, nu,
+                                             agg_words)
+
+            def close(acc, mu, sigma):
+                with jax.default_device(audit.device):
+                    return podr2.round_verdicts(key_ops, acc, mu, sigma)
             if warm:
                 with jax.default_device(audit.device):
                     jax.block_until_ready(
                         podr2.warm_round(key_ops, challenged, bucket))
-            return placed
+            return fold, close
 
         return self.programs.get(
             self._key(("verify_round", challenged, sectors, limbs, bucket),
                       degraded, lane), build)
 
     def _op_verify_round(self, batch, degraded=False, lane=None):
+        """A verify-round batch takes its proofs late: the rows are
+        laid out and the folds enqueued from the owed sets and the
+        round alone; then (the stage ``proofs``) every request is told
+        that its folds are out and its (mu, sigma) are taken, waiting
+        up to its deadline; then the close. Proofs in hand at submit
+        are there already: the same path, a wait of microseconds. A
+        request whose proofs fail it leaves the batch alone
+        (``_fail_members``); its missions close against zero proofs and
+        their verdicts are dropped."""
         from ..ops import podr2
 
         aux = batch[0].aux
+        sectors, limbs = batch[0].key[2:4]
+        # settled before this run (a salvage or a sibling lane's run
+        # of a batch that failed): no fold for them
+        self._fail_members(batch, {i: r.aux["proofs"].failure()
+                                   for i, r in enumerate(batch)})
         with self._stage("verify", "assemble"):
             if len(batch) == 1:
                 arrays = batch[0].arrays
             else:
                 arrays = {k: np.concatenate([r.arrays[k] for r in batch])
-                          for k in ("ids", "sizes", "mu", "sigma")}
-            rows = podr2.round_rows(arrays["ids"], arrays["sizes"],
-                                    arrays["mu"], arrays["sigma"])
+                          for k in ("ids", "sizes")}
+            rows = podr2.round_rows(arrays["ids"], arrays["sizes"])
         challenged = len(aux["idx"])
         with self._stage("verify", "dispatch"):
-            prog = self._round_program(
-                challenged, rows.mu.shape[1], rows.sigma.shape[1],
-                len(rows.mu), degraded, lane)
-            out = prog(rows, aux["idx"], aux["nu"], aux["agg_words"])
+            fold, close = self._round_program(
+                challenged, sectors, limbs, rows.bucket, degraded, lane)
+            acc = fold(rows, aux["idx"], aux["nu"], aux["agg_words"])
+        with self._stage("verify", "proofs"):
+            late = not all(r.aux["proofs"].here() for r in batch)
+            for r in batch:
+                r.aux["proofs"].release()
+            mu = np.zeros((rows.missions, sectors), np.uint32)
+            sigma = np.zeros((rows.missions, limbs), np.uint32)
+            failed, kept, at = {}, [], 0
+            for i, r in enumerate(batch):
+                n = len(r.arrays["sizes"])
+                try:
+                    mu[at:at + n], sigma[at:at + n] = r.aux["proofs"].take(
+                        r.deadline, (n, sectors), (n, limbs))
+                    kept.append(slice(at, at + n))
+                except Exception as e:  # noqa: BLE001 — that request's alone
+                    failed[i] = e
+                at += n
+        self._fail_members(batch, failed)
+        with self._stage("verify", "dispatch"):
+            out = close(acc, mu, sigma)
             self._count_verify(rows.missions, len(rows.steps) + 1,
-                               rows.rows_issued * challenged)
+                               rows.rows_issued * challenged, late)
         with self._stage("verify", "wait"):
             jax.block_until_ready(out)
+        self._stages_once()             # dispatch ran twice: one batch
         with self._stage("verify", "fetch"):
             out = np.asarray(out)
-            results, at = [], 0
-            for r in batch:
-                n = len(r.arrays["sizes"])
-                results.append(out[at:at + n])
-                at += n
+            results = [out[at] for at in kept]
         return results, rows.rows_issued
 
     def _op_prove(self, batch, degraded=False, lane=None):
@@ -2363,6 +2398,29 @@ class SubmissionEngine:
                          "lane": None if lane is None else lane.index})
 
 
+    def _fail_members(self, batch: list[_Request], failed: dict) -> None:
+        """Fail these members (index -> exception) of the batch this
+        thread runs, and no other: each is rejected, counted and taken
+        OUT of ``batch``, so the runner's results and _serve_batch's
+        accounting are the rest's. Where none would be left the last
+        stays and its failure is raised: the batch's own failure. An
+        index whose exception is None has not failed."""
+        failed = {i: e for i, e in failed.items() if e is not None}
+        if not failed:
+            return
+        gone = sorted(failed, reverse=True)
+        last = gone.pop(0) if len(failed) == len(batch) else None
+        now = time.monotonic()
+        with self._lock:
+            self.stats.classes[batch[0].cls].failed += len(gone)
+        for i in gone:
+            r = batch.pop(i)
+            r.future._reject(failed[i])
+            r.span.set(outcome="error", error=repr(failed[i])).finish()
+            self._observe_failure(r, now)
+        if last is not None:
+            raise failed[last]
+
 def make_engine(k: int | None = None, m: int | None = None, *,
                 rs_backend: str = "cpu", podr2_key=None,
                 audit_backend: str = "cpu",
@@ -2434,3 +2492,117 @@ def make_engine(k: int | None = None, m: int | None = None, *,
                             tracer=tracer, slo=slo, adaptive=adaptive,
                             admission=admission or None,
                             pool=pool or None, profile=profile)
+
+
+def _round_operands(fragment_ids, sizes, agg_words, mu, sigma, proofs,
+                    sectors: int, limbs: int) -> tuple:
+    """``submit_verify_round``'s arguments as the request holds them:
+    (ids [T, 2], sizes [M], the two aggregation key words, the
+    request's ``LateProofs``). Proofs given as arrays are checked here
+    and put; a ``LateProofs`` is checked when the batch takes it."""
+    ids = np.ascontiguousarray(np.asarray(fragment_ids,
+                                          dtype=np.uint32)).reshape(-1, 2)
+    sizes = np.ascontiguousarray(np.asarray(sizes, dtype=np.int64))
+    words = np.ascontiguousarray(np.asarray(agg_words, dtype=np.uint32))
+    if sizes.ndim != 1 or words.shape != (2,) \
+            or (len(sizes) and sizes.min() < 1) \
+            or int(sizes.sum()) != len(ids):
+        raise ValueError("expected ids [T, 2], sizes [M] >= 1 summing to "
+                         "T and two aggregation key words")
+    if (proofs is None) == (mu is None) or (mu is None) != (sigma is None):
+        raise ValueError("expected mu [M, sectors] and sigma [M, limbs], "
+                         "or proofs=LateProofs() to put them later")
+    if proofs is None:
+        proofs = LateProofs()
+        proofs.put(*proofs.shaped(mu, sigma, (len(sizes), sectors),
+                                  (len(sizes), limbs)))
+    return ids, sizes, words, proofs
+
+
+class LateProofs:
+    """The proofs of one ``submit_verify_round`` request, handed in
+    after the submit: a one-shot slot between the caller's thread and
+    the batch's.
+
+    The caller: ``folds_out()`` blocks until the engine has enqueued
+    the request's folds (the device is at work and the batcher wants
+    the interpreter no more: a thread that decodes in Python while the
+    batcher dispatches makes it wait a switch interval a call), then
+    ``put(mu, sigma)`` — mu [M, sectors], sigma [M, limbs] uint32 — or
+    ``fail(exc)``. The batch: ``take`` waits for them up to the
+    request's deadline. The first settlement stands: proofs that are
+    mis-shaped, failed, or late for the deadline fail the request for
+    good, whatever comes after."""
+
+    __slots__ = ("_out", "_in", "_mu", "_value", "future")
+
+    POLL_S = 0.05       # folds_out's look at the future, a failure's only
+
+    def __init__(self):
+        self._out = threading.Event()   # the engine: the folds are out
+        self._in = threading.Event()    # settled: proofs or a failure
+        self._mu = threading.Lock()
+        self._value: Any = None         # (mu, sigma) | BaseException
+        self.future: EngineFuture | None = None     # set by the submit
+
+    # -- the caller's side -------------------------------------------------
+    def folds_out(self, timeout: float | None = None) -> bool:
+        """Block until the request's folds are enqueued: True. False
+        when the request is over without them (rejected, expired,
+        closed) or ``timeout`` elapses."""
+        end = None if timeout is None else time.monotonic() + timeout
+        while not self._out.wait(self.POLL_S):
+            if self.future is not None and self.future.done() \
+                    or end is not None and time.monotonic() >= end:
+                return self._out.is_set()
+        return True
+
+    def put(self, mu, sigma) -> None:
+        self._settle((mu, sigma))
+
+    def fail(self, exc: BaseException) -> None:
+        self._settle(exc)
+
+    def _settle(self, value) -> None:
+        with self._mu:
+            if not self._in.is_set():
+                self._value = value
+                self._in.set()
+
+    # -- the batch's side --------------------------------------------------
+    def here(self) -> bool:
+        return self._in.is_set()
+
+    def failure(self) -> BaseException | None:
+        value = self._value
+        return value if isinstance(value, BaseException) else None
+
+    def release(self) -> None:
+        self._out.set()
+
+    @staticmethod
+    def shaped(mu, sigma, mu_shape: tuple, sigma_shape: tuple) -> tuple:
+        mu = np.asarray(mu, dtype=np.uint32)
+        sigma = np.asarray(sigma, dtype=np.uint32)
+        if mu.shape != mu_shape or sigma.shape != sigma_shape:
+            raise ValueError(f"expected mu {mu_shape} and sigma "
+                             f"{sigma_shape}, got {mu.shape} and "
+                             f"{sigma.shape}")
+        return mu, sigma
+
+    def take(self, deadline: float | None, mu_shape: tuple,
+             sigma_shape: tuple) -> tuple:
+        """The proofs, waited for until ``deadline`` (time.monotonic();
+        None: without limit), as uint32 arrays of these shapes. Raises
+        the request's failure, which then stands."""
+        if not self._in.wait(None if deadline is None else
+                             max(deadline - time.monotonic(), 0.0)):
+            self.fail(EngineTimeout("no proofs within the request's "
+                                    "timeout"))
+        if self.failure() is None:
+            try:
+                return self.shaped(*self._value, mu_shape, sigma_shape)
+            except (TypeError, ValueError) as e:
+                with self._mu:
+                    self._value = e
+        raise self.failure()

@@ -95,6 +95,17 @@ QUEUE_PARTS = ("coalesce", "wake")
 # to the class that was drained.
 DRAIN_TRIGGERS = ("idle", "window", "size", "forced")
 
+# A batch's wait for what its callers bring after their submit, a stage
+# beside the six and outside all of them (``late`` in a snapshot):
+#   proofs    a verify-round batch (serve/engine.py _op_verify_round):
+#             from its last fold's enqueue until every request's (mu,
+#             sigma) are in the batch's hands. The folds read nothing
+#             of the proofs, so a TEE submits the round first and
+#             decodes its wire proofs while the device folds; with the
+#             proofs handed in at submit the stage is a few
+#             microseconds. Counted once a verify-round batch
+LATE = ("proofs",)
+
 # Every account above keeps its DISTRIBUTION beside its sum: one
 # obs/prom.Histogram over obs.trace.STAGE_LADDER_S (one tuple for the
 # whole program) a (class, account), fed where the sums are, once a
@@ -104,7 +115,8 @@ DRAIN_TRIGGERS = ("idle", "window", "size", "forced")
 # window's percentile and its seconds above any bound
 # (``stages[..]["buckets"]``, ``queue[..]``, ``caller[..]``; the
 # 512-sample latency ring cannot be differenced).
-LADDERS = STAGES + tuple("queue." + p for p in QUEUE_PARTS) + CALLER
+LADDERS = STAGES + tuple("queue." + p for p in QUEUE_PARTS) + CALLER \
+    + LATE
 
 # The stages of a batch that are waits: an occurrence over
 # obs.trace.LONG_WAIT_S is kept with its context (LongWaits).
@@ -159,10 +171,11 @@ class ClassStats:
                  "linear_puts", "symbol_folds", "patterns_new",
                  "matrix_build_s", "result_bytes", "regroup_s",
                  "regrouped_bytes",
-                 "missions", "device_calls", "prf_evals",
+                 "missions", "device_calls", "prf_evals", "late_proofs",
                  "chunks", "gathered_bytes", "gather_seconds",
                  "latencies", "hist", "stage_n", "stage_s",
-                 "caller_n", "caller_s", "queue_s", "drains", "ladders")
+                 "caller_n", "caller_s", "queue_s", "late_n", "late_s",
+                 "drains", "ladders")
 
     def __init__(self):
         self.submitted = 0          # requests admitted to the queue
@@ -228,6 +241,11 @@ class ClassStats:
         self.missions = 0
         self.device_calls = 0
         self.prf_evals = 0
+        # verify class, a round (_op_verify_round): batches in which a
+        # request's proofs arrived after the batch's folds were
+        # enqueued (the decode ran under the folds); a batch whose
+        # proofs were all in hand by then leaves it
+        self.late_proofs = 0
         # prove class (engine.py _op_prove): device steps its batches
         # took (``device_calls`` counts them too: a step is a program
         # call), the bytes of challenged blocks and tag rows gathered
@@ -251,6 +269,9 @@ class ClassStats:
         self.caller_n = dict.fromkeys(CALLER, 0)
         self.caller_s = dict.fromkeys(CALLER, 0.0)
         self.queue_s = dict.fromkeys(QUEUE_PARTS, 0.0)
+        # a batch's waits for late operands (LATE)
+        self.late_n = dict.fromkeys(LATE, 0)
+        self.late_s = dict.fromkeys(LATE, 0.0)
         # drains of this class by what tripped them (DRAIN_TRIGGERS)
         self.drains = dict.fromkeys(DRAIN_TRIGGERS, 0)
         # each account's distribution (LADDERS)
@@ -260,8 +281,9 @@ class ClassStats:
     def add_stages(self, sink: dict):
         """Merge one batch's stage sink (``{"engine.<cls>.<stage>":
         [count, seconds]}``, obs.trace.stage's shape; the queue's halves
-        are ``engine.<cls>.queue.coalesce`` / ``.wake``): each entry
-        into its sum and, as one observation, into its ladder. Returns
+        are ``engine.<cls>.queue.coalesce`` / ``.wake``, a late
+        operand's wait ``engine.<cls>.proofs``): each entry into its
+        sum and, as one observation, into its ladder. Returns
         the batch's waits (LONG_WAIT_STAGES) that ran over LONG_WAIT_S
         as ``[(stage, seconds), ...]``, or None: the caller notes them
         outside its lock."""
@@ -271,6 +293,9 @@ class ClassStats:
             if stage in self.stage_n:
                 self.stage_n[stage] += n
                 self.stage_s[stage] += seconds
+            elif stage in self.late_n:
+                self.late_n[stage] += n
+                self.late_s[stage] += seconds
             else:
                 self.queue_s[stage.rpartition(".")[2]] += seconds
             self.ladders[stage].observe(seconds)
@@ -512,6 +537,7 @@ class EngineStats:
                 "missions": st.missions,
                 "device_calls": st.device_calls,
                 "prf_evals": st.prf_evals,
+                "late_proofs": st.late_proofs,
                 "chunks": st.chunks,
                 "gathered_bytes": st.gathered_bytes,
                 "gather_seconds": st.gather_seconds,
@@ -529,6 +555,9 @@ class EngineStats:
                                          st.queue_s[part],
                                          st.ladders["queue." + part])
                           for part in QUEUE_PARTS},
+                "late": {part: _account(st.late_n[part], st.late_s[part],
+                                        st.ladders[part])
+                         for part in LATE},
                 "drains": dict(st.drains),
             }
         out["long_waits"] = self.long_waits.snapshot()
@@ -561,6 +590,9 @@ class EngineStats:
                 out[f"cess_engine_{cls}_caller_{acct}_count"] = acc["n"]
             for part, acc in st.pop("queue").items():
                 out[f"cess_engine_{cls}_queue_{part}_seconds"] = acc["s"]
+            for part, acc in st.pop("late").items():
+                out[f"cess_engine_{cls}_stage_{part}_seconds"] = acc["s"]
+                out[f"cess_engine_{cls}_stage_{part}_count"] = acc["n"]
             for trigger, n in st.pop("drains").items():
                 out[f"cess_engine_{cls}_drains_{trigger}_total"] = n
             for name, val in st.items():

@@ -42,7 +42,7 @@ from cess_tpu.ops import podr2
 from cess_tpu.serve import AdmissionPolicy, make_engine
 from cess_tpu.serve.policy import CLASSES, EngineTimeout
 from cess_tpu.obs import flight
-from cess_tpu.serve.stats import (CALLER, LADDERS, QUEUE_PARTS, STAGES,
+from cess_tpu.serve.stats import (CALLER, LADDERS, LATE, QUEUE_PARTS, STAGES,
                                   ClassStats, EngineStats, StreamStats)
 from cess_tpu.serve.stream import StreamingIngest
 
@@ -493,7 +493,7 @@ def test_the_ladder_is_one_object_for_every_class_and_the_stream():
     hists += list(StreamStats().ladders.values())
     assert len(hists) == len(CLASSES) * len(LADDERS) + 5
     assert all(h.bounds is ladder for h in hists)
-    assert set(LADDERS) == set(STAGES) | set(CALLER) \
+    assert set(LADDERS) == set(STAGES) | set(CALLER) | set(LATE) \
         | {"queue." + part for part in QUEUE_PARTS}
 
 
@@ -515,7 +515,11 @@ def test_an_accounts_n_and_s_are_its_buckets_sums(pkey, cls):
     accounts = [(f"stages.{k}", v) for k, v in snap["stages"].items()]
     accounts += [(f"caller.{k}", v) for k, v in snap["caller"].items()]
     accounts += [(f"queue.{k}", v) for k, v in snap["queue"].items()]
-    assert len(accounts) == len(LADDERS)
+    # a late operand's wait is a verify round's (tests/test_verify_round.py
+    # drives it): here the account is there and empty
+    assert snap["late"] == {part: {"n": 0, "s": 0.0, "buckets": []}
+                            for part in LATE}
+    assert len(accounts) + len(LATE) == len(LADDERS)
     for name, acc in accounts:
         assert set(acc) == {"n", "s", "buckets"}, name
         assert acc["n"] == sum(n for _, n, _ in acc["buckets"]) > 0, name

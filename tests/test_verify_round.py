@@ -9,7 +9,11 @@ seeded ragged rounds, honest and under each tamper of the benchmark's
 cell; to themselves however the missions are submitted (a round, one by
 one, any order, with and without an engine: pad and batching
 independence); and to the compile counter: a second round of other sizes
-compiles nothing. Small sizes, CPU.
+compiles nothing. Since PR 56 the round's folds go to the device before
+a proof is decoded and the proofs reach the program at the close
+(``LateProofs``): the verdicts are held, mission for mission, to the
+order before it (decode first, the proofs in hand at the submit), and a
+request's late proofs are its own to fail. Small sizes, CPU.
 """
 import dataclasses
 import importlib
@@ -26,7 +30,8 @@ from cess_tpu.chain import audit as chain_audit
 from cess_tpu.node.offchain import Proof, TeeAgent
 from cess_tpu.ops import pfield as pf
 from cess_tpu.ops import podr2
-from cess_tpu.serve import AdmissionPolicy, make_engine
+from cess_tpu.serve import (AdmissionPolicy, EngineTimeout, LateProofs,
+                            make_engine)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCKS = 64
@@ -263,13 +268,21 @@ def test_a_bad_mission_fails_alone_and_never_raises(ref, key, engine, what,
     assert tee.verify_round([blobs[1]], [owed[1]], rnd.seed) == [want[1]]
 
 
-def test_a_round_of_nothing_but_bad_missions_touches_no_device(key, engine):
-    before = engine.stats_snapshot()["classes"]["verify"]["submitted"]
+def test_the_owed_sets_alone_decide_what_reaches_the_device(key, engine):
+    """A round with no owed fragment touches no device; an undecodable
+    proof over an owed set has its rows folded (the folds go out before
+    the decode) and its verdict forced False."""
+    def submitted():
+        return engine.stats_snapshot()["classes"]["verify"]["submitted"]
+
+    before = submitted()
     tee = make_tee(key, engine)
+    assert tee.verify_round([b"", b"x"], [[], []], b"s") == [False, False]
+    assert tee.verify_round([], [], b"s") == []
+    assert submitted() == before
     assert tee.verify_round([b"", b"x"], [[b"\x01" * 32], []], b"s") \
         == [False, False]
-    assert tee.verify_round([], [], b"s") == []
-    assert engine.stats_snapshot()["classes"]["verify"]["submitted"] == before
+    assert submitted() == before + 1
 
 
 # -- shapes: one program a mission bucket ----------------------------------
@@ -364,8 +377,9 @@ def test_more_rows_than_one_call_holds(ref, key, monkeypatch):
     rnd = make_round(ref, 10, sizes=sizes)
     rows = podr2.round_rows(
         podr2.fragment_ids_from_hashes(h for hs in rnd.owed for h in hs),
-        sizes, rnd.mu, rnd.sigma)
+        sizes)
     assert rows.steps == (2, 2, 1) and rows.rows_issued == 5 * podr2.ROUND_SUB
+    assert rows.missions == 4 and rows.bucket == 8
     sigma = rnd.sigma.copy()
     sigma[2, 0] ^= 1            # the mission that spans all three calls
     blobs = [wire(u, s) for u, s in zip(rnd.mu, sigma)]
@@ -389,6 +403,229 @@ def test_submit_refuses_a_mis_shaped_round(key, engine):
                                    words, mu, sigma)
 
 
+# -- the proofs come late (PR 56) ------------------------------------------
+def in_hand(key, engine, blobs, owed, seed) -> list:
+    """The order before PR 56, kept as the reference: every proof is
+    decoded first, only the decodable missions with an owed set go to
+    the device, their proofs in hand at the submit."""
+    tee = make_tee(key)
+    decoded = [tee._decode_proof(blob) for blob in blobs]
+    verdicts = [False] * len(blobs)
+    live = []
+    for i, (proof, hs) in enumerate(zip(decoded, owed)):
+        if proof is None:
+            continue
+        if len(hs):
+            live.append(i)
+        else:
+            verdicts[i] = not proof.sigma.any() and not proof.mu.any()
+    if live:
+        ids = np.concatenate([podr2.fragment_ids_from_hashes(owed[i])
+                              for i in live])
+        sizes = [len(owed[i]) for i in live]
+        mu = np.stack([decoded[i].mu for i in live])
+        sigma = np.stack([decoded[i].sigma for i in live])
+        idx, nu = (np.asarray(a) for a in podr2.gen_challenge(seed, BLOCKS))
+        words = podr2.aggregate_words(seed)
+        if engine is not None:
+            ok = engine.verify_round(ids, sizes, BLOCKS, idx, nu, words, mu,
+                                     sigma, timeout=120)
+        else:
+            ok = np.asarray(podr2.round_dispatch(
+                podr2.key_operands(key), podr2.round_rows(ids, sizes), idx,
+                nu, words, mu, sigma))
+        for i, good in zip(live, ok):
+            verdicts[i] = bool(good)
+    return verdicts
+
+
+LATE_CASES = {                  # kind -> the verdict of mission 1
+    "honest": True, "tampered": False, "undecodable": False,
+    "sigma_not_below_p": False, "empty_owed_zero_proof": True,
+    "empty_owed_nonzero_proof": False, "not_bytes": False}
+
+
+@pytest.mark.parametrize("kind", sorted(LATE_CASES))
+@pytest.mark.parametrize("through", ["engine", "direct"])
+def test_late_proofs_judge_as_proofs_in_hand(ref, key, engine, kind, through):
+    rnd = make_round(ref, 13, sizes=(3, 4, 2, 5))
+    blobs, owed = rnd.blobs, [list(hs) for hs in rnd.owed]
+    zero = np.zeros((podr2.SECTORS,), np.uint32)
+    if kind == "tampered":
+        blobs[1] = wire(rnd.mu[1] ^ np.uint32(2), rnd.sigma[1])
+    elif kind == "undecodable":
+        blobs[1] = blobs[1][:333]
+    elif kind == "sigma_not_below_p":
+        blobs[1] = wire(rnd.mu[1], np.full((2,), pf.P, np.uint32))
+    elif kind == "empty_owed_zero_proof":
+        owed[1], blobs[1] = [], wire(zero, zero[:2])
+    elif kind == "empty_owed_nonzero_proof":
+        owed[1] = []
+    elif kind == "not_bytes":
+        blobs[1] = None
+    eng = engine if through == "engine" else None
+    late = make_tee(key, eng).verify_round(blobs, owed, rnd.seed)
+    assert late == in_hand(key, eng, blobs, owed, rnd.seed)
+    assert late == [True, LATE_CASES[kind], True, True]
+
+
+@pytest.mark.parametrize("through", ["engine", "direct"])
+def test_a_partial_agent_still_judges(ref, key, engine, through):
+    """As the benchmark's prove cell builds its verifier
+    (traffic/prove_round.py): ``__new__`` and six attributes, a service
+    proof over an owed tuple and the zero idle proof over none."""
+    rnd = make_round(ref, 14, sizes=(6,))
+    tee = object.__new__(TeeAgent)
+    tee.key, tee.blocks = key, BLOCKS
+    tee.engine = engine if through == "engine" else None
+    tee.controller, tee.bls_sk, tee._submitted = "tee", None, set()
+    tee.warm_verify(1)
+    idle = wire(np.zeros(podr2.SECTORS, np.uint32), np.zeros(2, np.uint32))
+    assert tee.verify_round([rnd.blobs[0], idle], [tuple(rnd.owed[0]), ()],
+                            rnd.seed) == [True, True]
+    assert tee.verify_round([idle, idle], [tuple(rnd.owed[0]), ()],
+                            rnd.seed) == [False, True]
+
+
+def _request(eng, rnd: Round, m: int, proofs=None, timeout=None):
+    """Mission ``m`` of the round as a request of its own: its proofs
+    late (``proofs``) or in hand."""
+    idx, nu = (np.asarray(a) for a in podr2.gen_challenge(rnd.seed, BLOCKS))
+    early = () if proofs is not None else (rnd.mu[m:m + 1],
+                                           rnd.sigma[m:m + 1])
+    return eng.submit_verify_round(
+        podr2.fragment_ids_from_hashes(rnd.owed[m]), [len(rnd.owed[m])],
+        BLOCKS, idx, nu, podr2.aggregate_words(rnd.seed), *early,
+        timeout=timeout, proofs=proofs)
+
+
+@pytest.fixture
+def own_engine(key):
+    eng = make_engine(2, 1, podr2_key=key)
+    yield eng
+    eng.close()
+
+
+def _coalesced(eng, gate, rnd: Round, missions):
+    """Requests with late proofs for these missions, in ONE batch: they
+    queue behind a held batch and leave together when it is let go.
+    Returns [(future, its LateProofs)]; every request's folds are out."""
+    held = gate(eng, "verify_round")
+    first = _request(eng, rnd, 0)
+    assert held.running()
+    reqs = []
+    for m in missions:
+        late = LateProofs()
+        reqs.append((_request(eng, rnd, m, late, timeout=120), late))
+    held.open()
+    assert bool(first.result(timeout=120)[0])
+    for _, late in reqs:
+        assert late.folds_out(120)
+    return reqs
+
+
+def test_a_request_whose_decode_raises_fails_alone(ref, key, own_engine, gate):
+    rnd = make_round(ref, 15, sizes=(3, 7, 2))
+    a0 = own_engine.stats_snapshot()["classes"]["verify"]
+    (bad, bad_late), (good, good_late) = _coalesced(own_engine, gate, rnd,
+                                                    (1, 2))
+    bad_late.fail(codec.CodecError("no proof in these bytes"))
+    good_late.put(rnd.mu[2:3], rnd.sigma[2:3])
+    assert good.result(timeout=120).tolist() == [True]
+    with pytest.raises(codec.CodecError):
+        bad.result(timeout=120)
+    own_engine.flush()
+    b = own_engine.stats_snapshot()["classes"]["verify"]
+    assert b["batches"] - a0["batches"] == 2           # the held one, and theirs
+    assert b["failed"] - a0["failed"] == 1
+    assert b["completed"] - a0["completed"] == 2
+    assert b["batched_requests"] - a0["batched_requests"] == 2
+
+
+@pytest.mark.parametrize("fault", ["both_fail", "mis_shaped", "put_twice"])
+def test_late_proofs_are_their_requests_own(ref, key, own_engine, gate,
+                                            fault):
+    rnd = make_round(ref, 16, sizes=(3, 7, 2))
+    (one, one_late), (two, two_late) = _coalesced(own_engine, gate, rnd,
+                                                  (1, 2))
+    if fault == "both_fail":        # each its own failure, none the other's
+        one_late.fail(KeyError("one"))
+        two_late.fail(IndexError("two"))
+        with pytest.raises(KeyError):
+            one.result(timeout=120)
+        with pytest.raises(IndexError):
+            two.result(timeout=120)
+    elif fault == "mis_shaped":     # two missions' proofs for one mission
+        one_late.put(rnd.mu[:2], rnd.sigma[:2])
+        two_late.put(rnd.mu[2:3], rnd.sigma[2:3])
+        with pytest.raises(ValueError, match="expected mu"):
+            one.result(timeout=120)
+        assert two.result(timeout=120).tolist() == [True]
+    else:                           # the first settlement stands
+        one_late.put(rnd.mu[1:2], rnd.sigma[1:2])
+        one_late.fail(KeyError("late for it"))
+        two_late.fail(KeyError("first"))
+        two_late.put(rnd.mu[2:3], rnd.sigma[2:3])
+        assert one.result(timeout=120).tolist() == [True]
+        with pytest.raises(KeyError, match="first"):
+            two.result(timeout=120)
+    # the batcher is alive and serves the next round
+    assert _request(own_engine, rnd, 0).result(timeout=120).tolist() == [True]
+
+
+def test_proofs_that_never_come_fail_by_the_requests_timeout(ref, key,
+                                                             own_engine):
+    rnd = make_round(ref, 17, sizes=(4, 2))
+    a = own_engine.stats_snapshot()["classes"]["verify"]
+    never = LateProofs()
+    lost = _request(own_engine, rnd, 0, never, timeout=0.5)
+    assert never.folds_out(120)             # its rows were folded
+    with pytest.raises(EngineTimeout, match="no proofs"):
+        lost.result(timeout=120)
+    never.put(rnd.mu[:1], rnd.sigma[:1])    # too late: the failure stands
+    assert isinstance(never.failure(), EngineTimeout)
+    assert _request(own_engine, rnd, 1).result(timeout=120).tolist() == [True]
+    own_engine.flush()
+    b = own_engine.stats_snapshot()["classes"]["verify"]
+    assert b["failed"] - a["failed"] == 1 and b["timeouts"] == a["timeouts"]
+    assert b["completed"] - a["completed"] == 1
+    # a request that is over before its folds: the caller is told so
+    gone = LateProofs()
+    with pytest.raises(ValueError):
+        own_engine.submit_verify_round(
+            np.zeros((3, 2), np.uint32), [2], BLOCKS, np.zeros(1, np.int32),
+            np.zeros(1, np.uint32), np.zeros(2, np.uint32), proofs=gone)
+    assert not gone.folds_out(0.2)
+
+
+@pytest.mark.parametrize("proofs", ["late", "in_hand"])
+def test_late_proofs_and_their_stage_count_one_a_batch(ref, key, own_engine,
+                                                       proofs):
+    rnd = make_round(ref, 18)
+    tee = make_tee(key, own_engine)
+    a = own_engine.stats_snapshot()["classes"]["verify"]
+    for _ in range(3):
+        if proofs == "late":
+            assert all(tee.verify_round(rnd.blobs, rnd.owed, rnd.seed))
+        else:
+            assert in_hand(key, own_engine, rnd.blobs, rnd.owed, rnd.seed) \
+                == [True] * len(SIZES)
+    own_engine.flush()
+    b = own_engine.stats_snapshot()["classes"]["verify"]
+    assert b["batches"] - a["batches"] == 3
+    assert b["late_proofs"] - a["late_proofs"] == 3 * (proofs == "late")
+    stage = b["late"]["proofs"]
+    assert stage["n"] - a["late"]["proofs"]["n"] == 3
+    assert stage["n"] == sum(n for _, n, _ in stage["buckets"])
+    assert stage["s"] == pytest.approx(sum(s for _, _, s in stage["buckets"]))
+    assert b["device_calls"] - a["device_calls"] == 3 * 2   # a fold, a close
+    metrics = own_engine.stats_metrics()
+    assert metrics["cess_engine_verify_late_proofs"] == b["late_proofs"]
+    assert metrics["cess_engine_verify_stage_proofs_count"] == stage["n"]
+    assert metrics["cess_engine_verify_stage_proofs_seconds"] == stage["s"]
+    assert metrics["cess_engine_encode_stage_proofs_count"] == 0
+
+
 # -- spans -----------------------------------------------------------------
 def test_a_round_is_one_span_with_its_stages_inside(ref, key, engine):
     rnd = make_round(ref, 11, sizes=(2, 5))
@@ -399,9 +636,18 @@ def test_a_round_is_one_span_with_its_stages_inside(ref, key, engine):
         engine.flush()
     spans = {s["name"]: s for s in tracer.finished()}
     outer = spans["tee.round"]
-    for stage in ("decode", "ids", "challenge", "submit", "gather"):
+    order = ("ids", "challenge", "submit", "decode", "close", "gather")
+    for stage in order:
         assert spans[f"tee.round.{stage}"]["parent_id"] == outer["span_id"]
+    starts = [spans[f"tee.round.{stage}"]["ts_s"] for stage in order]
+    assert starts == sorted(starts)          # the decode after the submit
     assert "engine.verify" in spans and "engine.verify.dispatch" in spans
+    # the batch's wait for the proofs: from its folds' enqueue (which
+    # ended the TEE's submit stage) until the TEE's close put them
+    assert spans["engine.verify.proofs"]["parent_id"] \
+        == spans["engine.verify.dispatch"]["parent_id"]
+    assert spans["engine.verify.proofs"]["ts_s"] \
+        <= spans["tee.round.decode"]["ts_s"]
     # the round's derivation is the program's own stage inside the TEE's,
     # and the caller's side of the request lies where the caller was
     assert spans["podr2.challenge"]["parent_id"] \
